@@ -130,7 +130,7 @@ def _effective_waivers(waivers: FrozenSet[str]) -> FrozenSet[str]:
 # ------------------------------------------------------------ jaxpr walking --
 
 def _iter_bodies(jaxpr):
-    """Every jaxpr body reachable from `jaxpr` (pjit/scan/cond/shard_map/
+    """Every jaxpr body reachable from `jaxpr` (jit/scan/cond/shard_map/
     remat inners included), outermost first."""
     stack = [jaxpr]
     while stack:
@@ -223,11 +223,11 @@ def _audit_state_leaves(tree, where: str, direction: str) -> List[Finding]:
 
 
 def _innermost(jaxpr):
-    """Peel single-eqn pjit wrappers (a jitted fn traced by make_jaxpr is
-    one pjit eqn) down to the body whose outvars positionally match the
+    """Peel single-eqn jit wrappers (a jitted fn traced by make_jaxpr is
+    one jit eqn) down to the body whose outvars positionally match the
     flattened outputs."""
     while (len(jaxpr.eqns) == 1
-           and jaxpr.eqns[0].primitive.name == "pjit"
+           and jaxpr.eqns[0].primitive.name == "jit"
            and len(jaxpr.eqns[0].outvars) == len(jaxpr.outvars)):
         jaxpr = jaxpr.eqns[0].params["jaxpr"].jaxpr
     return jaxpr

@@ -38,6 +38,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal
 import numpy as np
 
 from . import Finding
@@ -45,7 +46,8 @@ from . import Finding
 # host-callback primitives: each one is a device→host→device round trip
 # inside the program — fatal to an async-dispatch hot path
 CALLBACK_PRIMITIVES = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
 })
 
 # jaxpr-level collective primitives (shard_map/pmap world). XLA-inserted
@@ -64,7 +66,7 @@ _SUBJAXPR_KEYS = ("jaxpr", "call_jaxpr", "cond_jaxpr", "body_jaxpr",
 
 
 def _sub_jaxprs(eqn) -> List[Any]:
-    """Every inner jaxpr of an eqn (pjit, scan, cond, shard_map, remat, …)."""
+    """Every inner jaxpr of an eqn (jit, scan, cond, shard_map, remat, …)."""
     subs: List[Any] = []
     for v in eqn.params.values():
         for x in (v if isinstance(v, (list, tuple)) else (v,)):
@@ -90,13 +92,13 @@ def collect_primitives(jaxpr) -> set:
 
 # primitives allowed to carry a uint8 input INTO a sub-jaxpr unchanged
 _PASSTHROUGH = frozenset({
-    "pjit", "closed_call", "core_call", "remat", "remat2", "checkpoint",
+    "jit", "closed_call", "core_call", "remat", "remat2", "checkpoint",
     "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
 })
 
 
 def _is_var(v) -> bool:
-    return not isinstance(v, jax.core.Literal)
+    return not isinstance(v, Literal)
 
 
 def _div_by_255(jaxpr, var) -> bool:
@@ -105,7 +107,7 @@ def _div_by_255(jaxpr, var) -> bool:
         if not any(u is var for u in eqn.invars if _is_var(u)):
             continue
         for other in eqn.invars:
-            if isinstance(other, jax.core.Literal):
+            if isinstance(other, Literal):
                 try:
                     val = float(np.asarray(other.val))
                 except (TypeError, ValueError):

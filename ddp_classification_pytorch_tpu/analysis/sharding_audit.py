@@ -100,21 +100,6 @@ _GROUPS_RE = re.compile(
 )
 
 
-def _payload_bytes(shape_str: str) -> int:
-    """Per-device payload of an HLO result shape (array or tuple literal);
-    unknown element types count 0 (conservative: never a false finding)."""
-    total = 0
-    for m in _SHAPE_RE.finditer(shape_str):
-        if m.group(1) not in _DTYPE_BYTES:
-            continue
-        n = 1
-        for d in m.group(2).split(","):
-            if d:
-                n *= int(d)
-        total += n * _DTYPE_BYTES[m.group(1)]
-    return total
-
-
 def parse_replica_groups(attr: str) -> Optional[frozenset]:
     """`replica_groups=...` → frozenset of frozensets of device ordinals.
 
@@ -189,11 +174,15 @@ def _spans_data(label: str) -> bool:
 # Counting the f32 shape would erase exactly the payload halving the bf16
 # reduction exists to buy (TPU ships the collective at bf16 natively), so
 # the inventory resolves each collective operand — through at most one
-# fusion — to such a round-trip and charges the op at the SOURCE dtype.
+# fusion — to such a round-trip and charges it at the SOURCE dtype.
+# HLO text prints operands with their types (`convert(f32[8]{0} %x)`) or
+# without (`convert(%x)`) depending on the XLA build, so dtypes are read
+# from each instruction's own RESULT type, which both forms carry.
+_DEF_RE = re.compile(r"%(?P<name>[\w.-]+)\s*=\s*(?P<dtype>[a-z0-9]+)\[")
 _CONVERT_RE = re.compile(
     r"%(?P<name>[\w.-]+)\s*=\s*(?P<dst>[a-z0-9]+)\[[\d,]*\]"
-    r"(?:\{[^}]*\})?\s*convert\((?P<src>[a-z0-9]+)\[[\d,]*\]"
-    r"(?:\{[^}]*\})?\s+%(?P<op>[\w.-]+)\)")
+    r"(?:\{[^}]*\})?\s*convert\((?:[a-z0-9]+\[[\d,]*\](?:\{[^}]*\})?\s+)?"
+    r"%(?P<op>[\w.-]+)\)")
 _FUSION_RE = re.compile(
     r"%(?P<name>[\w.-]+)\s*=\s*[a-z0-9]+\[[\d,]*\](?:\{[^}]*\})?\s*"
     r"fusion\(.*\bcalls=%(?P<comp>[\w.-]+)")
@@ -208,7 +197,8 @@ def _wire_dtypes(hlo_text: str) -> Dict[str, str]:
     fusions whose called computation contains such a pair. These are
     exactly the instructions CPU XLA materialises when promoting a
     sub-f32 collective to its f32-only reduction runtime."""
-    converts: Dict[str, Tuple[str, str, str]] = {}
+    dtype_of: Dict[str, str] = {}
+    converts: Dict[str, Tuple[str, str]] = {}
     comp_of: Dict[str, str] = {}
     fusions: Dict[str, str] = {}
     comp = ""
@@ -218,11 +208,13 @@ def _wire_dtypes(hlo_text: str) -> Dict[str, str]:
             if hm:
                 comp = hm.group("name")
             continue
+        dm = _DEF_RE.search(line)
+        if dm:
+            dtype_of[dm.group("name")] = dm.group("dtype")
         if " convert(" in line:
             cm = _CONVERT_RE.search(line)
             if cm:
-                converts[cm.group("name")] = (
-                    cm.group("dst"), cm.group("src"), cm.group("op"))
+                converts[cm.group("name")] = (cm.group("dst"), cm.group("op"))
                 comp_of[cm.group("name")] = comp
         elif " fusion(" in line and "calls=" in line:
             fm = _FUSION_RE.search(line)
@@ -230,12 +222,14 @@ def _wire_dtypes(hlo_text: str) -> Dict[str, str]:
                 fusions[fm.group("name")] = fm.group("comp")
     wire: Dict[str, str] = {}
     comp_wire: Dict[str, str] = {}
-    for name, (dst, src, op) in converts.items():
+    for name, (dst, op) in converts.items():
         inner = converts.get(op)
-        if (inner is None or src not in _DTYPE_BYTES
-                or dst not in _DTYPE_BYTES
+        if inner is None:
+            continue
+        src = inner[0]  # the narrow type: the inner convert's result
+        if (src not in _DTYPE_BYTES or dst not in _DTYPE_BYTES
                 or _DTYPE_BYTES[src] >= _DTYPE_BYTES[dst]
-                or inner[0] != src or inner[1] != dst):
+                or dtype_of.get(inner[1]) != dst):
             continue
         wire[name] = src
         c = comp_of.get(name, "")
@@ -248,61 +242,57 @@ def _wire_dtypes(hlo_text: str) -> Dict[str, str]:
     return wire
 
 
-def _wire_scale(operand_text: str, wire: Dict[str, str],
-                result_dtype: str) -> float:
-    """Payload scale for one collective op: when EVERY operand resolves
-    to a round-trip through one narrower dtype, the wire dtype of the
-    program is that SOURCE type and the payload scales by src/result
-    itemsize. 1.0 whenever the pattern doesn't match — unscaled is the
-    conservative (larger) count. `operand_text` starts at the
-    collective's opening paren."""
-    om = _OPERAND_RE.search(operand_text)
-    if not om or result_dtype not in _DTYPE_BYTES:
-        return 1.0
-    names = re.findall(r"%([\w.-]+)", om.group(1))
-    if not names:
-        return 1.0
-    dtypes = {wire.get(n) for n in names}
-    if len(dtypes) != 1:
-        return 1.0
-    (w,) = dtypes
-    if (w is None or w not in _DTYPE_BYTES
-            or _DTYPE_BYTES[w] >= _DTYPE_BYTES[result_dtype]):
-        return 1.0
-    return _DTYPE_BYTES[w] / _DTYPE_BYTES[result_dtype]
+def _wire_elements(line: str, m, wire: Dict[str, str]
+                   ) -> List[Tuple[str, int]]:
+    """One collective op (`m` = its `_OP_RE` match in `line`) → a
+    `(wire dtype, payload bytes)` pair per array it carries. A combined
+    collective has a tuple result whose i-th array is the reduction of
+    its i-th operand; an operand that resolves through `_wire_dtypes` to
+    a round-trip via a NARROWER dtype is charged at that source dtype.
+    Whenever the pattern doesn't match — or the result arrays cannot be
+    paired with the operands (async `-start` tuples) — the array keeps
+    its own dtype: unscaled is the conservative (larger) count."""
+    shapes = _SHAPE_RE.findall(m.group("shape"))
+    om = _OPERAND_RE.search(line[m.end() - 1:])
+    names = re.findall(r"%([\w.-]+)", om.group(1)) if om else []
+    if len(names) != len(shapes):
+        names = [""] * len(shapes)
+    out: List[Tuple[str, int]] = []
+    for (dtype, dims), name in zip(shapes, names):
+        n = 1
+        for d in dims.split(","):
+            if d:
+                n *= int(d)
+        w = wire.get(name)
+        if (w in _DTYPE_BYTES and dtype in _DTYPE_BYTES
+                and _DTYPE_BYTES[w] < _DTYPE_BYTES[dtype]):
+            dtype = w
+        out.append((dtype, n * _DTYPE_BYTES.get(dtype, 0)))
+    return out
 
 
 _SUB_F32_WIRE = frozenset({"bf16", "f16", "f8e4m3fn", "f8e5m2"})
 
 
 def collective_wire_dtypes(hlo_text: str) -> Dict[str, Dict[str, int]]:
-    """Per collective kind, op counts by WIRE dtype: `{kind: {dtype: n}}`.
-    The wire dtype is the op's own element type, except when every operand
-    resolves through `_wire_dtypes`' promotion round-trip — then it is the
-    SOURCE type the program requested (CPU XLA's f32-only reduction
-    runtime materialises bf16 collectives as convert pairs; TPU runs them
-    natively). This is the `dtype-wire` contract's HLO-tier input — the
-    same accounting `_wire_scale` uses for payload bytes, promoted from
-    byte-scaling evidence to a per-cell dtype table."""
+    """Per collective kind, counts of the arrays it carries by WIRE dtype:
+    `{kind: {dtype: n}}` (a combined collective counts once per array of
+    its tuple). The wire dtype is the array's own element type, except
+    when its operand resolves through `_wire_dtypes`' promotion
+    round-trip — then it is the SOURCE type the program requested (CPU
+    XLA's f32-only reduction runtime materialises bf16 collectives as
+    convert pairs; TPU runs them natively). This is the `dtype-wire`
+    contract's HLO-tier input — the same `_wire_elements` accounting the
+    inventory's payload bytes use, as a per-cell dtype table."""
     wire = _wire_dtypes(hlo_text)
     out: Dict[str, Dict[str, int]] = {}
     for line in hlo_text.splitlines():
         m = _OP_RE.search(line)
         if not m:
             continue
-        sm = _SHAPE_RE.search(m.group("shape"))
-        dtype = sm.group(1) if sm else "?"
-        om = _OPERAND_RE.search(line[m.end() - 1:])
-        names = re.findall(r"%([\w.-]+)", om.group(1)) if om else []
-        resolved = {wire.get(n) for n in names}
-        if names and len(resolved) == 1:
-            (w,) = resolved
-            if (w is not None and w in _DTYPE_BYTES
-                    and dtype in _DTYPE_BYTES
-                    and _DTYPE_BYTES[w] < _DTYPE_BYTES[dtype]):
-                dtype = w
         rec = out.setdefault(m.group("kind"), {})
-        rec[dtype] = rec.get(dtype, 0) + 1
+        for dtype, _ in _wire_elements(line, m, wire):
+            rec[dtype] = rec.get(dtype, 0) + 1
     return out
 
 
@@ -332,8 +322,8 @@ def collective_inventory(hlo_text: str, mesh=None) -> Dict[str, Any]:
     """Aggregate the compiled program's collectives per kind:
     `{kinds: {kind: {count, bytes, max_op_bytes, axes: {axis: bytes}}},
     total_bytes}`. Bytes are per-device payload per step, summed over ops
-    (CPU XLA does not combine the per-gradient all-reduces, so counts are
-    high and per-op payloads small — the BYTES are the invariant).
+    (how far the compiler combines the per-gradient all-reduces into
+    tuple ops varies with the XLA build — the BYTES are the invariant).
     Axis attribution needs `mesh`; unattributable groups land on
     'unknown' (never silently dropped).
 
@@ -342,8 +332,8 @@ def collective_inventory(hlo_text: str, mesh=None) -> Dict[str, Any]:
     collective as convert(bf16→f32) → collective(f32) → convert back —
     counting the f32 shape would erase exactly the payload halving a
     bf16 gradient reduction exists to buy (TPU runs the collective at
-    bf16 natively). `_wire_scale` detects that promotion pattern and
-    scales the op back to its source dtype."""
+    bf16 natively). `_wire_elements` detects that promotion pattern per
+    array of the op and charges it at its source dtype."""
     axis_parts = _axis_groupings(mesh) if mesh is not None else {}
     wire = _wire_dtypes(hlo_text)
     kinds: Dict[str, Dict[str, Any]] = {}
@@ -353,10 +343,7 @@ def collective_inventory(hlo_text: str, mesh=None) -> Dict[str, Any]:
         if not m:
             continue
         kind = m.group("kind")
-        sm = _SHAPE_RE.search(m.group("shape"))
-        payload = int(round(_payload_bytes(m.group("shape"))
-                            * _wire_scale(line[m.end() - 1:], wire,
-                                          sm.group(1) if sm else "")))
+        payload = sum(b for _, b in _wire_elements(line, m, wire))
         groups = parse_replica_groups(line)
         axis = "unknown"
         if groups is not None:

@@ -13,8 +13,10 @@ format.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import tempfile
 import threading
 from typing import Optional, Tuple
 
@@ -25,70 +27,90 @@ from .transforms import IMAGENET_MEAN, IMAGENET_STD
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "dataplane.cpp")
 _LIB_DIR = os.path.join(_REPO_ROOT, "native", "build")
-_LIB = os.path.join(_LIB_DIR, "libdataplane.so")
+_CXX = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC"]
+# libpng is optional: on hosts without it, fall back to a JPEG-only build
+# (-DDP_NO_PNG) rather than losing the whole native path — PNGs then take
+# the per-slot PIL retry, JPEGs stay native.
+_LINK_VARIANTS = (["-ljpeg", "-lpng", "-lpthread"],
+                  ["-DDP_NO_PNG", "-ljpeg", "-lpthread"])
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
+# why the native path is off (the compiler's / loader's own message);
+# "" while it is on or untried. The trainer banner and chip_smoke.py print it.
+build_error = ""
 
 
-def _build(target: str = _LIB) -> bool:
-    os.makedirs(_LIB_DIR, exist_ok=True)
-    base = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", target, _SRC]
-    # libpng is optional: on hosts without it, fall back to a JPEG-only
-    # build (-DDP_NO_PNG) rather than silently losing the whole native
-    # path — PNGs then take the per-slot PIL retry, JPEGs stay native.
-    for extra in (["-ljpeg", "-lpng", "-lpthread"],
-                  ["-DDP_NO_PNG", "-ljpeg", "-lpthread"]):
-        try:
-            subprocess.run(base + extra, check=True, capture_output=True,
-                           timeout=120)
-            return True
-        except Exception:
-            continue
-    return False
+def _lib_path(extra) -> str:
+    """The built library is keyed by a hash of the source AND the flags: a
+    binary left over from another source (a copied tree keeps no mtimes) is
+    simply never looked at."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CXX + list(extra)).encode())
+    return os.path.join(_LIB_DIR, f"libdataplane.{h.hexdigest()[:16]}.so")
+
+
+def _build(target: str, extra) -> str:
+    """Compile to `target` (atomically, through a temp file of this
+    process's own, so concurrent builders only ever race on the final
+    rename of identical bytes); returns "" or the failure text."""
+    tmp = ""
+    try:
+        os.makedirs(_LIB_DIR, exist_ok=True)
+        fd, tmp = tempfile.mkstemp(dir=_LIB_DIR, suffix=".so.tmp")
+        os.close(fd)
+        subprocess.run(_CXX + ["-o", tmp, _SRC] + list(extra), check=True,
+                       capture_output=True, timeout=120)
+        os.replace(tmp, target)
+        return ""
+    except subprocess.CalledProcessError as e:
+        return (e.stderr or b"").decode(errors="replace").strip()[-2000:]
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"{type(e).__name__}: {e}"
+    finally:
+        if tmp and os.path.exists(tmp):
+            os.unlink(tmp)
+
+
+def _load(path: str) -> ctypes.CDLL:
+    lib = ctypes.CDLL(path)
+    lib.dp_has_png.restype = ctypes.c_int
+    lib.dp_has_png.argtypes = []
+    lib.dp_load_batch.restype = ctypes.c_int
+    lib.dp_load_batch.argtypes = [
+        ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
+        ctypes.POINTER(ctypes.c_float), ctypes.c_int, ctypes.c_int,
+        ctypes.c_int, ctypes.c_int, ctypes.c_double, ctypes.c_double,
+        ctypes.c_uint64, ctypes.POINTER(ctypes.c_float),
+        ctypes.POINTER(ctypes.c_float), ctypes.c_int,
+    ]
+    return lib
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    """Load (building on first use) the native dataplane, or None."""
-    global _lib, _load_failed
+    """Load (building on first use) the native dataplane, or None — then
+    `build_error` says why."""
+    global _lib, _load_failed, build_error
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_LIB) or (
-            os.path.exists(_SRC) and os.path.getmtime(_SRC) > os.path.getmtime(_LIB)
-        ):
-            if not _build():
-                _load_failed = True
-                return None
-        path = _LIB
-        for attempt in (0, 1):
-            try:
-                lib = ctypes.CDLL(path)
-                lib.dp_has_png.restype = ctypes.c_int
-                lib.dp_has_png.argtypes = []
-                lib.dp_load_batch.restype = ctypes.c_int
-                lib.dp_load_batch.argtypes = [
-                    ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
-                    ctypes.POINTER(ctypes.c_float), ctypes.c_int, ctypes.c_int,
-                    ctypes.c_int, ctypes.c_int, ctypes.c_double, ctypes.c_double,
-                    ctypes.c_uint64, ctypes.POINTER(ctypes.c_float),
-                    ctypes.POINTER(ctypes.c_float), ctypes.c_int,
-                ]
-                _lib = lib
-                return _lib
-            except (OSError, AttributeError):
-                # AttributeError = a stale binary predating a symbol (the
-                # mtime guard can miss, e.g. copied trees). Rebuild to a
-                # FRESH path: dlopen caches by name and ctypes never
-                # dlcloses, so rebuilding in place would hand back the same
-                # stale handle. One retry, then the documented Python
-                # fallback.
-                path = os.path.join(_LIB_DIR, f"libdataplane.r{os.getpid()}.so")
-                if attempt == 0 and _build(path):
-                    continue
-                _load_failed = True
-                return None
+        errors = []
+        for extra in _LINK_VARIANTS:
+            path = _lib_path(extra)
+            err = "" if os.path.exists(path) else _build(path, extra)
+            if not err:
+                try:
+                    _lib = _load(path)
+                    return _lib
+                except (OSError, AttributeError) as e:
+                    err = f"{type(e).__name__}: {e}"
+            errors.append(f"[{' '.join(extra)}] {err}")
+        build_error = " ; ".join(errors)
+        _load_failed = True
+        return None
 
 
 _MEAN = (ctypes.c_float * 3)(*IMAGENET_MEAN)

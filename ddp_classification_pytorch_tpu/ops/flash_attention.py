@@ -33,6 +33,7 @@ the standard flash-attention memory shape, expressed the Pallas/Mosaic way
 from __future__ import annotations
 
 import functools
+import warnings
 from typing import Optional
 
 import jax
@@ -393,6 +394,12 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if not _supported(q.shape[1]):
         from .attention import attention
 
+        # a shape rule, not an error — but never a silent one: the caller
+        # asked for the kernel and is getting the (T, T)-materializing op
+        warnings.warn(
+            f"flash_attention: T={q.shape[1]} is not kernel-tileable "
+            "(need T <= 512 or a multiple of 128); falling through to the "
+            "dense op ops.attention.attention", stacklevel=2)
         return attention(q, k, v, causal=causal, scale=scale)
     return _flash(q, k, v, scale, causal)
 

@@ -93,8 +93,7 @@ def make_shard_map_train_step(
         return new_state, metrics
 
     # replication checking can't prove the in-shard optimizer update is
-    # replicated (it is, by construction: pmean'd grads); shard_map_unchecked
-    # disables it under either API spelling (check_rep pre-0.8, check_vma 0.8+)
+    # replicated (it is, by construction: pmean'd grads), so it is off
     sharded = shard_map_unchecked(
         per_shard, mesh=mesh, in_specs=(P(), P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=(P(), P()))

@@ -1,7 +1,7 @@
 """Pod-level fault tolerance: the cross-host coordination layer.
 
 Every robustness mechanism below this module is per-host — the sentinel
-and rc taxonomy (train/sentinel.py, cli/train.py), checksum-verified
+and rc classes (train/sentinel.py, cli/train.py), checksum-verified
 resume with quarantine (train/checkpoint.py), supervise.sh restart
 classification, and the StepHeartbeat. On a multi-host pod those pieces
 actively fight each other (the reference can only hang — a crashed

@@ -78,18 +78,15 @@ def make_mesh(spec: MeshSpec = MeshSpec(), devices: Optional[Sequence[Any]] = No
     dp, mp, pp = spec.resolve(len(devices))
     shape = (dp, mp, pp) if pp > 1 else (dp, mp)
     axes = (DATA_AXIS, MODEL_AXIS, PIPE_AXIS) if pp > 1 else (DATA_AXIS, MODEL_AXIS)
-    if mp > 1 or pp > 1:
+    if (mp > 1 or pp > 1) and devices[0].platform == "tpu":
         # ICI-aware layout: contiguous (ring-neighbor) device groups on the
         # model/pipe axes, so ppermute rings (ring attention, GPipe handoffs)
         # and TP collectives ride ICI neighbor links instead of striding the
-        # torus. Falls back to the trivial reshape off-TPU.
-        try:
-            from jax.experimental import mesh_utils
+        # torus. A failure here raises: on the chip a naive reshape would
+        # silently put the rings on the wrong links.
+        from jax.experimental import mesh_utils
 
-            arr = mesh_utils.create_device_mesh(shape, devices=devices)
-            return Mesh(arr, axes)
-        except Exception:
-            pass
+        return Mesh(mesh_utils.create_device_mesh(shape, devices=devices), axes)
     arr = np.asarray(devices).reshape(shape)
     return Mesh(arr, axes)
 

@@ -196,9 +196,13 @@ def load_bucket_executables(
             return None
         try:
             payload, in_tree, out_tree = pickle.loads(blob)
-            out[bucket] = deserialize_and_load(payload, in_tree, out_tree)
-        except Exception:  # noqa: BLE001 — a poisoned payload = miss
-            quarantine_file(path, "aot payload undeserializable",
+            # the executable runs on the serve mesh's devices, which may be
+            # a prefix of the backend's (serve_devices < visible devices)
+            out[bucket] = deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=list(mesh.devices.flat))
+        except Exception as e:  # noqa: BLE001 — a poisoned payload = miss
+            quarantine_file(path, f"aot payload undeserializable ({e!r})",
                             kind="aot payload")
             return None
     return out

@@ -1,7 +1,7 @@
 """Deterministic fault injection for the recovery chain.
 
-The supervisor stack (scripts/supervise.sh rc classification, the init
-watchdog + StepHeartbeat in `utils/backend_probe.py`, atomic checkpoint
+The supervisor stack (scripts/supervise.sh rc classification, the
+StepHeartbeat in `train/heartbeat.py`, atomic checkpoint
 writes and checksum-verified resume in `train/checkpoint.py`, and the
 non-finite step sentinel in `train/sentinel.py`) exists to survive
 failures that are, by nature, rare and hard to stage. This module makes

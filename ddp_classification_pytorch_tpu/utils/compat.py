@@ -1,18 +1,11 @@
-"""JAX version-compat shims shared across modules."""
+"""shard_map with replication checking off — the one spelling every
+hand-written collective in the repo goes through."""
 
 from __future__ import annotations
 
-try:  # jax>=0.8 top-level API; fall back for older jax
-    from jax import shard_map as _shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
+import jax
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, under either API spelling
-    (check_vma on jax>=0.8, check_rep before)."""
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return _shard_map(f, check_vma=False, **kwargs)
-    except TypeError:  # pragma: no cover — pre-0.8 spelling
-        return _shard_map(f, check_rep=False, **kwargs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

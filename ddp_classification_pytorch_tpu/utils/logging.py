@@ -81,6 +81,17 @@ class ProgressBar:
         sys.stdout.flush()
 
 
+def device_memory_line() -> str:
+    """Per-device peak HBM as the backend reports it (`n/a` where it does
+    not, e.g. CPU) — one figure per local device, so a state that landed
+    whole on the first chip shows."""
+    import jax
+
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", "n/a")
+             for d in jax.local_devices()]
+    return "peak_bytes_in_use per device: " + " ".join(map(str, peaks))
+
+
 class EtaLogger:
     """Per-N-step console line with batch time and ETA in minutes
     (BASELINE/main.py:295-303)."""

@@ -134,7 +134,7 @@ def test_callback_detector_fires_on_debug_print(audit):
                          no_donate_reason="fixture")
     findings = audit_entry(spec, audit.ctx)
     assert any(f.check == "callback" for f in findings)
-    assert any("debug_callback" in str(f.evidence) for f in findings)
+    assert any("debug_print" in str(f.evidence) for f in findings)
 
 
 def test_collective_detector_fires_and_allowlist_clears(audit):

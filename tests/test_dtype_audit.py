@@ -88,7 +88,7 @@ def dtype_audit():
 def test_d1_fires_on_f64_aval():
     """A NumPy f64 scalar leaking into a jit under x64 must be caught at
     the aval level, not discovered as a TPU-vs-CPU parity break."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         findings, _ = audit_program(lambda x: x * 2.0,
                                     (np.zeros((4,), np.float64),))
     assert any(f.check == "dtype-f64" for f in findings)
@@ -314,6 +314,28 @@ ENTRY %main (p0: f32[1024]) -> f32[1024] {
 }
 """
 
+# as the XLA of jax 0.9.0 prints it: operands without their types
+_PROMOTED_HLO_UNTYPED = """\
+ENTRY %main (p0: f32[1024]) -> f32[1024] {
+  %p0 = f32[1024]{0} parameter(0)
+  %narrow = bf16[1024]{0} convert(%p0), metadata={op_name="jit(f)/psum"}
+  %widen = f32[1024]{0} convert(%narrow)
+  %ar = f32[1024]{0} all-reduce(%widen), replica_groups={}
+  ROOT %r = f32[1024]{0} add(%ar, %p0)
+}
+"""
+
+# a widening convert of a value that was ALWAYS bf16 is not a promotion
+# round-trip: the program asked for an f32 reduction of it
+_WIDENED_ONLY_HLO = """\
+ENTRY %main (p0: bf16[1024]) -> f32[1024] {
+  %p0 = bf16[1024]{0} parameter(0)
+  %same = bf16[1024]{0} convert(%p0)
+  %widen = f32[1024]{0} convert(%same)
+  ROOT %ar = f32[1024]{0} all-reduce(%widen), replica_groups={}
+}
+"""
+
 _PLAIN_HLO = """\
 ENTRY %main (p0: f32[1024]) -> f32[1024] {
   %p0 = f32[1024] parameter(0)
@@ -322,13 +344,18 @@ ENTRY %main (p0: f32[1024]) -> f32[1024] {
 """
 
 
-def test_wire_dtype_resolves_promotion_roundtrip():
+@pytest.mark.parametrize("hlo,expected", [
+    pytest.param(_PROMOTED_HLO, "bf16", id="typed-operands"),
+    pytest.param(_PROMOTED_HLO_UNTYPED, "bf16", id="untyped-operands"),
+    pytest.param(_WIDENED_ONLY_HLO, "f32", id="widened-only"),
+    pytest.param(_PLAIN_HLO, "f32", id="plain"),
+])
+def test_wire_dtype_resolves_promotion_roundtrip(hlo, expected):
     """CPU XLA's f32-only reduction runtime materialises a requested bf16
     collective as convert(bf16)→all-reduce(f32)→convert-back; the table
-    must charge the op at the SOURCE dtype the program asked for."""
-    assert collective_wire_dtypes(_PROMOTED_HLO) == {
-        "all-reduce": {"bf16": 1}}
-    assert collective_wire_dtypes(_PLAIN_HLO) == {"all-reduce": {"f32": 1}}
+    must charge the op at the SOURCE dtype the program asked for —
+    whether or not the HLO text prints operand types."""
+    assert collective_wire_dtypes(hlo) == {"all-reduce": {expected: 1}}
 
 
 def test_wire_dtype_contract_fires_and_admits_declared():

@@ -149,11 +149,15 @@ def test_banked_accum_cells_amortize_the_wire():
             <= 1.05 * ar_anchor)
 
     # bf16 wire on the SUMMED grads: ≤0.55× the f32 anchor — the ÷2K
-    # compound — and it matches the K=1 bf16 cell (same wire, same bytes)
+    # compound — and it matches the K=1 bf16 cell (same wire, same bytes
+    # to 0.1%: in the K=1 program this XLA shares one all-reduce between
+    # each downsample BN's bias gradient and its twin on the main branch,
+    # 1792 B in all, which the scanned sum does not expose)
     ar_bf = acc_bf["collectives"]["all-reduce"]["bytes"]
-    assert ar_bf <= 0.55 * ar_anchor
-    assert ar_bf == programs["train_step_bf16@dp2"][
+    ar_bf_k1 = programs["train_step_bf16@dp2"][
         "collectives"]["all-reduce"]["bytes"]
+    assert ar_bf <= 0.55 * ar_anchor
+    assert abs(ar_bf - ar_bf_k1) <= 0.001 * ar_bf_k1
     assert "bf16" in acc_bf["wire_dtypes"]["all-reduce"]
 
     for key in ("train_step_accum4@dp2", "train_step_accum4@dp2tp2",

@@ -241,12 +241,16 @@ def test_dimension_bomb_header_reported_not_crashed(tmp_path, pngs, png_support)
     assert np.abs(out[1]).sum() > 0.0
 
 
-def test_stale_binary_without_new_symbol_recovers(tmp_path, monkeypatch):
-    """A stale libdataplane.so predating dp_has_png (mtime newer than the
-    source, so the rebuild guard misses) must not kill the native path:
-    get_lib rebuilds to a FRESH filename and loads that — rebuilding in
-    place cannot work because dlopen caches by name and ctypes never
-    dlcloses."""
+def _fresh_native(monkeypatch, native_mod, **attrs):
+    for k, v in dict(_lib=None, _load_failed=False, build_error="",
+                     **attrs).items():
+        monkeypatch.setattr(native_mod, k, v)
+
+
+def test_library_is_keyed_by_source_hash_not_mtime(tmp_path, monkeypatch):
+    """A binary left over from another source — newer mtime, even the old
+    fixed name — is never loaded: the path is a hash of dataplane.cpp and
+    the flags, so get_lib builds its own file next to the stale one."""
     import subprocess
 
     from ddp_classification_pytorch_tpu.data import native as native_mod
@@ -254,21 +258,40 @@ def test_stale_binary_without_new_symbol_recovers(tmp_path, monkeypatch):
     stale_src = tmp_path / "stale.cpp"
     stale_src.write_text(
         'extern "C" int dp_load_batch() { return -1; }\n')  # no dp_has_png
-    stale_lib = str(tmp_path / "libdataplane.so")
-    subprocess.run(["g++", "-shared", "-fPIC", "-o", stale_lib,
+    stale_lib = tmp_path / "libdataplane.so"
+    subprocess.run(["g++", "-shared", "-fPIC", "-o", str(stale_lib),
                     str(stale_src)], check=True)
     future = time.time() + 3600
-    os.utime(stale_lib, (future, future))  # defeat the mtime rebuild guard
+    os.utime(stale_lib, (future, future))
 
-    monkeypatch.setattr(native_mod, "_LIB", stale_lib)
-    monkeypatch.setattr(native_mod, "_LIB_DIR", str(tmp_path))
-    monkeypatch.setattr(native_mod, "_lib", None)
-    monkeypatch.setattr(native_mod, "_load_failed", False)
-    try:
-        lib = native_mod.get_lib()
-        assert lib is not None, "stale binary must trigger a fresh-path rebuild"
-        assert lib.dp_has_png() in (0, 1)
-    finally:
-        # never leak the stale/temp libs into the module for later tests
-        monkeypatch.setattr(native_mod, "_lib", None)
-        monkeypatch.setattr(native_mod, "_load_failed", False)
+    _fresh_native(monkeypatch, native_mod, _LIB_DIR=str(tmp_path))
+    lib = native_mod.get_lib()
+    assert lib is not None, native_mod.build_error
+    assert lib.dp_has_png() in (0, 1)
+    built = [p.name for p in tmp_path.glob("libdataplane.*.so")]
+    assert len(built) == 1 and built[0] in {
+        os.path.basename(native_mod._lib_path(v))
+        for v in native_mod._LINK_VARIANTS}
+    # the key moves with the source and with the flags
+    other = tmp_path / "other.cpp"
+    other.write_text("// different source\n")
+    monkeypatch.setattr(native_mod, "_SRC", str(other))
+    assert os.path.basename(native_mod._lib_path(
+        native_mod._LINK_VARIANTS[0])) not in built
+    assert (native_mod._lib_path(native_mod._LINK_VARIANTS[0])
+            != native_mod._lib_path(native_mod._LINK_VARIANTS[1]))
+
+
+def test_failed_build_surfaces_the_compiler_message(tmp_path, monkeypatch):
+    """The PIL fallback stays, but never silently: a build that fails
+    leaves the compiler's own words in `build_error`."""
+    from ddp_classification_pytorch_tpu.data import native as native_mod
+
+    broken = tmp_path / "broken.cpp"
+    broken.write_text("this is not C++ at all;\n")
+    _fresh_native(monkeypatch, native_mod, _SRC=str(broken),
+                  _LIB_DIR=str(tmp_path))
+    assert native_mod.get_lib() is None
+    assert "error" in native_mod.build_error
+    assert "broken.cpp" in native_mod.build_error
+    assert not list(tmp_path.glob("*.so")), "a failed build left a library"

@@ -2,23 +2,18 @@
 
 Round-3 advisor (medium): rc=1 used to mean BOTH a deterministic config
 error and any unhandled runtime exception, so `supervise.sh` stopped the
-whole chain on transient crashes (a tunneled XlaRuntimeError, in-process
+whole chain on transient crashes (an XlaRuntimeError, in-process
 OOM, dataloader IO) that `--auto_resume` exists to absorb. The contract
 now is:
 
 - rc 2 — deterministic config/usage error (argparse uses 2; the trainer
   maps its own config validation to SystemExit(2) BEFORE any backend
-  probe). supervise.sh stops immediately: restarting replays the bug.
+  use). supervise.sh stops immediately: restarting replays the bug.
 - bare rc 1 — unhandled runtime exception. Retryable with
   ``RUNTIME_BACKOFF_S`` backoff (default 30 s).
 - rc 3 — backend unreachable, long ``OUTAGE_BACKOFF_S`` backoff.
 
-`window_catcher.sh` (advisor low): a failing PROBE is only retried when
-the failure is outage-shaped (timeout / "backend unreachable"); a broken
-venv (ImportError, rc 126/127) stops the catcher loudly instead of
-polling every 10 minutes forever.
-
-The supervise/catcher tests drive the real scripts with a stub `python`
+The supervise tests drive the real script with a stub `python`
 on PATH whose per-call exit codes come from ``FAKE_RCS`` — no backend,
 no sleeps (backoffs are env-zeroed), so the suite stays fast.
 """
@@ -213,61 +208,3 @@ def test_fleet_coordinator_without_port_exits_2(capsys, tmp_path, monkeypatch):
     assert rc == 2, err[-500:]
     assert "config error" in err
     assert "host:port" in err
-
-
-def test_catcher_stops_loudly_on_broken_probe(tmp_path):
-    """rc 127 (missing interpreter) / ImportError is a broken harness, not an
-    outage — the catcher must stop with that rc, not poll forever."""
-    env = _stub_env(tmp_path, "127",
-                    stdout="bash: python3: command not found")
-    env["CATCHER_OUT"] = str(tmp_path / "out")
-    env["DOWN_POLL_S"] = "0"
-    p = subprocess.run(
-        ["bash", os.path.join(REPO, "scripts", "window_catcher.sh")],
-        env=env, capture_output=True, text=True, timeout=30)
-    assert p.returncode == 127, (p.returncode, p.stderr)
-    log = (tmp_path / "out" / "catcher.log").read_text()
-    # "command not found" hits the broken-harness signature grep; a bare
-    # unexplained rc would hit the "not outage-shaped" fallback — both stop
-    assert "broken-harness signature" in log or "not outage-shaped" in log
-    assert _calls(tmp_path) == 1
-
-
-def test_catcher_stops_when_unreachable_wraps_import_error(tmp_path):
-    """require_backend wraps the probe subprocess's stderr into its 'backend
-    unreachable' message, so a venv whose `import jax` dies reads as BOTH
-    outage and broken harness — the broken-harness signature must win."""
-    env = _stub_env(
-        tmp_path, "1",
-        stdout=("RuntimeError: JAX backend unreachable after 1 probes "
-                "(CalledProcessError: ModuleNotFoundError: "
-                "No module named 'jax')"))
-    env["CATCHER_OUT"] = str(tmp_path / "out")
-    env["DOWN_POLL_S"] = "0"
-    p = subprocess.run(
-        ["bash", os.path.join(REPO, "scripts", "window_catcher.sh")],
-        env=env, capture_output=True, text=True, timeout=30)
-    assert p.returncode == 1, (p.returncode, p.stderr)
-    log = (tmp_path / "out" / "catcher.log").read_text()
-    assert "broken-harness signature" in log
-    assert _calls(tmp_path) == 1
-
-
-def test_catcher_retries_outage_shaped_probe(tmp_path):
-    """A probe that times out / reports "backend unreachable" keeps polling —
-    bounded here by killing the catcher after a few cycles."""
-    env = _stub_env(
-        tmp_path, "1",  # stub repeats its last rc forever
-        stdout="RuntimeError: JAX backend unreachable after 1 probes")
-    env["CATCHER_OUT"] = str(tmp_path / "out")
-    env["DOWN_POLL_S"] = "0"
-    try:
-        subprocess.run(
-            ["bash", os.path.join(REPO, "scripts", "window_catcher.sh")],
-            env=env, capture_output=True, text=True, timeout=3)
-        raise AssertionError("catcher stopped on an outage-shaped probe")
-    except subprocess.TimeoutExpired:
-        pass  # still polling — the desired behavior
-    log = (tmp_path / "out" / "catcher.log").read_text()
-    assert "down at" in log
-    assert _calls(tmp_path) >= 2

@@ -255,32 +255,69 @@ ENTRY %main.1 (p0: f32[8,8]) -> f32[8,8] {
 """
 
 
-def test_wire_dtype_accounting_unwraps_promoted_collectives():
+# the same program as the XLA of jax 0.9.0 prints it: operands carry no
+# types, and the all-reduce combiner has merged the two reductions into one
+# tuple op whose arrays pair with its operands in order
+_PROMOTED_BF16_HLO_COMBINED = """\
+HloModule promoted, entry_computation_layout={(f32[8,8]{1,0})->(f32[8,8]{1,0}, f32[8,8]{1,0})}
+
+%region_0.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %add = f32[] add(%a, %b)
+}
+
+%fused_computation.1 (param_0.1: f32[8,8]) -> f32[8,8] {
+  %param_0.1 = f32[8,8]{1,0} parameter(0)
+  %convert.2 = bf16[8,8]{1,0} convert(%param_0.1), metadata={op_name="jit(step)/shard_map/psum"}
+  %convert.3 = f32[8,8]{1,0} convert(%convert.2)
+  ROOT %copy.1 = f32[8,8]{1,0} copy(%convert.3)
+}
+
+ENTRY %main.1 (p0: f32[8,8]) -> (f32[8,8], f32[8,8]) {
+  %p0 = f32[8,8]{1,0} parameter(0)
+  %convert_copy_fusion = f32[8,8]{1,0} fusion(%p0), kind=kLoop, calls=%fused_computation.1
+  ROOT %all-reduce.1 = (f32[8,8]{1,0}, f32[8,8]{1,0}) all-reduce(%convert_copy_fusion, %p0), channel_id=1, replica_groups={{0,1}}, use_global_device_ids=true, to_apply=%region_0.1
+}
+"""
+
+
+@pytest.mark.parametrize("hlo,n_ops", [
+    pytest.param(_PROMOTED_BF16_HLO, 2, id="typed-operands"),
+    pytest.param(_PROMOTED_BF16_HLO_COMBINED, 1, id="untyped-combined"),
+])
+def test_wire_dtype_accounting_unwraps_promoted_collectives(hlo, n_ops):
     """CPU XLA promotes every sub-f32 collective to its f32-only reduction
     runtime, leaving a convert(f32→bf16)→convert(bf16→f32) round-trip —
     usually folded into the kLoop fusion feeding the op. The inventory
-    must charge that op at the bf16 WIRE dtype (what a TPU ships) while
-    an un-converted operand keeps full f32 accounting."""
+    must charge that array at the bf16 WIRE dtype (what a TPU ships)
+    while an un-converted operand keeps full f32 accounting — in either
+    HLO text format, and array by array inside a combined op."""
     from ddp_classification_pytorch_tpu.analysis.sharding_audit import (
-        collective_inventory)
+        collective_inventory, collective_wire_dtypes)
 
-    inv = collective_inventory(_PROMOTED_BF16_HLO)
+    inv = collective_inventory(hlo)
     ar = inv["kinds"]["all-reduce"]
     full = 8 * 8 * 4
-    assert ar["count"] == 2
-    # op 1 rides the round-trip fusion → bf16 wire (half); op 2 is a
-    # genuine f32 collective → full
+    assert ar["count"] == n_ops
+    # array 1 rides the round-trip fusion → bf16 wire (half); array 2 is
+    # a genuine f32 reduction → full
     assert ar["bytes"] == full // 2 + full
-    assert ar["max_op_bytes"] == full
+    assert ar["max_op_bytes"] == (full if n_ops == 2 else full // 2 + full)
     assert inv["total_bytes"] == full // 2 + full
+    assert collective_wire_dtypes(hlo) == {
+        "all-reduce": {"bf16": 1, "f32": 1}}
 
 
 def test_committed_baseline_exhibits_zero_deltas():
     """The acceptance criterion made durable: the COMMITTED baseline must
-    keep showing (a) the ZeRO cell's peak-HBM cut vs the replicated
-    anchor (measured −22%; fenced at ≤0.9×), (b) its weight all-gathers
-    on the data axis, (c) the bf16 cell's reduction payload at ~half the
-    anchor's (fenced at ≤0.55×), and (d) full donation everywhere —
+    keep showing (a) the ZeRO cell holding half the momentum per device
+    (argument bytes) and a peak-HBM cut vs the replicated anchor (−6.6%
+    as XLA:CPU of jax 0.9.0 assigns buffers, where 0.4.37's read −22%:
+    the state saving is the same 22.3 MB, the ZeRO program's temporaries
+    grew; fenced at ≤0.95×), (b) its weight all-gathers on the data
+    axis, (c) the bf16 cell's reduction payload at ~half the anchor's
+    (fenced at ≤0.55×), and (d) full donation everywhere —
     regenerating the baseline from a regressed program fails here even
     if --update-baseline banked it."""
     path = os.path.join(
@@ -291,8 +328,12 @@ def test_committed_baseline_exhibits_zero_deltas():
     anchor = programs["train_step_replicated@dp2"]
     bf16 = programs["train_step_bf16@dp2"]
 
-    assert zero["peak_hbm_bytes"] <= 0.9 * anchor["peak_hbm_bytes"]
+    assert zero["peak_hbm_bytes"] <= 0.95 * anchor["peak_hbm_bytes"]
+    # the part of the cut ZeRO-1 owns: momentum is as large as the params
+    # the step all-gathers, and a dp=2 device keeps half of it
     ag = zero["collectives"]["all-gather"]
+    assert (anchor["arg_bytes"] - zero["arg_bytes"]
+            >= 0.45 * ag["bytes"])
     assert ag["bytes"] > 10 * 1024 * 1024
     assert set(ag["axes"]) <= {"data", "model"}
     # the sharded momentum leaves are in the cell's input-sharding digest
@@ -304,6 +345,7 @@ def test_committed_baseline_exhibits_zero_deltas():
     ar_bf16 = bf16["collectives"]["all-reduce"]["bytes"]
     ar_anchor = anchor["collectives"]["all-reduce"]["bytes"]
     assert ar_bf16 <= 0.55 * ar_anchor
+    assert "bf16" in bf16["wire_dtypes"]["all-reduce"]
     assert bf16["peak_hbm_bytes"] <= 1.01 * anchor["peak_hbm_bytes"]
 
     for key in ("train_step@dp2", "train_step@dp2tp2",
