@@ -46,6 +46,8 @@ import queue
 import threading
 from typing import Any, Callable, Iterable, Iterator, Optional
 
+from ..obs import spans
+
 
 class DevicePrefetcher:
     """Iterates device-staged batches from a host-batch iterable.
@@ -82,6 +84,8 @@ class DevicePrefetcher:
                     "make_global_array assemble) or an explicit assemble fn")
             assemble = self._default_assemble(mesh)
         self.host = host_batches
+        # the `loader` id of this pass's spans and counters (obs/spans.py)
+        self._loader = getattr(host_batches, "name", "train")
         self.depth = max(int(depth), 0)
         self._assemble = assemble
         self.overlap = bool(overlap)
@@ -106,15 +110,35 @@ class DevicePrefetcher:
 
         return assemble
 
+    def _stage(self, i: int, hb: Any) -> Any:
+        """`assemble` under its span, on whichever thread stages."""
+        parts = hb if isinstance(hb, (tuple, list)) else (hb,)
+        with spans.span("input.assemble", step=i, loader=self._loader,
+                        rows=len(parts[0]) if hasattr(parts[0], "__len__") else 0,
+                        bytes=sum(getattr(a, "nbytes", 0) for a in parts)):
+            out = self._assemble(i, hb)
+        self.staged += 1
+        return out
+
+    def _handed(self, starved: bool) -> None:
+        """Consumer side: one batch goes to the loop; `starved` = it was not
+        staged yet when the loop asked. Also noted on the loop's open span
+        (the trainer's `train.input_wait`), for a reading per step."""
+        spans.count("input_batches_total", loader=self._loader)
+        if starved:
+            spans.count("input_starved_total", loader=self._loader)
+        spans.note(starved=int(starved))
+
     def __iter__(self) -> Iterator[Any]:
         if self.depth == 0:
             # synchronous fallback: identical assembly calls in identical
-            # order, inline on the consumer thread (overlap ignored)
+            # order, inline on the consumer thread (overlap ignored); no
+            # staged queue, so the loop waits for every batch
             self.stager_thread = None
             self.fetch_thread = None
             for i, hb in enumerate(self.host):
-                out = self._assemble(i, hb)
-                self.staged += 1
+                out = self._stage(i, hb)
+                self._handed(starved=True)
                 yield out
             return
 
@@ -170,9 +194,7 @@ class DevicePrefetcher:
                         if item is None:
                             return
                         i, hb = item
-                        staged = self._assemble(i, hb)
-                        self.staged += 1
-                        if not put_or_stop(q, staged):
+                        if not put_or_stop(q, self._stage(i, hb)):
                             return
                 except BaseException as e:
                     error.append(e)
@@ -196,9 +218,7 @@ class DevicePrefetcher:
                     for i, hb in enumerate(it):
                         if stop.is_set():
                             return
-                        staged = self._assemble(i, hb)
-                        self.staged += 1
-                        if not put_or_stop(q, staged):
+                        if not put_or_stop(q, self._stage(i, hb)):
                             return
                 except BaseException as e:  # re-raised at the iteration site
                     error.append(e)
@@ -220,9 +240,11 @@ class DevicePrefetcher:
 
         try:
             while True:
+                starved = q.empty()
                 item = q.get()
                 if item is None:
                     break
+                self._handed(starved)
                 yield item
             if error:
                 # a silently truncated epoch would corrupt training

@@ -31,6 +31,8 @@ from typing import Iterator, Optional, Tuple
 
 import numpy as np
 
+from ..obs import spans
+
 
 def shard_indices_for_host(
     n: int,
@@ -93,7 +95,11 @@ class ShardedLoader:
         num_hosts: Optional[int] = None,
         batcher=None,
         chaos=None,
+        name: str = "train",
     ):
+        # which loader this is, on every span and counter of its batches
+        # (obs/spans.py): the trainer's val loader is "val"
+        self.name = name
         # batcher: optional native batch assembler
         # `(indices, epoch, batch_idx) -> (images, labels)` (see data/native.py);
         # replaces the per-sample Python/PIL path when set
@@ -235,14 +241,20 @@ class ShardedLoader:
                     if stop.is_set():
                         return
                     sl = indices[b * self.batch_size : (b + 1) * self.batch_size]
-                    if not put_or_stop(self._load_batch(b, sl)):
+                    # the span ends before the put: a producer blocked on a
+                    # full queue is waiting, not loading
+                    with spans.span("input.load", step=b, epoch=self.epoch,
+                                    loader=self.name):
+                        batch = self._load_batch(b, sl)
+                    if not put_or_stop(batch):
                         return
             except BaseException as e:  # re-raised in the consumer
                 error.append(e)
             finally:
                 put_or_stop(None)
 
-        t = threading.Thread(target=producer, daemon=True)
+        t = threading.Thread(target=producer, daemon=True,
+                             name="loader-producer")
         t.start()
         try:
             while True:

@@ -49,6 +49,7 @@ from ..data import native as native_mod
 from ..data.native import NativeBatcher
 from ..data.synthetic import SyntheticDataset
 from ..data.transforms import build_transform
+from ..obs import spans
 from ..obs.registry import Registry
 from ..ops.nested import best_k
 from ..parallel import fleet as fleetlib
@@ -145,6 +146,12 @@ def build_datasets(cfg: Config) -> Tuple[Any, Any]:
         f"dataset {d.dataset!r} has a transform preset but no build branch")
 
 
+# HELP lines of the input pipeline's counters (obs/spans.py) in metrics.prom
+_INPUT_COUNTER_HELP = {
+    "input_batches_total": "batches the input pipeline handed to a loop",
+    "input_starved_total": "of those, batches that were not staged yet when "
+                           "the loop asked",
+}
 
 
 class Trainer:
@@ -155,6 +162,21 @@ class Trainer:
         val_ds: Optional[Any] = None,
         mesh: Optional[Any] = None,
     ):
+        with spans.span("setup.trainer") as whole:
+            phases = self._setup(cfg, train_ds, val_ds, mesh)
+        host0_print(
+            f"[trainer] set-up: {whole.seconds:.2f} s ("
+            + ", ".join(f"{p.name.split('.', 1)[1]} {p.seconds:.2f}" for p in phases)
+            + ")")
+
+    def _setup(self, cfg: Config, train_ds, val_ds, mesh) -> list:
+        """Everything `__init__` builds -> its setup.* spans, in order."""
+        phases: list = []
+
+        def phase(name: str):
+            phases.append(spans.span("setup." + name))
+            return phases[-1]
+
         self.cfg = cfg
         # mid-run hang detector (inert at the default hang_timeout_s=0):
         # armed FIRST — mesh/loader/state construction below already does
@@ -214,54 +236,59 @@ class Trainer:
             tag=f"trainer[{cfg.workload}]", log=host0_print)
         self._compile_sentinel_ready = False
         if train_ds is None:
-            train_ds, val_ds = build_datasets(cfg)
+            with phase("datasets"):
+                train_ds, val_ds = build_datasets(cfg)
         self.train_ds, self.val_ds = train_ds, val_ds
 
-        spec = meshlib.MeshSpec(cfg.parallel.data_axis, cfg.parallel.model_axis,
-                                max(cfg.parallel.pipeline_stages, 1))
-        if mesh is not None:
-            self.mesh = mesh
-        elif cfg.parallel.dcn_slices:
-            # make_hybrid_mesh rejects pipeline_parallel > 1 (two-axis
-            # layout only) — the spec is passed whole so that validation
-            # actually sees the requested stages
-            self.mesh = meshlib.make_hybrid_mesh(
-                spec, dcn_data_parallel=cfg.parallel.dcn_slices)
-        else:
-            self.mesh = meshlib.make_mesh(spec)
+        with phase("mesh"):
+            spec = meshlib.MeshSpec(cfg.parallel.data_axis, cfg.parallel.model_axis,
+                                    max(cfg.parallel.pipeline_stages, 1))
+            if mesh is not None:
+                self.mesh = mesh
+            elif cfg.parallel.dcn_slices:
+                # make_hybrid_mesh rejects pipeline_parallel > 1 (two-axis
+                # layout only) — the spec is passed whole so that validation
+                # actually sees the requested stages
+                self.mesh = meshlib.make_hybrid_mesh(
+                    spec, dcn_data_parallel=cfg.parallel.dcn_slices)
+            else:
+                self.mesh = meshlib.make_mesh(spec)
 
-        train_batcher = make_native_batcher(train_ds, cfg, train=True)
-        val_batcher = make_native_batcher(val_ds, cfg, train=False)
-        self.native_dataplane = train_batcher is not None
-        if self.native_dataplane:
-            host0_print("[trainer] native C++ dataplane active")
-        elif native_mod.build_error:
-            # the PIL fallback stays, but never silently: say what the
-            # compiler / loader said
-            host0_print("[trainer] native dataplane unavailable, PIL "
-                        f"fallback: {native_mod.build_error}")
+        with phase("loaders"):
+            train_batcher = make_native_batcher(train_ds, cfg, train=True)
+            val_batcher = make_native_batcher(val_ds, cfg, train=False)
+            self.native_dataplane = train_batcher is not None
+            if self.native_dataplane:
+                host0_print("[trainer] native C++ dataplane active")
+            elif native_mod.build_error:
+                # the PIL fallback stays, but never silently: say what the
+                # compiler / loader said
+                host0_print("[trainer] native dataplane unavailable, PIL "
+                            f"fallback: {native_mod.build_error}")
 
-        self.train_loader = ShardedLoader(
-            train_ds, cfg.data.batch_size, shuffle=True, seed=cfg.run.seed,
-            num_workers=cfg.data.num_workers, prefetch=cfg.data.prefetch,
-            batcher=train_batcher, chaos=self.chaos or None)
-        self.val_loader = ShardedLoader(
-            val_ds, cfg.data.batch_size, shuffle=False, seed=cfg.run.seed,
-            num_workers=cfg.data.num_workers, prefetch=cfg.data.prefetch,
-            batcher=val_batcher)
+            self.train_loader = ShardedLoader(
+                train_ds, cfg.data.batch_size, shuffle=True, seed=cfg.run.seed,
+                num_workers=cfg.data.num_workers, prefetch=cfg.data.prefetch,
+                batcher=train_batcher, chaos=self.chaos or None)
+            self.val_loader = ShardedLoader(
+                val_ds, cfg.data.batch_size, shuffle=False, seed=cfg.run.seed,
+                num_workers=cfg.data.num_workers, prefetch=cfg.data.prefetch,
+                batcher=val_batcher, name="val")
 
         self.steps_per_epoch = max(len(self.train_loader), 1)
-        self.model, self.tx, self.state = create_train_state(
-            cfg, self.mesh, self.steps_per_epoch)
+        with phase("init_state"):
+            self.model, self.tx, self.state = create_train_state(
+                cfg, self.mesh, self.steps_per_epoch)
 
-        self.train_step = make_train_step(cfg, self.model, self.tx,
-                                          mesh=self.mesh,
-                                          chaos=self.chaos or None)
-        self.eval_step = make_eval_step(cfg, self.model, mesh=self.mesh)
-        self.nested_eval_step = (
-            make_nested_eval_step(cfg, self.model)
-            if cfg.model.head == "nested" else None
-        )
+        with phase("build_steps"):
+            self.train_step = make_train_step(cfg, self.model, self.tx,
+                                              mesh=self.mesh,
+                                              chaos=self.chaos or None)
+            self.eval_step = make_eval_step(cfg, self.model, mesh=self.mesh)
+            self.nested_eval_step = (
+                make_nested_eval_step(cfg, self.model)
+                if cfg.model.head == "nested" else None
+            )
 
         self._setup_profiler()
         self.records = RecordWriter(cfg.run.out_dir) if cfg.run.write_records else None
@@ -270,37 +297,38 @@ class Trainer:
             from ..utils.tensorboard import SummaryWriter
 
             self.tb = SummaryWriter(os.path.join(cfg.run.out_dir, "tb"))
-        self.ckpt = CheckpointManager(
-            cfg.run.out_dir,
-            save_every_epoch=cfg.run.save_every_epoch,
-            best_only=cfg.run.save_best_only,
-            keep=cfg.run.keep_checkpoints,
-            async_save=cfg.run.async_checkpoint,
-            chaos=self.chaos or None,
-        )
-        self.start_epoch = 0
-        if cfg.run.resume:
-            self.state = self.ckpt.restore(self.state, cfg.run.resume)
-            # meta lives next to the checkpoint being resumed (which may be a
-            # previous run's out_dir, not this one's)
-            meta = CheckpointManager.meta_for_checkpoint(cfg.run.resume)
-            self.start_epoch = int(meta.get("last_epoch", -1)) + 1
-            self.ckpt.best_metric = meta.get("best_metric", float("-inf"))
-            host0_print(f"resumed from {cfg.run.resume} at epoch {self.start_epoch}")
-        elif cfg.run.auto_resume:
-            # preemption recovery: restart command == start command; fresh
-            # runs fall through with start_epoch 0 (nothing in out_dir yet).
-            # On pods this is the resume CONSENSUS: host 0 alone scans/
-            # verifies/quarantines and broadcasts its choice; every host
-            # restores that exact file and the pod proves agreement with an
-            # all-gathered digest (mismatch ⇒ PodInconsistent, rc 9 at the
-            # CLI — never a silent split-brain resume). Single-process runs
-            # take the plain restore_latest path unchanged.
-            self.state, self.start_epoch = fleetlib.consensus_restore_latest(
-                self.ckpt, self.state)
-            if self.start_epoch:
-                host0_print(
-                    f"auto-resumed from {cfg.run.out_dir} at epoch {self.start_epoch}")
+        with phase("checkpoint"):
+            self.ckpt = CheckpointManager(
+                cfg.run.out_dir,
+                save_every_epoch=cfg.run.save_every_epoch,
+                best_only=cfg.run.save_best_only,
+                keep=cfg.run.keep_checkpoints,
+                async_save=cfg.run.async_checkpoint,
+                chaos=self.chaos or None,
+            )
+            self.start_epoch = 0
+            if cfg.run.resume:
+                self.state = self.ckpt.restore(self.state, cfg.run.resume)
+                # meta lives next to the checkpoint being resumed (which may be a
+                # previous run's out_dir, not this one's)
+                meta = CheckpointManager.meta_for_checkpoint(cfg.run.resume)
+                self.start_epoch = int(meta.get("last_epoch", -1)) + 1
+                self.ckpt.best_metric = meta.get("best_metric", float("-inf"))
+                host0_print(f"resumed from {cfg.run.resume} at epoch {self.start_epoch}")
+            elif cfg.run.auto_resume:
+                # preemption recovery: restart command == start command; fresh
+                # runs fall through with start_epoch 0 (nothing in out_dir yet).
+                # On pods this is the resume CONSENSUS: host 0 alone scans/
+                # verifies/quarantines and broadcasts its choice; every host
+                # restores that exact file and the pod proves agreement with an
+                # all-gathered digest (mismatch ⇒ PodInconsistent, rc 9 at the
+                # CLI — never a silent split-brain resume). Single-process runs
+                # take the plain restore_latest path unchanged.
+                self.state, self.start_epoch = fleetlib.consensus_restore_latest(
+                    self.ckpt, self.state)
+                if self.start_epoch:
+                    host0_print(
+                        f"auto-resumed from {cfg.run.out_dir} at epoch {self.start_epoch}")
         if self.start_epoch and self.records is not None:
             # keep the pre-preemption curve: reload history.json truncated to
             # the restored epoch so the resumed run appends, not overwrites
@@ -322,6 +350,7 @@ class Trainer:
             f"mesh={dict(zip(self.mesh.axis_names, self.mesh.devices.shape))} "
             f"steps/epoch={self.steps_per_epoch}"
         )
+        return phases
 
     # ---------------------------------------------------------------- fleet --
     def _defer_sigterm_to_epoch_boundary(self) -> None:
@@ -362,8 +391,27 @@ class Trainer:
         without an out_dir). Called at the log cadence and epoch end —
         existing host-sync points, so the scrape file adds no new sync."""
         if self.cfg.run.out_dir and is_host0():
+            self._publish_spans()
             self.obs.write_prom(
                 os.path.join(self.cfg.run.out_dir, "metrics.prom"))
+
+    def _publish_spans(self) -> None:
+        """The span recorder's per-name totals and the input pipeline's
+        counters (obs/spans.py; process totals) as counters of this
+        registry: `span_seconds_total{span="train.input_wait"} /
+        train_steps_total` is the input wait per step."""
+        def publish(name, help_text, labels, value):
+            c = self.obs.counter(name, help_text, labels)
+            c.inc(max(value - c.value, 0.0))
+
+        for name, (count, total_ns, _) in spans.totals().items():
+            publish("span_seconds_total", "seconds spent inside spans of this "
+                    "name (obs/spans.py)", {"span": name}, total_ns / 1e9)
+            publish("span_count_total", "spans of this name recorded",
+                    {"span": name}, count)
+        for (name, labels), n in spans.counters().items():
+            publish(name, _INPUT_COUNTER_HELP.get(name, ""),
+                    {k: str(v) for k, v in labels}, n)
 
     # -------------------------------------------------------------- profile --
     def _setup_profiler(self) -> None:
@@ -380,6 +428,20 @@ class Trainer:
                 and step == self._prof_start_step):
             jax.profiler.start_trace(self._prof_dir)
             self._prof_active = True
+            # for the length of the capture the program's spans are also
+            # the profiler's: same names, on its clock, beside the device ops
+            spans.RECORDER.annotate = self._profiler_annotation
+
+    @staticmethod
+    def _profiler_annotation(name: str, ids: Dict[str, Any]):
+        if name == "train.step_dispatch":
+            return jax.profiler.StepTraceAnnotation(name, step_num=ids["step"])
+        return jax.profiler.TraceAnnotation(name, **ids)
+
+    def _profile_stop(self) -> None:
+        spans.RECORDER.annotate = None
+        jax.profiler.stop_trace()
+        self._prof_active = False
 
     def _maybe_profile_stop(self, epoch: int, step: int, metrics) -> None:
         if not self._prof_active:
@@ -387,8 +449,7 @@ class Trainer:
         done = step - self._prof_start_step + 1 >= self._prof_steps
         if done or step == self.steps_per_epoch - 1:  # never leak past epoch 0
             jax.block_until_ready(metrics)
-            jax.profiler.stop_trace()
-            self._prof_active = False
+            self._profile_stop()
             self._prof_steps = 0
             host0_print(f"[trainer] profiler trace captured → {self._prof_dir}")
 
@@ -403,54 +464,66 @@ class Trainer:
                                 assemble=assemble,
                                 overlap=self.cfg.data.h2d_overlap)
 
+    def _log_sync(self, epoch: int, step: int, metrics, eta) -> None:
+        """What the loop does every `log_every` steps; the one place it
+        waits for the device inside an epoch."""
+        if eta is not None:
+            # the only host sync per log_every steps (reference syncs .item()
+            # on the same cadence, BASELINE:284-303)
+            eta.maybe_log(epoch, step,
+                          **{k: float(v) for k, v in metrics.items()})
+        # flush is a device round-trip too, so reaching here is proof the
+        # backend is answering — heartbeat it. It also raises
+        # SentinelDiverged on a sustained-NaN streak (pod mode: noted as
+        # abort intent instead — see _sentinel_flush).
+        self._sentinel_flush()
+        self._heartbeat.touch()
+        if self.fleet is not None:
+            # elastic lease heartbeat on the same cadence: a live mid-epoch
+            # host must never look dead to a rejoiner's lease scan (inert on
+            # non-elastic pods)
+            self.fleet.refresh_lease()
+        if self.compile_sentinel.armed:
+            # mid-epoch recompile detection at the same cadence; warn-only
+            # here — strict enforcement waits for the epoch boundary so a pod
+            # never aborts mid-collective
+            self.compile_sentinel.check(strict=False)
+        # refresh the scrape file on the same cadence (atomic rewrite; host 0
+        # only)
+        self._write_prom()
+
     def train_epoch(self, epoch: int, eta: Optional[EtaLogger] = None) -> Dict[str, float]:
         self.train_loader.set_epoch(epoch)
         sums = None  # device-side accumulation: no per-step host sync, so the
         n_batches = 0  # host keeps dispatching ahead of the device
-        it = iter(self._device_prefetcher(self.train_loader))
+        # every next() of the staged-batch iterator is a train.input_wait span
+        it = spans.timed("train.input_wait",
+                         self._device_prefetcher(self.train_loader), epoch=epoch)
         try:
-            for step, batch in enumerate(it):
-                self._maybe_profile_start(epoch, step)
-                self.state, metrics = self.train_step(self.state, *batch)
-                self._maybe_profile_stop(epoch, step, metrics)
-                n_batches += 1
-                self._steps_counter.inc()  # host-side int; no device touch
-                sums = metrics if sums is None else jax.tree_util.tree_map(
-                    jax.numpy.add, sums, metrics)
-                # device scalar only — the sentinel syncs it at flush points
-                self.sentinel.observe(metrics["step_ok"])
-                if self.chaos:
-                    self._host_step += 1
-                    self.chaos.maybe_sigterm(step=self._host_step - 1)
-                    self.chaos.maybe_peer_dead(step=self._host_step - 1)
-                    self.chaos.maybe_peer_slow(step=self._host_step - 1)
-                    self.chaos.maybe_host_lost(step=self._host_step - 1)
-                if step % self.cfg.run.log_every == 0:
-                    if eta is not None:
-                        # the only host sync per log_every steps (reference
-                        # syncs .item() on the same cadence, BASELINE:284-303)
-                        eta.maybe_log(epoch, step,
-                                      **{k: float(v) for k, v in metrics.items()})
-                    # flush is a device round-trip too, so reaching here is
-                    # proof the backend is answering — heartbeat it. It also
-                    # raises SentinelDiverged on a sustained-NaN streak
-                    # (pod mode: noted as abort intent instead — see
-                    # _sentinel_flush).
-                    self._sentinel_flush()
-                    self._heartbeat.touch()
-                    if self.fleet is not None:
-                        # elastic lease heartbeat on the same cadence: a
-                        # live mid-epoch host must never look dead to a
-                        # rejoiner's lease scan (inert on non-elastic pods)
-                        self.fleet.refresh_lease()
-                    if self.compile_sentinel.armed:
-                        # mid-epoch recompile detection at the same cadence;
-                        # warn-only here — strict enforcement waits for the
-                        # epoch boundary so a pod never aborts mid-collective
-                        self.compile_sentinel.check(strict=False)
-                    # refresh the scrape file on the same cadence (atomic
-                    # rewrite; host 0 only)
-                    self._write_prom()
+            with spans.span("train.epoch", epoch=epoch):
+                for step, batch in it:
+                    self._maybe_profile_start(epoch, step)
+                    # the call returns once the step is enqueued (first call:
+                    # traced, lowered, compiled or loaded from the cache), or
+                    # later where the runtime's queue of steps is full
+                    with spans.span("train.step_dispatch", step=step, epoch=epoch):
+                        self.state, metrics = self.train_step(self.state, *batch)
+                    self._maybe_profile_stop(epoch, step, metrics)
+                    n_batches += 1
+                    self._steps_counter.inc()  # host-side int; no device touch
+                    sums = metrics if sums is None else jax.tree_util.tree_map(
+                        jax.numpy.add, sums, metrics)
+                    # device scalar only — the sentinel syncs it at flush points
+                    self.sentinel.observe(metrics["step_ok"])
+                    if self.chaos:
+                        self._host_step += 1
+                        self.chaos.maybe_sigterm(step=self._host_step - 1)
+                        self.chaos.maybe_peer_dead(step=self._host_step - 1)
+                        self.chaos.maybe_peer_slow(step=self._host_step - 1)
+                        self.chaos.maybe_host_lost(step=self._host_step - 1)
+                    if step % self.cfg.run.log_every == 0:
+                        with spans.span("train.log_sync", step=step, epoch=epoch):
+                            self._log_sync(epoch, step, metrics, eta)
         finally:
             # a mid-epoch exception (divergence, injected fault, loader IO)
             # must stop and join the stager thread — a leaked stager would
@@ -601,7 +674,7 @@ class Trainer:
             # tracing into a dead run dir) ...
             if self._prof_active:
                 try:
-                    jax.profiler.stop_trace()
+                    self._profile_stop()
                 except Exception:
                     pass  # teardown must not mask the original exception
                 self._prof_active = False

@@ -153,7 +153,7 @@ class PLCTrainer(Trainer):
             predict_ds, self.cfg.data.batch_size, shuffle=False,
             seed=self.cfg.run.seed, num_workers=self.cfg.data.num_workers,
             prefetch=self.cfg.data.prefetch,
-            batcher=predict_batcher,
+            batcher=predict_batcher, name="predict",
         )
         # stage ONLY the image array — labels are discarded here, and None
         # placeholders have no business going through make_global_array's
