@@ -104,6 +104,10 @@ class ShardedLoader:
         # `(indices, epoch, batch_idx) -> (images, labels)` (see data/native.py);
         # replaces the per-sample Python/PIL path when set
         self.batcher = batcher
+        # which code fills this loader's batches, on every `input.load` span:
+        # the batcher's own name for its path (`native_u8` / `native_f32`),
+        # else the per-sample Python path
+        self.path = "python" if batcher is None else batcher.path
         # chaos: optional utils.chaos.FaultPlan — loader_io faults raise
         # IOError from _load_batch (the transient-crash shape supervise.sh
         # retries with backoff); None = no injection code in the hot path
@@ -244,8 +248,12 @@ class ShardedLoader:
                     # the span ends before the put: a producer blocked on a
                     # full queue is waiting, not loading
                     with spans.span("input.load", step=b, epoch=self.epoch,
-                                    loader=self.name):
+                                    loader=self.name, path=self.path):
                         batch = self._load_batch(b, sl)
+                    if self.batcher is not None:
+                        # the wire as the batch carries it, not as configured
+                        spans.count("input_native_batches_total",
+                                    loader=self.name, wire=batch[0].dtype.name)
                     if not put_or_stop(batch):
                         return
             except BaseException as e:  # re-raised in the consumer

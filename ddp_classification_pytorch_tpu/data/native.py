@@ -4,8 +4,10 @@ The reference feeds GPUs with torch DataLoader worker *processes* running
 PIL/torchvision per sample (BASELINE/main.py:130-131). Here the host hot path
 is one C call per batch (`native/dataplane.cpp`): libjpeg/libpng decode
 (dispatch on magic bytes) → torchvision-semantics RandomResizedCrop /
-resize+center-crop → flip → normalize, fanned over a thread pool in native
-code (no GIL, no per-sample Python). Falls back to the pure-Python pipeline
+resize+center-crop → flip → normalize (float32 wire) or quantize (uint8
+wire), fanned over a thread pool in native code (no GIL, no per-sample
+Python) and written straight into a batch buffer of the wire's own dtype.
+Falls back to the pure-Python pipeline
 automatically when the library can't be built or a file is an unsupported
 format.
 """
@@ -87,6 +89,13 @@ def _load(path: str) -> ctypes.CDLL:
         ctypes.c_uint64, ctypes.POINTER(ctypes.c_float),
         ctypes.POINTER(ctypes.c_float), ctypes.c_int,
     ]
+    lib.dp_load_batch_u8.restype = ctypes.c_int
+    lib.dp_load_batch_u8.argtypes = [
+        ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
+        ctypes.POINTER(ctypes.c_uint8), ctypes.c_int, ctypes.c_int,
+        ctypes.c_int, ctypes.c_int, ctypes.c_double, ctypes.c_double,
+        ctypes.c_uint64, ctypes.c_int,
+    ]
     return lib
 
 
@@ -115,10 +124,6 @@ def get_lib() -> Optional[ctypes.CDLL]:
 
 _MEAN = (ctypes.c_float * 3)(*IMAGENET_MEAN)
 _STD = (ctypes.c_float * 3)(*IMAGENET_STD)
-# identity "normalization" for the uint8 wire: (v/255 − 0)/(1/255) = v, so
-# the C side hands back raw 0..255 pixel values (float, pre-quantization)
-_MEAN_RAW = (ctypes.c_float * 3)(0.0, 0.0, 0.0)
-_STD_RAW = (ctypes.c_float * 3)(1.0 / 255.0, 1.0 / 255.0, 1.0 / 255.0)
 
 
 def native_decodes_png() -> bool:
@@ -137,13 +142,14 @@ def native_load_batch(
     scale: Tuple[float, float] = (0.8, 1.0),
     seed: int = 0,
     num_threads: int = 4,
-    raw: bool = False,
+    out_dtype: str = "float32",
 ) -> Optional[Tuple[np.ndarray, int]]:
-    """Decode+transform a list of JPEG/PNG paths into (B, S, S, 3) f32.
-
-    `raw` swaps the ImageNet constants for the identity pair, so the C side
-    returns un-normalized 0..255 pixel values (still float — the caller
-    quantizes; the uint8-wire path in NativeBatcher).
+    """Decode+transform a list of JPEG/PNG paths into a (B, S, S, 3) batch
+    of `out_dtype`: "float32" (ImageNet-normalized) or "uint8" (the 0..255
+    pixels, rounded half-to-even and clamped inside the C workers — the
+    uint8 wire; the jitted step normalizes on device). Both come from one
+    resample kernel: the uint8 batch is the quantized float one, byte for
+    byte, without the float one ever existing.
 
     Returns (batch, n_failures) or None when the native library is
     unavailable. Failure slots are zero-filled; the caller patches them via
@@ -152,14 +158,20 @@ def native_load_batch(
     lib = get_lib()
     if lib is None:
         return None
+    if out_dtype == "uint8":
+        ctype, call, norm = ctypes.c_uint8, lib.dp_load_batch_u8, ()
+    elif out_dtype == "float32":
+        ctype, call, norm = ctypes.c_float, lib.dp_load_batch, (_MEAN, _STD)
+    else:
+        raise ValueError(f"unknown native out_dtype {out_dtype!r}")
     n = len(paths)
-    out = np.empty((n, out_size, out_size, 3), np.float32)
+    out = np.empty((n, out_size, out_size, 3), out_dtype)
     arr = (ctypes.c_char_p * n)(*[os.fsencode(p) for p in paths])
-    errors = lib.dp_load_batch(
-        arr, n, out.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+    errors = call(
+        arr, n, out.ctypes.data_as(ctypes.POINTER(ctype)),
         out_size, out_size, int(train), resize_short,
         float(scale[0]), float(scale[1]), ctypes.c_uint64(seed),
-        _MEAN_RAW if raw else _MEAN, _STD_RAW if raw else _STD, num_threads,
+        *norm, num_threads,
     )
     return out, int(errors)
 
@@ -185,13 +197,16 @@ class NativeBatcher:
         self.seed = seed
         self.num_threads = num_threads
         self.resize_short = crop_size
-        # uint8 wire: the C call runs with identity mean/std (raw 0..255
-        # floats) and the batch is quantized to uint8 here; the jitted step
-        # normalizes on device. The native train flip stays on (the C
-        # signature ties it to `train`), so with the device epilogue's flip
-        # the sample is flipped twice with independent draws — the composed
-        # distribution is still flip-with-prob-0.5, augmentation-equivalent.
+        # uint8 wire: the C workers quantize (round half-to-even, clamp) and
+        # write the uint8 batch themselves; the jitted step normalizes on
+        # device. The native train flip stays on (the C signature ties it
+        # to `train`), so with the device epilogue's flip the sample is
+        # flipped twice with independent draws — the composed distribution
+        # is still flip-with-prob-0.5, augmentation-equivalent.
         self.out_dtype = out_dtype
+        # which code fills the batch, as the loader's `input.load` spans and
+        # `input_native_batches_total{wire}` name it (docs/observability.md)
+        self.path = "native_u8" if out_dtype == "uint8" else "native_f32"
         # mirror build_transform's output-size quirk (train@crop_size for
         # baseline) AND its out_dtype validation
         t = build_transform(preset, train, image_size, crop_size,
@@ -208,22 +223,18 @@ class NativeBatcher:
         labels = np.asarray(
             [self.dataset.labels[int(i)] for i in indices], np.int32)
         seed = (self.seed * 1_000_003 + epoch * 10_007 + batch_idx) & 0xFFFFFFFF
-        emit_uint8 = self.out_dtype == "uint8"
         res = native_load_batch(
             paths, self.out_size, self.train, self.resize_short,
-            self.scale, seed, self.num_threads, raw=emit_uint8)
+            self.scale, seed, self.num_threads, out_dtype=self.out_dtype)
         if res is None:
             raise RuntimeError("native dataplane unavailable")
         images, errors = res
-        if emit_uint8:
-            # quantize the C side's float resample output (PIL quantizes at
-            # the same point; ±0.5/255 vs the native-float path — within the
-            # documented "up to resampling details" envelope)
-            images = np.clip(np.rint(images), 0, 255).astype(np.uint8)
         if errors:
+            # zero-filled slots → the dataset's PIL transform, which yields
+            # the same wire dtype (PIL quantizes at the same point; within
+            # the documented "up to resampling details" envelope)
             rng = np.random.default_rng(seed)
-            for j in np.nonzero(
-                    np.abs(images.astype(np.float32)).sum(axis=(1, 2, 3)) == 0)[0]:
+            for j in np.nonzero(~images.reshape(len(images), -1).any(axis=1))[0]:
                 img, _ = self.dataset.__getitem__(int(indices[j]), rng)
                 images[j] = img
         return images, labels

@@ -151,6 +151,8 @@ _INPUT_COUNTER_HELP = {
     "input_batches_total": "batches the input pipeline handed to a loop",
     "input_starved_total": "of those, batches that were not staged yet when "
                            "the loop asked",
+    "input_native_batches_total": "batches the native dataplane filled, by "
+                                  "the dtype it wrote them in",
 }
 
 

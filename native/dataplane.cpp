@@ -5,15 +5,20 @@
 // input pipeline hot path — `DataLoader(num_workers=4, pin_memory=True)`
 // worker processes running PIL + torchvision transforms per sample
 // (reference BASELINE/main.py:58-76,130-131). One C call fills a whole
-// NHWC float32 batch buffer that jax can ship to device without further
-// host-side work. Decoding dispatches on file magic bytes to libjpeg or
+// NHWC batch buffer in the wire's own type, so jax can ship it to device
+// without further host-side work: `dp_load_batch` writes normalized float32,
+// `dp_load_batch_u8` the quantized 0..255 uint8 pixels the jitted step
+// normalizes on device (one resample kernel, two stores).
+// Decoding dispatches on file magic bytes to libjpeg or
 // libpng (PIL `convert("RGB")` semantics: palette/gray expanded, alpha
 // dropped); crops follow torchvision semantics (RandomResizedCrop(scale,
 // ratio 3/4..4/3, 10 tries, fallback center; val: resize-short-side +
 // center crop) so training recipes match the reference's augmentation
 // distribution.
 //
-// Build: g++ -O3 -march=native -shared -fPIC -o libdataplane.so dataplane.cpp -ljpeg -lpng -lpthread
+// Build (data/native.py::_CXX does it on first use, keyed by a hash of this
+// file and the flags):
+//   g++ -O3 -std=c++17 -shared -fPIC -o libdataplane.so dataplane.cpp -ljpeg -lpng -lpthread
 
 #include <algorithm>
 #include <atomic>
@@ -184,37 +189,80 @@ bool decode_image(const char* path, std::vector<uint8_t>& out, int& w, int& h) {
 }
 
 // ------------------------------------------------------------ resample -----
+// The two stores of the one resample kernel: what becomes of a bilinear
+// sample v (0..255, double) of channel c.
+
+// float32 wire: (v/255 - mean)/std.
+struct StoreF32 {
+  using type = float;
+  const float* mean;
+  const float* stdv;
+  float operator()(double v, int c) const {
+    return ((float)(v / 255.0) - mean[c]) / stdv[c];
+  }
+};
+
+// uint8 wire: the same float expression under the identity pair mean 0,
+// std (float)(1/255) — the float32 store's output in raw pixel units —
+// rounded half-to-even in the default rounding mode (= np.rint) and clamped
+// to a byte: byte for byte `clip(rint(x), 0, 255)` of the float32 batch
+// under that pair (tests/test_native_dataplane.py holds it to that).
+struct StoreU8 {
+  using type = uint8_t;
+  uint8_t operator()(double v, int) const {
+    const float inv = (float)(1.0 / 255.0);
+    float r = std::nearbyint(((float)(v / 255.0) - 0.0f) / inv);
+    return (uint8_t)std::min(std::max(r, 0.0f), 255.0f);
+  }
+};
+
+// One output column's source taps: byte offsets of the two clamped source
+// columns inside a row, and the weight of the second.
+struct ColTap {
+  int lo, hi;
+  double w;
+};
+
 // Bilinear sample from src (h×w RGB u8) region [y0,y0+ch)×[x0,x0+cw)
-// scaled to out_h×out_w, optional horizontal flip, normalized to f32 CHW-less
-// NHWC with (v/255 - mean)/std.
-void crop_resize_normalize(const uint8_t* src, int w, int h,
-                           double x0, double y0, double cw, double ch,
-                           float* dst, int out_w, int out_h, bool flip,
-                           const float* mean, const float* stdv) {
+// scaled to out_h×out_w, optional horizontal flip, stored NHWC through
+// `store`. Column taps are computed once per image (into `taps`, the
+// worker's scratch), row taps once per row; the sample itself is evaluated
+// in double.
+template <class Store>
+void crop_resize_store(const uint8_t* src, int w, int h,
+                       double x0, double y0, double cw, double ch,
+                       typename Store::type* dst, int out_w, int out_h,
+                       bool flip, std::vector<ColTap>& taps,
+                       const Store& store) {
   const double sx = cw / out_w, sy = ch / out_h;
-  for (int oy = 0; oy < out_h; ++oy) {
+  taps.resize(out_w);
+  for (int ox = 0; ox < out_w; ++ox) {
     // torchvision/PIL bilinear: sample at pixel centers
+    double fx = x0 + (ox + 0.5) * sx - 0.5;
+    int x_lo = (int)std::floor(fx);
+    taps[ox].lo = std::clamp(x_lo, 0, w - 1) * 3;
+    taps[ox].hi = std::clamp(x_lo + 1, 0, w - 1) * 3;
+    taps[ox].w = fx - x_lo;
+  }
+  for (int oy = 0; oy < out_h; ++oy) {
     double fy = y0 + (oy + 0.5) * sy - 0.5;
     int y_lo = (int)std::floor(fy);
     double wy = fy - y_lo;
-    int y0c = std::clamp(y_lo, 0, h - 1);
-    int y1c = std::clamp(y_lo + 1, 0, h - 1);
+    const uint8_t* row0 = src + (size_t)std::clamp(y_lo, 0, h - 1) * w * 3;
+    const uint8_t* row1 = src + (size_t)std::clamp(y_lo + 1, 0, h - 1) * w * 3;
+    typename Store::type* out_row = dst + (size_t)oy * out_w * 3;
     for (int ox = 0; ox < out_w; ++ox) {
-      double fx = x0 + (ox + 0.5) * sx - 0.5;
-      int x_lo = (int)std::floor(fx);
-      double wx = fx - x_lo;
-      int x0c = std::clamp(x_lo, 0, w - 1);
-      int x1c = std::clamp(x_lo + 1, 0, w - 1);
-      const uint8_t* p00 = src + ((size_t)y0c * w + x0c) * 3;
-      const uint8_t* p01 = src + ((size_t)y0c * w + x1c) * 3;
-      const uint8_t* p10 = src + ((size_t)y1c * w + x0c) * 3;
-      const uint8_t* p11 = src + ((size_t)y1c * w + x1c) * 3;
-      int out_x = flip ? (out_w - 1 - ox) : ox;
-      float* q = dst + ((size_t)oy * out_w + out_x) * 3;
+      const ColTap& t = taps[ox];
+      const double wx = t.w;
+      const uint8_t* p00 = row0 + t.lo;
+      const uint8_t* p01 = row0 + t.hi;
+      const uint8_t* p10 = row1 + t.lo;
+      const uint8_t* p11 = row1 + t.hi;
+      typename Store::type* q = out_row + (flip ? (out_w - 1 - ox) : ox) * 3;
       for (int c = 0; c < 3; ++c) {
         double v = (1 - wy) * ((1 - wx) * p00[c] + wx * p01[c]) +
                    wy * ((1 - wx) * p10[c] + wx * p11[c]);
-        q[c] = ((float)(v / 255.0) - mean[c]) / stdv[c];
+        q[c] = store(v, c);
       }
     }
   }
@@ -258,25 +306,25 @@ void rrc_box(Rng& rng, int w, int h, double smin, double smax,
 struct BatchJob {
   const char** paths;
   int n;
-  float* out;
   int out_h, out_w;
   int train;
   int resize_short;
   double scale_min, scale_max;
   uint64_t seed;
-  const float* mean;
-  const float* stdv;
   std::atomic<int> next{0};
   std::atomic<int> errors{0};
 };
 
-void worker(BatchJob* job) {
+template <class Store>
+void worker(BatchJob* job, typename Store::type* out, Store store) {
   std::vector<uint8_t> buf;
+  std::vector<ColTap> taps;
+  const size_t item = (size_t)job->out_h * job->out_w * 3;
   int w, h;
   for (;;) {
     int i = job->next.fetch_add(1);
     if (i >= job->n) return;
-    float* dst = job->out + (size_t)i * job->out_h * job->out_w * 3;
+    typename Store::type* dst = out + (size_t)i * item;
     bool ok = false;
     try {
       ok = decode_image(job->paths[i], buf, w, h);
@@ -287,7 +335,7 @@ void worker(BatchJob* job) {
     }
     if (!ok) {
       // unreadable/unsupported/oversized: zero-fill; caller retries via PIL
-      std::memset(dst, 0, sizeof(float) * job->out_h * job->out_w * 3);
+      std::memset(dst, 0, sizeof(typename Store::type) * item);
       job->errors.fetch_add(1);
       continue;
     }
@@ -306,26 +354,20 @@ void worker(BatchJob* job) {
       x0 = (w - cw) / 2.0;
       y0 = (h - ch) / 2.0;
     }
-    crop_resize_normalize(buf.data(), w, h, x0, y0, cw, ch, dst,
-                          job->out_w, job->out_h, flip, job->mean, job->stdv);
+    crop_resize_store(buf.data(), w, h, x0, y0, cw, ch, dst, job->out_w,
+                      job->out_h, flip, taps, store);
   }
 }
 
-}  // namespace
-
-extern "C" {
-
-// Fill out[n, out_h, out_w, 3] float32. Returns number of decode failures
-// (their slots are zero-filled; indices of failures are not reported — the
-// Python wrapper re-loads failed slots through PIL when the count is >0).
-int dp_load_batch(const char** paths, int n, float* out, int out_h, int out_w,
-                  int train, int resize_short, double scale_min,
-                  double scale_max, uint64_t seed, const float* mean,
-                  const float* stdv, int num_threads) {
+// Fan one batch over `num_threads` workers; returns the decode failures.
+template <class Store>
+int load_batch(const char** paths, int n, typename Store::type* out,
+               int out_h, int out_w, int train, int resize_short,
+               double scale_min, double scale_max, uint64_t seed,
+               int num_threads, Store store) {
   BatchJob job;
   job.paths = paths;
   job.n = n;
-  job.out = out;
   job.out_h = out_h;
   job.out_w = out_w;
   job.train = train;
@@ -333,18 +375,43 @@ int dp_load_batch(const char** paths, int n, float* out, int out_h, int out_w,
   job.scale_min = scale_min;
   job.scale_max = scale_max;
   job.seed = seed;
-  job.mean = mean;
-  job.stdv = stdv;
   int t = std::max(1, std::min(num_threads, n));
   if (t == 1) {
-    worker(&job);
+    worker<Store>(&job, out, store);
   } else {
     std::vector<std::thread> threads;
     threads.reserve(t);
-    for (int i = 0; i < t; ++i) threads.emplace_back(worker, &job);
+    for (int i = 0; i < t; ++i)
+      threads.emplace_back(worker<Store>, &job, out, store);
     for (auto& th : threads) th.join();
   }
   return job.errors.load();
+}
+
+}  // namespace
+
+extern "C" {
+
+// Fill out[n, out_h, out_w, 3] float32, normalized with mean/std. Returns
+// number of decode failures (their slots are zero-filled; indices of
+// failures are not reported — the Python wrapper re-loads failed slots
+// through PIL when the count is >0).
+int dp_load_batch(const char** paths, int n, float* out, int out_h, int out_w,
+                  int train, int resize_short, double scale_min,
+                  double scale_max, uint64_t seed, const float* mean,
+                  const float* stdv, int num_threads) {
+  return load_batch(paths, n, out, out_h, out_w, train, resize_short,
+                    scale_min, scale_max, seed, num_threads,
+                    StoreF32{mean, stdv});
+}
+
+// Fill out[n, out_h, out_w, 3] uint8 with the quantized 0..255 pixels (the
+// uint8 wire); same crops, flips and failure reporting as dp_load_batch.
+int dp_load_batch_u8(const char** paths, int n, uint8_t* out, int out_h,
+                     int out_w, int train, int resize_short, double scale_min,
+                     double scale_max, uint64_t seed, int num_threads) {
+  return load_batch(paths, n, out, out_h, out_w, train, resize_short,
+                    scale_min, scale_max, seed, num_threads, StoreU8{});
 }
 
 // Capability probe: 1 when this build decodes PNG, 0 for the JPEG-only

@@ -6,11 +6,15 @@ active, verifying the whole path produces finite metrics and the native
 batcher is actually engaged.
 """
 
+import os
+import time
+
 import numpy as np
 import pytest
 from PIL import Image
 
 from ddp_classification_pytorch_tpu.config import get_preset
+from ddp_classification_pytorch_tpu.obs import spans
 from ddp_classification_pytorch_tpu.train.loop import Trainer
 
 
@@ -29,6 +33,18 @@ def image_tree(tmp_path_factory):
                 ).astype(np.uint8)
                 Image.fromarray(img).save(d / f"{i}.jpg", quality=92)
     return root
+
+
+def _prom_rows(tr, tmp_path):
+    tr._write_prom()
+    with open(os.path.join(str(tmp_path), "metrics.prom")) as f:
+        return dict(ln.rsplit(" ", 1) for ln in f.read().splitlines()
+                    if not ln.startswith("#"))
+
+
+def _load_paths(mark_ns):
+    return {(s.ids["loader"], s.ids["path"]) for s in spans.snapshot()
+            if s.name == "input.load" and s.start_ns >= mark_ns}
 
 
 def test_imagefolder_native_train(image_tree, tmp_path):
@@ -51,10 +67,23 @@ def test_imagefolder_native_train(image_tree, tmp_path):
 
     tr = Trainer(cfg)
     assert tr.train_loader.batcher is not None, "native dataplane not engaged"
+    mark = time.perf_counter_ns()
     m = tr.train_epoch(0)
     assert np.isfinite(m["loss"])
     val = tr.evaluate()
     assert 0.0 <= val["val_top1"] <= 1.0
+    # engagement, as a run shows it: every batch of both loaders came through
+    # the uint8 native path (the default wire), and metrics.prom counts them
+    assert _load_paths(mark) == {("train", "native_u8"), ("val", "native_u8")}
+    rows = _prom_rows(tr, tmp_path)
+    counters = spans.counters()
+    for loader in ("train", "val"):
+        key = ("input_native_batches_total",
+               (("loader", loader), ("wire", "uint8")))
+        assert counters[key] >= len(getattr(tr, f"{loader}_loader"))
+        assert float(rows[
+            f'input_native_batches_total{{loader="{loader}",wire="uint8"}}'
+        ]) == counters[key]
 
 
 def test_imagefolder_python_fallback(image_tree, tmp_path):
@@ -78,5 +107,7 @@ def test_imagefolder_python_fallback(image_tree, tmp_path):
 
     tr = Trainer(cfg)
     assert tr.train_loader.batcher is None
+    mark = time.perf_counter_ns()
     m = tr.train_epoch(0)
     assert np.isfinite(m["loss"])
+    assert _load_paths(mark) == {("train", "python")}

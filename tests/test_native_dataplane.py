@@ -2,6 +2,7 @@
 PNGs, and checks transform semantics against the Python/PIL pipeline."""
 
 
+import ctypes
 import os
 import time
 
@@ -9,7 +10,9 @@ import numpy as np
 import pytest
 from PIL import Image
 
+from ddp_classification_pytorch_tpu.data.imagefolder import ImageFolderDataset
 from ddp_classification_pytorch_tpu.data.native import (
+    NativeBatcher,
     get_lib,
     native_decodes_png,
     native_load_batch,
@@ -17,6 +20,7 @@ from ddp_classification_pytorch_tpu.data.native import (
 from ddp_classification_pytorch_tpu.data.transforms import (
     IMAGENET_MEAN,
     IMAGENET_STD,
+    build_transform,
 )
 
 # PNG tests only apply to a full build; the JPEG-only -DDP_NO_PNG fallback
@@ -239,6 +243,104 @@ def test_dimension_bomb_header_reported_not_crashed(tmp_path, pngs, png_support)
     assert errors == 1
     assert np.abs(out[0]).sum() == 0.0
     assert np.abs(out[1]).sum() > 0.0
+
+
+# ------------------------------------------------------------- uint8 wire --
+def _float_batch(paths, size, train, seed, threads, mean, std):
+    """`dp_load_batch` called directly with a mean/std pair of the test's own
+    (the Python wrapper only ever passes ImageNet's)."""
+    fp = ctypes.POINTER(ctypes.c_float)
+    out = np.empty((len(paths), size, size, 3), np.float32)
+    arr = (ctypes.c_char_p * len(paths))(*[os.fsencode(p) for p in paths])
+    errors = get_lib().dp_load_batch(
+        arr, len(paths), out.ctypes.data_as(fp), size, size, int(train), 256,
+        0.8, 1.0, ctypes.c_uint64(seed), (ctypes.c_float * 3)(*mean),
+        (ctypes.c_float * 3)(*std), threads)
+    return out, errors
+
+
+@pytest.mark.parametrize("size", [224, 97, 33])
+@pytest.mark.parametrize("seed", [5, 4294967291])
+@pytest.mark.parametrize("kind", ["jpeg", "png", "mixed"])
+@pytest.mark.parametrize("threads", [1, 2, 8])
+@pytest.mark.parametrize("train", [True, False], ids=["train", "val"])
+def test_uint8_batch_is_the_quantized_float_batch(
+        request, jpegs, train, threads, kind, seed, size):
+    """The uint8 wire, byte for byte: what the C workers write equals
+    `clip(rint(x), 0, 255)` of the float batch under the identity pair
+    mean 0, std 1/255 — the composition the Python side ran before the
+    workers quantized themselves. Both come out of the one resample kernel,
+    so this pins the float store too."""
+    if kind == "jpeg":
+        paths = jpegs
+    else:
+        request.getfixturevalue("png_support")
+        pngs = request.getfixturevalue("pngs")[0]
+        paths = pngs if kind == "png" else jpegs[:2] + pngs + jpegs[2:]
+    got, errors = native_load_batch(paths, size, train, seed=seed,
+                                    num_threads=threads, out_dtype="uint8")
+    raw, raw_errors = _float_batch(paths, size, train, seed, threads,
+                                   (0.0,) * 3, (1.0 / 255.0,) * 3)
+    assert errors == raw_errors == 0
+    assert got.dtype == np.uint8 and got.shape == raw.shape
+    np.testing.assert_array_equal(
+        got, np.clip(np.rint(raw), 0, 255).astype(np.uint8))
+    # and the float32 wire is the same call with ImageNet's pair
+    f32, _ = native_load_batch(paths, size, train, seed=seed, num_threads=threads)
+    ref, _ = _float_batch(paths, size, train, seed, threads,
+                          IMAGENET_MEAN, IMAGENET_STD)
+    assert f32.dtype == np.float32
+    np.testing.assert_array_equal(f32, ref)
+
+
+def test_the_two_seeds_of_the_uint8_test_flip_some_images_and_not_others(jpegs):
+    """Red rises with x in every fixture JPEG, so a falling red ramp is a
+    flipped image: both seeds of the byte-identity test hold both kinds."""
+    for seed in (5, 4294967291):
+        out, _ = native_load_batch(jpegs, 97, train=True, seed=seed,
+                                   out_dtype="uint8")
+        red = out[..., 0].astype(np.int32)
+        flipped = (red[:, :, -8:].mean(axis=(1, 2)) < red[:, :, :8].mean(axis=(1, 2)))
+        assert flipped.any() and not flipped.all(), (seed, flipped)
+    val, _ = native_load_batch(jpegs, 97, train=False, seed=5, out_dtype="uint8")
+    red = val[..., 0].astype(np.int32)
+    assert (red[:, :, -8:].mean(axis=(1, 2)) > red[:, :, :8].mean(axis=(1, 2))).all()
+
+
+def test_bad_file_uint8_zero_filled_counted_and_patched_by_pil(tmp_path, jpegs):
+    """A file the C side declines (a BMP: neither magic) in uint8 mode: the
+    slot is zero bytes and counted; `NativeBatcher` re-loads it through the
+    dataset's PIL transform into a uint8 row, the other rows untouched."""
+    bmp = str(tmp_path / "really_a.bmp")
+    with Image.open(jpegs[0]) as im:
+        im.save(bmp)
+    paths = [jpegs[0], bmp, jpegs[1]]
+    out, errors = native_load_batch(paths, 64, train=False, seed=0,
+                                    num_threads=2, out_dtype="uint8")
+    assert errors == 1 and out.dtype == np.uint8
+    assert not out[1].any() and out[0].any() and out[2].any()
+
+    ds = ImageFolderDataset(
+        paths, np.zeros(3, np.int32), ["c0"],
+        build_transform("baseline", False, 64, 72, out_dtype="uint8"))
+    batcher = NativeBatcher(ds, "baseline", False, 64, 72, seed=0,
+                            num_threads=2, out_dtype="uint8")
+    images, labels = batcher(np.arange(3), 0, 0)
+    assert images.dtype == np.uint8 and images.shape == (3, 64, 64, 3)
+    assert labels.dtype == np.int32
+    direct, _ = native_load_batch(paths, 64, train=False, resize_short=72,
+                                  seed=batcher.seed * 1_000_003 & 0xFFFFFFFF,
+                                  num_threads=2, out_dtype="uint8")
+    np.testing.assert_array_equal(images[[0, 2]], direct[[0, 2]])
+    # the patched row is the same picture as its JPEG twin in row 0, through
+    # PIL instead of libjpeg (the BMP holds the decoded JPEG's pixels)
+    assert images[1].any()
+    assert np.abs(images[1].astype(np.int32) - images[0]).mean() < 4.0
+
+
+def test_native_load_batch_rejects_an_unknown_dtype(jpegs):
+    with pytest.raises(ValueError, match="out_dtype"):
+        native_load_batch(jpegs, 32, train=False, out_dtype="bfloat16")
 
 
 def _fresh_native(monkeypatch, native_mod, **attrs):

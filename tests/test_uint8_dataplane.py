@@ -18,7 +18,9 @@ import jax
 import jax.numpy as jnp
 
 from ddp_classification_pytorch_tpu.config import get_preset
+from ddp_classification_pytorch_tpu.data.imagefolder import ImageFolderDataset
 from ddp_classification_pytorch_tpu.data.loader import ShardedLoader
+from ddp_classification_pytorch_tpu.data.native import NativeBatcher
 from ddp_classification_pytorch_tpu.data.device_prefetch import DevicePrefetcher
 from ddp_classification_pytorch_tpu.data.synthetic import SyntheticDataset
 from ddp_classification_pytorch_tpu.data.transforms import (
@@ -26,6 +28,7 @@ from ddp_classification_pytorch_tpu.data.transforms import (
     normalize,
     preset_for_dataset,
 )
+from ddp_classification_pytorch_tpu.obs import spans
 from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
 from ddp_classification_pytorch_tpu.train.state import create_train_state
 from ddp_classification_pytorch_tpu.train.steps import (
@@ -115,6 +118,49 @@ def test_float32_wire_unchanged():
     ds = SyntheticDataset(32, 16, 4)  # default out_dtype
     img, _ = ds.__getitem__(0)
     assert img.dtype == np.float32
+
+
+# ----------------------------------------------- which path filled a batch --
+
+@pytest.mark.parametrize("wire,native,path", [
+    ("uint8", True, "native_u8"), ("float32", True, "native_f32"),
+    ("uint8", False, "python"), ("float32", False, "python")])
+def test_input_load_spans_name_the_path_that_filled_the_batch(
+        tmp_path, wire, native, path):
+    """Every `input.load` span carries `path`; a NativeBatcher-backed loader
+    also counts `input_native_batches_total{loader,wire}`, with the wire
+    read off the batch it produced; the per-sample Python path counts none."""
+    if native and not NativeBatcher.available():
+        pytest.skip("native dataplane not built on this host")
+    rng = np.random.default_rng(3)
+    paths = []
+    for i in range(8):
+        paths.append(str(tmp_path / f"{i}.jpg"))
+        Image.fromarray(rng.integers(0, 256, (40, 48, 3)).astype(np.uint8)).save(
+            paths[-1], quality=90)
+    ds = ImageFolderDataset(
+        paths, np.arange(8, dtype=np.int32) % 2, ["a", "b"],
+        build_transform("baseline", True, 24, 32, out_dtype=wire))
+    batcher = NativeBatcher(ds, "baseline", True, 24, 32, seed=1, num_threads=2,
+                            out_dtype=wire) if native else None
+    name = f"{path}-{wire}"  # a loader label of this case's own
+    loader = ShardedLoader(ds, 4, shuffle=False, num_workers=2, host_id=0,
+                           num_hosts=1, batcher=batcher, name=name)
+    try:
+        batches = list(loader)
+    finally:
+        loader.close()
+    assert len(batches) == 2
+    assert all(im.dtype == np.dtype(wire) and im.shape == (4, 32, 32, 3)
+               for im, _ in batches)
+    loads = [s for s in spans.snapshot()
+             if s.name == "input.load" and s.ids["loader"] == name]
+    assert [s.ids["step"] for s in loads] == [0, 1]
+    assert all(s.ids["path"] == path for s in loads)
+    counted = {dict(k[1])["wire"]: n for k, n in spans.counters().items()
+               if k[0] == "input_native_batches_total"
+               and dict(k[1])["loader"] == name}
+    assert counted == ({wire: 2} if native else {})
 
 
 # ------------------------------------------------------- step equivalence --
