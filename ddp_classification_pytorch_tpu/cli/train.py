@@ -21,6 +21,7 @@ Every behavior-affecting reference flag maps to a field of the Config tree:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 from typing import Optional, Sequence
 
 from ..config import Config, get_preset
@@ -40,7 +41,9 @@ def build_parser() -> argparse.ArgumentParser:
     d.add_argument("--train_dir", default="", help="explicit train dir (overrides --folder)")
     d.add_argument("--val_dir", default="", help="explicit val dir (overrides --folder)")
     d.add_argument("--dataset", default="",
-                   help="imagefolder | synthetic | plc | cifar10 | cifar100")
+                   help="imagefolder | synthetic | plc | cifar10 | cifar100 | "
+                        "tokens (--train_dir names a flat file of int32 "
+                        "ids, cut into rows of --seq_len + 1)")
     d.add_argument("--synthetic_size", type=int, default=0,
                    help="train-set size for --dataset synthetic (default "
                         "512); drills shrink it so multi-process restart "
@@ -83,7 +86,11 @@ def build_parser() -> argparse.ArgumentParser:
     m = p.add_argument_group("model")
     m.add_argument("--model", "--arch", dest="model", default="",
                    help="resnet18/34/50/101/152 | vgg19_bn | tresnet_m | "
-                        "vit_t16/s16/b16 (reference --model + extensions)")
+                        "vit_t16/s16/b16 (reference --model + extensions) | "
+                        "decoder_lm (a token decoder: next-token training "
+                        "as per-position classification; sizes in the "
+                        "'decoder' group, defaults = the published "
+                        "SmallThinker-21BA3B-Instruct)")
     m.add_argument("--flash_attention", action="store_true",
                    help="ViT: Pallas streaming attention kernel for the "
                         "unsharded path")
@@ -117,6 +124,25 @@ def build_parser() -> argparse.ArgumentParser:
                    help="multistep milestones (NESTED/train.py:472)")
     o.add_argument("--warmUpIter", type=int, default=-1,
                    help="linear warmup iterations (NESTED/train.py:466)")
+    o.add_argument("--adam_b2", type=float, default=-1.0,
+                   help="Adam's second-moment decay (default 0.999)")
+
+    dec = p.add_argument_group(
+        "decoder", "sizes of --model decoder_lm (config.DecoderConfig); "
+        "--num_classes follows --vocab_size")
+    for flag, kind in (("vocab_size", int), ("hidden_size", int),
+                       ("num_layers", int), ("num_heads", int),
+                       ("num_kv_heads", int), ("head_dim", int),
+                       ("expert_width", int), ("num_experts", int),
+                       ("experts_held", int), ("first_expert", int),
+                       ("top_k", int), ("window", int), ("seq_len", int),
+                       ("head_block", int), ("rope_theta", float),
+                       ("rms_eps", float)):
+        dec.add_argument(f"--{flag}", type=kind, default=None)
+    for flag in ("rope_layout", "window_layout"):
+        dec.add_argument(f"--{flag}", default=None,
+                         help="comma-separated 0/1 per layer, repeated to "
+                              "the depth (e.g. 0,1,1,1)")
 
     a = p.add_argument_group("arcface")
     a.add_argument("--arc_s", type=float, default=-1.0)
@@ -368,6 +394,20 @@ def config_from_args(args: argparse.Namespace) -> Config:
         cfg.optim.milestones = tuple(args.lrSchedule)
     if args.warmUpIter >= 0:
         cfg.optim.warmup_iters = args.warmUpIter
+    if args.adam_b2 >= 0:
+        cfg.optim.adam_b2 = args.adam_b2
+    dc = cfg.model.decoder
+    for f in dataclasses.fields(dc):
+        value = getattr(args, f.name, None)
+        if value is not None:
+            setattr(dc, f.name, tuple(int(x) for x in value.split(","))
+                    if f.name.endswith("_layout") else value)
+    if cfg.model.arch == "decoder_lm":
+        if args.num_classes and args.num_classes != dc.vocab_size:
+            raise ValueError(
+                f"--num_classes {args.num_classes} != --vocab_size "
+                f"{dc.vocab_size}: the decoder classifies over its vocabulary")
+        cfg.data.num_classes = dc.vocab_size
     if args.noise_rate >= 0:
         cfg.optim.noise_rate = args.noise_rate
     if args.num_gradual >= 0:

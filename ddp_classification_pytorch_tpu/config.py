@@ -68,6 +68,50 @@ class DataConfig:
 
 
 @dataclass
+class DecoderConfig:
+    """Sizes of the token decoder (`--model decoder_lm`, models/decoder_lm.py)
+    — the one place they live; the CLI fills it. Defaults are the published
+    SmallThinker-21BA3B-Instruct config.json (PowerInfer, arXiv:2507.20984).
+
+    A deployment that spreads a layer's experts and the vocabulary's rows
+    over several chips gives each chip its share: `experts_held` experts
+    starting at `first_expert` (the router keeps its full width
+    `num_experts` and its `top_k`), and `vocab_size` rows of the vocabulary
+    (embedding, head, loss and token ids are over the slice)."""
+
+    vocab_size: int = 151936
+    hidden_size: int = 2560
+    num_layers: int = 52
+    num_heads: int = 28
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    expert_width: int = 768          # moe_ffn_hidden_size (ReGLU)
+    num_experts: int = 64            # router width
+    experts_held: int = 0            # 0 = all of them
+    first_expert: int = 0
+    top_k: int = 6
+    # per-layer 0/1 lists, repeated to num_layers: rotary embedding or none
+    # (NoPE), sliding window or full causal
+    rope_layout: Sequence[int] = (0, 1, 1, 1)
+    window_layout: Sequence[int] = (0, 1, 1, 1)
+    window: int = 4096
+    rope_theta: float = 1.5e6
+    rms_eps: float = 1e-6
+    seq_len: int = 8192              # tokens per row (the file holds T + 1)
+    # rows of (B·T) the head and its loss take at a time, so that float32
+    # logits over the vocabulary never stand whole (ops/lm_head.py)
+    head_block: int = 2048
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    def layout(self, which: Sequence[int]) -> tuple:
+        """A layout list repeated to the depth."""
+        return tuple(int(which[i % len(which)]) for i in range(self.num_layers))
+
+
+@dataclass
 class ModelConfig:
     """Backbone + head selection.
 
@@ -130,6 +174,8 @@ class ModelConfig:
     # #5; A/B harness scripts/ab_vit_perf.py). Off = the standard
     # f32-LN recipe every convergence record uses.
     ln_bf16: bool = False
+    # arch == "decoder_lm": every size of the token decoder
+    decoder: DecoderConfig = field(default_factory=DecoderConfig)
 
 
 @dataclass
@@ -146,6 +192,7 @@ class OptimConfig:
     optimizer: str = "sgd"  # sgd | adam
     lr: float = 1e-3
     momentum: float = 0.9
+    adam_b2: float = 0.999  # optax's default; language-model recipes use 0.95
     weight_decay: float = 0.0
     # Per-group hyperparameters for the head param group (ArcFace margin
     # head — the reference builds ONE optimizer over TWO param groups,

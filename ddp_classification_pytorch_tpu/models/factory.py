@@ -83,6 +83,35 @@ def build_backbone(cfg: ModelConfig, num_classes: int = 0,
     raise ValueError(f"unknown arch {cfg.arch!r}")
 
 
+def build_decoder_lm(cfg: ModelConfig, num_classes: int,
+                     mesh: Optional[Any] = None) -> nn.Module:
+    """The token decoder (models/decoder_lm.py): a per-token classifier over
+    the vocabulary, so `num_classes` IS its vocabulary (the CLI keeps the two
+    equal). A `model` mesh axis > 1 shards the expert banks over it."""
+    from ..parallel.mesh import MODEL_AXIS
+    from .decoder_lm import DecoderLM
+
+    dc = cfg.decoder
+    if cfg.head != "fc":
+        raise ValueError(f"decoder_lm trains with head='fc', got {cfg.head!r}")
+    if num_classes != dc.vocab_size:
+        raise ValueError(
+            f"decoder_lm classifies over its vocabulary: num_classes "
+            f"{num_classes} != vocab_size {dc.vocab_size}")
+    if dc.num_heads % dc.num_kv_heads:
+        raise ValueError(f"{dc.num_heads} query heads do not divide over "
+                         f"{dc.num_kv_heads} KV heads")
+    if dc.first_expert + dc.held > dc.num_experts:
+        raise ValueError(
+            f"experts {dc.first_expert}..{dc.first_expert + dc.held - 1} are "
+            f"not among the router's {dc.num_experts}")
+    mp = mesh.shape.get(MODEL_AXIS, 1) if mesh is not None else 1
+    return DecoderLM(dc, dtype=jnp.dtype(cfg.dtype), remat=cfg.remat,
+                     mesh=mesh if mp > 1 else None,
+                     expert_axis=MODEL_AXIS if mp > 1 else None,
+                     flash_min_tokens=cfg.flash_min_tokens)
+
+
 class ClassifierModel(nn.Module):
     """backbone → logits (BASELINE/CDR shape)."""
 
@@ -174,6 +203,8 @@ def build_model(cfg: ModelConfig, num_classes: int,
             cfg.arch, num_classes, mesh, pipeline_microbatches,
             dtype=jnp.dtype(cfg.dtype), axis_name=pipe_axis, remat=cfg.remat,
             ln_bf16=cfg.ln_bf16)
+    if cfg.arch == "decoder_lm":
+        return build_decoder_lm(cfg, num_classes, mesh)
     if cfg.head == "fc":
         return ClassifierModel(build_backbone(cfg, num_classes, axis_name, mesh))
     if cfg.head == "arcface":

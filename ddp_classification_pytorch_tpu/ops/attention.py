@@ -50,22 +50,38 @@ def attention(
     v: jnp.ndarray,
     causal: bool = False,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Dense scaled-dot-product attention.
 
-    q, k, v: (B, T, H, D). Returns (B, T, H, D) in q.dtype. Softmax in f32.
+    q: (B, T, H, D); k, v: (B, T, H_kv, D) with H a multiple of H_kv (query
+    head h reads KV head h // (H / H_kv); no repeated K/V is built).
+    Returns (B, T, H, D) in q.dtype. Softmax in f32. `window` (causal only)
+    keeps keys j with i − window < j ≤ i.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
+    if window is not None and not causal:
+        raise ValueError("attention: a window needs causal=True")
+    b, t, h, d = q.shape
+    grouped = k.shape[2] != h
+    if grouped:
+        q = q.reshape(b, t, k.shape[2], h // k.shape[2], d)
+    qk, pv = (("bqhgd,bkhd->bhgqk", "bhgqk,bkhd->bqhgd") if grouped
+              else ("bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd"))
+    s = jnp.einsum(qk, q, k, preferred_element_type=jnp.float32) * scale
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(tk)[None, :] <= jnp.arange(tq)[:, None]
+        rows, cols = jnp.arange(tq)[:, None], jnp.arange(tk)[None, :]
+        mask = cols <= rows
+        if window is not None:
+            mask &= cols > rows - window
         s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+    out = jnp.einsum(pv, p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
+    if grouped:
+        out = out.reshape(b, t, h, d)
     return out.astype(q.dtype)
 
 

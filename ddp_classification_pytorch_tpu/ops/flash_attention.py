@@ -27,7 +27,15 @@ the standard flash-attention memory shape, expressed the Pallas/Mosaic way
 - the O(T·D) guarantee holds for token counts the kernels tile cleanly
   (T ≤ 512 or any multiple of 128 — every ViT in models/vit.py); other T
   route to the dense op, which materializes the (T, T) scores in both
-  passes (see `_supported`).
+  passes (see `_supported`);
+- `window` (causal only) keeps keys j with i − window < j ≤ i: tiles that
+  lie wholly outside that band are skipped on BOTH sides in all three
+  kernels (compute by `pl.when`, DMA by clamping the streamed block's index
+  to the band), so a window layer costs its band, not the triangle;
+- grouped KV heads (H_q = g·H_kv, models/decoder_lm.py): the K/V index maps
+  read block `i // g` for query head `i`, and the dK/dV kernel walks the g
+  query heads of its KV head in a second sequential grid dimension — no
+  repeated K/V is ever materialized.
 """
 
 from __future__ import annotations
@@ -38,6 +46,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -69,16 +78,44 @@ def _block(t: int, cap: int = 1024) -> int:
 # forward
 # ---------------------------------------------------------------------------
 
-def _causal_mask(bq: int, bk: int, jq, jk):
+def _causal_mask(bq: int, bk: int, jq, jk, window=None):
     """(bq, bk) bool, True where query row ≥ key col in GLOBAL indices for
-    q-block jq / kv-block jk (block-local iota + block offsets)."""
+    q-block jq / kv-block jk (block-local iota + block offsets) and, with
+    `window`, also col > row − window."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + jq * bq
     cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + jk * bk
-    return rows >= cols
+    allowed = rows >= cols
+    if window is not None:
+        allowed &= cols > rows - window
+    return allowed
+
+
+def _tile_live(bq: int, bk: int, jq, jk, window=None):
+    """Whether tile (q-block jq, kv-block jk) holds any allowed pair: its
+    first col ≤ its last row and, with `window`, its last col > its first
+    row − window."""
+    live = jk * bk < (jq + 1) * bq
+    if window is not None:
+        live &= (jk + 1) * bk - 1 > jq * bq - window
+    return live
+
+
+def _first_kv_block(bq: int, bk: int, jq, window):
+    """Lowest live kv-block of q-block jq (0 without a window)."""
+    if window is None:
+        return 0
+    return jnp.maximum(jq * bq - window + 1, 0) // bk
+
+
+def _last_q_block(bq: int, bk: int, jk, nq: int, window):
+    """Highest live q-block of kv-block jk (the last one without a window)."""
+    if window is None:
+        return nq - 1
+    return jnp.minimum(((jk + 1) * bk + window - 2) // bq, nq - 1)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                  *, scale, nk, causal):
+                  *, scale, nk, causal, window=None):
     """One (batch·head, q-block, kv-block) grid step.
 
     The kv axis is the LAST grid dimension — sequential on TPU — so the
@@ -105,7 +142,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale          # (bq, bk)
         if causal:
-            allowed = _causal_mask(bq, bk, jq, kk)
+            allowed = _causal_mask(bq, bk, jq, kk, window)
             s = jnp.where(allowed, s, _NEG_INF)
         m = m_scr[:]
         m_cur = jnp.max(s, axis=-1, keepdims=True)               # (bq, 1)
@@ -116,6 +153,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         # finite after the first step and exp(−NEG_INF − m) underflows to
         # exactly 0.
         p = jnp.exp(s - m_new[:, :1])                            # (bq, bk)
+        if window is not None:
+            # the first live tile of a window band can hold rows with no
+            # allowed column yet (m_new still −1e30, so exp(s − m_new) = 1
+            # on masked entries): those have to be zeroed explicitly
+            p = jnp.where(allowed, p, 0.0)
         l_new = l_scr[:] * corr + jnp.broadcast_to(
             jnp.sum(p, axis=-1, keepdims=True), (bq, _LANES))
         pv = jax.lax.dot_general(
@@ -130,7 +172,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         # FLOPs. The K/V index maps clamp to the diagonal block for these
         # steps, so the already-resident tile is re-referenced and the DMA
         # is elided too (halved HBM traffic).
-        pl.when(kk * bk < (jq + 1) * bq)(_update)
+        pl.when(_tile_live(bq, bk, jq, kk, window))(_update)
     else:
         _update()
 
@@ -140,9 +182,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[0] = m_scr[:, :1] + jnp.log(l_scr[:, :1])
 
 
-def _flash_forward(q3, k3, v3, scale, causal=False):
-    """(bh, T, D) ×3 → (out (bh, T, D), lse (bh, T, 1) f32)."""
+def _flash_forward(q3, k3, v3, scale, causal=False, window=None):
+    """q (bh, T, D), k/v (bh // g, T, D) → (out (bh, T, D), lse (bh, T, 1)
+    f32); g query heads share each KV head."""
     bh, t, d = q3.shape
+    group = bh // k3.shape[0]
     # cap 512 matches the backward's VMEM reasoning: at 1024 blocks with
     # d=128, the (bq, bk) f32 score+probability tiles (~8 MB) plus operands
     # and double-buffered K/V approach the 16 MB budget on some generations
@@ -154,12 +198,14 @@ def _flash_forward(q3, k3, v3, scale, causal=False):
         # the fetched kv block to the diagonal makes those steps re-request
         # the resident tile, so their DMA is elided as well (bq == bk by
         # construction of _block).
-        kv_idx = lambda i, j, kk: (i, jnp.minimum(kk, j), 0)  # noqa: E731
+        kv_idx = lambda i, j, kk: (  # noqa: E731
+            i // group,
+            jnp.clip(kk, _first_kv_block(bq, bk, j, window), j), 0)
     else:
-        kv_idx = lambda i, j, kk: (i, kk, 0)  # noqa: E731
+        kv_idx = lambda i, j, kk: (i // group, kk, 0)  # noqa: E731
     return pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, nk=t // bk,
-                          causal=causal),
+                          causal=causal, window=window),
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
             jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
@@ -183,6 +229,7 @@ def _flash_forward(q3, k3, v3, scale, causal=False):
             pltpu.VMEM((bq, d), jnp.float32),        # output accumulator
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(q3, k3, v3)
 
 
@@ -191,7 +238,7 @@ def _flash_forward(q3, k3, v3, scale, causal=False):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref,
-               dq_scr, *, scale, nk, causal):
+               dq_scr, *, scale, nk, causal, window=None):
     """Grid (bh, q-block, kv-block): stream K/V past a fixed q block,
     accumulating dQ = Σ_k dS·K·scale in VMEM scratch."""
     jq, kk = pl.program_id(1), pl.program_id(2)
@@ -216,7 +263,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref,
             # lse is finite, so exp(−NEG_INF − lse) underflows to exactly
             # 0 — masking s alone zeroes P (and thus dS) on forbidden
             # entries.
-            s = jnp.where(_causal_mask(bq, bk, jq, kk), s, _NEG_INF)
+            s = jnp.where(_causal_mask(bq, bk, jq, kk, window), s, _NEG_INF)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(
             do, vb, (((1,), (1,)), ((), ())),
@@ -227,7 +274,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref,
             preferred_element_type=jnp.float32) * scale
 
     if causal:
-        pl.when(kk * bk < (jq + 1) * bq)(_update)  # skip fully-future tiles
+        pl.when(_tile_live(bq, bk, jq, kk, window))(_update)
     else:
         _update()
 
@@ -237,15 +284,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref,
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, scale, nq, causal):
-    """Grid (bh, kv-block, q-block): stream Q/dO past a fixed kv block,
-    accumulating dK = Σ_q dSᵀ·Q·scale and dV = Σ_q Pᵀ·dO in VMEM scratch."""
-    jk, qq = pl.program_id(1), pl.program_id(2)
+                dk_ref, dv_ref, dk_scr, dv_scr, *, scale, nq, causal,
+                window=None, group=1):
+    """Grid (kv heads, kv-block, query head of the group, q-block): stream
+    the Q/dO of every query head that reads this KV head past a fixed kv
+    block, accumulating dK = Σ_q dSᵀ·Q·scale and dV = Σ_q Pᵀ·dO in VMEM
+    scratch (both trailing grid dimensions are sequential)."""
+    jk, gg, qq = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     kb = k_ref[0]                               # (bk, D) input dtype
     bk, d = kb.shape
     bq = q_ref.shape[1]
 
-    @pl.when(qq == 0)
+    @pl.when((gg == 0) & (qq == 0))
     def _init():
         dk_scr[:] = jnp.zeros((bk, d), jnp.float32)
         dv_scr[:] = jnp.zeros((bk, d), jnp.float32)
@@ -261,7 +311,7 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref,
             preferred_element_type=jnp.float32) * scale          # (bq, bk)
         if causal:
             # q-block index is the LAST grid dim here; kv-block is dim 1
-            s = jnp.where(_causal_mask(bq, bk, qq, jk), s, _NEG_INF)
+            s = jnp.where(_causal_mask(bq, bk, qq, jk, window), s, _NEG_INF)
         p = jnp.exp(s - lse)
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -275,39 +325,48 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref,
             preferred_element_type=jnp.float32) * scale
 
     if causal:
-        pl.when(jk * bk < (qq + 1) * bq)(_update)  # skip fully-future tiles
+        pl.when(_tile_live(bq, bk, qq, jk, window))(_update)
     else:
         _update()
 
-    @pl.when(qq == nq - 1)
+    @pl.when((gg == group - 1) & (qq == nq - 1))
     def _write():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_backward_impl(q3, k3, v3, do3, lse, dsum, scale, causal=False):
-    """(bh, T, D) q/k/v/dO + (bh, T, 1) lse/Δ → (dq, dk, dv), O(T·D) HBM.
+def _flash_backward_impl(q3, k3, v3, do3, lse, dsum, scale, causal=False,
+                         window=None):
+    """(bh, T, D) q/dO, (bh // g, T, D) k/v + (bh, T, 1) lse/Δ → (dq, dk,
+    dv), O(T·D) HBM.
 
     The score tile is recomputed per block pair in both kernels; the only
     HBM residuals are out/lse from the forward. Blocks are capped at 512 so
     the (bq, bk) f32 score/probability tiles plus the (block, D) operand
     tiles fit VMEM alongside the accumulators."""
     bh, t, d = q3.shape
+    bh_kv = k3.shape[0]
+    group = bh // bh_kv
     bq = _block(t, cap=512)
     bk = _block(t, cap=512)
     nq, nk = t // bq, t // bk
 
     if causal:
         # Same DMA-elision trick as the forward: compute-skipped steps
-        # re-request the diagonal block (bq == bk by construction).
-        kv_idx = lambda i, j, kk: (i, jnp.minimum(kk, j), 0)  # noqa: E731
-        q_row_idx = lambda i, j, qq: (i, jnp.maximum(qq, j), 0)  # noqa: E731
+        # re-request a block of the band (bq == bk by construction).
+        kv_idx = lambda i, j, kk: (  # noqa: E731
+            i // group,
+            jnp.clip(kk, _first_kv_block(bq, bk, j, window), j), 0)
+        q_row_idx = lambda i, j, g, qq: (  # noqa: E731
+            i * group + g,
+            jnp.clip(qq, j, _last_q_block(bq, bk, j, nq, window)), 0)
     else:
-        kv_idx = lambda i, j, kk: (i, kk, 0)  # noqa: E731
-        q_row_idx = lambda i, j, qq: (i, qq, 0)  # noqa: E731
+        kv_idx = lambda i, j, kk: (i // group, kk, 0)  # noqa: E731
+        q_row_idx = lambda i, j, g, qq: (i * group + g, qq, 0)  # noqa: E731
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, nk=nk, causal=causal),
+        functools.partial(_dq_kernel, scale=scale, nk=nk, causal=causal,
+                          window=window),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
         grid=(bh, nq, nk),
         in_specs=[
@@ -326,19 +385,21 @@ def _flash_backward_impl(q3, k3, v3, do3, lse, dsum, scale, causal=False):
                                memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_dq",
     )(q3, k3, v3, do3, lse, dsum)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, nq=nq, causal=causal),
+        functools.partial(_dkv_kernel, scale=scale, nq=nq, causal=causal,
+                          window=window, group=group),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), v3.dtype),
+            jax.ShapeDtypeStruct((bh_kv, t, d), k3.dtype),
+            jax.ShapeDtypeStruct((bh_kv, t, d), v3.dtype),
         ],
-        grid=(bh, nk, nq),
+        grid=(bh_kv, nk, group, nq),
         in_specs=[
-            pl.BlockSpec((1, bk, d), lambda i, j, qq: (i, j, 0),
+            pl.BlockSpec((1, bk, d), lambda i, j, g, qq: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda i, j, qq: (i, j, 0),
+            pl.BlockSpec((1, bk, d), lambda i, j, g, qq: (i, j, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bq, d), q_row_idx, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bq, d), q_row_idx, memory_space=pltpu.VMEM),
@@ -346,9 +407,9 @@ def _flash_backward_impl(q3, k3, v3, do3, lse, dsum, scale, causal=False):
             pl.BlockSpec((1, bq, 1), q_row_idx, memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda i, j, qq: (i, j, 0),
+            pl.BlockSpec((1, bk, d), lambda i, j, g, qq: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda i, j, qq: (i, j, 0),
+            pl.BlockSpec((1, bk, d), lambda i, j, g, qq: (i, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         scratch_shapes=[
@@ -356,6 +417,7 @@ def _flash_backward_impl(q3, k3, v3, do3, lse, dsum, scale, causal=False):
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_dkv",
     )(k3, v3, q3, do3, lse, dsum)
     return dq, dk, dv
 
@@ -376,21 +438,29 @@ def _to4(x3, b, h):
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     scale: Optional[float] = None,
-                    causal: bool = False) -> jnp.ndarray:
+                    causal: bool = False,
+                    window: Optional[int] = None) -> jnp.ndarray:
     """Scaled-dot-product attention, (B, T, H, D) → (B, T, H, D), optionally
-    causal (row i attends keys ≤ i, matching ops/attention.py::attention).
+    causal (row i attends keys ≤ i, matching ops/attention.py::attention)
+    and, with `window`, banded (keys i − window < j ≤ i). k/v may carry
+    fewer heads than q (H_q a multiple of H_kv: grouped-query attention).
 
     Forward and backward are both Pallas streaming kernels: O(T·D) HBM
     traffic, no (T, T) tensor materialized in either pass. Token counts
     the kernels cannot tile cleanly (see `_supported`) fall back to the
     framework's dense op — same math, same signature.
     """
-    if q.shape != k.shape or q.shape != v.shape:
+    if (k.shape != v.shape or q.shape[:2] != k.shape[:2]
+            or q.shape[3] != k.shape[3] or q.shape[2] % k.shape[2]):
         # Self-attention kernel: one T for q and kv. Without this check a
         # shorter k/v would silently read clamped (repeated) tail blocks.
         raise ValueError(
-            f"flash_attention requires q/k/v of equal shape, got "
-            f"{q.shape}/{k.shape}/{v.shape}")
+            f"flash_attention requires q/k/v of equal shape (or k/v with a "
+            f"divisor of q's heads), got {q.shape}/{k.shape}/{v.shape}")
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
+    if window is not None and window >= q.shape[1]:
+        window = None  # the band is the whole triangle
     if not _supported(q.shape[1]):
         from .attention import attention
 
@@ -400,26 +470,31 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             f"flash_attention: T={q.shape[1]} is not kernel-tileable "
             "(need T <= 512 or a multiple of 128); falling through to the "
             "dense op ops.attention.attention", stacklevel=2)
-        return attention(q, k, v, causal=causal, scale=scale)
-    return _flash(q, k, v, scale, causal)
+        return attention(q, k, v, causal=causal, scale=scale, window=window)
+    return _flash(q, k, v, scale, causal, window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, scale, causal):
-    return _fa_fwd(q, k, v, scale, causal)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, scale, causal, window=None):
+    return _fa_fwd(q, k, v, scale, causal, window)[0]
 
 
-def _fa_fwd(q, k, v, scale, causal):
+def _fa_fwd(q, k, v, scale, causal, window=None):
     s = scale if scale is not None else q.shape[-1] ** -0.5
     b, _, h, _ = q.shape
     q3, k3, v3 = _to3(q), _to3(k), _to3(v)
-    out3, lse = _flash_forward(q3, k3, v3, s, causal)
+    out3, lse = _flash_forward(q3, k3, v3, s, causal, window)
+    # names for a rematerialization policy: a caller that saves these two
+    # (`jax.checkpoint_policies.save_only_these_names`) does not run the
+    # forward kernel again in its backward pass
+    out3 = checkpoint_name(out3, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     # Residuals keep the 3D views the backward kernels consume directly —
     # saving the 4D originals instead would re-pay three transpose passes.
     return _to4(out3, b, h), (q3, k3, v3, out3, lse)
 
 
-def _fa_bwd(scale, causal, res, g):
+def _fa_bwd(scale, causal, window, res, g):
     q3, k3, v3, out3, lse = res
     # Re-resolve from the static nondiff arg: the kernels bake `scale` into
     # their compiled body, so it must stay a Python float, not a residual
@@ -432,8 +507,9 @@ def _fa_bwd(scale, causal, res, g):
     dsum = jnp.sum(do3.astype(jnp.float32) * out3.astype(jnp.float32),
                    axis=-1, keepdims=True)
     dq3, dk3, dv3 = _flash_backward_impl(q3, k3, v3, do3, lse, dsum, s,
-                                         causal)
-    return (_to4(dq3, b, h), _to4(dk3, b, h), _to4(dv3, b, h))
+                                         causal, window)
+    h_kv = k3.shape[0] // b
+    return (_to4(dq3, b, h), _to4(dk3, b, h_kv), _to4(dv3, b, h_kv))
 
 
 _flash.defvjp(_fa_fwd, _fa_bwd)

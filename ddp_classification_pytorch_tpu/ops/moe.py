@@ -23,10 +23,23 @@ first:
   axis serves ONE role per config: class-TP | SP | PP | EP).
 - Router math in f32 (softmax over expert logits); expert matmuls in the
   model's compute dtype with f32 accumulation.
+
+`sparse_moe` is the dispatch core for many narrow experts (the token
+decoder, models/decoder_lm.py): dense dispatch of top-6 of 64 would cost
+10.7x the active FLOPs. It routes over the router's full width, sorts the
+token-slots by expert, runs grouped matmuls (`jax.lax.ragged_dot`: one
+kernel over the ragged groups on the TPU) over the experts HELD HERE only,
+and combines. Dropless: the slot buffer has the static worst-case length
+(every slot on a held expert), so uneven loads lose nothing. It is told
+which experts it holds (`first_expert`, the banks' leading dimension), so
+one chip of an expert-parallel layout runs it without the exchange and a
+`model` axis > 1 runs the same function per shard with the psum above.
+The ViT's split-FFN path above still dispatches densely (ROADMAP D6).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -141,3 +154,142 @@ def moe_mlp(
         out_specs=x_spec,
     )
     return f(x, gates, w_in, b_in, w_out, b_out).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# sparse dropless dispatch over the experts held here
+# ---------------------------------------------------------------------------
+
+def route_top_k(logits: jnp.ndarray, top_k: int):
+    """(N, E) f32 router logits → (expert ids (N, k) i32, weights (N, k)
+    f32): the k largest logits and the softmax over those k (= the full
+    softmax renormalised over the chosen)."""
+    vals, idx = jax.lax.top_k(logits.astype(jnp.float32), top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+
+
+@jax.custom_vjp
+def _dispatch_rows(u, order, inv, mine):
+    """The token's row of u (N, C) for every sorted slot: slot s belongs to
+    token s % N (slots are laid out choice-major, s = j·N + n, so that
+    (S, C) ↔ (k, N, C) is a free reshape). `order` is a permutation of the
+    S = k·N slots and `inv` its inverse, so the transpose is a gather too
+    (no scatter-add): d_u[n] = Σ_j d_rows[inv[j·N + n]] over the slots
+    `mine` — the rows of the others lie past the last group, where the
+    grouped matmuls' transposes leave whatever the buffer held."""
+    return u[order % u.shape[0]]
+
+
+def _dispatch_fwd(u, order, inv, mine):
+    return u[order % u.shape[0]], (inv, mine, u.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    inv, mine, n = res
+    g = jnp.where(mine[:, None], g[inv], jnp.zeros((), g.dtype))
+    return g.reshape(-1, n, g.shape[-1]).sum(axis=0), None, None, None
+
+
+_dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _collect_slots(y, order, inv, mine):
+    """The sorted rows y (S, C) back in slot order, zero for the slots of
+    experts not held here (their rows lie past the last group and were never
+    written). Transpose: the gather g[order]; a slot not `mine` carries a
+    zero cotangent already (its gate weight is zero)."""
+    return jnp.where(mine[:, None], y[inv], jnp.zeros((), y.dtype))
+
+
+_collect_slots.defvjp(
+    lambda y, order, inv, mine: (_collect_slots(y, order, inv, mine), order),
+    lambda order, g: (g[order], None, None, None))
+
+
+def _sparse_experts(u, logits, w_gate, w_up, w_down, *, top_k, first_expert,
+                    dtype):
+    n, _ = u.shape
+    held = w_gate.shape[0]
+    with jax.named_scope("moe.route"):
+        idx, w = route_top_k(logits, top_k)
+    with jax.named_scope("moe.dispatch"):
+        local = idx.T.reshape(-1) - first_expert          # (S,) S = k·N
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)                # others sort last
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        load = (key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :]
+                ).sum(axis=0, dtype=jnp.int32)            # slots per expert
+        rows = _dispatch_rows(u.astype(dtype), order, inv, mine)
+    with jax.named_scope("moe.experts"):
+        def grouped(x, bank):
+            return jax.lax.ragged_dot(x, bank, load, preferred_element_type=dtype)
+
+        # ReGLU without bias; gate and up as one grouped matmul over the
+        # banks side by side. Rows past the last group belong to no expert
+        # held here; what the kernels leave in them is not defined and
+        # nothing below reads it
+        width = w_gate.shape[-1]
+        both = grouped(rows, jnp.concatenate(
+            [w_gate.astype(dtype), w_up.astype(dtype)], axis=-1))
+        y = grouped(jax.nn.relu(both[:, :width]) * both[:, width:],
+                    w_down.astype(dtype))
+    with jax.named_scope("moe.combine"):
+        y = _collect_slots(y, order, inv, mine).reshape(top_k, n, -1)
+        w = jnp.where(mine.reshape(top_k, n), w.T, 0.0)
+        out = (w[..., None] * y.astype(jnp.float32)).sum(axis=0)
+    return out, load
+
+
+def sparse_moe(
+    u: jnp.ndarray,
+    logits: jnp.ndarray,
+    w_gate: jnp.ndarray,
+    w_up: jnp.ndarray,
+    w_down: jnp.ndarray,
+    *,
+    top_k: int,
+    first_expert: int = 0,
+    dtype=jnp.bfloat16,
+    mesh: Optional[Mesh] = None,
+    axis: Optional[str] = None,
+    batch_axis: Optional[str] = None,
+):
+    """Sparse mixture of ReGLU experts for the tokens u (N, C).
+
+    `logits` (N, E) are the router's, over ALL E experts, handed in by the
+    caller (the decoder takes them before attention). The banks w_gate /
+    w_up (e, C, H) and w_down (e, H, C) are the e experts held here, ids
+    `first_expert .. first_expert + e − 1`; slots routed elsewhere add
+    nothing. Returns (y (N, C) f32 = Σ over the chosen held experts of
+    weight · expert(u), load (e,) i32 token-slots each held expert took).
+
+    Under a `model` axis > 1 the banks are sharded on their leading
+    dimension, every shard computes its own experts' part and one psum
+    completes the sum (`load` comes back for all the banks' experts in
+    order; with `batch_axis` the tokens stay sharded over it and the loads
+    are summed over it)."""
+    e = w_gate.shape[0]
+    if not first_expert + e <= logits.shape[-1]:
+        raise ValueError(
+            f"experts {first_expert}..{first_expert + e - 1} are not among "
+            f"the router's {logits.shape[-1]}")
+    core = functools.partial(_sparse_experts, top_k=top_k, dtype=dtype)
+    n_shards = mesh.shape[axis] if (mesh is not None and axis) else 1
+    if n_shards <= 1:
+        return core(u, logits, w_gate, w_up, w_down, first_expert=first_expert)
+    if e % n_shards:
+        raise ValueError(f"num experts {e} not divisible by axis size {n_shards}")
+
+    def body(u, logits, w_gate, w_up, w_down):
+        first = first_expert + jax.lax.axis_index(axis) * w_gate.shape[0]
+        part, load = core(u, logits, w_gate, w_up, w_down, first_expert=first)
+        if batch_axis:
+            load = jax.lax.psum(load, batch_axis)
+        return jax.lax.psum(part, axis), load             # EP combine
+
+    bank, rows = P(axis, None, None), P(batch_axis, None)
+    return shard_map_unchecked(
+        body, mesh=mesh, in_specs=(rows, rows, bank, bank, bank),
+        out_specs=(rows, P(axis)))(u, logits, w_gate, w_up, w_down)

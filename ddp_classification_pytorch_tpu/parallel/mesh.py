@@ -253,7 +253,8 @@ def _spec_for_param(path: str, value: Any, model_axis_size: int,
     if "margin" in path and path.endswith("weight']") and value.ndim == 2:
         return P(MODEL_AXIS, None)
     if any(f"'{name}'" in path for name in
-           ("moe_w_in", "moe_b_in", "moe_w_out", "moe_b_out")) and (
+           ("moe_w_in", "moe_b_in", "moe_w_out", "moe_b_out",
+            "w_gate", "w_up", "w_down")) and (
             value.shape[0] % model_axis_size == 0):
         # Exactly the MoE expert banks (E, ...) — matched by name, not by a
         # 'moe_' substring, so a future moe_-prefixed non-bank param can't be

@@ -106,6 +106,12 @@ def build_datasets(cfg: Config) -> Tuple[Any, Any]:
                                d.num_classes, seed=cfg.run.seed,
                                item_offset=size, out_dtype=d.input_dtype)
         return train, val
+    if d.dataset == "tokens":
+        from ..data.tokens import TokenDataset
+
+        seq_len = cfg.model.decoder.seq_len
+        return (TokenDataset(d.train_dir, seq_len),
+                TokenDataset(d.val_dir or d.train_dir, seq_len))
     preset = dataset_transform_preset(d)
     if preset is None:
         raise ValueError(f"unknown dataset {d.dataset!r}")
@@ -473,7 +479,10 @@ class Trainer:
             # the only host sync per log_every steps (reference syncs .item()
             # on the same cadence, BASELINE:284-303)
             eta.maybe_log(epoch, step,
-                          **{k: float(v) for k, v in metrics.items()})
+                          **{k: float(v) for k, v in metrics.items()
+                             if v.ndim == 0})
+        if "moe_load" in metrics:
+            self._publish_moe_load(np.asarray(metrics["moe_load"]))
         # flush is a device round-trip too, so reaching here is proof the
         # backend is answering — heartbeat it. It also raises
         # SentinelDiverged on a sustained-NaN streak (pod mode: noted as
@@ -493,6 +502,26 @@ class Trainer:
         # refresh the scrape file on the same cadence (atomic rewrite; host 0
         # only)
         self._write_prom()
+
+    def _publish_moe_load(self, load: np.ndarray) -> None:
+        """The logged step's routing, as the step's metrics carry it —
+        `load` (L, e): token-slots each held expert took in each layer."""
+        for i, row in enumerate(load):
+            layer = {"layer": str(i)}
+            self.obs.gauge("moe_expert_load_max", "token-slots of the "
+                           "busiest held expert in the logged step",
+                           layer).set(float(row.max()))
+            self.obs.gauge("moe_expert_load_mean", "mean token-slots of a "
+                           "held expert in the logged step",
+                           layer).set(float(row.mean()))
+        dc = self.cfg.model.decoder
+        routed = float(self.cfg.data.batch_size * jax.process_count()
+                       * dc.seq_len * dc.top_k * dc.num_layers)
+        for held, n in (("true", float(load.sum())),
+                        ("false", routed - float(load.sum()))):
+            self.obs.counter("moe_slots_routed_total", "token-slots the "
+                             "routers of the logged steps sent to experts "
+                             "held here / elsewhere", {"held": held}).inc(n)
 
     def train_epoch(self, epoch: int, eta: Optional[EtaLogger] = None) -> Dict[str, float]:
         self.train_loader.set_epoch(epoch)
@@ -536,7 +565,8 @@ class Trainer:
         if sums is None:
             return {"loss": 0.0, "top1": 0.0, "top3": 0.0,
                     "step_ok": 1.0, "grad_norm": 0.0}
-        out = {k: float(v) / n_batches for k, v in sums.items()}  # host sync
+        out = {k: float(v) / n_batches for k, v in sums.items()
+               if v.ndim == 0}  # host sync; per-expert arrays stay out
         self._heartbeat.touch()
         return out
 

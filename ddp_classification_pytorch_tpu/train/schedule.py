@@ -73,7 +73,7 @@ def _group_tx(cfg: OptimConfig, schedule) -> optax.GradientTransformation:
     if cfg.optimizer == "sgd":
         base = optax.sgd(schedule, momentum=cfg.momentum)
     elif cfg.optimizer == "adam":
-        base = optax.adam(schedule)
+        base = optax.adam(schedule, b2=cfg.adam_b2)
     else:
         raise ValueError(f"unknown optimizer {cfg.optimizer!r}")
     if cfg.weight_decay:

@@ -59,6 +59,9 @@ def create_train_state(
 
     h = w = cfg.data.image_size
     img = jnp.zeros((2, h, w, 3), jnp.float32)
+    if cfg.model.arch == "decoder_lm":
+        # token ids; parameters do not depend on T, so a few positions do
+        img = jnp.zeros((2, min(cfg.model.decoder.seq_len, 8)), jnp.int32)
     rngs = {"params": p_rng, "dropout": d_rng}
     if cfg.model.head == "arcface":
         variables = model.init(rngs, img, jnp.zeros((2,), jnp.int32), train=False)

@@ -111,7 +111,7 @@ def _cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
 
 
 def _train_metrics(loss, logits, labels) -> Dict[str, jnp.ndarray]:
-    n = labels.shape[0]
+    n = labels.size
     return {
         "loss": loss,
         "top1": topk_correct(logits, labels, 1) / n,
@@ -221,6 +221,16 @@ def make_train_step(
         grad_section = _accum_grad_section(cfg, mesh, grad_accum,
                                            jnp.float32)
 
+    if cfg.model.arch == "decoder_lm":
+        if grad_section is not None:
+            raise ValueError(
+                "decoder_lm takes its head and loss in row blocks inside the "
+                "step; grad_accum > 1 and grad_reduce_dtype=bfloat16 (the "
+                "shard_map gradient sections) do not carry that loss yet")
+        loss_fn, metrics_fn = _lm_loss(cfg, model)
+        return _build_step(tx, base_rng, loss_fn, metrics_fn, chaos=chaos,
+                           flip=False, mesh=mesh, zero=zero)
+
     return _build_step(tx, base_rng, _dense_loss_fn(cfg, model),
                        lambda loss, logits, labels: _train_metrics(loss, logits, labels),
                        chaos=chaos, flip=flip, mesh=mesh, zero=zero,
@@ -293,6 +303,43 @@ def _dense_loss_fn(cfg: Config, model: Any):
         return loss, (mutated.get("batch_stats", batch_stats), logits)
 
     return loss_fn
+
+
+def _lm_sums(cfg: Config, model: Any, params, tokens, targets, train: bool,
+             weights=None):
+    """Token decoder: Σ cross-entropy, top-1 and top-3 counts over every
+    position (each scaled by `weights`), and the layers' expert loads — the
+    hidden states go through head and loss in row blocks."""
+    from ..ops.lm_head import blocked_cross_entropy
+
+    hidden, load = model.apply({"params": params}, tokens, train=train,
+                               method="hidden")
+    with jax.named_scope("lm_head"):
+        ce, t1, t3 = blocked_cross_entropy(
+            hidden.reshape(-1, hidden.shape[-1]), params["lm_head"]["kernel"],
+            targets.reshape(-1), cfg.model.decoder.head_block,
+            jnp.dtype(cfg.model.dtype), weights=weights)
+    return ce, t1, t3, load
+
+
+def _lm_loss(cfg: Config, model: Any):
+    """Loss/metrics pair of the token decoder (models/decoder_lm.py): mean
+    next-token cross-entropy over every position, head and loss in row
+    blocks (ops/lm_head.py — the (B·T, V) float32 logits never stand whole).
+    `images` are token ids (B, T) and `labels` the same rows shifted by one.
+    The step's metrics also carry `moe_load` (L, e): the token-slots each
+    held expert took in each layer — the loop's gauges and the benchmark's
+    imbalance metric read it; nothing in the step depends on it."""
+    def loss_fn(params, batch_stats, tokens, targets, rng):
+        ce, t1, t3, load = _lm_sums(cfg, model, params, tokens, targets, True)
+        return ce / targets.size, (batch_stats, (t1, t3, load))
+
+    def metrics_fn(loss, aux, labels):
+        t1, t3, load = aux
+        return {"loss": loss, "top1": t1 / labels.size, "top3": t3 / labels.size,
+                "moe_load": load.astype(jnp.float32)}
+
+    return loss_fn, metrics_fn
 
 
 def make_phase_probes(
@@ -723,6 +770,17 @@ def make_eval_step(
     if workload == "arcface" and cfg.parallel.arcface_sharded_ce:
         _require_sharded_ce_mesh(mesh)
         return _make_arcface_sharded_eval(cfg, model, mesh)
+    if cfg.model.arch == "decoder_lm":
+        def lm_step(state: TrainState, tokens: jnp.ndarray,
+                    targets: jnp.ndarray, valid: jnp.ndarray):
+            """Counts over every position of the rows where valid == 1."""
+            per_token = jnp.repeat(valid, targets.shape[1])
+            ce, t1, t3, _ = _lm_sums(cfg, model, state.params, tokens,
+                                     targets, False, weights=per_token)
+            return {"loss_sum": ce, "top1": t1, "top3": t3,
+                    "n": per_token.sum()}
+
+        return jax.jit(lm_step)  # no donation: state live across val batches
 
     def step(state: TrainState, images: jnp.ndarray, labels: jnp.ndarray,
              valid: jnp.ndarray):

@@ -244,3 +244,82 @@ def test_resnet50_dp4_step(topo):
     args = compiled.memory_analysis().argument_size_in_bytes
     assert args < replicated - 0.5 * leaf_bytes(state.opt_state), (
         args, replicated)
+
+
+# ------------------------------------------------- the token decoder (PR 28) --
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["causal", "window4096"])
+def test_flash_attention_grouped_heads_and_window_compile(one_chip, kernels, window):
+    """SmallThinker's attention at its published widths: 28 query heads on 4
+    KV heads of 128, 8,192 tokens, full causal or a window of 4,096."""
+    _, fa = kernels
+    q = jax.ShapeDtypeStruct((1, 8192, 28, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16, sharding=one_chip)
+
+    def fwd_and_vjp(q, k, v):
+        def loss(q, k, v):
+            out = fa.flash_attention(q, k, v, causal=True, window=window)
+            return jnp.sum(out.astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(fwd_and_vjp, q, kv, kv)
+    assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
+
+
+def test_sparse_experts_compile_to_grouped_matmul_kernels(one_chip):
+    """The expert layer at the published widths, one chip's 16 of 64
+    experts, 16,384 tokens, top-6: the grouped matmuls lower to the
+    compiler's ragged-dot kernels (not to a dense masked product)."""
+    from ddp_classification_pytorch_tpu.ops.moe import sparse_moe
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    u, logits = sds((16384, 2560), jnp.bfloat16), sds((16384, 64), jnp.float32)
+    bank = sds((16, 2560, 768), jnp.float32)
+    down = sds((16, 768, 2560), jnp.float32)
+
+    def fwd_and_vjp(u, logits, *w):
+        def loss(u, logits, *w):
+            y, _ = sparse_moe(u, logits, *w, top_k=6)
+            return jnp.sum(y)
+        return jax.value_and_grad(loss, argnums=(0, 2, 3, 4))(u, logits, *w)
+
+    compiled = jax.jit(fwd_and_vjp).lower(u, logits, bank, bank, down).compile()
+    assert "ragged-dot" in compiled.as_text()
+    # grouped, not dense: far under 16 experts x every slot
+    dense = 3 * 2 * 98304 * 3 * 2560 * 768 * 16
+    assert compiled.cost_analysis()["flops"] < dense / 8
+
+
+def test_decoder_train_step_fits_one_chip(topo, kernels):
+    """The benchmark's SmallThinker cell as `cli.train` builds it (656 M
+    float32 parameters under Adam, 2 rows of 8,192 tokens, --remat, the head
+    in row blocks): the first configuration whose constraint is memory."""
+    import json
+    import os
+
+    from ddp_classification_pytorch_tpu.cli.train import build_parser, config_from_args
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "smallthinker_21b_a3b.json")) as f:
+        conf = json.load(f)
+    cfg = config_from_args(build_parser().parse_args(
+        conf["argv"] + ["--dataset", "tokens", "--batchsize",
+                        str(conf["batch_per_chip"])]))
+    mesh = meshlib.make_mesh(meshlib.MeshSpec(1, 1), devices=topo.devices[:1])
+    with mesh:
+        model, tx, state = _abstract_state(cfg, mesh)
+        assert sum(a.size for a in jax.tree_util.tree_leaves(state.params)) \
+            == conf["parameters"] == 656529920
+        step = make_train_step(cfg, model, tx, mesh=mesh)
+        tokens = jax.ShapeDtypeStruct(
+            (cfg.data.batch_size, cfg.model.decoder.seq_len), jnp.int32,
+            sharding=meshlib.batch_sharding(mesh))
+        compiled = step.lower(state, tokens, tokens).compile()
+    assert _device_bytes(compiled) < 0.9 * HBM_BYTES
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 0.99 * m.output_size_in_bytes
+    text = compiled.as_text()
+    assert "ragged-dot" in text and text.count("tpu_custom_call") >= 12
