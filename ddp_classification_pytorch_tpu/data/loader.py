@@ -14,6 +14,13 @@ This is the corrected, TPU-native replacement for the reference's
 - **Worker parallelism** via a thread pool (PIL/numpy release the GIL in the
   hot paths) + a bounded background prefetch queue — the host-side analogue of
   `num_workers` + `pin_memory`.
+- **Batches filled in place.** The Python path cuts a batch's rows into at
+  most `num_workers` contiguous runs; each run is one pool task that writes
+  its rows straight into the batch buffer: no future, generator or tuple a
+  row, no second copy. A pass recycles a buffer once nothing but the pass
+  owns it (`sys.getrefcount`), so a steady consumer stops paying the page
+  faults of a fresh allocation every batch, and a batch a consumer holds is
+  never written again.
 
 The loader yields host-local numpy batches; `parallel/mesh.py:make_global_array`
 assembles them into a globally-sharded `jax.Array` over the `data` axis, and
@@ -25,9 +32,10 @@ thread so the H2D stage overlaps device compute (the full `pin_memory` +
 from __future__ import annotations
 
 import queue
+import sys
 import threading
-from concurrent.futures import ThreadPoolExecutor
-from typing import Iterator, Optional, Tuple
+from concurrent.futures import ThreadPoolExecutor, wait
+from typing import Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -71,15 +79,63 @@ def shard_indices_for_host(
     return idx[host_id * per_host : (host_id + 1) * per_host]
 
 
+class _LazyRng:
+    """`np.random.default_rng(key)`, built when first drawn from: seeding a
+    generator costs 40 us under the GIL, and a dataset that never reads its
+    `rng` (or only tests it: `rng or …`) never pays it. A dataset that draws
+    gets the draws of the generator itself."""
+
+    __slots__ = ("_key", "_rng")
+
+    def __init__(self, key: Tuple[int, int, int, int]):
+        self._key = key
+        self._rng = None
+
+    def __getattr__(self, name: str):
+        if self._rng is None:
+            self._rng = np.random.default_rng(self._key)
+        return getattr(self._rng, name)
+
+
+def _owners(held: List[np.ndarray], k: int) -> int:
+    return sys.getrefcount(held[k])
+
+
+# what `_owners` reads when the list is an array's one owner: taken from the
+# interpreter, which decides how many references the call itself holds
+_SOLE_OWNER = _owners([np.empty(0, np.uint8)], 0)
+
+
+def _batch_buffer(held: List[np.ndarray], room: int, shape: Tuple[int, ...],
+                  dtype: np.dtype) -> Tuple[np.ndarray, bool]:
+    """An array of `shape` and `dtype` to fill, and whether it is one of
+    `held` written before. `held` are the buffers one pass has handed out; one
+    comes back only while `held` is its sole owner: a consumer's reference, a
+    view (its `base`), a device transfer in flight or a CPU device array
+    aliasing it all count. Past `room` entries a new buffer is not tracked,
+    so a consumer that keeps every batch pins at most `room` more."""
+    for k in range(len(held)):
+        if (_owners(held, k) == _SOLE_OWNER and held[k].shape == shape
+                and held[k].dtype == dtype):
+            return held[k], True
+    buf = np.empty(shape, dtype)
+    if len(held) < room:
+        held.append(buf)
+    return buf, False
+
+
 class ShardedLoader:
     """Iterates (images, labels) numpy batches for this host.
 
     dataset must support `__len__` and `__getitem__(i, rng)` →
-    (HWC image, int label). The image dtype IS the H2D wire format and is
-    preserved verbatim through batching (`np.stack`): uint8 datasets
+    (HWC image, int label). The image dtype IS the H2D wire format: the
+    batch buffer takes the shape and dtype of the batch's first row, every
+    other row must match both (`ValueError` at the iteration site) and is
+    written into it as it is, never cast or broadcast: uint8 datasets
     (data.input_dtype == "uint8", the default — ¼ the transfer bytes) yield
     uint8 batches the jitted step normalizes on device; float32 datasets
-    yield the legacy pre-normalized wire.
+    yield the legacy pre-normalized wire. A yielded batch belongs to whoever
+    holds it, for as long as they hold it.
     """
 
     def __init__(
@@ -195,28 +251,66 @@ class ShardedLoader:
         pos = start + np.arange(self.batch_size)
         return (pos < len(self.dataset)).astype(np.float32)
 
-    def _load_batch(self, batch_idx: int, indices: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    def _item(self, j: int, i: int) -> Tuple[np.ndarray, object]:
+        """Row `j` of a batch: sample `i`, with the generator that is the
+        row's own whatever thread loads it and however the batch is cut."""
+        item = self.dataset.__getitem__(
+            i, _LazyRng((self.seed, self.epoch, i, j)))
+        # PLCDataset yields (image, label, index) (PLC/FolderDataset.py:56-75);
+        # the trailing index is positional bookkeeping we recover from `i`
+        return np.asarray(item[0]), item[1]
+
+    def _load_batch(self, batch_idx: int, indices: np.ndarray,
+                    held: List[np.ndarray]) -> Tuple[np.ndarray, np.ndarray]:
+        """One batch; the Python path writes its rows in place into a buffer
+        from `held`, the buffers this pass has handed out (`_batch_buffer`)."""
         if self.chaos is not None:
             self.chaos.maybe_fail_loader(epoch=self.epoch, batch=batch_idx)
         if self.batcher is not None:
             return self.batcher(indices, self.epoch, batch_idx)
 
-        def load(j_and_i):
-            j, i = j_and_i
-            rng = np.random.default_rng(
-                (self.seed, self.epoch, int(i), j)
-            )
-            item = self.dataset.__getitem__(int(i), rng)
-            # PLCDataset yields (image, label, index) (PLC/FolderDataset.py:56-75);
-            # the trailing index is positional bookkeeping we recover from `i`
-            return item[0], item[1]
+        indices = indices.tolist()
+        rows = len(indices)
+        first, label = self._item(0, indices[0])
+        # a steady pass has one buffer being filled, `prefetch` queued, one
+        # in the consumer's hand and a few with a stager and its transfers
+        images, reused = _batch_buffer(
+            held, self.prefetch + 4, (rows,) + first.shape, first.dtype)
+        # a label is a class id, or a row of them (data/tokens.py)
+        labels = np.empty((rows,) + np.shape(label), np.int32)
+        images[0], labels[0] = first, label
 
-        if self._pool is not None:
-            items = list(self._pool.map(load, enumerate(indices)))
+        def fill(lo: int, hi: int) -> None:
+            for j in range(lo, hi):
+                image, label = self._item(j, indices[j])
+                # an assignment would broadcast a (224, 224, 1) row and cast
+                # a float one; `np.stack` refused the first
+                if (image.shape != first.shape or image.dtype != first.dtype
+                        or np.shape(label) != labels.shape[1:]):
+                    raise ValueError(
+                        f"row {j} of the batch (sample {indices[j]}) is "
+                        f"{image.dtype.name}{list(image.shape)} with a label "
+                        f"of shape {list(np.shape(label))}; row 0 is "
+                        f"{first.dtype.name}{list(first.shape)} with "
+                        f"{list(labels.shape[1:])}: all rows of a batch "
+                        "must have the same shape and dtype")
+                images[j], labels[j] = image, label
+
+        # contiguous runs of rows 1.., one task each: at most one a worker
+        chunks = max(min(self.num_workers, rows - 1), 1) if self._pool else 1
+        if chunks == 1:
+            fill(1, rows)
         else:
-            items = [load(ji) for ji in enumerate(indices)]
-        images = np.stack([im for im, _ in items])
-        labels = np.asarray([lb for _, lb in items], np.int32)
+            cuts = [1 + (rows - 1) * c // chunks for c in range(chunks + 1)]
+            tasks = [self._pool.submit(fill, lo, hi)
+                     for lo, hi in zip(cuts, cuts[1:])]
+            # every task has left the buffer before an error leaves here
+            wait(tasks)
+            for task in tasks:
+                task.result()
+        spans.count("input_batch_buffers_total", loader=self.name,
+                    reused=str(int(reused)))
+        spans.note(rows=rows, chunks=chunks)
         return images, labels
 
     def __iter__(self) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
@@ -240,6 +334,7 @@ class ShardedLoader:
             return False
 
         def producer():
+            held: List[np.ndarray] = []
             try:
                 for b in range(n_batches):
                     if stop.is_set():
@@ -249,7 +344,7 @@ class ShardedLoader:
                     # full queue is waiting, not loading
                     with spans.span("input.load", step=b, epoch=self.epoch,
                                     loader=self.name, path=self.path):
-                        batch = self._load_batch(b, sl)
+                        batch = self._load_batch(b, sl, held)
                     if self.batcher is not None:
                         # the wire as the batch carries it, not as configured
                         spans.count("input_native_batches_total",

@@ -2,9 +2,10 @@
 into rows of T + 1 — row i gives (ids[0:T], ids[1:T+1]): the inputs and, as
 the per-position labels, the same ids shifted by one. Rows are packed text:
 documents follow one another with no padding, and a row attends across their
-boundaries. The loader stacks the two halves as it stacks images and labels
-(`ShardedLoader`: `np.stack` / `np.asarray`), so a batch is two (B, T) int32
-arrays and nothing downstream knows it is not an image batch.
+boundaries. The loader batches the two halves as it batches images and labels
+(`ShardedLoader`: rows of the first item's shape, labels cast to int32), so a
+batch is two (B, T) int32 arrays and nothing downstream knows it is not an
+image batch.
 """
 
 from __future__ import annotations

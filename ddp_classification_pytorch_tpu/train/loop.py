@@ -159,6 +159,8 @@ _INPUT_COUNTER_HELP = {
                            "the loop asked",
     "input_native_batches_total": "batches the native dataplane filled, by "
                                   "the dtype it wrote them in",
+    "input_batch_buffers_total": "batches the loader's Python path filled, "
+                                 "by whether the buffer was a recycled one",
 }
 
 

@@ -2,7 +2,9 @@
 - producer exceptions must surface at the iteration site, not truncate epochs;
 - valid_mask marks wrap-padding exactly;
 - warmup overlays the decay schedule without shifting its milestones;
-- 3-tuple datasets (PLC (image, label, index)) load through ShardedLoader.
+- 3-tuple datasets (PLC (image, label, index)) load through ShardedLoader;
+- a batch filled in place by chunked worker tasks is the batch the per-row
+  `np.stack` path made, and a recycled buffer is never one somebody holds.
 """
 
 import numpy as np
@@ -10,6 +12,7 @@ import pytest
 
 from ddp_classification_pytorch_tpu.config import OptimConfig
 from ddp_classification_pytorch_tpu.data.loader import ShardedLoader
+from ddp_classification_pytorch_tpu.obs import spans
 from ddp_classification_pytorch_tpu.train.schedule import build_schedule
 
 
@@ -160,6 +163,190 @@ def test_abandoned_iteration_does_not_deadlock():
     # no strict assert on thread count (pytest has helpers), but a second
     # full iteration must work — would hang if the producer deadlocked
     assert len(list(loader)) == 8
+
+
+# --- the Python path fills a batch in place ---------------------------------
+
+SEED, EPOCH = 4321, 2
+
+
+class RowsDataset:
+    """40 samples of one kind; `kind` picks what a row is and whether it
+    reads its generator."""
+
+    def __init__(self, kind):
+        self.kind = kind
+
+    def __len__(self):
+        return 40
+
+    def __getitem__(self, i, rng=None):
+        kind = self.kind
+        if kind == "uint8_hwc":
+            return np.full((5, 4, 3), i, np.uint8) + np.arange(3, dtype=np.uint8), i % 7
+        if kind == "uint8_pages":  # a batch large enough to be mapped, which
+            # is when the CPU backend's device arrays alias the host's
+            return np.full((128, 128, 3), i, np.uint8), i
+        if kind == "float32":
+            return np.full((3, 3, 3), i / 7, np.float32), np.int64(i)
+        if kind == "plc_triple":
+            return np.full((2, 2, 3), i, np.float32), i % 3, i
+        if kind == "tokens":  # data/tokens.py: two halves of one int32 row
+            row = np.arange(i, i + 9, dtype=np.int32)
+            return row[:-1], row[1:]
+        if kind == "draws":  # every draw the repo's transforms make
+            a = rng.uniform(0.08, 1.0)
+            b = rng.integers(0, 9)
+            c = rng.normal(size=(2, 3))
+            d = rng.permutation(6)[:3]
+            return (c * a + d).astype(np.float32), int(b)
+        if kind == "rng_or":  # data/cifar.py:70
+            rng = rng or np.random.default_rng()
+            return rng.integers(0, 256, (4, 4, 3), dtype=np.uint8), i
+        raise AssertionError(kind)
+
+
+def stack_reference(dataset, indices, seed, epoch):
+    """The algorithm this loader had: a generator a row, a list, `np.stack`."""
+    items = []
+    for j, i in enumerate(indices):
+        rng = np.random.default_rng((seed, epoch, int(i), j))
+        item = dataset.__getitem__(int(i), rng)
+        items.append((item[0], item[1]))
+    return (np.stack([im for im, _ in items]),
+            np.asarray([lb for _, lb in items], np.int32))
+
+
+def assert_batches_are_reference(loader, batches):
+    indices = loader._epoch_indices()
+    assert len(batches) == len(loader) > 0
+    for b, (images, labels) in enumerate(batches):
+        sl = indices[b * loader.batch_size:(b + 1) * loader.batch_size]
+        ref_images, ref_labels = stack_reference(
+            loader.dataset, sl, loader.seed, loader.epoch)
+        for got, ref in ((images, ref_images), (labels, ref_labels)):
+            assert got.dtype == ref.dtype and got.shape == ref.shape
+            assert got.tobytes() == ref.tobytes(), f"batch {b}"
+
+
+@pytest.mark.parametrize("batch,workers", [
+    (16, 1), (16, 2), (16, 8),
+    (3, 8),    # fewer rows than workers
+    (13, 8),   # rows 1.. not divisible into equal runs
+    (1, 8),    # row 0 is the batch
+])
+@pytest.mark.parametrize("kind", ["uint8_hwc", "float32", "plc_triple",
+                                  "tokens", "draws", "rng_or"])
+def test_in_place_batches_are_the_stacked_batches(kind, batch, workers):
+    loader = ShardedLoader(RowsDataset(kind), batch_size=batch, shuffle=True,
+                           seed=SEED, num_workers=workers, host_id=0,
+                           num_hosts=1)
+    loader.set_epoch(EPOCH)
+    assert_batches_are_reference(loader, list(loader))
+
+
+@pytest.mark.parametrize("workers", [1, 2, 8])
+def test_row_generator_is_keyed_by_seed_epoch_sample_and_row(workers):
+    """The draws of row j are `default_rng((seed, epoch, i, j))`'s, whatever
+    thread loads the row and however the batch is cut."""
+    loader = ShardedLoader(RowsDataset("draws"), batch_size=8, shuffle=True,
+                           seed=SEED, num_workers=workers, host_id=0,
+                           num_hosts=1)
+    loader.set_epoch(EPOCH)
+    indices = loader._epoch_indices()
+    for b, (images, labels) in enumerate(loader):
+        for j in range(8):
+            rng = np.random.default_rng((SEED, EPOCH, int(indices[b * 8 + j]), j))
+            a, lb = rng.uniform(0.08, 1.0), rng.integers(0, 9)
+            c = rng.normal(size=(2, 3))
+            d = rng.permutation(6)[:3]
+            np.testing.assert_array_equal(images[j], (c * a + d).astype(np.float32))
+            assert labels[j] == lb
+
+
+class OddRowDataset:
+    """Sample 11 differs from the rest in `what`."""
+
+    def __init__(self, what):
+        self.what = what
+
+    def __len__(self):
+        return 16
+
+    def __getitem__(self, i, rng=None):
+        odd = i == 11
+        if self.what == "shape" and odd:  # would broadcast into (4, 4, 3)
+            return np.ones((4, 4, 1), np.uint8), 0
+        if self.what == "dtype" and odd:  # would be cast to uint8
+            return np.full((4, 4, 3), 0.5, np.float32), 0
+        if self.what == "label_shape" and odd:
+            return np.ones((4, 4, 3), np.uint8), np.zeros(2, np.int32)
+        return np.ones((4, 4, 3), np.uint8), 0
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+@pytest.mark.parametrize("what", ["shape", "dtype", "label_shape"])
+def test_row_unlike_row_zero_raises_at_the_iteration_site(what, workers):
+    loader = ShardedLoader(OddRowDataset(what), batch_size=8, shuffle=False,
+                           num_workers=workers, host_id=0, num_hosts=1)
+    it = iter(loader)
+    next(it)  # batch 0 holds samples 0-7: fine
+    with pytest.raises(ValueError, match="same shape and dtype"):
+        next(it)
+
+
+def buffer_counts(name):
+    c = spans.counters()
+    return [c.get(("input_batch_buffers_total",
+                   (("loader", name), ("reused", r))), 0) for r in "01"]
+
+
+@pytest.mark.parametrize("keep", ["batches", "views", "device_arrays"])
+def test_kept_batches_are_never_written_again(keep):
+    """Whoever holds a batch, a view of it, or a CPU device array that
+    aliases it, owns it: at the end of the epoch every one is intact. A
+    buffer comes back only where freeing it would have been as safe: never
+    under a batch or a view, and under a device array only where the
+    backend copied."""
+    name = f"keeps_{keep}"
+    loader = ShardedLoader(RowsDataset("uint8_pages"), batch_size=8,
+                           shuffle=True, seed=SEED, num_workers=4, prefetch=1,
+                           host_id=0, num_hosts=1, name=name)
+    if keep == "batches":
+        kept = list(loader)
+    elif keep == "views":
+        kept = [(images[:], labels) for images, labels in loader]
+    else:
+        from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
+
+        mesh = meshlib.make_mesh()  # conftest's eight CPU devices, a row each
+        kept = [meshlib.make_global_array(batch, mesh) for batch in loader]
+        kept = [(np.asarray(images), np.asarray(labels)) for images, labels in kept]
+    assert_batches_are_reference(loader, kept)
+    fresh, reused = buffer_counts(name)
+    assert fresh + reused == len(loader)
+    assert reused == 0 or keep == "device_arrays"
+
+
+def test_dropped_batches_give_their_buffers_back():
+    loader = ShardedLoader(RowsDataset("uint8_hwc"), batch_size=4,
+                           shuffle=True, seed=SEED, num_workers=2, prefetch=1,
+                           host_id=0, num_hosts=1, name="drops")
+    seen = 0
+    for b, (images, labels) in enumerate(loader):
+        ref = stack_reference(loader.dataset,
+                              loader._epoch_indices()[b * 4:(b + 1) * 4],
+                              SEED, 0)
+        assert images.tobytes() == ref[0].tobytes()
+        seen += 1
+    fresh, reused = buffer_counts("drops")
+    assert fresh + reused == seen == 10
+    # one being filled, one queued, one in this loop's hand, one more the
+    # producer still names
+    assert fresh <= 4 and reused >= 6
+    load = [s for s in spans.snapshot()
+            if s.name == "input.load" and s.ids["loader"] == "drops"]
+    assert [(s.ids["rows"], s.ids["chunks"]) for s in load] == [(4, 2)] * 10
 
 
 def test_warmup_does_not_shift_milestones():
