@@ -456,18 +456,19 @@ def audit_program(fn, args: Tuple[Any, ...], name: str = "<fixture>",
     return findings, summary
 
 
-# -------------------------------------------------------- bench evidence --
+# ------------------------------------------------- one-program evidence --
 
 def step_dtype_evidence(fn, args: Tuple[Any, ...]) -> Dict[str, Any]:
-    """bench.py's dtype evidence, from one trace of the already-built step:
-    `bf16_op_fraction` (FLOP-weighted fraction of dot/conv work with
-    sub-f32 operands — picks the MFU roofline denominator) and
+    """Dtype evidence from one trace of an already-built step (called by
+    tests/test_dtype_audit.py only; the matrix goes through `audit_program`
+    itself — ROADMAP D7): `bf16_op_fraction` (FLOP-weighted fraction of
+    dot/conv work with sub-f32 operands) and
     `accum_dtype_ok` (the UNWAIVABLE contracts hold: no f64, no large
     sub-f32 reduction, no sub-f32 exp/log, no round-trip cast chain —
     trunk bf16 matmuls are the declared design and report via the
     fraction, not this flag)."""
     findings, summary = audit_program(
-        fn, args, name="<bench>",
+        fn, args, name="<evidence>",
         waivers=frozenset({WAIVER_BF16_TRUNK, WAIVER_BF16_WIRE}))
     return {
         "bf16_op_fraction": summary["bf16_op_fraction"],

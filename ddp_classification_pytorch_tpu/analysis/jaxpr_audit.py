@@ -208,9 +208,8 @@ def donation_evidence(jitted_fn, args: Sequence[Any],
     as donated-but-not-usable (each one is a buffer round-tripping HBM).
 
     AOT `lower().compile()` does not populate the jit call cache, so this
-    costs one compile; callers on scarce accelerators run it where a compile
-    is already budgeted (bench warmup) — the persistent cache makes it a
-    cache hit on TPU."""
+    costs one compile (`audit_donation` is the caller; a persistent-cache
+    hit on the TPU)."""
     donated = sum(_leaf_bytes(l) for i in donated_argnums
                   for l in jax.tree_util.tree_leaves(args[i]))
     with warnings.catch_warnings(record=True) as caught:

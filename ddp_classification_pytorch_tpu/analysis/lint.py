@@ -39,11 +39,11 @@ from typing import Iterable, List, Optional, Sequence, Tuple
 
 from . import Finding
 
-# the documented exit codes (docs/operations.md failure-mode matrix +
-# bench.py's 5 "deadline" row + the elastic pod codes: 10 pod-unviable,
-# 11 pod-reform); signal deaths (130/137/143) are raised by the runtime,
-# never by our code, so they are deliberately NOT listed
-RC_CATALOGUE = frozenset({0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11})
+# the documented exit codes (docs/operations.md failure-mode matrix,
+# including the elastic pod codes: 10 pod-unviable, 11 pod-reform); signal
+# deaths (130/137/143) are raised by the runtime, never by our code, so
+# they are deliberately NOT listed
+RC_CATALOGUE = frozenset({0, 1, 2, 3, 4, 6, 7, 8, 9, 10, 11})
 
 # call idioms that synchronize the host against the device (or smuggle host
 # wall-clock into a trace) when they appear inside a step factory
@@ -122,7 +122,6 @@ def lint_step_factories(factories: Optional[Iterable[str]] = None
             "ddp_classification_pytorch_tpu.train.steps:_arcface_sharded_loss",
             "ddp_classification_pytorch_tpu.train.steps:_make_arcface_sharded_eval",
             "ddp_classification_pytorch_tpu.train.steps:_dense_loss_fn",
-            "ddp_classification_pytorch_tpu.train.steps:make_phase_probes",
         })
     findings: List[Finding] = []
     by_module: dict = {}
@@ -147,12 +146,7 @@ def lint_step_factories(factories: Optional[Iterable[str]] = None
 _JIT_DELEGATES = frozenset({"_build_step", "_make_arcface_sharded_eval"})
 
 # jit sites deliberately OUTSIDE the registry, each with the reviewed why
-_JIT_EXEMPT = {
-    "make_phase_probes":
-        "bench-only fwd/bwd timing probes over the SAME production loss "
-        "(obs breakdown attribution) — never a production hot path; the "
-        "production program they time IS registered",
-}
+_JIT_EXEMPT: dict = {}
 
 
 def lint_jit_source(src: str, registered: Iterable[str],

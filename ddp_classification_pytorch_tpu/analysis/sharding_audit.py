@@ -625,8 +625,8 @@ def _compile_with_evidence(jitted_fn, args: Sequence[Any],
     carries the donation fields (donated bytes are per-device LOCAL under
     a sharded mesh — `shard_shape` — matching the per-device alias table
     memory_analysis reports), the collective inventory, and the memory
-    budget — the superset bench.py and the sharded audit both ride, so
-    neither pays a second compile."""
+    budget — the superset `step_comms_evidence` and the sharded audit both
+    ride, so neither pays a second compile."""
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         compiled = jitted_fn.lower(*args).compile()
@@ -653,10 +653,12 @@ def _compile_with_evidence(jitted_fn, args: Sequence[Any],
 def step_comms_evidence(jitted_fn, args: Sequence[Any],
                         donated_argnums: Sequence[int] = (0,),
                         mesh=None) -> Dict[str, Any]:
-    """bench.py's evidence surface: the donation fields
+    """The evidence of one jitted program without a `ShardedCase` around it
+    (called by tests/test_analysis.py only; the audit matrix calls
+    `_compile_with_evidence` itself — ROADMAP D7): the donation fields
     (jaxpr_audit.donation_evidence-compatible) plus
     `collective_bytes_per_step` and `peak_hbm_bytes`, from a single
-    compile in the warmup window (a persistent-cache hit on TPU)."""
+    compile."""
     ev, _ = _compile_with_evidence(jitted_fn, args, donated_argnums, mesh)
     return ev
 

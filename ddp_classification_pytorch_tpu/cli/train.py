@@ -101,7 +101,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "v5e crossover region; 0 = kernel always)")
     m.add_argument("--ln_bf16", action="store_true",
                    help="ViT: LayerNorms in bf16 instead of f32 (bandwidth "
-                        "experiment; scripts/ab_vit_perf.py measures it)")
+                        "experiment; no chip reading yet, ROADMAP S4)")
     m.add_argument("--variant", default="", help="imagenet | cifar stem")
     m.add_argument("--pretrained", action="store_true",
                    help="load converted torchvision weights")

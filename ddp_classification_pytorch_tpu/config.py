@@ -171,7 +171,7 @@ class ModelConfig:
     flash_min_tokens: int = 1024
     # ViT only: run the LayerNorms in the compute dtype (bf16) instead of
     # f32 — a bandwidth experiment for the HBM-bound ViT step (VERDICT r3
-    # #5; A/B harness scripts/ab_vit_perf.py). Off = the standard
+    # #5; no chip reading exists, ROADMAP S4). Off = the standard
     # f32-LN recipe every convergence record uses.
     ln_bf16: bool = False
     # arch == "decoder_lm": every size of the token decoder
@@ -359,9 +359,8 @@ class RunConfig:
 def dp_round_up_buckets(buckets: Sequence[int], dp: int) -> tuple:
     """Round each bucket UP to the next dp multiple and dedup (ascending):
     the compile-count bound survives data-parallel serving — at most
-    len(buckets) padded shapes, each evenly shardable over 'data'. Shared
-    by `ServeConfig.resolve_buckets` (auto-buckets) and `bench.py --serve`
-    (which must run its default bucket list on whatever mesh exists)."""
+    len(buckets) padded shapes, each evenly shardable over 'data'. Called
+    by `ServeConfig.resolve_buckets` (auto-buckets)."""
     if dp < 1:
         raise ValueError(f"dp must be >= 1, got {dp}")
     return tuple(sorted({((int(b) + dp - 1) // dp) * dp for b in buckets}))

@@ -7,8 +7,9 @@ data`) — used to run synchronously inside the Python step loop: every step
 paid it before the next device step could dispatch. jax's async dispatch
 hides device latency behind host code, not host latency behind device code,
 so that per-step host time was pure pipeline stall (SURVEY §7.3 ranks input
-throughput the #1 hard part; neither bench.py — device-only by design — nor
-bench_input.py — host-only — could see this stage).
+throughput the #1 hard part). The stage is measured where it runs: the
+`input.assemble` and `train.input_wait` spans (obs/spans.py), which the
+benchmark's per-layer metrics read.
 
 `DevicePrefetcher` moves that stage onto a background *stager* thread that
 keeps up to `depth` fully-formed, globally-sharded device batches staged

@@ -1,7 +1,7 @@
 """Synthetic dataset for tests and benchmarks — deterministic, no filesystem.
 
 The reference has no equivalent (it always trains from real folders); this is
-framework infrastructure for the test/bench strategy (SURVEY §4): shapes match
+framework infrastructure for the test strategy (SURVEY §4): shapes match
 the real pipeline so the jitted train step is identical.
 """
 

@@ -80,7 +80,7 @@ class Block(nn.Module):
 
     `ln_bf16` runs the LayerNorms in the block compute dtype instead of
     f32 — a bandwidth experiment for the HBM-bound ViT step (VERDICT r3
-    #5; the bench-scale A/B lives in scripts/ab_vit_perf.py). Params stay
+    #5; no chip reading exists, ROADMAP S4). Params stay
     f32 either way; default remains the f32-LN recipe."""
 
     dim: int

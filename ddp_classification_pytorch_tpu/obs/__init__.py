@@ -1,7 +1,7 @@
 """Unified observability spine — dependency-free telemetry for every
-subsystem (trainer, serve, fleet, scenario, bench).
+subsystem (trainer, serve, fleet, scenario).
 
-Four planes, one package:
+Three planes, one package:
 
 - `obs.registry` — Prometheus-style counters/gauges/bounded-window
   histograms with a text-exposition exporter (`/metrics`,
@@ -12,11 +12,6 @@ Four planes, one package:
   work that really ran (set-up phases, the input pipeline's stages, the
   step loop), always on, bounded; read by `Trainer._write_prom`
   (`span_seconds_total{span=…}`) and by the benchmark's per-layer readers.
-- `obs.trace` — NOT a recorder of what ran: a Chrome-trace parser that
-  buckets device activity by op-name keywords per `StepTraceAnnotation`
-  window, and `SpanRecorder`, which turns separately compiled probe
-  timings into synthetic spans for that parser (`bench.py --trace` only;
-  superseded, see PERF.md §3).
 - `obs.events` — the machine-readable event plane (`events.jsonl`),
   promoted from `scenario/events.py` (which remains as a compat
   re-export). `emit()` stays env-gated and unconditionally cheap.
@@ -26,5 +21,5 @@ device value or appears inside a jitted program (`analysis/lint.py`
 host-sync pass stays green over the instrumented factories).
 """
 
-from . import events, registry, spans, trace  # noqa: F401
+from . import events, registry, spans  # noqa: F401
 from .registry import Registry  # noqa: F401

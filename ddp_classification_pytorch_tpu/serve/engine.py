@@ -34,7 +34,7 @@ training stack's own primitives:
 
 The engine is fully exercisable in-process: construct it without `start()`
 and drive `process_once()` directly — no thread, no socket (how the tier-1
-tests and `bench.py --serve` use it). The stdlib HTTP front-end
+tests use it). The stdlib HTTP front-end
 (serve/http.py) is a thin layer over `submit()`.
 
 One engine is one replica's data plane. The fleet control plane

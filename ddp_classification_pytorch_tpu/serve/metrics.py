@@ -6,8 +6,8 @@ gauge and the latency window live as instruments in an
 `obs.registry.Registry` (one per ServeMetrics — engines in one process
 never cross-talk), so the SAME numbers back three surfaces at once:
 
-- the legacy dict `snapshot()` (`/healthz`, `/metrics.json`, bench's
-  serve row, the console `log_line`) — keys and values unchanged;
+- the legacy dict `snapshot()` (`/healthz`, `/metrics.json`, the
+  console `log_line`) — keys and values unchanged;
 - the Prometheus text exposition `/metrics` serves
   (`registry.expose()`), where the serve/engine instrument families
   live next to the watcher's (serve/reload.py registers into the same

@@ -273,9 +273,7 @@ def _dense_loss_fn(cfg: Config, model: Any):
     `loss_fn(params, batch_stats, images, labels, rng) -> (loss,
     (new_batch_stats, logits))` with the per-workload forward dispatch
     (baseline/cdr: plain CE; arcface: margin logits; nested: per-batch
-    prefix mask k ~ Gaussian, NESTED/train.py:247-250). Factored out of
-    `make_train_step` so bench's phase probes (`make_phase_probes`) time
-    the EXACT production loss, not a re-derivation that could drift."""
+    prefix mask k ~ Gaussian, NESTED/train.py:247-250)."""
     workload = cfg.model.head
     if workload == "nested":
         dist = jnp.asarray(gaussian_dist(0.0, cfg.model.nested_std, feat_dim_for(cfg.model)))
@@ -340,50 +338,6 @@ def _lm_loss(cfg: Config, model: Any):
                 "moe_load": load.astype(jnp.float32)}
 
     return loss_fn, metrics_fn
-
-
-def make_phase_probes(
-    cfg: Config,
-    model: Any,
-    base_rng: Optional[jax.Array] = None,
-    mesh: Optional[Any] = None,
-) -> Dict[str, Callable]:
-    """Sub-programs of the train step for bench's step-time decomposition:
-    `{"fwd": (state, images, labels) -> loss,
-      "fwd_bwd": (state, images, labels) -> (loss, grad_norm)}`.
-
-    Both close over the SAME loss_fn the production step uses (the dense
-    one, or the partial-FC path under `parallel.arcface_sharded_ce`), with
-    the same rng fold and device input epilogue, so t(fwd) and
-    t(fwd_bwd) − t(fwd) are honest fwd/bwd attributions of the real
-    program — the CPU-safe breakdown when the profiler's op names carry no
-    phase information (obs/trace.py). `fwd_bwd` returns the grad global
-    norm so the gradients stay live (XLA would otherwise DCE the entire
-    backward pass). No donation: the same state times every probe call."""
-    workload = cfg.model.head
-    if base_rng is None:
-        base_rng = jax.random.PRNGKey(cfg.run.seed + 1)
-    flip = _train_flip_enabled(cfg)
-    if cfg.parallel.arcface_sharded_ce and workload == "arcface":
-        _require_sharded_ce_mesh(mesh)
-        loss_fn, _ = _arcface_sharded_loss(cfg, model, mesh)
-    else:
-        loss_fn = _dense_loss_fn(cfg, model)
-
-    def fwd(state: TrainState, images: jnp.ndarray, labels: jnp.ndarray):
-        rng = jax.random.fold_in(base_rng, state.step)
-        images = device_input_epilogue(images, rng, flip=flip)
-        loss, _ = loss_fn(state.params, state.batch_stats, images, labels, rng)
-        return loss
-
-    def fwd_bwd(state: TrainState, images: jnp.ndarray, labels: jnp.ndarray):
-        rng = jax.random.fold_in(base_rng, state.step)
-        images = device_input_epilogue(images, rng, flip=flip)
-        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            state.params, state.batch_stats, images, labels, rng)
-        return loss, optax.global_norm(grads)
-
-    return {"fwd": jax.jit(fwd), "fwd_bwd": jax.jit(fwd_bwd)}
 
 
 def _require_sharded_ce_mesh(mesh) -> None:

@@ -1,5 +1,5 @@
-"""Persistent XLA compilation cache setup (shared by the CLIs, bench.py and
-chip_smoke.py)."""
+"""Persistent XLA compilation cache setup (shared by `cli.train`,
+`cli.serve`, the benchmark's runners and chip_smoke.py)."""
 
 from __future__ import annotations
 

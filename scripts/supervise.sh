@@ -118,7 +118,7 @@ while true; do
   # retry budget replaying it; bare 1 is an UNHANDLED runtime exception
   # (transient XlaRuntimeError, in-process OOM, dataloader
   # IO) — retryable, but with a backoff so a crash loop doesn't spin;
-  # 3 is "no backend" (bench.py's code for "no TPU found"), where an
+  # 3 is "no backend" (a launcher's code for "no TPU found"), where an
   # immediate restart finds the same — back off long enough for an
   # outage to pass; 6 is "rendezvous failed"
   # (parallel/fleet.py: jax.distributed.initialize never completed within

@@ -412,8 +412,8 @@ def test_serve_steady_state_recompile_counted_and_strict_fatal():
 
 
 def test_donation_evidence_fields():
-    """bench.py's e2e evidence rides this helper: the fields must exist and
-    a fully-aliasable donated arg must report coverage 1.0."""
+    """`audit_donation` rides this helper: the fields must exist and a
+    fully-aliasable donated arg must report coverage 1.0."""
     fn = jax.jit(lambda s, x: (s + x.sum(), x * 2), donate_argnums=0)
     ev = donation_evidence(fn, (jnp.zeros((32, 32), jnp.float32),
                                 jax.ShapeDtypeStruct((4,), jnp.float32)))
@@ -577,7 +577,7 @@ def test_empty_replica_groups_attributes_to_full_mesh(audit):
 
 
 def test_step_comms_evidence_fields(audit):
-    """bench.py's e2e evidence rides this helper: donation fields plus the
+    """One program's evidence outside the matrix: donation fields plus the
     comms/memory fields, all from ONE compile."""
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P

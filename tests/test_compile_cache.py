@@ -68,7 +68,7 @@ def test_no_code_path_sets_the_directory_when_the_variable_is_set():
         for d, _, files in os.walk(os.path.join(REPO, root)):
             hits += [os.path.join(d, f) for f in files if f.endswith(".py")]
     hits += [os.path.join(REPO, f)
-             for f in ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+             for f in ("chip_smoke.py", "__graft_entry__.py")]
     writers = []
     for path in hits:
         with open(path) as f:

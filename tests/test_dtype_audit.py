@@ -467,7 +467,7 @@ def test_committed_baseline_has_dtype_sections():
     assert "bf16" in sharded["wire_dtypes"].get("all-reduce", {})
 
 
-# --------------------------------------------------- bench evidence --
+# --------------------------------------------- one-program evidence --
 
 
 def test_step_dtype_evidence_shape():

@@ -1,18 +1,15 @@
 """Observability spine (obs/): registry semantics + exposition, atomic
-scrape-file rewrite, Chrome-trace parsing, the SpanRecorder fallback, and
-the promoted event plane's compat surface.
+scrape-file rewrite, and the promoted event plane's compat surface (the
+span recorder has tests/test_spans.py).
 
 The registry tests pin the operational contracts the instruments are
 trusted for: thread-safe counting, quantiles bit-identical to the legacy
 ServeMetrics estimator (so `/metrics` and `/metrics.json` can never
 disagree about p99), deterministic exposition (golden-testable), and a
 `write_prom` a concurrent scraper can read mid-rewrite without ever seeing
-a torn file. The trace tests run the SAME parser bench's --trace path uses
-over a checked-in fixture shaped like a real CPU capture — known bucket
-sums, unknown-op-goes-to-idle, window clipping, per-lane overlap union.
+a torn file.
 """
 
-import gzip
 import json
 import os
 import threading
@@ -20,11 +17,7 @@ import threading
 import pytest
 
 from ddp_classification_pytorch_tpu.obs import events as obs_events
-from ddp_classification_pytorch_tpu.obs import trace as tracelib
 from ddp_classification_pytorch_tpu.obs.registry import Registry
-
-FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       "data", "fixture.trace.json")
 
 
 # ----------------------------------------------------------------- registry --
@@ -167,189 +160,6 @@ def test_write_prom_atomic_under_concurrent_reads(tmp_path):
     assert not [p for p in os.listdir(tmp_path) if p.endswith(".tmp")]
 
 
-# -------------------------------------------------------------------- trace --
-
-def test_classify_table():
-    assert tracelib.classify("all-reduce.5") == "collectives"
-    assert tracelib.classify("ReduceScatter-start") == "collectives"
-    # every op kind the ZeRO-1 step puts on the wire (reduce-scatter of
-    # grads, all-gather of updated params, GSPMD's permute decomposition)
-    assert tracelib.classify("reduce-scatter.4") == "collectives"
-    assert tracelib.classify("all-gather-start.2") == "collectives"
-    assert tracelib.classify("collective-permute.7") == "collectives"
-    assert tracelib.classify("TransferToDevice") == "h2d"
-    assert tracelib.classify("copy-start.3") == "h2d"
-    assert tracelib.classify("transpose(dot.7)") == "bwd"
-    assert tracelib.classify("gradients/conv1") == "bwd"
-    assert tracelib.classify("adamw.update") == "optimizer"
-    assert tracelib.classify("forward/block1") == "fwd"
-    # exact bucket names map to themselves (the SpanRecorder contract)
-    for b in tracelib.BUCKETS:
-        assert tracelib.classify(b) == b
-    # unknown ops are NOT guessed — they become idle via the remainder
-    assert tracelib.classify("dot.3") is None
-    assert tracelib.classify("reduce-window.2") is None
-    assert tracelib.classify("fusion.12") is None
-
-
-def test_parse_fixture_trace():
-    """The checked-in fixture (shaped like a real CPU `.trace.json.gz`
-    payload) parses to known per-step sums: overlapping same-lane events
-    union, a window-straddling event clips, unknown ops land in idle, and
-    the six buckets sum to the wall time exactly."""
-    with open(FIXTURE) as f:
-        steps = tracelib.parse_chrome_trace(json.load(f))
-    assert [s["step"] for s in steps] == [0, 1]
-    s0, s1 = steps
-    assert s0["step_ms"] == pytest.approx(10.0)
-    # all-reduce.5 [1500,3500] and .6 [2000,3000] share a lane → union 2 ms;
-    # all-gather.1 [500,1500] clips to the window start → +0.5 ms
-    assert s0["collectives"] == pytest.approx(2.5)
-    assert s0["h2d"] == pytest.approx(1.0)
-    assert s0["fwd"] == 0.0 and s0["bwd"] == 0.0 and s0["optimizer"] == 0.0
-    assert s0["idle"] == pytest.approx(6.5)  # dot.3 (unknown) → remainder
-    assert s1["step_ms"] == pytest.approx(8.0)
-    assert s1["bwd"] == pytest.approx(2.0)
-    assert s1["optimizer"] == pytest.approx(1.0)
-    # the ZeRO-1 step's op kinds (reduce-scatter.4 1 ms + collective-
-    # permute.2 0.8 ms) land in collectives, NOT idle — a trace of the
-    # sharded-optimizer step keeps the breakdown honest
-    assert s1["collectives"] == pytest.approx(1.8)
-    assert s1["idle"] == pytest.approx(3.2)  # reduce-window.2 is unknown
-    for s in steps:
-        assert sum(s[b] for b in tracelib.BUCKETS) == pytest.approx(
-            s["step_ms"])
-
-
-def _x(name, ts_us, dur_us, tid=0):
-    return {"ph": "X", "name": name, "pid": 1, "tid": tid,
-            "ts": float(ts_us), "dur": float(dur_us)}
-
-
-def test_parse_accum_window_buckets_and_amortization():
-    """Scanned gradient accumulation: ONE StepTraceAnnotation window (one
-    optimizer step) containing K=4 microbatch fwd/bwd executions and a
-    single deferred all-reduce. The per-lane union must sum the K disjoint
-    same-lane spans (and union a nested one) with the six buckets still
-    covering the wall time exactly; the collective lane carries ONE
-    reduction's time per window — the same absolute payload as a K=1
-    window but ÷K per microbatch, so its share of the wall shrinks vs the
-    K=1 fixture below."""
-    # K=1 reference: 4 optimizer steps, each its own 10 ms window with its
-    # own 2 ms gradient all-reduce (the per-step reduction being amortized)
-    k1_events = []
-    for n in range(4):
-        base = n * 11_000.0  # 10 ms window + 1 ms gap
-        k1_events += [
-            {**_x("bench_step", base, 10_000.0),
-             "args": {"step_num": n}},
-            _x("forward/block", base, 3_000.0),
-            _x("transpose(dot.1)", base + 3_000, 3_000.0),
-            _x("all-reduce.1", base + 6_000, 2_000.0),
-            _x("optimizer/sgd", base + 8_000, 1_000.0),
-        ]
-    k1 = tracelib.parse_chrome_trace({"traceEvents": k1_events})
-    assert len(k1) == 4
-
-    # K=4 accumulated step: one 40 ms window, 4 scanned microbatches on
-    # the same lane, ONE deferred all-reduce at the optimizer boundary
-    ev = [{**_x("bench_step", 0.0, 40_000.0), "args": {"step_num": 0}}]
-    for mb in range(4):
-        base = mb * 6_500.0
-        ev.append(_x("forward/block", base, 3_000.0))
-        ev.append(_x("transpose(dot.1)", base + 3_000, 3_000.0))
-    # a fusion nested inside microbatch 0's fwd span, same lane: must
-    # union into the covering span, not double-count
-    ev.append(_x("forward/stem_fusion", 500.0, 1_000.0))
-    ev.append(_x("all-reduce.1", 26_000.0, 2_000.0))
-    ev.append(_x("optimizer/sgd", 28_000.0, 1_000.0))
-    (acc,) = tracelib.parse_chrome_trace({"traceEvents": ev})
-
-    assert acc["step_ms"] == pytest.approx(40.0)
-    # 4 disjoint 3 ms fwd spans; the nested fusion unions away
-    assert acc["fwd"] == pytest.approx(12.0)
-    assert acc["bwd"] == pytest.approx(12.0)
-    assert acc["optimizer"] == pytest.approx(1.0)
-    # exactly ONE reduction's microseconds in the whole optimizer step —
-    # equal to a single K=1 window's collective time (payload parity)...
-    assert acc["collectives"] == pytest.approx(k1[0]["collectives"])
-    # ...so the collective share of the wall is ~K× smaller than K=1
-    k1_share = sum(s["collectives"] for s in k1) / sum(
-        s["step_ms"] for s in k1)
-    acc_share = acc["collectives"] / acc["step_ms"]
-    assert acc_share < k1_share / 3.5
-    # the invariant the whole breakdown hangs on: buckets sum to the wall
-    # time exactly, idle the remainder — even with K scanned microbatches
-    # inside one window
-    assert sum(acc[b] for b in tracelib.BUCKETS) == pytest.approx(
-        acc["step_ms"])
-    for s in k1:
-        assert sum(s[b] for b in tracelib.BUCKETS) == pytest.approx(
-            s["step_ms"])
-
-
-def test_aggregate_means_and_empty():
-    with open(FIXTURE) as f:
-        agg = tracelib.aggregate(tracelib.parse_chrome_trace(json.load(f)))
-    assert agg["n_steps"] == 2
-    assert agg["step_ms"] == pytest.approx(9.0)
-    assert agg["collectives"] == pytest.approx(2.15)
-    assert tracelib.aggregate([]) == {}
-
-
-def test_find_trace_file_and_gz_roundtrip(tmp_path):
-    """find_trace_file walks the jax.profiler layout and load_chrome_trace
-    is gzip-aware — the exact path bench's --trace capture goes through."""
-    d = tmp_path / "plugins" / "profile" / "2026_08_05"
-    d.mkdir(parents=True)
-    with open(FIXTURE, "rb") as f:
-        payload = f.read()
-    gz = d / "host.trace.json.gz"
-    with gzip.open(gz, "wb") as f:
-        f.write(payload)
-    assert tracelib.find_trace_file(str(tmp_path)) == str(gz)
-    steps = tracelib.breakdown_from_trace_dir(str(tmp_path))
-    assert [s["step"] for s in steps] == [0, 1]
-    assert tracelib.find_trace_file(str(tmp_path / "plugins" / "empty")) is None
-    assert tracelib.breakdown_from_trace_dir(str(tmp_path / "nope")) == []
-
-
-def test_span_recorder_roundtrip():
-    """Host-measured phases → synthetic trace → the SAME parser → the same
-    numbers back, with idle as the unattributed remainder."""
-    rec = tracelib.SpanRecorder()
-    rec.add_step(0, 0.010, {"fwd": 0.004, "bwd": 0.003, "optimizer": 0.001})
-    rec.add_step(1, 0.012, {"fwd": 0.005, "bwd": 0.004, "optimizer": 0.001})
-    steps = rec.breakdown()
-    assert [s["step"] for s in steps] == [0, 1]
-    assert steps[0]["fwd"] == pytest.approx(4.0)
-    assert steps[0]["idle"] == pytest.approx(2.0)
-    agg = tracelib.aggregate(steps)
-    assert agg["fwd"] == pytest.approx(4.5)
-    assert sum(agg[b] for b in tracelib.BUCKETS) == pytest.approx(
-        agg["step_ms"], rel=1e-6)
-
-
-def test_span_recorder_clips_overflowing_phases():
-    """A probe mis-measurement larger than the step window must clip — the
-    buckets can never sum past the wall time."""
-    rec = tracelib.SpanRecorder()
-    rec.add_step(0, 0.005, {"fwd": 0.004, "bwd": 0.004, "optimizer": 0.002})
-    (s,) = rec.breakdown()
-    assert s["fwd"] == pytest.approx(4.0)
-    assert s["bwd"] == pytest.approx(1.0)  # clipped at the window edge
-    assert s["optimizer"] == 0.0 and s["idle"] == 0.0
-    assert sum(s[b] for b in tracelib.BUCKETS) == pytest.approx(s["step_ms"])
-
-
-def test_span_recorder_rejects_unknown_phase():
-    rec = tracelib.SpanRecorder()
-    with pytest.raises(ValueError):
-        rec.add_step(0, 0.01, {"fwdd": 0.001})
-    with pytest.raises(ValueError):
-        rec.add_step(0, 0.01, {"idle": 0.001})  # idle is derived, not fed
-
-
 # ------------------------------------------------------------- event plane --
 
 def test_scenario_events_is_compat_reexport():
@@ -446,7 +256,7 @@ def test_http_metrics_exposition_and_json(tmp_path):
 
 def test_serve_metrics_registry_bridge_preserves_legacy_snapshot():
     """The instrument-backed ServeMetrics must report the EXACT legacy
-    snapshot keys/values (bench's serve row and /healthz key on them)."""
+    snapshot keys/values (`/healthz` and `/metrics.json` key on them)."""
     from ddp_classification_pytorch_tpu.serve.metrics import ServeMetrics
 
     m = ServeMetrics(latency_window=8)

@@ -1,14 +1,19 @@
-"""Ops-script consistency guards.
+"""Ops-script and document consistency guards.
 
 Scripts are not exercised by the unit suite, so give them the cheap static
 guards: every shell script must parse, and every repo path a script
 references must exist — a renamed helper breaks the referencing script
-exactly when someone reaches for it.
+exactly when someone reaches for it. The documents get the same guard: a
+file one of them names in code must be in the tree.
 """
 
+import functools
+import glob
 import os
 import re
 import subprocess
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCRIPTS = os.path.join(REPO, "scripts")
@@ -49,6 +54,51 @@ def test_script_repo_references_exist():
                     or os.path.isdir(os.path.join(REPO, mod))):
                 missing.append((os.path.basename(path), m.group(1)))
     assert not missing, missing
+
+
+# documents that describe the tree as it is (CHANGES.md, PERF.md and
+# ROADMAP.md are history: they name what was deleted)
+_DOCS = (["README.md", ".claude/skills/verify/SKILL.md"]
+         + sorted(os.path.relpath(p, REPO)
+                  for p in glob.glob(os.path.join(REPO, "docs", "*.md"))))
+_DOC_PATH = re.compile(
+    r"(?<![\w/.$<>~{}-])((?:[\w.-]+/)*[\w.-]+\.(?:py|sh|json))\b")
+# the upstream repo's files, cited as the reference
+_UPSTREAM = re.compile(r"^(?:(?:BASELINE|ARCFACE|CDR|NESTED|PLC)/.*|main\.py)$")
+# written by a run, under a directory the reader chose
+_WRITTEN_BY_A_RUN = {"report.json", "seed_spec.json", "manifest.json",
+                     "chiprun_out/chip_smoke.json"}
+
+
+@functools.lru_cache(maxsize=None)
+def _file_names_in_tree():
+    names = set()
+    for _, dirs, files in os.walk(REPO):
+        # hidden directories hold caches and unpacked copies of other commits
+        dirs[:] = [x for x in dirs if not x.startswith(".") and x != "chiprun_out"]
+        names.update(files)
+    return frozenset(names)
+
+
+@pytest.mark.parametrize("doc", _DOCS)
+def test_document_names_only_files_that_exist(doc):
+    """Every `*.py` / `*.sh` / `*.json` a document names in a code span or a
+    command exists: with a directory part, under the checkout or the
+    package; bare, somewhere in the tree under that name."""
+    with open(os.path.join(REPO, doc)) as f:
+        code = "\n".join(re.findall(r"```.*?```|`[^`\n]+`", f.read(), flags=re.S))
+    missing = set()
+    for path in set(_DOC_PATH.findall(code)) - _WRITTEN_BY_A_RUN:
+        if _UPSTREAM.match(path):
+            continue
+        if "/" in path:
+            found = any(os.path.exists(os.path.join(root, path)) for root in
+                        (REPO, os.path.join(REPO, "ddp_classification_pytorch_tpu")))
+        else:
+            found = path in _file_names_in_tree()
+        if not found:
+            missing.add(path)
+    assert not missing, (doc, sorted(missing))
 
 
 def _script_body(name):
@@ -184,13 +234,10 @@ def test_lint_script_flags_match_analyze_cli():
     assert "JAX_PLATFORMS=cpu" in body
 
 
-def test_zero_opt_knobs_locked_in_both_entrypoints():
-    """The ZeRO-1 / wire-dtype knobs must stay addressable from both
-    entrypoints with matching value sets: cli.train (underscore spelling,
-    feeds cfg.parallel) and bench.py (dashed spelling, feeds the e2e
-    row's collective/HBM evidence). The A/B workflow documented in
-    docs/performance.md dies silently if either side drops or renames a
-    knob — the drift failure mode this file exists to guard."""
+def test_zero_opt_knobs_locked_in_cli_train():
+    """The ZeRO-1 / wire-dtype knobs stay addressable from cli.train with
+    their value sets (underscore spelling, feeds cfg.parallel):
+    docs/performance.md's knob tables name them."""
     from ddp_classification_pytorch_tpu.cli.train import build_parser
 
     actions = {}
@@ -203,23 +250,12 @@ def test_zero_opt_knobs_locked_in_both_entrypoints():
         "cli.train lost --grad_reduce_dtype"
     assert set(actions["--grad_reduce_dtype"].choices) == \
         {"", "float32", "bfloat16"}
-    # bench.py is a script, not an importable module (import runs backend
-    # probes) — lock the dashed spellings and their value sets textually
-    with open(os.path.join(REPO, "bench.py")) as f:
-        src = f.read()
-    assert '"--zero-opt"' in src, "bench.py lost --zero-opt"
-    assert '"auto", "on", "off"' in src
-    assert '"--grad-reduce-dtype"' in src, "bench.py lost --grad-reduce-dtype"
-    assert '"float32", "bfloat16"' in src
 
 
-def test_grad_accum_h2d_knobs_locked_in_both_entrypoints():
-    """The grad-accum / H2D-overlap knobs must stay addressable from both
-    entrypoints: cli.train (underscore `--grad_accum`, dashed
-    `--h2d-overlap`; feed cfg.parallel/cfg.data) and bench.py (dashed
-    spellings; feed the e2e row's grad_accum /
-    collective_bytes_per_optimizer_step / h2d_overlap evidence). Same
-    drift guard as the ZeRO knobs above."""
+def test_grad_accum_h2d_knobs_locked_in_cli_train():
+    """The grad-accum / H2D-overlap knobs stay addressable from cli.train
+    (underscore `--grad_accum`, dashed `--h2d-overlap`; feed
+    cfg.parallel/cfg.data). Same drift guard as the ZeRO knobs above."""
     from ddp_classification_pytorch_tpu.cli.train import build_parser
 
     known = set()
@@ -231,10 +267,6 @@ def test_grad_accum_h2d_knobs_locked_in_both_entrypoints():
     assert "--grad_accum" in known, "cli.train lost --grad_accum"
     assert actions["--grad_accum"].type is int
     assert "--h2d-overlap" in known, "cli.train lost --h2d-overlap"
-    with open(os.path.join(REPO, "bench.py")) as f:
-        src = f.read()
-    assert '"--grad-accum"' in src, "bench.py lost --grad-accum"
-    assert '"--h2d-overlap"' in src, "bench.py lost --h2d-overlap"
 
 
 def test_serve_dp_aot_knobs_locked():

@@ -90,24 +90,27 @@ def test_preset_for_dataset_map():
 
 # ---------------------------------------------------------- wire plumbing --
 
-def test_loader_and_prefetcher_propagate_uint8():
-    """dataset uint8 → host batches uint8 → staged global arrays uint8
-    (¼ the H2D bytes), labels untouched."""
-    ds = SyntheticDataset(64, 16, 4, out_dtype="uint8")
+@pytest.mark.parametrize("wire,px_bytes", [("uint8", 1), ("float32", 4)])
+def test_loader_and_prefetcher_keep_the_wire_dtype(wire, px_bytes):
+    """dataset dtype → host batches → staged global arrays, unchanged: the
+    uint8 wire ships 1 B/px (¼ the H2D bytes of float32's 4), labels
+    untouched."""
+    ds = SyntheticDataset(64, 16, 4, out_dtype=wire)
     img, _ = ds.__getitem__(0)
-    assert img.dtype == np.uint8
+    assert img.dtype == np.dtype(wire)
     loader = ShardedLoader(ds, 16, shuffle=True, num_workers=1,
                            host_id=0, num_hosts=1)
     try:
         images, labels = next(iter(loader))
-        assert images.dtype == np.uint8 and images.shape == (16, 16, 16, 3)
+        assert images.dtype == np.dtype(wire)
+        assert images.shape == (16, 16, 16, 3)
         assert labels.dtype == np.int32
         mesh = meshlib.make_mesh()
         it = iter(DevicePrefetcher(loader, mesh, depth=1))
         try:
             g_images, g_labels = next(it)
-            assert g_images.dtype == jnp.uint8
-            assert g_images.nbytes * 4 == g_images.size * 4  # 1 B/px wire
+            assert g_images.dtype == jnp.dtype(wire)
+            assert g_images.nbytes == g_images.size * px_bytes
         finally:
             it.close()
     finally:
