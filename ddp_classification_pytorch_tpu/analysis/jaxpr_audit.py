@@ -400,16 +400,10 @@ def abstract_state(state, mesh, zero_opt: str = "auto"):
     `lower().compile()`, so one cached state init serves every audited
     mesh without per-mesh init compiles."""
     from ..parallel import mesh as meshlib
+    from ..train.state import state_shardings
 
-    zero = meshlib.zero_opt_enabled(zero_opt, mesh)
-    shardings = type(state)(
-        step=meshlib.replicated(mesh),
-        params=meshlib.param_shardings(state.params, mesh),
-        batch_stats=jax.tree_util.tree_map(
-            lambda _: meshlib.replicated(mesh), state.batch_stats),
-        opt_state=meshlib.opt_shardings(state.opt_state, mesh,
-                                        zero_data=zero),
-    )
+    shardings = state_shardings(
+        state, mesh, meshlib.zero_opt_enabled(zero_opt, mesh))
     return jax.tree_util.tree_map(
         lambda leaf, sh: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
                                               sharding=sh),

@@ -54,6 +54,7 @@ from ..obs.registry import Registry
 from ..ops.nested import best_k
 from ..parallel import fleet as fleetlib
 from ..parallel import mesh as meshlib
+from ..utils import cache as progcache
 from ..utils import chaos as chaoslib
 from ..utils.logging import EtaLogger, RecordWriter, host0_print, is_host0
 from .checkpoint import CheckpointManager
@@ -176,7 +177,10 @@ class Trainer:
             phases = self._setup(cfg, train_ds, val_ds, mesh)
         host0_print(
             f"[trainer] set-up: {whole.seconds:.2f} s ("
-            + ", ".join(f"{p.name.split('.', 1)[1]} {p.seconds:.2f}" for p in phases)
+            + ", ".join(
+                f"{p.name.split('.', 1)[1]} {p.seconds:.2f}"
+                + "".join(f" {k}={v}" for k, v in p.ids.items())
+                for p in phases)
             + ")")
 
     def _setup(self, cfg: Config, train_ds, val_ds, mesh) -> list:
@@ -287,8 +291,13 @@ class Trainer:
 
         self.steps_per_epoch = max(len(self.train_loader), 1)
         with phase("init_state"):
+            n0, s0 = progcache.compiled()
             self.model, self.tx, self.state = create_train_state(
                 cfg, self.mesh, self.steps_per_epoch)
+            # one jitted program and the seed's key: an eager op that
+            # creeps into set-up shows here before it shows in seconds
+            n1, s1 = progcache.compiled()
+            spans.note(compiles=n1 - n0, compile_s=round(s1 - s0, 3))
 
         with phase("build_steps"):
             self.train_step = make_train_step(cfg, self.model, self.tx,

@@ -45,67 +45,86 @@ def create_train_state(
 ):
     """Build (model, tx, sharded TrainState) for a workload config.
 
-    Parameters are initialized on host, placed according to
-    `parallel.mesh.param_shardings` (replicated under pure DP; class-dim
-    sharded heads under a >1 'model' axis), and the optimizer state is created
-    *under jit* so XLA propagates the parameter shardings into the momentum
-    tree — no hand-written opt-state sharding rules.
+    The state is the output of ONE jitted program: the initialisers' draws
+    from `rng`, the optimizer's zeros and step 0, each leaf born in its
+    final `NamedSharding` (`state_shardings`, read from the program's
+    output shapes before anything runs). Nothing here computes on the
+    device op by op: an eager `model.init` is a few hundred one-op
+    compiles at every process start, none of them long enough for the
+    persistent cache to keep. XLA drops the dummy forward that `init`
+    traces, since no output depends on it.
+
+    `--pretrained_path` makes it two programs with a host step between:
+    the variables, the numpy/torch overlay, then the state around them.
     """
+    if cfg.model.pretrained and not cfg.model.pretrained_path:
+        raise ValueError(
+            "model.pretrained=True requires model.pretrained_path: this "
+            "environment cannot download torchvision weights (zero "
+            "egress); supply a local .pth (torchvision state_dict or "
+            "reference NESTED format) via --pretrained_path")
     model = build_model(cfg.model, cfg.data.num_classes, mesh=mesh,
                         pipeline_microbatches=cfg.parallel.pipeline_microbatches)
-    if rng is None:
-        rng = jax.random.PRNGKey(cfg.run.seed)
-    p_rng, d_rng = jax.random.split(rng)
-
-    h = w = cfg.data.image_size
-    img = jnp.zeros((2, h, w, 3), jnp.float32)
-    if cfg.model.arch == "decoder_lm":
-        # token ids; parameters do not depend on T, so a few positions do
-        img = jnp.zeros((2, min(cfg.model.decoder.seq_len, 8)), jnp.int32)
-    rngs = {"params": p_rng, "dropout": d_rng}
-    if cfg.model.head == "arcface":
-        variables = model.init(rngs, img, jnp.zeros((2,), jnp.int32), train=False)
-    elif cfg.model.head == "nested":
-        variables = model.init(rngs, img, None, train=False)
-    else:
-        variables = model.init(rngs, img, train=False)
-
-    if cfg.model.pretrained:
-        if not cfg.model.pretrained_path:
-            raise ValueError(
-                "model.pretrained=True requires model.pretrained_path: this "
-                "environment cannot download torchvision weights (zero "
-                "egress); supply a local .pth (torchvision state_dict or "
-                "reference NESTED format) via --pretrained_path")
-        variables = _load_pretrained(cfg, variables)
-    params = variables["params"]
-    batch_stats = variables.get("batch_stats", {})
-
     tx = build_optimizer(cfg.optim, steps_per_epoch, freeze_bn=cfg.model.freeze_bn,
                          grad_accum=cfg.parallel.grad_accum)
+    if rng is None:
+        rng = jax.random.PRNGKey(cfg.run.seed)
 
-    params = jax.device_put(params, meshlib.param_shardings(params, mesh))
-    batch_stats = jax.device_put(batch_stats, meshlib.replicated(mesh))
-    # jit does NOT propagate param shardings into the momentum leaves (they
-    # land on one device); re-place them under the explicit rules so the
-    # whole state carries NamedShardings — required for restore, where leaves
-    # are device_put onto the template's shardings (parallel/mesh.py).
+    def init_variables(rng):
+        p_rng, d_rng = jax.random.split(rng)
+        if cfg.model.arch == "decoder_lm":
+            # token ids; parameters do not depend on T, so a few positions do
+            inputs = [jnp.zeros((2, min(cfg.model.decoder.seq_len, 8)), jnp.int32)]
+        else:
+            h = w = cfg.data.image_size
+            inputs = [jnp.zeros((2, h, w, 3), jnp.float32)]
+        if cfg.model.head == "arcface":
+            inputs.append(jnp.zeros((2,), jnp.int32))  # labels
+        elif cfg.model.head == "nested":
+            inputs.append(None)
+        return model.init({"params": p_rng, "dropout": d_rng}, *inputs, train=False)
+
+    def state_around(variables):
+        return TrainState(
+            step=jnp.zeros((), jnp.int32),
+            params=variables["params"],
+            batch_stats=variables.get("batch_stats", {}),
+            opt_state=tx.init(variables["params"]),
+        )
+
     # Under ZeRO-1 (parallel.zero_opt, default auto=on when the data axis
     # spans devices) each big momentum leaf additionally partitions over
     # 'data' — the step's output constraints (train/steps.py) keep the
     # layout stable, so every state buffer aliases across steps.
     zero = meshlib.zero_opt_enabled(cfg.parallel.zero_opt, mesh)
-    opt_state = jax.jit(tx.init)(params)
-    opt_state = jax.device_put(
-        opt_state, meshlib.opt_shardings(opt_state, mesh, zero_data=zero))
 
-    state = TrainState(
-        step=jax.device_put(jnp.zeros((), jnp.int32), meshlib.replicated(mesh)),
-        params=params,
-        batch_stats=batch_stats,
-        opt_state=opt_state,
-    )
+    def born_sharded(make, arg):
+        shapes = jax.eval_shape(make, arg)
+        return jax.jit(make, out_shardings=state_shardings(shapes, mesh, zero))(arg)
+
+    if cfg.model.pretrained:
+        variables = _load_pretrained(cfg, jax.jit(init_variables)(rng))
+        state = born_sharded(state_around, variables)
+    else:
+        state = born_sharded(lambda r: state_around(init_variables(r)), rng)
     return model, tx, state
+
+
+def state_shardings(state: TrainState, mesh: Any, zero: bool) -> TrainState:
+    """The declared layout: the `NamedSharding` of every leaf of a TrainState
+    (arrays, avals or tracers: only shape and dtype are read). Parameters by
+    `param_shardings` (replicated under pure DP; class-dim sharded heads
+    under a >1 'model' axis), batch stats and step replicated, the optimizer
+    state by `opt_shardings` (ZeRO-1's data-axis shards when `zero`). The
+    initial state is born in it, the train step pins its output to it, and
+    restore places onto it (parallel/mesh.py)."""
+    rep = meshlib.replicated(mesh)
+    return TrainState(
+        step=rep,
+        params=meshlib.param_shardings(state.params, mesh),
+        batch_stats=jax.tree_util.tree_map(lambda _: rep, state.batch_stats),
+        opt_state=meshlib.opt_shardings(state.opt_state, mesh, zero_data=zero),
+    )
 
 
 def _load_pretrained(cfg: Config, variables):

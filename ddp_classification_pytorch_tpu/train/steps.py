@@ -54,7 +54,7 @@ from ..ops.nested import (
     sample_mask_dims,
 )
 from ..utils.metrics import topk_correct, topk_hits
-from .state import TrainState
+from .state import TrainState, state_shardings
 
 Batch = Tuple[jnp.ndarray, jnp.ndarray]  # (images NHWC u8|f32, labels i32)
 
@@ -554,22 +554,9 @@ def _constrain_state(state: TrainState, mesh: Any, zero: bool) -> TrainState:
     audit cell: donation coverage 0.47 unconstrained, 1.0 with these
     constraints. Specs are computed from the tracer trees at trace time,
     so they follow the state's actual shapes."""
-    from ..parallel import mesh as meshlib
-
-    def c(x, sharding):
-        return jax.lax.with_sharding_constraint(x, sharding)
-
-    rep = meshlib.replicated(mesh)
-    return state.replace(
-        step=c(state.step, rep),
-        params=jax.tree_util.tree_map(
-            c, state.params, meshlib.param_shardings(state.params, mesh)),
-        batch_stats=jax.tree_util.tree_map(
-            lambda x: c(x, rep), state.batch_stats),
-        opt_state=jax.tree_util.tree_map(
-            c, state.opt_state,
-            meshlib.opt_shardings(state.opt_state, mesh, zero_data=zero)),
-    )
+    return jax.tree_util.tree_map(
+        jax.lax.with_sharding_constraint, state,
+        state_shardings(state, mesh, zero))
 
 
 def _build_step(tx, base_rng, loss_fn, metrics_fn, chaos=None, flip=False,

@@ -13,9 +13,10 @@ DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
 
 # what the compiler did in this process, from jax.monitoring's own events:
-# entries read from / written to the persistent cache, and backend compile
-# seconds (chip_smoke.py reads the line the CLIs print from this)
-_stats = {"dir": "", "hits": 0, "misses": 0, "compile_s": 0.0}
+# entries read from / written to the persistent cache, and the backend
+# programs built (compiled, or loaded from that cache) with their seconds
+# (chip_smoke.py reads the line the CLIs print from this)
+_stats = {"dir": "", "hits": 0, "misses": 0, "compiles": 0, "compile_s": 0.0}
 _EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
            "/jax/compilation_cache/cache_misses": "misses"}
 _listening = False
@@ -34,10 +35,20 @@ def _listen() -> None:
 
     def on_duration(event, secs, **_):
         if event == "/jax/core/compile/backend_compile_duration":
+            _stats["compiles"] += 1
             _stats["compile_s"] += secs
 
     monitoring.register_event_listener(on_event)
     monitoring.register_event_duration_secs_listener(on_duration)
+
+
+def compiled() -> "tuple[int, float]":
+    """(backend programs built so far in this process, their seconds): read
+    it before and after a stretch of set-up to see what that stretch
+    compiled. One eager op on the device is one program, so a stretch that
+    should be a single jitted call reads 1 to 3 here, not hundreds."""
+    _listen()
+    return _stats["compiles"], _stats["compile_s"]
 
 
 def compile_stats_line() -> str:

@@ -29,8 +29,8 @@ from ddp_classification_pytorch_tpu.analysis.sharding_audit import (
 from ddp_classification_pytorch_tpu.config import get_preset
 from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
 from ddp_classification_pytorch_tpu.train.state import (
-    TrainState,
     create_train_state,
+    state_shardings,
 )
 from ddp_classification_pytorch_tpu.train.steps import (
     make_topk_predict_step,
@@ -157,14 +157,8 @@ def _abstract_state(cfg, mesh):
         return state
 
     shape = jax.eval_shape(build)
-    rep = meshlib.replicated(mesh)
-    zero = meshlib.zero_opt_enabled(cfg.parallel.zero_opt, mesh)
-    shardings = TrainState(
-        step=rep,
-        params=meshlib.param_shardings(shape.params, mesh),
-        batch_stats=jax.tree_util.tree_map(lambda _: rep, shape.batch_stats),
-        opt_state=meshlib.opt_shardings(shape.opt_state, mesh,
-                                        zero_data=zero))
+    shardings = state_shardings(
+        shape, mesh, meshlib.zero_opt_enabled(cfg.parallel.zero_opt, mesh))
     state = jax.tree_util.tree_map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         shape, shardings)

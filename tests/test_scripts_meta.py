@@ -67,7 +67,7 @@ _DOC_PATH = re.compile(
 _UPSTREAM = re.compile(r"^(?:(?:BASELINE|ARCFACE|CDR|NESTED|PLC)/.*|main\.py)$")
 # written by a run, under a directory the reader chose
 _WRITTEN_BY_A_RUN = {"report.json", "seed_spec.json", "manifest.json",
-                     "chiprun_out/chip_smoke.json"}
+                     "chiprun_out/chip_smoke.json", ".chip_smoke/chip_smoke.json"}
 
 
 @functools.lru_cache(maxsize=None)

@@ -237,6 +237,16 @@ def test_setup_spans_nest_under_setup_trainer(trainer, capsys):
     line = [ln for ln in capsys.readouterr().out.splitlines()
             if ln.startswith("[trainer] set-up: ")]
     assert len(line) == 1 and "init_state" in line[0] and "datasets" not in line[0]
+    init = [s for s in since(mark) if s.name == "setup.init_state"][-1]
+    assert f"compiles={init.ids['compiles']} compile_s={init.ids['compile_s']}" in line[0]
+
+
+def test_init_state_span_says_what_it_compiled(trainer):
+    """One jitted program and the seed's key (train/state.py): the count and
+    the seconds of the backend compiles that fell inside the span."""
+    init = {s.name: s for s in trainer.setup_spans}["setup.init_state"]
+    assert 1 <= init.ids["compiles"] <= 3
+    assert 0.0 < init.ids["compile_s"] <= (init.end_ns - init.start_ns) * 1e-9
 
 
 @pytest.mark.parametrize("depth,overlap,stager", [
