@@ -89,7 +89,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "vit_t16/s16/b16 (reference --model + extensions) | "
                         "decoder_lm (a token decoder: next-token training "
                         "as per-position classification; sizes in the "
-                        "'decoder' group, defaults = the published "
+                        "'decoder' group: grouped-query or latent "
+                        "attention, dense / routed / shared feed-forward, "
+                        "softmax or sigmoid router, a multi-token-prediction "
+                        "module; defaults = the published "
                         "SmallThinker-21BA3B-Instruct)")
     m.add_argument("--flash_attention", action="store_true",
                    help="ViT: Pallas streaming attention kernel for the "
@@ -137,7 +140,16 @@ def build_parser() -> argparse.ArgumentParser:
                        ("experts_held", int), ("first_expert", int),
                        ("top_k", int), ("window", int), ("seq_len", int),
                        ("head_block", int), ("rope_theta", float),
-                       ("rms_eps", float)):
+                       ("rms_eps", float),
+                       # the kinds of layer (config.DecoderConfig says what
+                       # each value means)
+                       ("attention", str), ("q_rank", int), ("kv_rank", int),
+                       ("rope_dim", int), ("v_head_dim", int),
+                       ("rope_pairing", str), ("dense_layers", int),
+                       ("dense_width", int), ("activation", str),
+                       ("router", str), ("router_scale", float),
+                       ("router_tap", str), ("shared_experts", int),
+                       ("mtp_layers", int), ("mtp_weight", float)):
         dec.add_argument(f"--{flag}", type=kind, default=None)
     for flag in ("rope_layout", "window_layout"):
         dec.add_argument(f"--{flag}", default=None,

@@ -69,9 +69,14 @@ class DataConfig:
 
 @dataclass
 class DecoderConfig:
-    """Sizes of the token decoder (`--model decoder_lm`, models/decoder_lm.py)
-    — the one place they live; the CLI fills it. Defaults are the published
-    SmallThinker-21BA3B-Instruct config.json (PowerInfer, arXiv:2507.20984).
+    """Sizes and kinds of the token decoder (`--model decoder_lm`,
+    models/decoder_lm.py) — the one place they live; the CLI fills it.
+    Defaults are the published SmallThinker-21BA3B-Instruct config.json
+    (PowerInfer, arXiv:2507.20984). The layer is described by data: the
+    attention kind, the feed-forward kind per layer (`dense_layers` leading
+    dense ones, then routed experts with or without a shared expert), the
+    router's scoring and tap, the activation, the rotary pairing, and a
+    multi-token-prediction module after the last layer.
 
     A deployment that spreads a layer's experts and the vocabulary's rows
     over several chips gives each chip its share: `experts_held` experts
@@ -101,6 +106,34 @@ class DecoderConfig:
     # rows of (B·T) the head and its loss take at a time, so that float32
     # logits over the vocabulary never stand whole (ops/lm_head.py)
     head_block: int = 2048
+    # attention: "gqa" = grouped-query heads of `head_dim`, rotary over the
+    # whole head; "mla" = latent attention (DeepSeek-V2/V3): queries through
+    # a rank-`q_rank` bottleneck, keys and values from a rank-`kv_rank`
+    # latent, scores over `head_dim` dims without position + `rope_dim`
+    # rotary dims whose key part is ONE head shared by every query head,
+    # values of `v_head_dim`
+    attention: str = "gqa"
+    q_rank: int = 0
+    kv_rank: int = 0
+    rope_dim: int = 0
+    v_head_dim: int = 0              # 0 = head_dim
+    rope_pairing: str = "half"       # "half": i with i + D/2 | "interleaved": 2i with 2i + 1
+    # feed-forward: the first `dense_layers` layers are one gated MLP of
+    # `dense_width`; the others route over the experts
+    dense_layers: int = 0
+    dense_width: int = 0
+    activation: str = "relu"         # the gate's: "relu" (ReGLU) | "silu" (SwiGLU)
+    # router: "softmax" = softmax over the top-k logits; "sigmoid" = sigmoid
+    # scores, top-k of score + a bias only selection reads, weights = the
+    # chosen scores renormalised and times `router_scale`
+    router: str = "softmax"
+    router_scale: float = 1.0
+    router_tap: str = "pre"          # reads the layer's normed input: "pre" attention | "post"
+    shared_experts: int = 0          # experts every token takes (width x this many)
+    # multi-token prediction (DeepSeek-V3 eq. 21-25): 0 or 1 extra layer that
+    # predicts the token after next through the shared embedding and head
+    mtp_layers: int = 0
+    mtp_weight: float = 0.3
 
     @property
     def held(self) -> int:
@@ -109,6 +142,16 @@ class DecoderConfig:
     def layout(self, which: Sequence[int]) -> tuple:
         """A layout list repeated to the depth."""
         return tuple(int(which[i % len(which)]) for i in range(self.num_layers))
+
+    @property
+    def value_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    def moe_layer_names(self) -> tuple:
+        """What each row of the step's `moe_load` is: the layers that route,
+        then the prediction module's."""
+        return (tuple(str(i) for i in range(self.dense_layers, self.num_layers))
+                + ("mtp",) * self.mtp_layers)
 
 
 @dataclass

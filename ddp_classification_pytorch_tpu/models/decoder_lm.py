@@ -1,42 +1,75 @@
 """A decoder over token ids — next-token training as classification of every
 position over the vocabulary.
 
-The layer is the one SmallThinker-21BA3B-Instruct publishes (PowerInfer,
-arXiv:2507.20984; every size comes from `config.DecoderConfig`, which the CLI
-fills — no table of variants here). With x (B, T, C), every projection
-without bias:
+ONE layer module, described by data (`config.DecoderConfig`, which the CLI
+fills — no table of variants, no second model file). Two published layers
+are its fixed points: SmallThinker-21BA3B-Instruct (PowerInfer,
+arXiv:2507.20984; the defaults) and the DeepSeek-V3 layer (arXiv:2412.19437
+§2.1-2.2) as JoyAI-LLM-Flash configures it. With x (B, T, C), every
+projection without bias:
 
     h  = RMSNorm(x)                        input norm
-    r  = h W_r                             router logits, taken BEFORE attention
-    a  = attention(q, k, v)                H query heads on H_kv KV heads;
-                                           layers with rope_layout = 1 rotate q
-                                           and k (rotate-half, whole head_dim),
-                                           the others carry no position at all;
+    a  = attention(h)                      "gqa": H query heads on H_kv KV
+                                           heads of head_dim; layers with
+                                           rope_layout = 1 rotate q and k over
+                                           the whole head, the others carry no
+                                           position at all
+                                           "mla": latent attention —
+                                           c_q = RMSNorm(h W_qa);  q = c_q W_qb
+                                             → H x [q_nope head_dim | q_rope rope_dim]
+                                           [c | k_r] = h W_kva     k_r: ONE head
+                                           [k_nope | v] = RMSNorm(c) W_kvb
+                                           rotary on q_rope and k_r only;
+                                           scores (q_nope·k_nope + q_rope·k_r)
+                                           / sqrt(head_dim + rope_dim)
                                            mask: causal, and where
                                            window_layout = 1 also j > i − window
     x1 = x + a W_o
     u  = RMSNorm(x1)
-    y  = Σ_{e ∈ top-k(r)} softmax(r[top-k])_e · W_down^e(relu(W_gate^e u) · W_up^e u)
+    y  = layers < dense_layers: W_down(act(W_gate u) · W_up u), one gated MLP
+         the others: Σ_{e ∈ chosen} g_e · W_down^e(act(W_gate^e u) · W_up^e u)
+                     + (shared_experts > 0) the same unit on every token
     x2 = x1 + y
 
-then a final RMSNorm and an untied head. No dense feed-forward layer, no
-shared expert, no auxiliary loss. The router reads `h` (the normed input):
-the published description says "router placed before attention" and no more.
+The router's logits r = t W_r are taken from t = h (router_tap "pre": before
+attention, as SmallThinker places it) or t = u ("post"); "softmax" scoring
+chooses the top-k of r and weighs them by their softmax, "sigmoid" chooses
+the top-k of sigmoid(r) + bias and weighs by the chosen sigmoid(r),
+renormalised and times router_scale (ops/moe.py::route_top_k). The bias is a
+leaf that selection alone reads: its gradient is zero and no rule moves it
+here (ROADMAP, Reach). act = relu (ReGLU) or silu (SwiGLU). Rotary pairing:
+"half" (i with i + D/2) or "interleaved" (2i with 2i + 1).
+
+Then a final RMSNorm and an untied head. With mtp_layers = 1 a multi-token
+prediction module (DeepSeek-V3 eq. 21-25) follows the last layer: with hL its
+output before the final norm and `targets` the row shifted by one,
+
+    h'  = [RMSNorm(hL) ; RMSNorm(Emb(targets))] W_eh        Emb shared
+    h'' = one more routed layer, its own weights
+    RMSNorm(h'') → the shared head, against the token after next
+
+(the step, train/steps.py::_lm_loss, adds mtp_weight x that loss).
 
 This chip may hold a share of each layer (`experts_held`, `first_expert`,
 a slice of the vocabulary): the router keeps its full width, the expert
 layer (ops/moe.py::sparse_moe) computes its own experts' part, and under a
 `model` mesh axis > 1 the banks shard over it and a psum completes the sum.
+A shared expert is held by every chip and counted once.
 
 TPU-first: bf16 matmuls with f32 accumulation, f32 params, norms, router and
-softmax; attention through the Pallas flash kernels (window band and grouped
-KV heads, ops/flash_attention.py) wherever they tile T, else the dense op;
-`hidden` stops before the head so the train step can take head and loss in
-row blocks (ops/lm_head.py).
+softmax; attention through the Pallas flash kernels (window band, grouped KV
+heads, the latent scores' shared rotary key: ops/flash_attention.py) wherever
+they tile T, else the dense op; `hidden` stops before the head so the train
+step can take head and loss in row blocks (ops/lm_head.py).
+
+Device scopes (`jax.named_scope`, docs/observability.md): `attn`, `ffn`,
+`moe.route` / `.dispatch` / `.experts` / `.combine` / `.shared`, `mtp`
+(outermost, around the whole module), `lm_head`.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -46,7 +79,7 @@ import jax.numpy as jnp
 from ..config import DecoderConfig
 from ..ops.attention import attention, flash_supported
 from ..ops.flash_attention import flash_attention
-from ..ops.moe import sparse_moe
+from ..ops.moe import GATE_ACTIVATIONS, sparse_moe
 
 
 def rotate_half(x: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -60,6 +93,18 @@ def rotate_half(x: jnp.ndarray, theta: float) -> jnp.ndarray:
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., : d // 2], x32[..., d // 2:]
     return (x32 * cos + jnp.concatenate([-x2, x1], axis=-1) * sin).astype(x.dtype)
+
+
+def rotate_interleaved(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary embedding pairing dimension 2i with 2i + 1. The pairs are
+    brought side to side first (even dimensions, then odd) and rotated as
+    halves: q and k come out in the same permuted order, which their dot
+    product does not see."""
+    return rotate_half(
+        jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1), theta)
+
+
+_ROTARY = {"half": rotate_half, "interleaved": rotate_interleaved}
 
 
 class RMSNorm(nn.Module):
@@ -100,19 +145,30 @@ class DecoderLayer(nn.Module):
     mesh: Optional[Any] = None
     expert_axis: Optional[str] = None
     flash_min_tokens: int = 1024
+    routed: bool = True     # False: one dense gated MLP (a leading layer)
 
-    @nn.compact
-    def __call__(self, x: jnp.ndarray):
-        c = self.cfg
-        b, t, dim = x.shape
-        h32 = RMSNorm(c.rms_eps, name="norm_in")(x)
+    def _gated_mlp(self, u, width: int, prefix: str):
+        """W_down(act(W_gate u) · W_up u): the dense layer's feed-forward
+        and the shared expert."""
+        act = GATE_ACTIVATIONS[self.cfg.activation]
+        gate = _dense(width, self.dtype, f"{prefix}_gate")(u)
+        up = _dense(width, self.dtype, f"{prefix}_up")(u)
+        return _dense(u.shape[-1], self.dtype, f"{prefix}_down")(act(gate) * up)
+
+    def _router_logits(self, t32):
         with jax.named_scope("moe.route"):
             router = self.param("router", nn.initializers.lecun_normal(),
-                                (dim, c.num_experts), jnp.float32)
-            logits = jnp.einsum("btc,ce->bte", h32, router,
-                                precision=jax.lax.Precision.HIGHEST)
-        with jax.named_scope("attn"):
-            h = h32.astype(self.dtype)
+                                (t32.shape[-1], self.cfg.num_experts), jnp.float32)
+            return jnp.einsum("btc,ce->bte", t32, router,
+                              precision=jax.lax.Precision.HIGHEST)
+
+    def _qkv(self, h):
+        """→ q, k, v (B, T, heads, ·) and the scores' second part (q_rope,
+        k_rope) or ()."""
+        c = self.cfg
+        b, t, _ = h.shape
+        rotary = functools.partial(_ROTARY[c.rope_pairing], theta=c.rope_theta)
+        if c.attention == "gqa":
             q = _dense(c.num_heads * c.head_dim, self.dtype, "q")(h)
             k = _dense(c.num_kv_heads * c.head_dim, self.dtype, "k")(h)
             v = _dense(c.num_kv_heads * c.head_dim, self.dtype, "v")(h)
@@ -120,15 +176,55 @@ class DecoderLayer(nn.Module):
             k = k.reshape(b, t, c.num_kv_heads, c.head_dim)
             v = v.reshape(b, t, c.num_kv_heads, c.head_dim)
             if self.rope:
-                q, k = rotate_half(q, c.rope_theta), rotate_half(k, c.rope_theta)
+                q, k = rotary(q), rotary(k)
+            return q, k, v, ()
+        # latent attention: queries through a normed bottleneck; one normed
+        # latent gives every head its position-free key and its value, and
+        # one rotary key head serves all query heads
+        heads, hd, dr = c.num_heads, c.head_dim, c.rope_dim
+        cq = RMSNorm(c.rms_eps, name="q_norm")(
+            _dense(c.q_rank, self.dtype, "q_a")(h)).astype(self.dtype)
+        q = _dense(heads * (hd + dr), self.dtype, "q_b")(cq)
+        q = q.reshape(b, t, heads, hd + dr)
+        kv = _dense(c.kv_rank + dr, self.dtype, "kv_a")(h)
+        ckv = RMSNorm(c.rms_eps, name="kv_norm")(
+            kv[..., :c.kv_rank]).astype(self.dtype)
+        kv_b = _dense(heads * (hd + c.value_dim), self.dtype, "kv_b")(ckv)
+        kv_b = kv_b.reshape(b, t, heads, hd + c.value_dim)
+        q_rope, k_rope = q[..., hd:], kv[..., c.kv_rank:].reshape(b, t, 1, dr)
+        if self.rope:
+            q_rope, k_rope = rotary(q_rope), rotary(k_rope)
+        return q[..., :hd], kv_b[..., :hd], kv_b[..., hd:], (q_rope, k_rope)
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        c = self.cfg
+        b, t, dim = x.shape
+        h32 = RMSNorm(c.rms_eps, name="norm_in")(x)
+        if self.routed and c.router_tap == "pre":
+            logits = self._router_logits(h32)
+        with jax.named_scope("attn"):
+            q, k, v, rope = self._qkv(h32.astype(self.dtype))
             # the kernels where they tile T and beat the dense op
             # (ModelConfig.flash_min_tokens), else the (T, T) op
             core = (flash_attention
                     if flash_supported(t) and t >= self.flash_min_tokens
                     else attention)
-            a = core(q, k, v, causal=True, window=self.window)
+            a = core(q, k, v, causal=True, window=self.window,
+                     **(dict(q_rope=rope[0], k_rope=rope[1]) if rope else {}))
             x = x + _dense(dim, self.dtype, "o")(a.reshape(b, t, -1))
-        u = RMSNorm(c.rms_eps, name="norm_post")(x).astype(self.dtype)
+        u32 = RMSNorm(c.rms_eps, name="norm_post")(x)
+        u = u32.astype(self.dtype)
+        if not self.routed:
+            with jax.named_scope("ffn"):
+                return x + self._gated_mlp(u, c.dense_width, "ffn").astype(x.dtype), None
+        if c.router_tap != "pre":
+            logits = self._router_logits(u32)
+        route = None
+        if c.router == "sigmoid":
+            route = dict(scoring="sigmoid", scale=c.router_scale,
+                         bias=self.param("router_bias", nn.initializers.zeros,
+                                         (c.num_experts,), jnp.float32))
         init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
         w_gate = self.param("w_gate", init, (c.held, dim, c.expert_width), jnp.float32)
@@ -146,14 +242,41 @@ class DecoderLayer(nn.Module):
             u.reshape(b * t, dim), logits.reshape(b * t, -1), w_gate, w_up,
             w_down, top_k=c.top_k, first_expert=c.first_expert,
             dtype=self.dtype, mesh=self.mesh, axis=self.expert_axis,
-            batch_axis=batch_axis)
-        return x + y.reshape(b, t, dim).astype(x.dtype), load
+            batch_axis=batch_axis, activation=c.activation, route=route)
+        y = y.reshape(b, t, dim)
+        if c.shared_experts:
+            with jax.named_scope("moe.shared"):
+                y = y + self._gated_mlp(
+                    u, c.shared_experts * c.expert_width, "shared")
+        return x + y.astype(x.dtype), load
+
+
+class MTPModule(nn.Module):
+    """Multi-token prediction, depth 1 (DeepSeek-V3 eq. 21-23): the last
+    layer's output (before the final norm) and the embedding of the NEXT
+    token, each normed, joined in the paper's order [h ; e], projected to C
+    and put through one more routed layer → final-normed states whose head
+    output predicts the token after next."""
+
+    make_layer: Any         # name → a routed DecoderLayer
+    eps: float
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, h_last: jnp.ndarray, emb_next: jnp.ndarray):
+        joined = jnp.concatenate(
+            [RMSNorm(self.eps, name="norm_h")(h_last),
+             RMSNorm(self.eps, name="norm_e")(emb_next)], axis=-1)
+        x = _dense(h_last.shape[-1], self.dtype, "proj")(joined.astype(self.dtype))
+        x, load = self.make_layer("layer")(x)
+        return RMSNorm(self.eps, name="norm_final")(x).astype(self.dtype), load
 
 
 class DecoderLM(nn.Module):
     """tokens (B, T) i32 → logits (B, T, V) f32; `hidden` → the final-normed
-    states (B, T, C) and the per-layer token-slot loads of the held experts
-    (L, e) — what the row-blocked head and the step's metrics take."""
+    states (B, T, C) and the token-slot loads of the held experts, one row a
+    routing layer (`DecoderConfig.moe_layer_names`) — what the row-blocked
+    head and the step's metrics take."""
 
     cfg: DecoderConfig
     dtype: Any = jnp.bfloat16
@@ -173,24 +296,43 @@ class DecoderLM(nn.Module):
         layer = (nn.remat(DecoderLayer, policy=jax.checkpoint_policies
                           .save_only_these_names("flash_out", "flash_lse"))
                  if self.remat else DecoderLayer)
-        rope, window = c.layout(c.rope_layout), c.layout(c.window_layout)
-        self.layers = [
-            layer(c, bool(rope[i]), c.window if window[i] else None,
-                  self.dtype, self.mesh, self.expert_axis,
-                  self.flash_min_tokens, name=f"layer{i}")
-            for i in range(c.num_layers)]
+
+        def build(i: int, name: str):
+            return layer(c, bool(c.rope_layout[i % len(c.rope_layout)]),
+                         c.window if c.window_layout[i % len(c.window_layout)]
+                         else None,
+                         self.dtype, self.mesh, self.expert_axis,
+                         self.flash_min_tokens, i >= c.dense_layers, name=name)
+
+        self.layers = [build(i, f"layer{i}") for i in range(c.num_layers)]
         self.norm_final = RMSNorm(c.rms_eps, name="norm_final")
         self.lm_head = Head(c.vocab_size, self.dtype, name="lm_head")
+        if c.mtp_layers:
+            # its layer continues the layouts: index = the depth
+            self.mtp = MTPModule(functools.partial(build, c.num_layers),
+                                 c.rms_eps, self.dtype, name="mtp")
 
-    def hidden(self, tokens: jnp.ndarray, train: bool = True):
+    def hidden(self, tokens: jnp.ndarray, train: bool = True,
+               targets: Optional[jnp.ndarray] = None):
+        """→ (h, loads). With `targets` (the row shifted by one) and a
+        prediction module also its states: (h, loads, h_mtp), the module's
+        loads in the last row."""
         x = self.embed(tokens).astype(self.dtype)
         loads = []
         for layer in self.layers:
             x, load = layer(x)
-            loads.append(load)
-        return self.norm_final(x).astype(self.dtype), jnp.stack(loads)
+            if load is not None:
+                loads.append(load)
+        out = self.norm_final(x).astype(self.dtype)
+        if targets is None or not self.cfg.mtp_layers:
+            return out, jnp.stack(loads)
+        with jax.named_scope("mtp"):
+            h_mtp, load = self.mtp(x, self.embed(targets).astype(self.dtype))
+        return out, jnp.stack(loads + [load]), h_mtp
 
     def __call__(self, tokens: jnp.ndarray, train: bool = True) -> jnp.ndarray:
-        h, _ = self.hidden(tokens, train)
+        # init has to reach the prediction module's leaves too
+        targets = tokens if self.is_initializing() else None
+        h = self.hidden(tokens, train, targets)[0]
         with jax.named_scope("lm_head"):
             return self.lm_head(h)

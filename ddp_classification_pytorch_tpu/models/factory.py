@@ -105,6 +105,21 @@ def build_decoder_lm(cfg: ModelConfig, num_classes: int,
         raise ValueError(
             f"experts {dc.first_expert}..{dc.first_expert + dc.held - 1} are "
             f"not among the router's {dc.num_experts}")
+    kinds = {"attention": ("gqa", "mla"), "rope_pairing": ("half", "interleaved"),
+             "activation": ("relu", "silu"), "router": ("softmax", "sigmoid"),
+             "router_tap": ("pre", "post")}
+    for key, allowed in kinds.items():
+        if getattr(dc, key) not in allowed:
+            raise ValueError(f"decoder {key}={getattr(dc, key)!r}: one of {allowed}")
+    if dc.attention == "mla" and not (dc.q_rank and dc.kv_rank and dc.rope_dim):
+        raise ValueError("latent attention needs --q_rank, --kv_rank and --rope_dim")
+    if not 0 <= dc.dense_layers <= dc.num_layers or (dc.dense_layers
+                                                     and not dc.dense_width):
+        raise ValueError(f"{dc.dense_layers} dense layers of width "
+                         f"{dc.dense_width} in a depth of {dc.num_layers}")
+    if dc.mtp_layers not in (0, 1):
+        raise ValueError("multi-token prediction is built at depth 0 or 1, "
+                         f"got mtp_layers={dc.mtp_layers}")
     mp = mesh.shape.get(MODEL_AXIS, 1) if mesh is not None else 1
     return DecoderLM(dc, dtype=jnp.dtype(cfg.dtype), remat=cfg.remat,
                      mesh=mesh if mp > 1 else None,

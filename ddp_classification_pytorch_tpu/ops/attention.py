@@ -51,14 +51,23 @@ def attention(
     causal: bool = False,
     scale: Optional[float] = None,
     window: Optional[int] = None,
+    q_rope: Optional[jnp.ndarray] = None,
+    k_rope: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Dense scaled-dot-product attention.
 
-    q: (B, T, H, D); k, v: (B, T, H_kv, D) with H a multiple of H_kv (query
-    head h reads KV head h // (H / H_kv); no repeated K/V is built).
-    Returns (B, T, H, D) in q.dtype. Softmax in f32. `window` (causal only)
-    keeps keys j with i − window < j ≤ i.
+    q: (B, T, H, D); k: (B, T, H_kv, D), v: (B, T, H_kv, Dv) with H a
+    multiple of H_kv (query head h reads KV head h // (H / H_kv); no repeated
+    K/V is built). Returns (B, T, H, Dv) in q.dtype. Softmax in f32. `window`
+    (causal only) keeps keys j with i − window < j ≤ i. `q_rope` (B, T, H,
+    Dr) / `k_rope` (B, T, H_r, Dr) are a second part of the scores (latent
+    attention): here they are simply joined to q and k, `k_rope` repeated to
+    k's heads — the small-T op may; the kernels do not.
     """
+    if q_rope is not None:
+        k_rope = jnp.repeat(k_rope, k.shape[2] // k_rope.shape[2], axis=2)
+        q = jnp.concatenate([q, q_rope], axis=-1)
+        k = jnp.concatenate([k, k_rope], axis=-1)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if window is not None and not causal:
@@ -81,7 +90,7 @@ def attention(
     out = jnp.einsum(pv, p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     if grouped:
-        out = out.reshape(b, t, h, d)
+        out = out.reshape(b, t, h, v.shape[-1])
     return out.astype(q.dtype)
 
 

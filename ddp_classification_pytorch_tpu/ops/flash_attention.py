@@ -35,7 +35,14 @@ the standard flash-attention memory shape, expressed the Pallas/Mosaic way
 - grouped KV heads (H_q = g·H_kv, models/decoder_lm.py): the K/V index maps
   read block `i // g` for query head `i`, and the dK/dV kernel walks the g
   query heads of its KV head in a second sequential grid dimension — no
-  repeated K/V is ever materialized.
+  repeated K/V is ever materialized;
+- the value dimension is v's own (it need not be the score dimension), and
+  the scores may have a second part (`q_rope`, `k_rope`: latent attention,
+  DeepSeek-V2/V3): S = (Q·Kᵀ + Q_r·K_rᵀ)·scale, two matmuls a tile, with
+  K_r held by FEWER heads than K (one for all, in the published models) and
+  indexed `i // g_r` the same way — no key that repeats K_r per head, no
+  value padded to the score dimension. dK_r sums over every query head that
+  read it inside the dK/dV kernel's sequential head dimension.
 """
 
 from __future__ import annotations
@@ -114,33 +121,47 @@ def _last_q_block(bq: int, bk: int, jk, nq: int, window):
     return jnp.minimum(((jk + 1) * bk + window - 2) // bq, nq - 1)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                  *, scale, nk, causal, window=None):
+def _scores(q, kb, rope, scale):
+    """(bq, bk) f32 scaled scores of one tile; `rope` = (q_r tile, k_r tile)
+    adds the second part of a latent-attention score before the scale."""
+    s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if rope is not None:
+        s = s + jax.lax.dot_general(rope[0], rope[1], (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+    return s * scale
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale, nk, causal, window=None,
+                  rope=False):
     """One (batch·head, q-block, kv-block) grid step.
 
     The kv axis is the LAST grid dimension — sequential on TPU — so the
     online-softmax accumulators persist in VMEM scratch across kv steps and
-    only one (block_k, D) K/V tile is resident at a time."""
+    only one (block_k, D) K/V tile is resident at a time. With `rope` two
+    more inputs follow v: the q_r tile and the (shared) k_r tile."""
+    if rope:
+        qr_ref, kr_ref, *rest = rest
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     jq, kk = pl.program_id(1), pl.program_id(2)
     # Operands stay in their input dtype (bf16 in the default recipe) so the
     # MXU runs at full rate; every accumulation is f32 via
     # preferred_element_type, and the softmax statistics are f32 throughout.
     q = q_ref[0]                                # (bq, D)
-    bq, d = q.shape
+    bq = q.shape[0]
     bk = k_ref.shape[1]
 
     @pl.when(kk == 0)
     def _init():
         m_scr[:] = jnp.full((bq, _LANES), _NEG_INF, jnp.float32)
         l_scr[:] = jnp.zeros((bq, _LANES), jnp.float32)
-        acc_scr[:] = jnp.zeros((bq, d), jnp.float32)
+        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     def _update():
         kb = k_ref[0]                           # (bk, D)
-        vb = v_ref[0]
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale          # (bq, bk)
+        vb = v_ref[0]                           # (bk, Dv)
+        s = _scores(q, kb, (qr_ref[0], kr_ref[0]) if rope else None,
+                    scale)                                       # (bq, bk)
         if causal:
             allowed = _causal_mask(bq, bk, jq, kk, window)
             s = jnp.where(allowed, s, _NEG_INF)
@@ -182,11 +203,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         lse_ref[0] = m_scr[:, :1] + jnp.log(l_scr[:, :1])
 
 
-def _flash_forward(q3, k3, v3, scale, causal=False, window=None):
-    """q (bh, T, D), k/v (bh // g, T, D) → (out (bh, T, D), lse (bh, T, 1)
-    f32); g query heads share each KV head."""
+def _flash_forward(q3, k3, v3, scale, causal=False, window=None, qr3=None,
+                   kr3=None):
+    """q (bh, T, D), k (bh // g, T, D), v (bh // g, T, Dv) → (out (bh, T,
+    Dv), lse (bh, T, 1) f32); g query heads share each KV head. `qr3` (bh,
+    T, Dr) and `kr3` (bh // g_r, T, Dr): the scores' second part."""
     bh, t, d = q3.shape
+    dv = v3.shape[-1]
     group = bh // k3.shape[0]
+    rope = qr3 is not None
     # cap 512 matches the backward's VMEM reasoning: at 1024 blocks with
     # d=128, the (bq, bk) f32 score+probability tiles (~8 MB) plus operands
     # and double-buffered K/V approach the 16 MB budget on some generations
@@ -198,49 +223,63 @@ def _flash_forward(q3, k3, v3, scale, causal=False, window=None):
         # the fetched kv block to the diagonal makes those steps re-request
         # the resident tile, so their DMA is elided as well (bq == bk by
         # construction of _block).
-        kv_idx = lambda i, j, kk: (  # noqa: E731
-            i // group,
-            jnp.clip(kk, _first_kv_block(bq, bk, j, window), j), 0)
+        kv_block = lambda j, kk: jnp.clip(  # noqa: E731
+            kk, _first_kv_block(bq, bk, j, window), j)
     else:
-        kv_idx = lambda i, j, kk: (i // group, kk, 0)  # noqa: E731
+        kv_block = lambda j, kk: kk  # noqa: E731
+    kv_idx = lambda i, j, kk: (i // group, kv_block(j, kk), 0)  # noqa: E731
+    q_idx = lambda i, j, kk: (i, j, 0)  # noqa: E731
+    in_specs = [
+        pl.BlockSpec((1, bq, d), q_idx, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bk, d), kv_idx, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, bk, dv), kv_idx, memory_space=pltpu.VMEM),
+    ]
+    args = (q3, k3, v3)
+    if rope:
+        dr, rgroup = qr3.shape[-1], bh // kr3.shape[0]
+        in_specs += [
+            pl.BlockSpec((1, bq, dr), q_idx, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bk, dr),
+                         lambda i, j, kk: (i // rgroup, kv_block(j, kk), 0),
+                         memory_space=pltpu.VMEM),
+        ]
+        args += (qr3, kr3)
     return pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, nk=t // bk,
-                          causal=causal, window=window),
+                          causal=causal, window=window, rope=rope),
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), q3.dtype),
             jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
         ],
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), kv_idx, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), kv_idx, memory_space=pltpu.VMEM),
-        ],
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bq, dv), q_idx, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, bq, 1), q_idx, memory_space=pltpu.VMEM),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, _LANES), jnp.float32),   # running max m
             pltpu.VMEM((bq, _LANES), jnp.float32),   # normalizer l
-            pltpu.VMEM((bq, d), jnp.float32),        # output accumulator
+            pltpu.VMEM((bq, dv), jnp.float32),       # output accumulator
         ],
         interpret=_interpret(),
         name="flash_fwd",
-    )(q3, k3, v3)
+    )(*args)
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref,
-               dq_scr, *, scale, nk, causal, window=None):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
+               scale, nk, causal, window=None, rope=False):
     """Grid (bh, q-block, kv-block): stream K/V past a fixed q block,
-    accumulating dQ = Σ_k dS·K·scale in VMEM scratch."""
+    accumulating dQ = Σ_k dS·K·scale in VMEM scratch (and, with `rope`,
+    dQ_r = Σ_k dS·K_r·scale beside it: inputs q_r, k_r follow Δ)."""
+    if rope:
+        qr_ref, kr_ref, dq_ref, dqr_ref, dq_scr, dqr_scr = rest
+    else:
+        dq_ref, dq_scr = rest
     jq, kk = pl.program_id(1), pl.program_id(2)
     q = q_ref[0]                                # (bq, D) input dtype
     bq, d = q.shape
@@ -249,16 +288,17 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref,
     @pl.when(kk == 0)
     def _init():
         dq_scr[:] = jnp.zeros((bq, d), jnp.float32)
+        if rope:
+            dqr_scr[:] = jnp.zeros(dqr_scr.shape, jnp.float32)
 
     def _update():
         kb = k_ref[0]                           # (bk, D)
         vb = v_ref[0]
-        do = do_ref[0]                          # (bq, D)
+        do = do_ref[0]                          # (bq, Dv)
         lse = lse_ref[0]                        # (bq, 1) f32
         dsum = dsum_ref[0]                      # (bq, 1) f32
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale          # (bq, bk)
+        s = _scores(q, kb, (qr_ref[0], kr_ref[0]) if rope else None,
+                    scale)                                       # (bq, bk)
         if causal:
             # lse is finite, so exp(−NEG_INF − lse) underflows to exactly
             # 0 — masking s alone zeroes P (and thus dS) on forbidden
@@ -272,6 +312,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref,
         dq_scr[:] += jax.lax.dot_general(
             ds, kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
+        if rope:
+            dqr_scr[:] += jax.lax.dot_general(
+                ds, kr_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
 
     if causal:
         pl.when(_tile_live(bq, bk, jq, kk, window))(_update)
@@ -281,41 +325,58 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, dq_ref,
     @pl.when(kk == nk - 1)
     def _write():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        if rope:
+            dqr_ref[0] = dqr_scr[:].astype(dqr_ref.dtype)
 
 
-def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, scale, nq, causal,
-                window=None, group=1):
+def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref, *rest,
+                scale, nq, causal, window=None, group=1, heads=None):
     """Grid (kv heads, kv-block, query head of the group, q-block): stream
     the Q/dO of every query head that reads this KV head past a fixed kv
     block, accumulating dK = Σ_q dSᵀ·Q·scale and dV = Σ_q Pᵀ·dO in VMEM
-    scratch (both trailing grid dimensions are sequential)."""
+    scratch (both trailing grid dimensions are sequential).
+
+    With `heads` (latent attention: inputs k_r, q_r follow Δ) the first grid
+    dimension walks the heads of K_r and the third the `heads` query heads
+    that read each: dK and dV still close after every `group` of them, and
+    dK_r = Σ_q dSᵀ·Q_r·scale runs on over all."""
+    rope = heads is not None
+    if rope:
+        kr_ref, qr_ref, dk_ref, dv_ref, dkr_ref, dk_scr, dv_scr, dkr_scr = rest
+    else:
+        dk_ref, dv_ref, dk_scr, dv_scr = rest
     jk, gg, qq = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     kb = k_ref[0]                               # (bk, D) input dtype
     bk, d = kb.shape
     bq = q_ref.shape[1]
+    # this KV head's place among the query heads that read it
+    place = gg % group if rope else gg
 
-    @pl.when((gg == 0) & (qq == 0))
+    @pl.when((place == 0) & (qq == 0))
     def _init():
         dk_scr[:] = jnp.zeros((bk, d), jnp.float32)
-        dv_scr[:] = jnp.zeros((bk, d), jnp.float32)
+        dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    if rope:
+        @pl.when((gg == 0) & (qq == 0))
+        def _init_rope():
+            dkr_scr[:] = jnp.zeros(dkr_scr.shape, jnp.float32)
 
     def _update():
         vb = v_ref[0]
         q = q_ref[0]                            # (bq, D)
-        do = do_ref[0]                          # (bq, D)
+        do = do_ref[0]                          # (bq, Dv)
         lse = lse_ref[0]                        # (bq, 1) f32
         dsum = dsum_ref[0]                      # (bq, 1) f32
-        s = jax.lax.dot_general(
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale          # (bq, bk)
+        s = _scores(q, kb, (qr_ref[0], kr_ref[0]) if rope else None,
+                    scale)                                       # (bq, bk)
         if causal:
             # q-block index is the LAST grid dim here; kv-block is dim 1
             s = jnp.where(_causal_mask(bq, bk, qq, jk, window), s, _NEG_INF)
         p = jnp.exp(s - lse)
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # (bk, D)
+            preferred_element_type=jnp.float32)                  # (bk, Dv)
         dp = jax.lax.dot_general(
             do, vb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                  # (bq, bk)
@@ -323,103 +384,132 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref,
         dk_scr[:] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
+        if rope:
+            dkr_scr[:] += jax.lax.dot_general(
+                ds, qr_ref[0], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
 
     if causal:
         pl.when(_tile_live(bq, bk, qq, jk, window))(_update)
     else:
         _update()
 
-    @pl.when((gg == group - 1) & (qq == nq - 1))
+    @pl.when((place == group - 1) & (qq == nq - 1))
     def _write():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
+    if rope:
+        @pl.when((gg == heads - 1) & (qq == nq - 1))
+        def _write_rope():
+            dkr_ref[0] = dkr_scr[:].astype(dkr_ref.dtype)
+
 
 def _flash_backward_impl(q3, k3, v3, do3, lse, dsum, scale, causal=False,
-                         window=None):
-    """(bh, T, D) q/dO, (bh // g, T, D) k/v + (bh, T, 1) lse/Δ → (dq, dk,
-    dv), O(T·D) HBM.
+                         window=None, qr3=None, kr3=None):
+    """(bh, T, D) q, (bh, T, Dv) dO, (bh // g, T, D | Dv) k | v + (bh, T, 1)
+    lse/Δ → (dq, dk, dv), O(T·D) HBM; with the scores' second part (`qr3`
+    (bh, T, Dr), `kr3` (bh // g_r, T, Dr)) → (dq, dk, dv, dq_r, dk_r).
 
     The score tile is recomputed per block pair in both kernels; the only
     HBM residuals are out/lse from the forward. Blocks are capped at 512 so
     the (bq, bk) f32 score/probability tiles plus the (block, D) operand
     tiles fit VMEM alongside the accumulators."""
     bh, t, d = q3.shape
+    dv = v3.shape[-1]
     bh_kv = k3.shape[0]
     group = bh // bh_kv
+    rope = qr3 is not None
     bq = _block(t, cap=512)
     bk = _block(t, cap=512)
     nq, nk = t // bq, t // bk
+    # the dK/dV kernel's first grid dimension and the query heads under each
+    # of its entries: the KV heads and their group, or K_r's heads and theirs
+    outer = kr3.shape[0] if rope else bh_kv
+    heads = bh // outer
 
     if causal:
         # Same DMA-elision trick as the forward: compute-skipped steps
         # re-request a block of the band (bq == bk by construction).
-        kv_idx = lambda i, j, kk: (  # noqa: E731
-            i // group,
-            jnp.clip(kk, _first_kv_block(bq, bk, j, window), j), 0)
-        q_row_idx = lambda i, j, g, qq: (  # noqa: E731
-            i * group + g,
-            jnp.clip(qq, j, _last_q_block(bq, bk, j, nq, window)), 0)
+        kv_block = lambda j, kk: jnp.clip(  # noqa: E731
+            kk, _first_kv_block(bq, bk, j, window), j)
+        q_block = lambda j, qq: jnp.clip(  # noqa: E731
+            qq, j, _last_q_block(bq, bk, j, nq, window))
     else:
-        kv_idx = lambda i, j, kk: (i // group, kk, 0)  # noqa: E731
-        q_row_idx = lambda i, j, g, qq: (i * group + g, qq, 0)  # noqa: E731
+        kv_block = lambda j, kk: kk  # noqa: E731
+        q_block = lambda j, qq: qq  # noqa: E731
+    kv_idx = lambda i, j, kk: (i // group, kv_block(j, kk), 0)  # noqa: E731
+    q_row_idx = lambda i, j, g, qq: (  # noqa: E731
+        i * heads + g, q_block(j, qq), 0)
+    q_idx = lambda i, j, kk: (i, j, 0)  # noqa: E731
 
+    def vmem(shape, index):
+        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+    in_specs = [vmem((1, bq, d), q_idx), vmem((1, bk, d), kv_idx),
+                vmem((1, bk, dv), kv_idx), vmem((1, bq, dv), q_idx),
+                vmem((1, bq, 1), q_idx), vmem((1, bq, 1), q_idx)]
+    args = (q3, k3, v3, do3, lse, dsum)
+    out_shape = jax.ShapeDtypeStruct((bh, t, d), q3.dtype)
+    out_specs = vmem((1, bq, d), q_idx)
+    scratch = [pltpu.VMEM((bq, d), jnp.float32)]
+    if rope:
+        dr = qr3.shape[-1]
+        in_specs += [vmem((1, bq, dr), q_idx),
+                     vmem((1, bk, dr),
+                          lambda i, j, kk: (i // heads, kv_block(j, kk), 0))]
+        args += (qr3, kr3)
+        out_shape = [out_shape, jax.ShapeDtypeStruct((bh, t, dr), qr3.dtype)]
+        out_specs = [out_specs, vmem((1, bq, dr), q_idx)]
+        scratch.append(pltpu.VMEM((bq, dr), jnp.float32))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, nk=nk, causal=causal,
-                          window=window),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
+                          window=window, rope=rope),
+        out_shape=out_shape,
         grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), kv_idx, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), kv_idx, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), lambda i, j, kk: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda i, j, kk: (i, j, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
         interpret=_interpret(),
         name="flash_dq",
-    )(q3, k3, v3, do3, lse, dsum)
+    )(*args)
 
-    dk, dv = pl.pallas_call(
+    if rope:
+        # the KV head of query head g under K_r's head i, and its block
+        kv_own = lambda i, j, g, qq: ((i * heads + g) // group, j, 0)  # noqa: E731
+    else:
+        kv_own = lambda i, j, g, qq: (i, j, 0)  # noqa: E731
+    in_specs = [vmem((1, bk, d), kv_own), vmem((1, bk, dv), kv_own),
+                vmem((1, bq, d), q_row_idx), vmem((1, bq, dv), q_row_idx),
+                vmem((1, bq, 1), q_row_idx), vmem((1, bq, 1), q_row_idx)]
+    args = (k3, v3, q3, do3, lse, dsum)
+    out_shape = [jax.ShapeDtypeStruct((bh_kv, t, d), k3.dtype),
+                 jax.ShapeDtypeStruct((bh_kv, t, dv), v3.dtype)]
+    out_specs = [vmem((1, bk, d), kv_own), vmem((1, bk, dv), kv_own)]
+    scratch = [pltpu.VMEM((bk, d), jnp.float32),
+               pltpu.VMEM((bk, dv), jnp.float32)]
+    if rope:
+        kr_own = lambda i, j, g, qq: (i, j, 0)  # noqa: E731
+        in_specs += [vmem((1, bk, dr), kr_own), vmem((1, bq, dr), q_row_idx)]
+        args += (kr3, qr3)
+        out_shape.append(jax.ShapeDtypeStruct(kr3.shape, kr3.dtype))
+        out_specs.append(vmem((1, bk, dr), kr_own))
+        scratch.append(pltpu.VMEM((bk, dr), jnp.float32))
+    dkv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, nq=nq, causal=causal,
-                          window=window, group=group),
-        out_shape=[
-            jax.ShapeDtypeStruct((bh_kv, t, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh_kv, t, d), v3.dtype),
-        ],
-        grid=(bh_kv, nk, group, nq),
-        in_specs=[
-            pl.BlockSpec((1, bk, d), lambda i, j, g, qq: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda i, j, g, qq: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, d), q_row_idx, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, d), q_row_idx, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), q_row_idx, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq, 1), q_row_idx, memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda i, j, g, qq: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), lambda i, j, g, qq: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
+                          window=window, group=group,
+                          heads=heads if rope else None),
+        out_shape=out_shape,
+        grid=(outer, nk, heads, nq),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
         interpret=_interpret(),
         name="flash_dkv",
-    )(k3, v3, q3, do3, lse, dsum)
-    return dq, dk, dv
+    )(*args)
+    if rope:
+        return dq[0], dkv[0], dkv[1], dq[1], dkv[2]
+    return dq, dkv[0], dkv[1]
 
 
 # ---------------------------------------------------------------------------
@@ -439,24 +529,42 @@ def _to4(x3, b, h):
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     scale: Optional[float] = None,
                     causal: bool = False,
-                    window: Optional[int] = None) -> jnp.ndarray:
-    """Scaled-dot-product attention, (B, T, H, D) → (B, T, H, D), optionally
+                    window: Optional[int] = None,
+                    q_rope: Optional[jnp.ndarray] = None,
+                    k_rope: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Scaled-dot-product attention, (B, T, H, D) → (B, T, H, Dv), optionally
     causal (row i attends keys ≤ i, matching ops/attention.py::attention)
     and, with `window`, banded (keys i − window < j ≤ i). k/v may carry
-    fewer heads than q (H_q a multiple of H_kv: grouped-query attention).
+    fewer heads than q (H_q a multiple of H_kv: grouped-query attention),
+    and v its own last dimension Dv.
+
+    `q_rope` (B, T, H, Dr) and `k_rope` (B, T, H_r, Dr), H_kv a multiple of
+    H_r, add q_rope·k_ropeᵀ to the scores (latent attention: the rotary part
+    of the key is one head that every query head reads); the default scale is
+    then (D + Dr)^-½.
 
     Forward and backward are both Pallas streaming kernels: O(T·D) HBM
     traffic, no (T, T) tensor materialized in either pass. Token counts
     the kernels cannot tile cleanly (see `_supported`) fall back to the
     framework's dense op — same math, same signature.
     """
-    if (k.shape != v.shape or q.shape[:2] != k.shape[:2]
+    if (k.shape[:3] != v.shape[:3] or q.shape[:2] != k.shape[:2]
             or q.shape[3] != k.shape[3] or q.shape[2] % k.shape[2]):
         # Self-attention kernel: one T for q and kv. Without this check a
         # shorter k/v would silently read clamped (repeated) tail blocks.
         raise ValueError(
             f"flash_attention requires q/k/v of equal shape (or k/v with a "
-            f"divisor of q's heads), got {q.shape}/{k.shape}/{v.shape}")
+            f"divisor of q's heads, v with its own last dimension), got "
+            f"{q.shape}/{k.shape}/{v.shape}")
+    if (q_rope is None) != (k_rope is None):
+        raise ValueError("flash_attention: q_rope and k_rope come together")
+    if q_rope is not None and (
+            q_rope.shape[:3] != q.shape[:3] or k_rope.shape[:2] != k.shape[:2]
+            or k_rope.shape[3] != q_rope.shape[3]
+            or k.shape[2] % k_rope.shape[2]):
+        raise ValueError(
+            f"flash_attention: q_rope {q_rope.shape} / k_rope {k_rope.shape} "
+            f"do not go with q {q.shape} / k {k.shape}")
     if window is not None and not causal:
         raise ValueError("flash_attention: a window needs causal=True")
     if window is not None and window >= q.shape[1]:
@@ -470,7 +578,10 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             f"flash_attention: T={q.shape[1]} is not kernel-tileable "
             "(need T <= 512 or a multiple of 128); falling through to the "
             "dense op ops.attention.attention", stacklevel=2)
-        return attention(q, k, v, causal=causal, scale=scale, window=window)
+        return attention(q, k, v, causal=causal, scale=scale, window=window,
+                         q_rope=q_rope, k_rope=k_rope)
+    if q_rope is not None:
+        return _flash_rope(q, k, v, q_rope, k_rope, scale, causal, window)
     return _flash(q, k, v, scale, causal, window)
 
 
@@ -479,11 +590,13 @@ def _flash(q, k, v, scale, causal, window=None):
     return _fa_fwd(q, k, v, scale, causal, window)[0]
 
 
-def _fa_fwd(q, k, v, scale, causal, window=None):
-    s = scale if scale is not None else q.shape[-1] ** -0.5
+def _fa_fwd(q, k, v, scale, causal, window=None, q_rope=None, k_rope=None):
+    rope = () if q_rope is None else (_to3(q_rope), _to3(k_rope))
+    s = scale if scale is not None else (
+        q.shape[-1] + (rope[0].shape[-1] if rope else 0)) ** -0.5
     b, _, h, _ = q.shape
     q3, k3, v3 = _to3(q), _to3(k), _to3(v)
-    out3, lse = _flash_forward(q3, k3, v3, s, causal, window)
+    out3, lse = _flash_forward(q3, k3, v3, s, causal, window, *rope)
     # names for a rematerialization policy: a caller that saves these two
     # (`jax.checkpoint_policies.save_only_these_names`) does not run the
     # forward kernel again in its backward pass
@@ -491,28 +604,42 @@ def _fa_fwd(q, k, v, scale, causal, window=None):
     lse = checkpoint_name(lse, "flash_lse")
     # Residuals keep the 3D views the backward kernels consume directly —
     # saving the 4D originals instead would re-pay three transpose passes.
-    return _to4(out3, b, h), (q3, k3, v3, out3, lse)
+    return _to4(out3, b, h), (q3, k3, v3, out3, lse) + rope
 
 
 def _fa_bwd(scale, causal, window, res, g):
-    q3, k3, v3, out3, lse = res
+    q3, k3, v3, out3, lse, *rope = res
     # Re-resolve from the static nondiff arg: the kernels bake `scale` into
     # their compiled body, so it must stay a Python float, not a residual
     # array.
-    s = scale if scale is not None else q3.shape[-1] ** -0.5
+    s = scale if scale is not None else (
+        q3.shape[-1] + (rope[0].shape[-1] if rope else 0)) ** -0.5
     b, _, h, _ = g.shape  # cotangent carries the static 4D layout
     do3 = _to3(g)
     # Softmax-gradient row term Δ = rowsum(dO ⊙ O): one elementwise pass,
     # f32, shaped like lse so the kernels read it as a (bq, 1) tile.
     dsum = jnp.sum(do3.astype(jnp.float32) * out3.astype(jnp.float32),
                    axis=-1, keepdims=True)
-    dq3, dk3, dv3 = _flash_backward_impl(q3, k3, v3, do3, lse, dsum, s,
-                                         causal, window)
+    dq3, dk3, dv3, *drope = _flash_backward_impl(
+        q3, k3, v3, do3, lse, dsum, s, causal, window, *rope)
     h_kv = k3.shape[0] // b
-    return (_to4(dq3, b, h), _to4(dk3, b, h_kv), _to4(dv3, b, h_kv))
+    out = (_to4(dq3, b, h), _to4(dk3, b, h_kv), _to4(dv3, b, h_kv))
+    if rope:
+        out += (_to4(drope[0], b, h), _to4(drope[1], b, rope[1].shape[0] // b))
+    return out
 
 
 _flash.defvjp(_fa_fwd, _fa_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_rope(q, k, v, q_rope, k_rope, scale, causal, window=None):
+    return _fa_fwd(q, k, v, scale, causal, window, q_rope, k_rope)[0]
+
+
+_flash_rope.defvjp(
+    lambda q, k, v, q_rope, k_rope, scale, causal, window:
+    _fa_fwd(q, k, v, scale, causal, window, q_rope, k_rope), _fa_bwd)
 
 
 # ---------------------------------------------------------------------------

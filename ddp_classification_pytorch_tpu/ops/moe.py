@@ -39,7 +39,6 @@ The ViT's split-FFN path above still dispatches densely (ROADMAP D6).
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -160,12 +159,37 @@ def moe_mlp(
 # sparse dropless dispatch over the experts held here
 # ---------------------------------------------------------------------------
 
-def route_top_k(logits: jnp.ndarray, top_k: int):
+def route_top_k(logits: jnp.ndarray, top_k: int, *, scoring: str = "softmax",
+                bias: Optional[jnp.ndarray] = None, scale: float = 1.0):
     """(N, E) f32 router logits → (expert ids (N, k) i32, weights (N, k)
-    f32): the k largest logits and the softmax over those k (= the full
-    softmax renormalised over the chosen)."""
-    vals, idx = jax.lax.top_k(logits.astype(jnp.float32), top_k)
-    return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+    f32). "softmax": the k largest logits and the softmax over those k (=
+    the full softmax renormalised over the chosen). "sigmoid" (DeepSeek-V3):
+    scores s = sigmoid(logits); the k largest of s + `bias` are chosen —
+    the bias (E,) steers selection and nothing else, its gradient is zero —
+    and the weights are the chosen experts' UNBIASED scores, renormalised
+    over the chosen and times `scale`."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        vals, idx = jax.lax.top_k(logits, top_k)
+        return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+    if scoring != "sigmoid":
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    scores = jax.nn.sigmoid(logits)
+    biased = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    # the k largest by k passes of argmax (ties to the lower id, as top_k's),
+    # each pass reading its expert's unbiased score through the same one-hot
+    # mask: no sort, and no gather whose transpose is a scatter-add into
+    # (N, E)
+    picked, chosen = [], []
+    for _ in range(top_k):
+        best = jnp.argmax(biased, axis=-1)
+        hit = jax.nn.one_hot(best, biased.shape[-1], dtype=bool)
+        picked.append(best)
+        chosen.append(jnp.sum(jnp.where(hit, scores, 0.0), axis=-1))
+        biased = jnp.where(hit, -jnp.inf, biased)
+    idx, chosen = jnp.stack(picked, axis=-1), jnp.stack(chosen, axis=-1)
+    weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), weights
 
 
 @jax.custom_vjp
@@ -207,12 +231,16 @@ _collect_slots.defvjp(
     lambda order, g: (g[order], None, None, None))
 
 
+# the gate of a gated unit: ReGLU | SwiGLU
+GATE_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
+
+
 def _sparse_experts(u, logits, w_gate, w_up, w_down, *, top_k, first_expert,
-                    dtype):
+                    dtype, activation="relu", route=None):
     n, _ = u.shape
     held = w_gate.shape[0]
     with jax.named_scope("moe.route"):
-        idx, w = route_top_k(logits, top_k)
+        idx, w = route_top_k(logits, top_k, **(route or {}))
     with jax.named_scope("moe.dispatch"):
         local = idx.T.reshape(-1) - first_expert          # (S,) S = k·N
         mine = (local >= 0) & (local < held)
@@ -226,15 +254,15 @@ def _sparse_experts(u, logits, w_gate, w_up, w_down, *, top_k, first_expert,
         def grouped(x, bank):
             return jax.lax.ragged_dot(x, bank, load, preferred_element_type=dtype)
 
-        # ReGLU without bias; gate and up as one grouped matmul over the
-        # banks side by side. Rows past the last group belong to no expert
-        # held here; what the kernels leave in them is not defined and
-        # nothing below reads it
+        # a gated unit without bias (ReGLU | SwiGLU); gate and up as one
+        # grouped matmul over the banks side by side. Rows past the last
+        # group belong to no expert held here; what the kernels leave in
+        # them is not defined and nothing below reads it
         width = w_gate.shape[-1]
         both = grouped(rows, jnp.concatenate(
             [w_gate.astype(dtype), w_up.astype(dtype)], axis=-1))
-        y = grouped(jax.nn.relu(both[:, :width]) * both[:, width:],
-                    w_down.astype(dtype))
+        y = grouped(GATE_ACTIVATIONS[activation](both[:, :width])
+                    * both[:, width:], w_down.astype(dtype))
     with jax.named_scope("moe.combine"):
         y = _collect_slots(y, order, inv, mine).reshape(top_k, n, -1)
         w = jnp.where(mine.reshape(top_k, n), w.T, 0.0)
@@ -255,11 +283,15 @@ def sparse_moe(
     mesh: Optional[Mesh] = None,
     axis: Optional[str] = None,
     batch_axis: Optional[str] = None,
+    activation: str = "relu",
+    route: Optional[dict] = None,
 ):
-    """Sparse mixture of ReGLU experts for the tokens u (N, C).
+    """Sparse mixture of gated experts (`activation` on the gate: "relu" =
+    ReGLU, "silu" = SwiGLU) for the tokens u (N, C).
 
     `logits` (N, E) are the router's, over ALL E experts, handed in by the
-    caller (the decoder takes them before attention). The banks w_gate /
+    caller (the decoder takes them before or after attention); `route` are
+    `route_top_k`'s keywords (scoring, bias, scale). The banks w_gate /
     w_up (e, C, H) and w_down (e, H, C) are the e experts held here, ids
     `first_expert .. first_expert + e − 1`; slots routed elsewhere add
     nothing. Returns (y (N, C) f32 = Σ over the chosen held experts of
@@ -275,21 +307,29 @@ def sparse_moe(
         raise ValueError(
             f"experts {first_expert}..{first_expert + e - 1} are not among "
             f"the router's {logits.shape[-1]}")
-    core = functools.partial(_sparse_experts, top_k=top_k, dtype=dtype)
+    route = dict(route or {})
+    bias = route.pop("bias", None)
+
+    def core(u, logits, w_gate, w_up, w_down, bias, first_expert):
+        return _sparse_experts(
+            u, logits, w_gate, w_up, w_down, top_k=top_k, dtype=dtype,
+            first_expert=first_expert, activation=activation,
+            route=route if bias is None else dict(route, bias=bias))
+
     n_shards = mesh.shape[axis] if (mesh is not None and axis) else 1
     if n_shards <= 1:
-        return core(u, logits, w_gate, w_up, w_down, first_expert=first_expert)
+        return core(u, logits, w_gate, w_up, w_down, bias, first_expert)
     if e % n_shards:
         raise ValueError(f"num experts {e} not divisible by axis size {n_shards}")
 
-    def body(u, logits, w_gate, w_up, w_down):
+    def body(u, logits, w_gate, w_up, w_down, bias):
         first = first_expert + jax.lax.axis_index(axis) * w_gate.shape[0]
-        part, load = core(u, logits, w_gate, w_up, w_down, first_expert=first)
+        part, load = core(u, logits, w_gate, w_up, w_down, bias, first)
         if batch_axis:
             load = jax.lax.psum(load, batch_axis)
         return jax.lax.psum(part, axis), load             # EP combine
 
     bank, rows = P(axis, None, None), P(batch_axis, None)
     return shard_map_unchecked(
-        body, mesh=mesh, in_specs=(rows, rows, bank, bank, bank),
-        out_specs=(rows, P(axis)))(u, logits, w_gate, w_up, w_down)
+        body, mesh=mesh, in_specs=(rows, rows, bank, bank, bank, P()),
+        out_specs=(rows, P(axis)))(u, logits, w_gate, w_up, w_down, bias)

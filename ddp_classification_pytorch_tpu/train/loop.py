@@ -516,18 +516,20 @@ class Trainer:
 
     def _publish_moe_load(self, load: np.ndarray) -> None:
         """The logged step's routing, as the step's metrics carry it —
-        `load` (L, e): token-slots each held expert took in each layer."""
-        for i, row in enumerate(load):
-            layer = {"layer": str(i)}
+        `load` (L, e): token-slots each held expert took in each routing
+        layer (`DecoderConfig.moe_layer_names`: the layer's index, or "mtp"
+        for the prediction module's)."""
+        dc = self.cfg.model.decoder
+        for name, row in zip(dc.moe_layer_names(), load):
+            layer = {"layer": name}
             self.obs.gauge("moe_expert_load_max", "token-slots of the "
                            "busiest held expert in the logged step",
                            layer).set(float(row.max()))
             self.obs.gauge("moe_expert_load_mean", "mean token-slots of a "
                            "held expert in the logged step",
                            layer).set(float(row.mean()))
-        dc = self.cfg.model.decoder
         routed = float(self.cfg.data.batch_size * jax.process_count()
-                       * dc.seq_len * dc.top_k * dc.num_layers)
+                       * dc.seq_len * dc.top_k * len(load))
         for held, n in (("true", float(load.sum())),
                         ("false", routed - float(load.sum()))):
             self.obs.counter("moe_slots_routed_total", "token-slots the "
@@ -679,6 +681,13 @@ class Trainer:
                 last = {**train_m, **val_m, "epoch_time": time.time() - t0}
                 self._epochs_counter.inc()
                 self._loss_gauge.set(last.get("loss", 0.0))
+                for part in ("loss_main", "loss_mtp"):
+                    if part in last:  # a decoder with a prediction module
+                        self.obs.gauge(
+                            f"train_{part}", "mean of the step's metric "
+                            f"`{part}` over the last completed epoch "
+                            "(train_loss = loss_main + mtp_weight x loss_mtp)"
+                        ).set(last[part])
                 if "val_top1" in last:
                     self._val_top1_gauge.set(last["val_top1"])
                 self._epoch_seconds_gauge.set(last["epoch_time"])

@@ -304,20 +304,35 @@ def _dense_loss_fn(cfg: Config, model: Any):
 
 
 def _lm_sums(cfg: Config, model: Any, params, tokens, targets, train: bool,
-             weights=None):
+             weights=None, mtp: bool = False):
     """Token decoder: Σ cross-entropy, top-1 and top-3 counts over every
-    position (each scaled by `weights`), and the layers' expert loads — the
-    hidden states go through head and loss in row blocks."""
+    position (each scaled by `weights`), and the routing layers' expert
+    loads — the hidden states go through head and loss in row blocks. With
+    `mtp` (a configuration with a prediction module) a fifth value: the
+    module's Σ cross-entropy against the token after next, through the same
+    head and the same embedding, the row's last position weighted 0."""
     from ..ops.lm_head import blocked_cross_entropy
 
-    hidden, load = model.apply({"params": params}, tokens, train=train,
-                               method="hidden")
-    with jax.named_scope("lm_head"):
-        ce, t1, t3 = blocked_cross_entropy(
+    def head_sums(hidden, targets, weights):
+        return blocked_cross_entropy(
             hidden.reshape(-1, hidden.shape[-1]), params["lm_head"]["kernel"],
             targets.reshape(-1), cfg.model.decoder.head_block,
             jnp.dtype(cfg.model.dtype), weights=weights)
-    return ce, t1, t3, load
+
+    hidden, load, *h_mtp = model.apply(
+        {"params": params}, tokens, train=train, method="hidden",
+        **({"targets": targets} if mtp else {}))
+    with jax.named_scope("lm_head"):
+        ce, t1, t3 = head_sums(hidden, targets, weights)
+    if not mtp:
+        return ce, t1, t3, load
+    with jax.named_scope("mtp"), jax.named_scope("lm_head"):
+        # position i predicts targets[i + 1]; the last has nothing to predict
+        # (under causal attention its state reaches no other position's loss)
+        after_next = jnp.roll(targets, -1, axis=1)
+        live = jnp.ones(targets.shape, jnp.float32).at[:, -1].set(0.0)
+        ce_mtp, _, _ = head_sums(h_mtp[0], after_next, live.reshape(-1))
+    return ce, t1, t3, load, ce_mtp
 
 
 def _lm_loss(cfg: Config, model: Any):
@@ -325,16 +340,30 @@ def _lm_loss(cfg: Config, model: Any):
     next-token cross-entropy over every position, head and loss in row
     blocks (ops/lm_head.py — the (B·T, V) float32 logits never stand whole).
     `images` are token ids (B, T) and `labels` the same rows shifted by one.
+    With a multi-token-prediction module (`decoder.mtp_layers`) the loss is
+    loss_main + mtp_weight · loss_mtp, the second the mean over the T − 1
+    positions that have a token after next; `loss` is the total and
+    `loss_main` / `loss_mtp` stand beside it.
     The step's metrics also carry `moe_load` (L, e): the token-slots each
-    held expert took in each layer — the loop's gauges and the benchmark's
-    imbalance metric read it; nothing in the step depends on it."""
+    held expert took in each routing layer — the loop's gauges and the
+    benchmark's imbalance metric read it; nothing in the step depends on it."""
+    dc = cfg.model.decoder
+    mtp = bool(dc.mtp_layers)
+
     def loss_fn(params, batch_stats, tokens, targets, rng):
-        ce, t1, t3, load = _lm_sums(cfg, model, params, tokens, targets, True)
-        return ce / targets.size, (batch_stats, (t1, t3, load))
+        ce, t1, t3, load, *ce_mtp = _lm_sums(cfg, model, params, tokens,
+                                             targets, True, mtp=mtp)
+        main = ce / targets.size
+        if not mtp:
+            return main, (batch_stats, (t1, t3, load))
+        after_next = ce_mtp[0] / (targets.shape[0] * (targets.shape[1] - 1))
+        return (main + dc.mtp_weight * after_next,
+                (batch_stats, (t1, t3, load, main, after_next)))
 
     def metrics_fn(loss, aux, labels):
-        t1, t3, load = aux
-        return {"loss": loss, "top1": t1 / labels.size, "top3": t3 / labels.size,
+        t1, t3, load, *parts = aux
+        return {"loss": loss, **dict(zip(("loss_main", "loss_mtp"), parts)),
+                "top1": t1 / labels.size, "top3": t3 / labels.size,
                 "moe_load": load.astype(jnp.float32)}
 
     return loss_fn, metrics_fn

@@ -317,3 +317,67 @@ def test_decoder_train_step_fits_one_chip(topo, kernels):
     assert m.alias_size_in_bytes >= 0.99 * m.output_size_in_bytes
     text = compiled.as_text()
     assert "ragged-dot" in text and text.count("tpu_custom_call") >= 12
+
+
+# ------------------------------- latent attention, the second decoder (PR 32) --
+
+def test_flash_attention_latent_scores_compile(one_chip, kernels):
+    """JoyAI-LLM-Flash's attention at its published widths: 32 heads, scores
+    over 128 + 64 (the 64 rotary dims of the key ONE head for all 32), values
+    of 128, 8,192 tokens. No pad to 192, no key that repeats the rotary head:
+    Mosaic takes the (512, 64) blocks as they are."""
+    _, fa = kernels
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    args = (sds(1, 8192, 32, 128), sds(1, 8192, 32, 128), sds(1, 8192, 32, 128),
+            sds(1, 8192, 32, 64), sds(1, 8192, 1, 64))
+
+    def fwd_and_vjp(*a):
+        def loss(q, k, v, q_rope, k_rope):
+            out = fa.flash_attention(q, k, v, causal=True, q_rope=q_rope,
+                                     k_rope=k_rope)
+            return jnp.sum(out.astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*a)
+
+    text = _compiled_text(fwd_and_vjp, *args)
+    assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
+
+
+def test_latent_decoder_train_step_fits_one_chip(topo, kernels):
+    """The benchmark's JoyAI-LLM-Flash cell as `cli.train` builds it (680 M
+    float32 parameters under Adam, 2 rows of 8,192 tokens, --remat, two
+    losses through one head in row blocks)."""
+    import json
+    import os
+
+    from ddp_classification_pytorch_tpu.cli.train import build_parser, config_from_args
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "joyai_llm_flash.json")) as f:
+        conf = json.load(f)
+    cfg = config_from_args(build_parser().parse_args(
+        conf["argv"] + ["--dataset", "tokens", "--batchsize",
+                        str(conf["batch_per_chip"])]))
+    mesh = meshlib.make_mesh(meshlib.MeshSpec(1, 1), devices=topo.devices[:1])
+    with mesh:
+        model, tx, state = _abstract_state(cfg, mesh)
+        assert sum(a.size for a in jax.tree_util.tree_leaves(state.params)) \
+            == conf["parameters"] == 680441088
+        step = make_train_step(cfg, model, tx, mesh=mesh)
+        tokens = jax.ShapeDtypeStruct(
+            (cfg.data.batch_size, cfg.model.decoder.seq_len), jnp.int32,
+            sharding=meshlib.batch_sharding(mesh))
+        compiled = step.lower(state, tokens, tokens).compile()
+    # 15.8 GB by the compiler's count (state 10.9 GB, temporaries 4.9): over
+    # the 0.9 of 16 GB the other steps keep to, so held against what the
+    # chip's allocator hands out (`memory_stats()["bytes_limit"]` of a v5e,
+    # PR 32's chip runs, which peak at 15.52 GB: PERF.md section 5)
+    assert _device_bytes(compiled) < 0.95 * 16_909_336_064
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 0.99 * m.output_size_in_bytes
+    text = compiled.as_text()
+    # 6 attention blocks x (forward, dq, dkv) kernels
+    assert "ragged-dot" in text and text.count("tpu_custom_call") >= 18
