@@ -30,7 +30,17 @@ decoder, models/decoder_lm.py): dense dispatch of top-6 of 64 would cost
 token-slots by expert, runs grouped matmuls (`jax.lax.ragged_dot`: one
 kernel over the ragged groups on the TPU) over the experts HELD HERE only,
 and combines. Dropless: the slot buffer has the static worst-case length
-(every slot on a held expert), so uneven loads lose nothing. It is told
+(every slot on a held expert), so uneven loads lose nothing. Two spaces,
+both of S = k·N rows: the SLOTS in choice-major order (slot j·N + n is token
+n's j-th choice, so (S, C) ↔ (k, N, C) is a free reshape) and the ROWS sorted
+by expert that the grouped matmuls work on; `order` takes a row to its slot,
+`inv` a slot to its row. Gathers cross between them, never scatters. From an
+N-row source to the rows: the dispatch forward (`_dispatch_rows`: u[order %
+N]), again when remat recomputes it, and the combine backward (`_combine`:
+the output's cotangent g[order % N], whose products with the weights and
+with y stay among the rows). From the S-row buffer to the slots, the dearer
+kind: the combine forward (y[inv]) and the dispatch backward (d_rows[inv]);
+the combine's weight gradients cross as S scalars. It is told
 which experts it holds (`first_expert`, the banks' leading dimension), so
 one chip of an expert-parallel layout runs it without the exchange and a
 `model` axis > 1 runs the same function per shard with the psum above.
@@ -218,17 +228,39 @@ _dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _collect_slots(y, order, inv, mine):
-    """The sorted rows y (S, C) back in slot order, zero for the slots of
-    experts not held here (their rows lie past the last group and were never
-    written). Transpose: the gather g[order]; a slot not `mine` carries a
-    zero cotangent already (its gate weight is zero)."""
-    return jnp.where(mine[:, None], y[inv], jnp.zeros((), y.dtype))
+def _combine(y, w, order, inv, mine):
+    """out[n] = Σ_j w[j, n] · y[inv[j·N + n]] (N, C) f32 over the slots
+    `mine`: the sorted rows y (S, C) weighted back onto their tokens. w
+    (k, N) f32 is zero where not `mine`; the mask stays all the same, since
+    those slots' rows lie past the last group and were never written.
+
+    The backward stays among the sorted rows: the token's cotangent for every
+    sorted row is one gather from the N-row g, g_tok = g[order % N]; d_y is
+    its product with the row's weight (in f32, rounded once) and d_w the
+    row-wise dot of g_tok with y, brought to slot order as S scalars.
+    Nothing of shape (k, N, C) is built, and nothing reads the forward's
+    y[inv]: under remat it is not computed a second time."""
+    k, n = w.shape
+    slots = jnp.where(mine[:, None], y[inv], jnp.zeros((), y.dtype))
+    return (w[..., None] * slots.reshape(k, n, -1).astype(jnp.float32)).sum(axis=0)
 
 
-_collect_slots.defvjp(
-    lambda y, order, inv, mine: (_collect_slots(y, order, inv, mine), order),
-    lambda order, g: (g[order], None, None, None))
+def _combine_fwd(y, w, order, inv, mine):
+    return _combine(y, w, order, inv, mine), (y, w, order, inv, mine)
+
+
+def _combine_bwd(res, g):
+    y, w, order, inv, mine = res
+    g_tok = g[order % w.shape[1]]                         # (S, C) f32
+    d_y = (w.reshape(-1)[order][:, None] * g_tok).astype(y.dtype)
+    # a row past the last group may hold anything, NaN too: its dot is
+    # dropped here, and its d_y above is 0 · g
+    dots = (g_tok * y.astype(jnp.float32)).sum(axis=-1)
+    d_w = jnp.where(mine, dots[inv], 0.0).reshape(w.shape)
+    return d_y, d_w, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 # the gate of a gated unit: ReGLU | SwiGLU
@@ -264,9 +296,8 @@ def _sparse_experts(u, logits, w_gate, w_up, w_down, *, top_k, first_expert,
         y = grouped(GATE_ACTIVATIONS[activation](both[:, :width])
                     * both[:, width:], w_down.astype(dtype))
     with jax.named_scope("moe.combine"):
-        y = _collect_slots(y, order, inv, mine).reshape(top_k, n, -1)
         w = jnp.where(mine.reshape(top_k, n), w.T, 0.0)
-        out = (w[..., None] * y.astype(jnp.float32)).sum(axis=0)
+        out = _combine(y, w, order, inv, mine)
     return out, load
 
 

@@ -300,16 +300,20 @@ def test_flash_attention_refuses_parts_that_do_not_go_together():
 # (f) ----------------------------------------------------------------------
 
 @pytest.mark.parametrize("extra,want", [
-    ((), "ce7429dc320dcbf74332829d80f95ff71918160fc31d0f591222c7e0f2cc59eb"),
+    ((), "2db75f41754dcf9b660717243bc58cf02cc15a287de6284447b17a837c89910a"),
     (("--flash_min_tokens", "0"),
-     "b78d202dc6593f70687ac793ed4a271589ce75d9b566d2237dca2b2438a700d1"),
+     "b65fd5f5eea41e030f799778e7a9a66ab77bd39d0692b7942632cce61ca2e57d"),
 ], ids=["dense_op", "flash_kernels"])
 def test_the_first_decoders_argv_still_builds_the_program_it_built(extra, want):
-    """`st21b_ep4_8k`'s argv (its rehearsal sizes) yields the leaves and the
-    lowered step it yielded at the parent of the PR that made the layer a
-    description (PR 32): sha256 of the parameters' paths, shapes and dtypes and
-    of the step's StableHLO text, taken there with this same code. A change of
-    JAX moves both hashes: take them again from that commit."""
+    """`st21b_ep4_8k`'s argv (its rehearsal sizes) yields the leaves it
+    yielded at the parent of the PR that made the layer a description (PR 32)
+    and the lowered step it yielded at PR 34: sha256 of the parameters' paths,
+    shapes and dtypes and of the step's StableHLO text, taken there with this
+    same code. PR 34 moved the two step hashes by intent and left the leaves'
+    alone: the expert layer's combine became one custom_vjp op whose backward
+    is written over the sorted rows (ops/moe.py::_combine), so the program
+    text changed in every routing layer. A change of JAX moves all three: take
+    them again from that commit."""
     from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
     from ddp_classification_pytorch_tpu.train.state import create_train_state
     from ddp_classification_pytorch_tpu.train.steps import make_train_step

@@ -7,6 +7,7 @@ tokens` through `cli.train`. CPU, toy sizes."""
 import importlib
 import json
 import os
+import re
 import sys
 
 import jax
@@ -17,6 +18,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+from benchmark.reference import joyai_llm_flash as ref_sigmoid  # noqa: E402
 from benchmark.reference import smallthinker as ref  # noqa: E402
 from benchmark.reference.common import make_params  # noqa: E402
 from ddp_classification_pytorch_tpu.cli.train import (  # noqa: E402
@@ -234,6 +236,60 @@ def test_rows_past_the_last_group_are_never_read(monkeypatch):
     for g, r in zip(got[1], want[1]):
         assert bool(jnp.isfinite(g).all())
         np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("case", ["softmax_reglu_first_0", "sigmoid_bias_swiglu_first_2"])
+def test_the_combines_backward_stays_among_the_sorted_rows(case):
+    """Under remat a routing layer's gradient crosses between the slots and
+    the sorted rows five times, not six: the dispatch forward, recomputed and
+    backward, the combine forward and backward. The recomputed combine gather
+    has no reader, and the output's cotangent (N, C) is never written out to
+    (k, N, C) (ops/moe.py::_combine). Counted in the program the CPU's
+    compiler leaves; values and gradients beside it."""
+    n, c, experts, held, top_k = 40, 24, 8, 4, 3      # no two sizes alike
+    slots = top_k * n
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    u, logits = jax.random.normal(ks[0], (n, c)), jax.random.normal(ks[1], (n, experts))
+    w, cot = banks(ks[2], held, c=c), jax.random.normal(ks[3], (n, c))
+    if case == "softmax_reglu_first_0":
+        first, kw = 0, {}
+
+        def dense(u, logits, *w):
+            return dense_mixture(u, logits, w, top_k, first)
+    else:
+        first, bias = 2, 0.1 * jax.random.normal(ks[4], (experts,))
+        kw = dict(activation="silu", route=dict(scoring="sigmoid", bias=bias, scale=2.5))
+        arch = {"top_k": top_k, "first_expert": first, "router_scale": 2.5}
+
+        def dense(u, logits, *w):
+            idx, weight = ref_sigmoid.route(logits, bias, arch)
+            return ref_sigmoid.held_experts(u, idx, weight, *w, arch, lambda x: x)
+
+    @jax.checkpoint
+    def layer(u, logits, *w):
+        return u + sparse_moe(u, logits, *w, top_k=top_k, first_expert=first,
+                              dtype=jnp.float32, **kw)[0]
+
+    step = jax.jit(jax.value_and_grad(lambda *a: (layer(*a) * cot).sum(),
+                                      argnums=range(5)))
+    got = step(u, logits, *w)
+    want = jax.value_and_grad(lambda u, *a: ((u + dense(u, *a)) * cot).sum(),
+                              argnums=range(5))(u, logits, *w)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
+    for g, r in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
+
+    text = step.lower(u, logits, *w).compile().as_text()
+
+    def results(op):   # result dims (ones dropped) and attributes of every `op`
+        return [([int(d) for d in dims.split(",") if d not in ("", "1")], rest)
+                for dims, rest in re.findall(rf"= \w+\[([\d,]*)\]\S* {op}\((.*)", text)]
+
+    row_gathers = [dims for dims, _ in results("gather") if dims == [slots, c]]
+    assert 3 <= len(row_gathers) <= 5, row_gathers
+    spread = [dims for dims, rest in results("broadcast")
+              if dims == [top_k, n, c] and "dimensions={1,2}" in rest]
+    assert not spread, "the output's cotangent is broadcast over the choices"
 
 
 # (d) ----------------------------------------------------------------------
