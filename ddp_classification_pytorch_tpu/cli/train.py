@@ -89,10 +89,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "vit_t16/s16/b16 (reference --model + extensions) | "
                         "decoder_lm (a token decoder: next-token training "
                         "as per-position classification; sizes in the "
-                        "'decoder' group: grouped-query or latent "
-                        "attention, dense / routed / shared feed-forward, "
-                        "softmax or sigmoid router, a multi-token-prediction "
-                        "module; defaults = the published "
+                        "'decoder' group: per layer grouped-query or latent "
+                        "attention or a gated short convolution, dense / "
+                        "routed / shared feed-forward, softmax or sigmoid "
+                        "router, a multi-token-prediction module, a tied or "
+                        "untied head; defaults = the published "
                         "SmallThinker-21BA3B-Instruct)")
     m.add_argument("--flash_attention", action="store_true",
                    help="ViT: Pallas streaming attention kernel for the "
@@ -145,16 +146,20 @@ def build_parser() -> argparse.ArgumentParser:
                        # each value means)
                        ("attention", str), ("q_rank", int), ("kv_rank", int),
                        ("rope_dim", int), ("v_head_dim", int),
-                       ("rope_pairing", str), ("dense_layers", int),
+                       ("rope_pairing", str), ("qk_norm", int),
+                       ("dense_layers", int),
                        ("dense_width", int), ("activation", str),
                        ("router", str), ("router_scale", float),
+                       ("router_eps", float),
                        ("router_tap", str), ("shared_experts", int),
-                       ("mtp_layers", int), ("mtp_weight", float)):
+                       ("mtp_layers", int), ("mtp_weight", float),
+                       ("tied_embeddings", int)):
         dec.add_argument(f"--{flag}", type=kind, default=None)
-    for flag in ("rope_layout", "window_layout"):
+    for flag in ("rope_layout", "window_layout", "conv_layout"):
         dec.add_argument(f"--{flag}", default=None,
                          help="comma-separated 0/1 per layer, repeated to "
-                              "the depth (e.g. 0,1,1,1)")
+                              "the depth (e.g. 0,1,1,1); conv_layout: 1 = the "
+                              "gated short convolution in attention's place")
 
     a = p.add_argument_group("arcface")
     a.add_argument("--arc_s", type=float, default=-1.0)

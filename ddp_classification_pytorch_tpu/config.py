@@ -73,10 +73,15 @@ class DecoderConfig:
     models/decoder_lm.py) — the one place they live; the CLI fills it.
     Defaults are the published SmallThinker-21BA3B-Instruct config.json
     (PowerInfer, arXiv:2507.20984). The layer is described by data: the
-    attention kind, the feed-forward kind per layer (`dense_layers` leading
-    dense ones, then routed experts with or without a shared expert), the
-    router's scoring and tap, the activation, the rotary pairing, and a
-    multi-token-prediction module after the last layer.
+    token mixer per layer (`conv_layout`: the configured attention, or the
+    gated short convolution of LFM2, LiquidAI's `lfm2` / `lfm2_moe`), the
+    attention kind and its QK-norm, the feed-forward kind per layer
+    (`dense_layers` leading dense ones, then routed experts with or without
+    a shared expert), the router's scoring and tap, the activation, the
+    rotary pairing, a head of its own or tied to the embedding, and a
+    multi-token-prediction module after the last layer. Three published
+    layers are its fixed points: SmallThinker's, the DeepSeek-V3 layer as
+    JoyAI-LLM-Flash configures it, and LFM2-8B-A1B's.
 
     A deployment that spreads a layer's experts and the vocabulary's rows
     over several chips gives each chip its share: `experts_held` experts
@@ -118,6 +123,12 @@ class DecoderConfig:
     rope_dim: int = 0
     v_head_dim: int = 0              # 0 = head_dim
     rope_pairing: str = "half"       # "half": i with i + D/2 | "interleaved": 2i with 2i + 1
+    qk_norm: int = 0                 # 1: RMSNorm on every "gqa" query and key head before the rotary embedding
+    # the token mixer, a 0/1 list repeated to the depth like the two above:
+    # 1 = the gated short convolution (LFM2: [B | C | X] = h W_in, a causal
+    # depthwise convolution of 3 taps (`decoder_lm.CONV_TAPS`) over B * X, gated by C,
+    # then W_out) stands where attention stands; 0 = the attention above
+    conv_layout: Sequence[int] = (0,)
     # feed-forward: the first `dense_layers` layers are one gated MLP of
     # `dense_width`; the others route over the experts
     dense_layers: int = 0
@@ -128,12 +139,16 @@ class DecoderConfig:
     # chosen scores renormalised and times `router_scale`
     router: str = "softmax"
     router_scale: float = 1.0
+    router_eps: float = 0.0          # "sigmoid": added to the chosen scores' sum (LFM2: 1e-6)
     router_tap: str = "pre"          # reads the layer's normed input: "pre" attention | "post"
     shared_experts: int = 0          # experts every token takes (width x this many)
     # multi-token prediction (DeepSeek-V3 eq. 21-25): 0 or 1 extra layer that
     # predicts the token after next through the shared embedding and head
     mtp_layers: int = 0
     mtp_weight: float = 0.3
+    # 1: the head is the embedding transposed (no `lm_head` leaf; the
+    # table's gradient sums the lookup's scatter-add and the head's matmul)
+    tied_embeddings: int = 0
 
     @property
     def held(self) -> int:
@@ -152,6 +167,13 @@ class DecoderConfig:
         then the prediction module's."""
         return (tuple(str(i) for i in range(self.dense_layers, self.num_layers))
                 + ("mtp",) * self.mtp_layers)
+
+    def layer_kinds(self) -> tuple:
+        """(operator, ffn) of each of the `num_layers` layers: operator
+        "conv" or the attention kind, ffn "dense" | "routed"."""
+        return tuple(("conv" if conv else self.attention,
+                      "dense" if i < self.dense_layers else "routed")
+                     for i, conv in enumerate(self.layout(self.conv_layout)))
 
 
 @dataclass

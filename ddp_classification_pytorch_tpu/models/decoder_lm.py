@@ -2,15 +2,30 @@
 position over the vocabulary.
 
 ONE layer module, described by data (`config.DecoderConfig`, which the CLI
-fills — no table of variants, no second model file). Two published layers
+fills — no table of variants, no second model file). Three published layers
 are its fixed points: SmallThinker-21BA3B-Instruct (PowerInfer,
-arXiv:2507.20984; the defaults) and the DeepSeek-V3 layer (arXiv:2412.19437
-§2.1-2.2) as JoyAI-LLM-Flash configures it. With x (B, T, C), every
-projection without bias:
+arXiv:2507.20984; the defaults), the DeepSeek-V3 layer (arXiv:2412.19437
+§2.1-2.2) as JoyAI-LLM-Flash configures it, and LFM2-8B-A1B (LiquidAI,
+`lfm2_moe`: most layers mix tokens by a gated short convolution and not by
+attention). With x (B, T, C), every projection without bias:
 
     h  = RMSNorm(x)                        input norm
-    a  = attention(h)                      "gqa": H query heads on H_kv KV
-                                           heads of head_dim; layers with
+    a  = layers with conv_layout = 1: the gated short convolution —
+                                           [B | C | X] = h W_in   (C, 3C)
+                                           z   = B * X
+                                           c_t = Σ_j w[j] * z_{t−(L−1)+j}
+                                             depthwise over L = CONV_TAPS = 3
+                                             taps, causal (z_{<0} = 0), no
+                                             bias, no activation
+                                           a   = (C * c) W_out
+                                           (it crosses document boundaries
+                                           inside a packed row, as attention
+                                           does)
+         the others: attention(h) W_o      "gqa": H query heads on H_kv KV
+                                           heads of head_dim; with qk_norm an
+                                           RMSNorm (one scale of head_dim for
+                                           all heads) on every q and k head
+                                           first; layers with
                                            rope_layout = 1 rotate q and k over
                                            the whole head, the others carry no
                                            position at all
@@ -24,7 +39,7 @@ projection without bias:
                                            / sqrt(head_dim + rope_dim)
                                            mask: causal, and where
                                            window_layout = 1 also j > i − window
-    x1 = x + a W_o
+    x1 = x + a
     u  = RMSNorm(x1)
     y  = layers < dense_layers: W_down(act(W_gate u) · W_up u), one gated MLP
          the others: Σ_{e ∈ chosen} g_e · W_down^e(act(W_gate^e u) · W_up^e u)
@@ -35,12 +50,15 @@ The router's logits r = t W_r are taken from t = h (router_tap "pre": before
 attention, as SmallThinker places it) or t = u ("post"); "softmax" scoring
 chooses the top-k of r and weighs them by their softmax, "sigmoid" chooses
 the top-k of sigmoid(r) + bias and weighs by the chosen sigmoid(r),
-renormalised and times router_scale (ops/moe.py::route_top_k). The bias is a
+renormalised (over their sum + router_eps) and times router_scale
+(ops/moe.py::route_top_k). The bias is a
 leaf that selection alone reads: its gradient is zero and no rule moves it
 here (ROADMAP, Reach). act = relu (ReGLU) or silu (SwiGLU). Rotary pairing:
 "half" (i with i + D/2) or "interleaved" (2i with 2i + 1).
 
-Then a final RMSNorm and an untied head. With mtp_layers = 1 a multi-token
+Then a final RMSNorm and a head: its own kernel, or with tied_embeddings the
+embedding transposed (no `lm_head` leaf then; the table's gradient is the sum
+of the lookup's scatter-add and the head's matmul). With mtp_layers = 1 a multi-token
 prediction module (DeepSeek-V3 eq. 21-25) follows the last layer: with hL its
 output before the final norm and `targets` the row shifted by one,
 
@@ -62,7 +80,8 @@ heads, the latent scores' shared rotary key: ops/flash_attention.py) wherever
 they tile T, else the dense op; `hidden` stops before the head so the train
 step can take head and loss in row blocks (ops/lm_head.py).
 
-Device scopes (`jax.named_scope`, docs/observability.md): `attn`, `ffn`,
+Device scopes (`jax.named_scope`, docs/observability.md): `attn`, `conv`
+(with `conv.in`, `conv.mix`, `conv.out` inside it), `ffn`,
 `moe.route` / `.dispatch` / `.experts` / `.combine` / `.shared`, `mtp`
 (outermost, around the whole module), `lm_head`.
 """
@@ -80,6 +99,10 @@ from ..config import DecoderConfig
 from ..ops.attention import attention, flash_supported
 from ..ops.flash_attention import flash_attention
 from ..ops.moe import GATE_ACTIVATIONS, sparse_moe
+
+# taps of the gated short convolution (LFM2's published `conv_L_cache`); a
+# constant until a second published length exists
+CONV_TAPS = 3
 
 
 def rotate_half(x: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -123,6 +146,19 @@ def _dense(features: int, dtype, name: str) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=dtype, name=name)
 
 
+def head_kernel(params, cfg: DecoderConfig) -> jnp.ndarray:
+    """The head's (C, V) kernel in the model's parameter tree: its own leaf,
+    or the embedding transposed where the model is tied."""
+    if cfg.tied_embeddings:
+        return params["embed"]["embedding"].T
+    return params["lm_head"]["kernel"]
+
+
+def _logits(h: jnp.ndarray, kernel: jnp.ndarray, dtype) -> jnp.ndarray:
+    return jnp.dot(h.astype(dtype), kernel.astype(dtype),
+                   preferred_element_type=jnp.float32)
+
+
 class Head(nn.Module):
     """The untied vocabulary head: (.., C) → f32 logits (.., V)."""
 
@@ -133,8 +169,7 @@ class Head(nn.Module):
     def __call__(self, h: jnp.ndarray) -> jnp.ndarray:
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (h.shape[-1], self.vocab_size), jnp.float32)
-        return jnp.dot(h.astype(self.dtype), kernel.astype(self.dtype),
-                       preferred_element_type=jnp.float32)
+        return _logits(h, kernel, self.dtype)
 
 
 class DecoderLayer(nn.Module):
@@ -146,6 +181,7 @@ class DecoderLayer(nn.Module):
     expert_axis: Optional[str] = None
     flash_min_tokens: int = 1024
     routed: bool = True     # False: one dense gated MLP (a leading layer)
+    conv: bool = False      # True: the gated short convolution, no attention
 
     def _gated_mlp(self, u, width: int, prefix: str):
         """W_down(act(W_gate u) · W_up u): the dense layer's feed-forward
@@ -175,6 +211,9 @@ class DecoderLayer(nn.Module):
             q = q.reshape(b, t, c.num_heads, c.head_dim)
             k = k.reshape(b, t, c.num_kv_heads, c.head_dim)
             v = v.reshape(b, t, c.num_kv_heads, c.head_dim)
+            if c.qk_norm:   # over the head's dims, one scale for all heads
+                q = RMSNorm(c.rms_eps, name="q_head_norm")(q).astype(self.dtype)
+                k = RMSNorm(c.rms_eps, name="k_head_norm")(k).astype(self.dtype)
             if self.rope:
                 q, k = rotary(q), rotary(k)
             return q, k, v, ()
@@ -196,6 +235,39 @@ class DecoderLayer(nn.Module):
             q_rope, k_rope = rotary(q_rope), rotary(k_rope)
         return q[..., :hd], kv_b[..., :hd], kv_b[..., hd:], (q_rope, k_rope)
 
+    def _short_conv(self, h):
+        """LFM2's token mixer: h (B, T, C) → (B, T, C). `W_in` is ONE matmul
+        of 3C columns; the taps are shifted multiply-adds in f32 over the
+        gate product as the compute dtype holds it; plain XLA."""
+        dim, taps = h.shape[-1], CONV_TAPS
+        with jax.named_scope("conv.in"):
+            gate_b, gate_c, xs = jnp.split(
+                _dense(3 * dim, self.dtype, "conv_in")(h), 3, axis=-1)
+        with jax.named_scope("conv.mix"):
+            w = self.param("conv_taps", nn.initializers.lecun_normal(),
+                           (taps, dim), jnp.float32)
+            z = (gate_b * xs).astype(jnp.float32)
+            t = z.shape[1]
+            # tap j reads position t − (L − 1) + j: z shifted down the row,
+            # zeros before the row's start
+            mixed = sum(w[j] * jnp.pad(z, ((0, 0), (taps - 1 - j, 0), (0, 0)))[:, :t]
+                        for j in range(taps))
+            y = (gate_c.astype(jnp.float32) * mixed).astype(self.dtype)
+        with jax.named_scope("conv.out"):
+            return _dense(dim, self.dtype, "conv_out")(y)
+
+    def _attention(self, h):
+        b, t, dim = h.shape
+        q, k, v, rope = self._qkv(h)
+        # the kernels where they tile T and beat the dense op
+        # (ModelConfig.flash_min_tokens), else the (T, T) op
+        core = (flash_attention
+                if flash_supported(t) and t >= self.flash_min_tokens
+                else attention)
+        a = core(q, k, v, causal=True, window=self.window,
+                 **(dict(q_rope=rope[0], k_rope=rope[1]) if rope else {}))
+        return _dense(dim, self.dtype, "o")(a.reshape(b, t, -1))
+
     @nn.compact
     def __call__(self, x: jnp.ndarray):
         c = self.cfg
@@ -203,16 +275,9 @@ class DecoderLayer(nn.Module):
         h32 = RMSNorm(c.rms_eps, name="norm_in")(x)
         if self.routed and c.router_tap == "pre":
             logits = self._router_logits(h32)
-        with jax.named_scope("attn"):
-            q, k, v, rope = self._qkv(h32.astype(self.dtype))
-            # the kernels where they tile T and beat the dense op
-            # (ModelConfig.flash_min_tokens), else the (T, T) op
-            core = (flash_attention
-                    if flash_supported(t) and t >= self.flash_min_tokens
-                    else attention)
-            a = core(q, k, v, causal=True, window=self.window,
-                     **(dict(q_rope=rope[0], k_rope=rope[1]) if rope else {}))
-            x = x + _dense(dim, self.dtype, "o")(a.reshape(b, t, -1))
+        with jax.named_scope("conv" if self.conv else "attn"):
+            mix = self._short_conv if self.conv else self._attention
+            x = x + mix(h32.astype(self.dtype))
         u32 = RMSNorm(c.rms_eps, name="norm_post")(x)
         u = u32.astype(self.dtype)
         if not self.routed:
@@ -223,6 +288,7 @@ class DecoderLayer(nn.Module):
         route = None
         if c.router == "sigmoid":
             route = dict(scoring="sigmoid", scale=c.router_scale,
+                         eps=c.router_eps,
                          bias=self.param("router_bias", nn.initializers.zeros,
                                          (c.num_experts,), jnp.float32))
         init = nn.initializers.variance_scaling(
@@ -302,11 +368,13 @@ class DecoderLM(nn.Module):
                          c.window if c.window_layout[i % len(c.window_layout)]
                          else None,
                          self.dtype, self.mesh, self.expert_axis,
-                         self.flash_min_tokens, i >= c.dense_layers, name=name)
+                         self.flash_min_tokens, i >= c.dense_layers,
+                         bool(c.conv_layout[i % len(c.conv_layout)]), name=name)
 
         self.layers = [build(i, f"layer{i}") for i in range(c.num_layers)]
         self.norm_final = RMSNorm(c.rms_eps, name="norm_final")
-        self.lm_head = Head(c.vocab_size, self.dtype, name="lm_head")
+        if not c.tied_embeddings:
+            self.lm_head = Head(c.vocab_size, self.dtype, name="lm_head")
         if c.mtp_layers:
             # its layer continues the layouts: index = the depth
             self.mtp = MTPModule(functools.partial(build, c.num_layers),
@@ -325,7 +393,9 @@ class DecoderLM(nn.Module):
                 loads.append(load)
         out = self.norm_final(x).astype(self.dtype)
         if targets is None or not self.cfg.mtp_layers:
-            return out, jnp.stack(loads)
+            # a decoder of dense layers only routes nothing: no row
+            return out, (jnp.stack(loads) if loads
+                         else jnp.zeros((0, self.cfg.held), jnp.int32))
         with jax.named_scope("mtp"):
             h_mtp, load = self.mtp(x, self.embed(targets).astype(self.dtype))
         return out, jnp.stack(loads + [load]), h_mtp
@@ -335,4 +405,6 @@ class DecoderLM(nn.Module):
         targets = tokens if self.is_initializing() else None
         h = self.hidden(tokens, train, targets)[0]
         with jax.named_scope("lm_head"):
+            if self.cfg.tied_embeddings:
+                return _logits(h, self.embed.embedding.T, self.dtype)
             return self.lm_head(h)

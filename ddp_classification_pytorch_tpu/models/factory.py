@@ -117,6 +117,11 @@ def build_decoder_lm(cfg: ModelConfig, num_classes: int,
                                                      and not dc.dense_width):
         raise ValueError(f"{dc.dense_layers} dense layers of width "
                          f"{dc.dense_width} in a depth of {dc.num_layers}")
+    if dc.qk_norm and dc.attention != "gqa":
+        raise ValueError("--qk_norm norms grouped-query heads; latent "
+                         "attention norms its two latents already")
+    if any(v not in (0, 1) for v in dc.conv_layout):
+        raise ValueError(f"conv_layout {tuple(dc.conv_layout)} is 0/1 per layer")
     if dc.mtp_layers not in (0, 1):
         raise ValueError("multi-token prediction is built at depth 0 or 1, "
                          f"got mtp_layers={dc.mtp_layers}")

@@ -170,14 +170,16 @@ def moe_mlp(
 # ---------------------------------------------------------------------------
 
 def route_top_k(logits: jnp.ndarray, top_k: int, *, scoring: str = "softmax",
-                bias: Optional[jnp.ndarray] = None, scale: float = 1.0):
+                bias: Optional[jnp.ndarray] = None, scale: float = 1.0,
+                eps: float = 0.0):
     """(N, E) f32 router logits → (expert ids (N, k) i32, weights (N, k)
     f32). "softmax": the k largest logits and the softmax over those k (=
     the full softmax renormalised over the chosen). "sigmoid" (DeepSeek-V3):
     scores s = sigmoid(logits); the k largest of s + `bias` are chosen —
     the bias (E,) steers selection and nothing else, its gradient is zero —
     and the weights are the chosen experts' UNBIASED scores, renormalised
-    over the chosen and times `scale`."""
+    over the chosen (their sum + `eps`: LFM2 publishes 1e-6, DeepSeek-V3
+    none) and times `scale`."""
     logits = logits.astype(jnp.float32)
     if scoring == "softmax":
         vals, idx = jax.lax.top_k(logits, top_k)
@@ -198,7 +200,9 @@ def route_top_k(logits: jnp.ndarray, top_k: int, *, scoring: str = "softmax",
         chosen.append(jnp.sum(jnp.where(hit, scores, 0.0), axis=-1))
         biased = jnp.where(hit, -jnp.inf, biased)
     idx, chosen = jnp.stack(picked, axis=-1), jnp.stack(chosen, axis=-1)
-    weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    total = jnp.sum(chosen, axis=-1, keepdims=True)
+    # no `+ 0.0` in the program of a router that publishes no epsilon
+    weights = scale * chosen / (total + eps if eps else total)
     return idx.astype(jnp.int32), weights
 
 
@@ -322,7 +326,7 @@ def sparse_moe(
 
     `logits` (N, E) are the router's, over ALL E experts, handed in by the
     caller (the decoder takes them before or after attention); `route` are
-    `route_top_k`'s keywords (scoring, bias, scale). The banks w_gate /
+    `route_top_k`'s keywords (scoring, bias, scale, eps). The banks w_gate /
     w_up (e, C, H) and w_down (e, H, C) are the e experts held here, ids
     `first_expert .. first_expert + e − 1`; slots routed elsewhere add
     nothing. Returns (y (N, C) f32 = Σ over the chosen held experts of

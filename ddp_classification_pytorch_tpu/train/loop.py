@@ -33,6 +33,7 @@ TPU-first details the reference has no analogue for:
 
 from __future__ import annotations
 
+import collections
 import os
 import time
 from typing import Any, Dict, Optional, Tuple
@@ -298,6 +299,8 @@ class Trainer:
             # creeps into set-up shows here before it shows in seconds
             n1, s1 = progcache.compiled()
             spans.note(compiles=n1 - n0, compile_s=round(s1 - s0, 3))
+            if cfg.model.arch == "decoder_lm":
+                self._publish_layer_kinds()
 
         with phase("build_steps"):
             self.train_step = make_train_step(cfg, self.model, self.tx,
@@ -513,6 +516,17 @@ class Trainer:
         # refresh the scrape file on the same cadence (atomic rewrite; host 0
         # only)
         self._write_prom()
+
+    def _publish_layer_kinds(self) -> None:
+        """The layout the decoder was built with: how many of its layers mix
+        tokens by which operator before which feed-forward — a static
+        counter, and the same counts beside `init_state` in the set-up line."""
+        kinds = collections.Counter(self.cfg.model.decoder.layer_kinds())
+        for (operator, ffn), n in sorted(kinds.items()):
+            self.obs.counter("decoder_layers_total", "layers of the token "
+                             "decoder by token mixer and feed-forward",
+                             {"operator": operator, "ffn": ffn}).inc(n)
+            spans.note(**{f"{operator}_{ffn}": n})
 
     def _publish_moe_load(self, load: np.ndarray) -> None:
         """The logged step's routing, as the step's metrics carry it —

@@ -310,12 +310,16 @@ def _lm_sums(cfg: Config, model: Any, params, tokens, targets, train: bool,
     loads — the hidden states go through head and loss in row blocks. With
     `mtp` (a configuration with a prediction module) a fifth value: the
     module's Σ cross-entropy against the token after next, through the same
-    head and the same embedding, the row's last position weighted 0."""
+    head and the same embedding, the row's last position weighted 0. A tied
+    model's head is its embedding transposed (`head_kernel`)."""
+    from ..models.decoder_lm import head_kernel
     from ..ops.lm_head import blocked_cross_entropy
+
+    kernel = head_kernel(params, cfg.model.decoder)
 
     def head_sums(hidden, targets, weights):
         return blocked_cross_entropy(
-            hidden.reshape(-1, hidden.shape[-1]), params["lm_head"]["kernel"],
+            hidden.reshape(-1, hidden.shape[-1]), kernel,
             targets.reshape(-1), cfg.model.decoder.head_block,
             jnp.dtype(cfg.model.dtype), weights=weights)
 

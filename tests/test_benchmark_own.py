@@ -31,3 +31,58 @@ from test_rehearsal import (  # noqa: E402,F401
     test_benchmark_json_names_units_and_moves,
     test_run_py_names_no_cell_config_mix_or_metric,
 )
+
+
+def _token_cells():
+    import json
+
+    with open(os.path.join(_ROOT, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    files = {c["name"]: c["file"] for c in spec["configs"]}
+    out = []
+    for w in spec["workloads"]:
+        with open(os.path.join(_ROOT, files[w["config"]])) as f:
+            conf = json.load(f)
+        if conf["runner"] == "train_lm":
+            out.append((w["name"], spec, conf))
+    return out
+
+
+_TOKEN_CELLS = _token_cells()
+
+
+@pytest.mark.parametrize("cell,spec,conf", _TOKEN_CELLS,
+                         ids=[c[0] for c in _TOKEN_CELLS])
+def test_token_cell_has_its_reference_its_counts_and_its_readers(cell, spec, conf):
+    """Every decoder cell (`st21b_ep4_8k`, `joyai_ep16_8k`, `lfm2_ep4_8k`):
+    what the runner and the readers import by the configuration's names is
+    there, the file's parameter count is the reference's own, and the
+    rehearsal is held to the same numbers as the chip run."""
+    import importlib
+
+    import numpy as np
+
+    ref = importlib.import_module(f"benchmark.reference.{conf['reference']}")
+    flops = importlib.import_module(f"benchmark.flops.{conf['flops']}")
+    arch = conf["arch"]
+    assert sum(int(np.prod(s[0])) for s in ref.param_spec(arch).values()) \
+        == conf["parameters"]
+    assert callable(ref.loss_for(arch)) and callable(ref.loss_for(arch, "fp8"))
+    assert flops.train_flops_per_image(arch, 0) > 0
+    assert set(conf["limits"]) == set(conf["rehearse"]["limits"])
+    assert all(0 < v < 1 for v in conf["limits"].values())
+    assert set(conf["rehearse"]["arch"]) == set(arch)
+    # the readers the cell lists (data: a decoder without routed experts or
+    # without attention lists fewer), and the counts that each share divides by
+    mine = [m["name"] for m in spec["per_layer"] if cell in m.get("workloads", [cell])]
+    assert "mfu_pct" in mine
+    for name in mine:
+        assert callable(importlib.import_module(f"benchmark.layers.{name}").read)
+    rows = conf["batch_per_chip"]
+    if "attn_roofline_pct" in mine:
+        assert flops.train_flops_per_image(arch, 0) > flops.attention_flops(arch, rows) / rows > 0
+    if "moe_gmm_roofline_pct" in mine:
+        assert flops.gmm_flops(1.0, arch) == 6.0 * 3 * arch["hidden_size"] * arch["expert_width"]
+    # a published key the file lists as reduced differs from `published`
+    for key in conf["reduced"]:
+        assert conf[key] != conf["published"][key], key

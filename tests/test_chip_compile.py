@@ -381,3 +381,63 @@ def test_latent_decoder_train_step_fits_one_chip(topo, kernels):
     text = compiled.as_text()
     # 6 attention blocks x (forward, dq, dkv) kernels
     assert "ragged-dot" in text and text.count("tpu_custom_call") >= 18
+
+
+# ------------- the short convolution beside 64-wide heads, the third decoder --
+
+def test_flash_attention_64_wide_heads_compile(one_chip, kernels):
+    """LFM2-8B-A1B's attention at its published widths: 32 query heads on 8
+    KV heads of 64, 2 rows of 8,192 tokens, full causal. The head is not
+    padded to 128: Mosaic takes the (512, 64) blocks of q, k and v as they
+    are in all three kernels."""
+    _, fa = kernels
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 64), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 64), jnp.bfloat16, sharding=one_chip)
+
+    def fwd_and_vjp(q, k, v):
+        def loss(q, k, v):
+            out = fa.flash_attention(q, k, v, causal=True)
+            return jnp.sum(out.astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(fwd_and_vjp, q, kv, kv)
+    assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
+
+
+def test_hybrid_decoder_train_step_fits_one_chip(topo, kernels):
+    """The benchmark's LFM2-8B-A1B cell as `cli.train` builds it (508 M
+    float32 parameters under Adam, four short-convolution layers and one
+    attention layer, 2 rows of 8,192 tokens, --remat, the tied head in row
+    blocks), with the compiler's memory count printed."""
+    import json
+    import os
+
+    from ddp_classification_pytorch_tpu.cli.train import build_parser, config_from_args
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "lfm2_8b_a1b.json")) as f:
+        conf = json.load(f)
+    cfg = config_from_args(build_parser().parse_args(
+        conf["argv"] + ["--dataset", "tokens", "--batchsize",
+                        str(conf["batch_per_chip"])]))
+    mesh = meshlib.make_mesh(meshlib.MeshSpec(1, 1), devices=topo.devices[:1])
+    with mesh:
+        model, tx, state = _abstract_state(cfg, mesh)
+        assert "lm_head" not in state.params      # tied: one table
+        assert sum(a.size for a in jax.tree_util.tree_leaves(state.params)) \
+            == conf["parameters"] == 507820288
+        step = make_train_step(cfg, model, tx, mesh=mesh)
+        tokens = jax.ShapeDtypeStruct(
+            (cfg.data.batch_size, cfg.model.decoder.seq_len), jnp.int32,
+            sharding=meshlib.batch_sharding(mesh))
+        compiled = step.lower(state, tokens, tokens).compile()
+    m = compiled.memory_analysis()
+    print(f"lfm2_ep4_8k step by the compiler's count: arguments "
+          f"{m.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{m.temp_size_in_bytes / 1e9:.2f} GB, device total "
+          f"{_device_bytes(compiled) / 1e9:.2f} GB")
+    assert _device_bytes(compiled) < 0.9 * HBM_BYTES
+    assert m.alias_size_in_bytes >= 0.99 * m.output_size_in_bytes
+    text = compiled.as_text()
+    # one attention block x (forward, dq, dkv); the convolution is plain XLA
+    assert "ragged-dot" in text and text.count("tpu_custom_call") >= 3
