@@ -97,7 +97,7 @@ import jax.numpy as jnp
 
 from ..config import DecoderConfig
 from ..ops.attention import attention, flash_supported
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import backward_path, flash_attention
 from ..ops.moe import GATE_ACTIVATIONS, sparse_moe
 
 # taps of the gated short convolution (LFM2's published `conv_L_cache`); a
@@ -170,6 +170,26 @@ class Head(nn.Module):
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (h.shape[-1], self.vocab_size), jnp.float32)
         return _logits(h, kernel, self.dtype)
+
+
+def _takes_kernels(t: int, flash_min_tokens: int) -> bool:
+    """Rows of `t` tokens go through the flash kernels: where they tile T and
+    beat the dense op (ModelConfig.flash_min_tokens); else the (T, T) op."""
+    return flash_supported(t) and t >= flash_min_tokens
+
+
+def flash_backward_path(cfg: DecoderConfig, dtype,
+                        flash_min_tokens: int) -> Optional[str]:
+    """Which backward the attention layers' kernels take at the configured
+    row length ("fused" | "split", ops/flash_attention.py::backward_path);
+    None where no layer reaches the kernels (convolutions only, or rows the
+    dense op takes)."""
+    if (all(op == "conv" for op, _ in cfg.layer_kinds())
+            or not _takes_kernels(cfg.seq_len, flash_min_tokens)):
+        return None
+    widths = ((cfg.head_dim, cfg.value_dim, cfg.rope_dim)
+              if cfg.attention == "mla" else (cfg.head_dim, cfg.head_dim))
+    return backward_path(cfg.seq_len, widths, jnp.dtype(dtype).itemsize)
 
 
 class DecoderLayer(nn.Module):
@@ -259,10 +279,7 @@ class DecoderLayer(nn.Module):
     def _attention(self, h):
         b, t, dim = h.shape
         q, k, v, rope = self._qkv(h)
-        # the kernels where they tile T and beat the dense op
-        # (ModelConfig.flash_min_tokens), else the (T, T) op
-        core = (flash_attention
-                if flash_supported(t) and t >= self.flash_min_tokens
+        core = (flash_attention if _takes_kernels(t, self.flash_min_tokens)
                 else attention)
         a = core(q, k, v, causal=True, window=self.window,
                  **(dict(q_rope=rope[0], k_rope=rope[1]) if rope else {}))

@@ -8,19 +8,32 @@ softmax statistics on-chip, so BOTH passes read/write only O(T·D) from HBM —
 the standard flash-attention memory shape, expressed the Pallas/Mosaic way
 (same conventions as ops/pallas_kernels.py, the repo's TPU-proven kernel):
 
-- grid over (batch·heads, rows-of-blocks, cols-of-blocks); the LAST grid
-  dimension is sequential on TPU, so accumulators live in VMEM scratch
-  across its steps and only one (block, D) tile of the streamed operand is
-  resident at a time — max sequence length is HBM-bound, not VMEM-bound;
+- the forward grids over (batch·heads, rows-of-blocks, cols-of-blocks); the
+  LAST grid dimension is sequential on TPU, so accumulators live in VMEM
+  scratch across its steps and only one (block, D) tile of the streamed
+  operand is resident at a time;
 - forward carries online-softmax stats (running max m, normalizer l) as
   (block_q, 128) lane-replicated f32 tiles and additionally writes the
   per-row logsumexp (the flash residual) as a (bh, T, 1) f32 array;
-- backward is the classic two-kernel split: one kernel grids over q-blocks
-  and streams K/V to accumulate dQ; the other grids over kv-blocks and
-  streams Q/dO to accumulate dK and dV. Both recompute the (bq, bk) score
-  tile from Q·Kᵀ and reconstruct P = exp(S − lse) — no (T, T) tensor ever
-  exists in HBM. The softmax-gradient row term Δ = rowsum(dO ⊙ O) is a
-  cheap elementwise XLA op outside the kernels;
+- backward is ONE kernel (`flash_dkvq`): it holds a q block fixed and
+  streams K/V past it (from the last block down, so that a causal row's dead
+  steps come first and every fetch hides under a live tile), rebuilds the
+  (bq, bk) score tile from Q·Kᵀ, P = exp(S − lse), dP = dO·Vᵀ and
+  dS = P ⊙ (dP − Δ) ONCE a live tile, and feeds all of dQ (a (bq, D)
+  scratch), dK and dV from them. dK and dV (and dK_r) stay in VMEM for the
+  WHOLE T of a KV head, f32, across the query heads of its group, so nothing
+  partial ever goes to HBM and no (T, T) tensor exists there either. That is
+  T·(D + Dv)·8 bytes beside the tiles (16-24 MiB at 8,192 tokens: the kernel
+  asks for its own `vmem_limit_bytes`, computed from its shapes). Where it
+  passes `_VMEM_BUDGET` (T of 40 thousand at 128-wide heads) the backward is the
+  classic two-kernel split instead: one kernel grids over q-blocks and
+  streams K/V to accumulate dQ, the other grids over kv-blocks and streams
+  Q/dO to accumulate dK and dV, each rebuilding P and dS, with only (block,
+  D) accumulators resident — max sequence length is HBM-bound, not
+  VMEM-bound. Shapes choose the path, nothing a caller sets;
+  `flash_backward_total{path}` counts it as the caller is traced. The
+  softmax-gradient row term Δ = rowsum(dO ⊙ O) is a cheap elementwise XLA op
+  outside the kernels;
 - every matmul runs on the MXU with f32 accumulation
   (`preferred_element_type`); CPU/tests run the same kernels in interpret
   mode;
@@ -29,12 +42,12 @@ the standard flash-attention memory shape, expressed the Pallas/Mosaic way
   route to the dense op, which materializes the (T, T) scores in both
   passes (see `_supported`);
 - `window` (causal only) keeps keys j with i − window < j ≤ i: tiles that
-  lie wholly outside that band are skipped on BOTH sides in all three
-  kernels (compute by `pl.when`, DMA by clamping the streamed block's index
+  lie wholly outside that band are skipped on BOTH sides in every kernel
+  (compute by `pl.when`, DMA by clamping the streamed block's index
   to the band), so a window layer costs its band, not the triangle;
 - grouped KV heads (H_q = g·H_kv, models/decoder_lm.py): the K/V index maps
-  read block `i // g` for query head `i`, and the dK/dV kernel walks the g
-  query heads of its KV head in a second sequential grid dimension — no
+  read block `i // g` for query head `i`, and the backward walks the g query
+  heads of a KV head in a sequential grid dimension of their own — no
   repeated K/V is ever materialized;
 - the value dimension is v's own (it need not be the score dimension), and
   the scores may have a second part (`q_rope`, `k_rope`: latent attention,
@@ -42,7 +55,7 @@ the standard flash-attention memory shape, expressed the Pallas/Mosaic way
   K_r held by FEWER heads than K (one for all, in the published models) and
   indexed `i // g_r` the same way — no key that repeats K_r per head, no
   value padded to the score dimension. dK_r sums over every query head that
-  read it inside the dK/dV kernel's sequential head dimension.
+  read it inside the backward's sequential head dimension.
 """
 
 from __future__ import annotations
@@ -56,6 +69,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..obs import spans
 
 _LANES = 128
 _NEG_INF = -1e30
@@ -212,9 +227,10 @@ def _flash_forward(q3, k3, v3, scale, causal=False, window=None, qr3=None,
     dv = v3.shape[-1]
     group = bh // k3.shape[0]
     rope = qr3 is not None
-    # cap 512 matches the backward's VMEM reasoning: at 1024 blocks with
-    # d=128, the (bq, bk) f32 score+probability tiles (~8 MB) plus operands
-    # and double-buffered K/V approach the 16 MB budget on some generations
+    # cap 512 matches the backward's VMEM reasoning (`_TILE_VMEM`): at 1024
+    # blocks with d=128, the (bq, bk) f32 score+probability tiles (~8 MB)
+    # plus operands and double-buffered K/V approach the compiler's default
+    # 16 MiB
     bq = _block(t, cap=512)
     bk = _block(t, cap=512)
     grid = (bh, t // bq, t // bk)
@@ -271,51 +287,169 @@ def _flash_forward(q3, k3, v3, scale, causal=False, window=None, qr3=None,
 # backward
 # ---------------------------------------------------------------------------
 
+# What the backward may ask of a core's VMEM (128 MiB on a v5e), and the part
+# of it the (block, ·) tiles of one grid step were sized for: the blocks are
+# capped at 512 so that the (bq, bk) f32 score / probability / dP / dS tiles,
+# the operand tiles and their double buffers stay under the compiler's default
+# 16 MiB. The fused kernel asks for that plus its whole-T accumulators.
+_VMEM_BUDGET = 96 * 2 ** 20
+_TILE_VMEM = 16 * 2 ** 20
+
+
+def _fused_vmem_bytes(t: int, widths, itemsize: int) -> int:
+    """VMEM the fused backward needs for a sequence of `t` tokens whose K, V
+    (and K_r) are `widths` wide: an f32 accumulator of the whole T for each
+    (lanes padded to 128) and its output block, double-buffered by the
+    pipeline, beside one grid step's tiles."""
+    lanes = sum(-(-w // _LANES) * _LANES for w in widths)
+    return t * lanes * (4 + 2 * itemsize) + _TILE_VMEM
+
+
+def _kv_widths(k3, v3, kr3):
+    return (k3.shape[-1], v3.shape[-1]) + (() if kr3 is None
+                                           else (kr3.shape[-1],))
+
+
+def backward_path(t: int, widths, itemsize: int) -> str:
+    """"fused" | "split": which backward a call with `t` tokens and K, V (and
+    K_r) of these `widths` takes — a byte count of its shapes against
+    `_VMEM_BUDGET`, nothing a caller sets."""
+    return ("fused" if _fused_vmem_bytes(t, widths, itemsize) <= _VMEM_BUDGET
+            else "split")
+
+
+def _dot(a, b, contract):
+    """a·b over `contract` = (axis of a, axis of b), f32 accumulation."""
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _p_ds(q, kb, vb, do, lse, dsum, rope, scale, allowed):
+    """One tile's P = exp(S − lse) and dS = P ⊙ (dO·Vᵀ − Δ), both (bq, bk) in
+    the operands' dtype: everything the gradients' matmuls read."""
+    s = _scores(q, kb, rope, scale)
+    if allowed is not None:
+        # lse is finite, so exp(−NEG_INF − lse) underflows to exactly 0 —
+        # masking s alone zeroes P (and thus dS) on forbidden entries.
+        s = jnp.where(allowed, s, _NEG_INF)
+    p = jnp.exp(s - lse)
+    dp = _dot(do, vb, (1, 1))
+    return p.astype(do.dtype), (p * (dp - dsum)).astype(q.dtype)
+
+
+def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
+                 scale, nq, nk, causal, window=None, group=1, heads=1,
+                 rope=False):
+    """Grid (outer heads, query head under each, q-block, kv-block from the
+    last down), all sequential: stream K/V past a fixed q block, as the
+    forward does, and build each live tile's P and dS ONCE for all of
+    dQ += dS·K·scale (a (bq, D) scratch, written after the kv sweep),
+    dK += dSᵀ·Q·scale and dV += Pᵀ·dO. The last two are kept for the WHOLE T
+    in f32 scratch, at rows kk·bk, across the `group` query heads that read
+    one KV head, and written once after the last of them.
+
+    `outer` walks the KV heads and `heads` = `group`; with `rope` (latent
+    attention: q_r, k_r follow Δ; dQ_r, dK_r among the outputs) it walks the
+    heads of K_r, dK and dV still close after every `group` of the `heads`
+    query heads under each, and dK_r = Σ dSᵀ·Q_r·scale runs on over all."""
+    if rope:
+        (qr_ref, kr_ref, dq_ref, dk_ref, dv_ref, dqr_ref, dkr_ref,
+         dq_scr, dk_acc, dv_acc, dqr_scr, dkr_acc) = rest
+    else:
+        dq_ref, dk_ref, dv_ref, dq_scr, dk_acc, dv_acc = rest
+    gg, jq, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    # the kv blocks are walked from the last to the first (`_backward_fused`)
+    kk = nk - 1 - step
+    q = q_ref[0]                                # (bq, D) input dtype
+    bq = q.shape[0]
+    bk = k_ref.shape[1]
+    # this query head's place among those that read its KV head
+    place = gg % group
+    first = (jq == 0) & (step == 0)
+    last = (jq == nq - 1) & (step == nk - 1)
+
+    @pl.when((place == 0) & first)
+    def _init_kv():
+        dk_acc[:] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[:] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    @pl.when(step == 0)
+    def _init_q():
+        dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
+        if rope:
+            dqr_scr[:] = jnp.zeros(dqr_scr.shape, jnp.float32)
+
+    if rope:
+        @pl.when((gg == 0) & first)
+        def _init_rope():
+            dkr_acc[:] = jnp.zeros(dkr_acc.shape, jnp.float32)
+
+    def _update():
+        kb = k_ref[0]                           # (bk, D)
+        do = do_ref[0]                          # (bq, Dv)
+        p, ds = _p_ds(q, kb, v_ref[0], do, lse_ref[0], dsum_ref[0],
+                      (qr_ref[0], kr_ref[0]) if rope else None, scale,
+                      _causal_mask(bq, bk, jq, kk, window) if causal else None)
+        rows = (slice(None) if nk == 1
+                else pl.ds(pl.multiple_of(kk * bk, bk), bk))
+        dv_acc[rows, :] += _dot(p, do, (0, 0))                   # (bk, Dv)
+        dk_acc[rows, :] += _dot(ds, q, (0, 0)) * scale           # (bk, D)
+        dq_scr[:] += _dot(ds, kb, (1, 0)) * scale                # (bq, D)
+        if rope:
+            dkr_acc[rows, :] += _dot(ds, qr_ref[0], (0, 0)) * scale
+            dqr_scr[:] += _dot(ds, kr_ref[0], (1, 0)) * scale
+
+    if causal:
+        pl.when(_tile_live(bq, bk, jq, kk, window))(_update)
+    else:
+        _update()
+
+    @pl.when(step == nk - 1)
+    def _write_q():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        if rope:
+            dqr_ref[0] = dqr_scr[:].astype(dqr_ref.dtype)
+
+    @pl.when((place == group - 1) & last)
+    def _write_kv():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    if rope:
+        @pl.when((gg == heads - 1) & last)
+        def _write_rope():
+            dkr_ref[0] = dkr_acc[:].astype(dkr_ref.dtype)
+
+
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
                scale, nk, causal, window=None, rope=False):
-    """Grid (bh, q-block, kv-block): stream K/V past a fixed q block,
-    accumulating dQ = Σ_k dS·K·scale in VMEM scratch (and, with `rope`,
-    dQ_r = Σ_k dS·K_r·scale beside it: inputs q_r, k_r follow Δ)."""
+    """The split path's first kernel. Grid (bh, q-block, kv-block): stream
+    K/V past a fixed q block, accumulating dQ = Σ_k dS·K·scale in VMEM
+    scratch (and, with `rope`, dQ_r = Σ_k dS·K_r·scale beside it: inputs
+    q_r, k_r follow Δ)."""
     if rope:
         qr_ref, kr_ref, dq_ref, dqr_ref, dq_scr, dqr_scr = rest
     else:
         dq_ref, dq_scr = rest
     jq, kk = pl.program_id(1), pl.program_id(2)
     q = q_ref[0]                                # (bq, D) input dtype
-    bq, d = q.shape
+    bq = q.shape[0]
     bk = k_ref.shape[1]
 
     @pl.when(kk == 0)
     def _init():
-        dq_scr[:] = jnp.zeros((bq, d), jnp.float32)
+        dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
         if rope:
             dqr_scr[:] = jnp.zeros(dqr_scr.shape, jnp.float32)
 
     def _update():
         kb = k_ref[0]                           # (bk, D)
-        vb = v_ref[0]
-        do = do_ref[0]                          # (bq, Dv)
-        lse = lse_ref[0]                        # (bq, 1) f32
-        dsum = dsum_ref[0]                      # (bq, 1) f32
-        s = _scores(q, kb, (qr_ref[0], kr_ref[0]) if rope else None,
-                    scale)                                       # (bq, bk)
-        if causal:
-            # lse is finite, so exp(−NEG_INF − lse) underflows to exactly
-            # 0 — masking s alone zeroes P (and thus dS) on forbidden
-            # entries.
-            s = jnp.where(_causal_mask(bq, bk, jq, kk, window), s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # (bq, bk)
-        ds = (p * (dp - dsum)).astype(kb.dtype)
-        dq_scr[:] += jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        _, ds = _p_ds(q, kb, v_ref[0], do_ref[0], lse_ref[0], dsum_ref[0],
+                      (qr_ref[0], kr_ref[0]) if rope else None, scale,
+                      _causal_mask(bq, bk, jq, kk, window) if causal else None)
+        dq_scr[:] += _dot(ds, kb, (1, 0)) * scale
         if rope:
-            dqr_scr[:] += jax.lax.dot_general(
-                ds, kr_ref[0], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+            dqr_scr[:] += _dot(ds, kr_ref[0], (1, 0)) * scale
 
     if causal:
         pl.when(_tile_live(bq, bk, jq, kk, window))(_update)
@@ -331,10 +465,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref, *rest,
                 scale, nq, causal, window=None, group=1, heads=None):
-    """Grid (kv heads, kv-block, query head of the group, q-block): stream
-    the Q/dO of every query head that reads this KV head past a fixed kv
-    block, accumulating dK = Σ_q dSᵀ·Q·scale and dV = Σ_q Pᵀ·dO in VMEM
-    scratch (both trailing grid dimensions are sequential).
+    """The split path's second kernel. Grid (kv heads, kv-block, query head
+    of the group, q-block): stream the Q/dO of every query head that reads
+    this KV head past a fixed kv block, accumulating dK = Σ_q dSᵀ·Q·scale and
+    dV = Σ_q Pᵀ·dO in VMEM scratch (both trailing grid dimensions are
+    sequential).
 
     With `heads` (latent attention: inputs k_r, q_r follow Δ) the first grid
     dimension walks the heads of K_r and the third the `heads` query heads
@@ -347,14 +482,14 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref, *rest,
         dk_ref, dv_ref, dk_scr, dv_scr = rest
     jk, gg, qq = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     kb = k_ref[0]                               # (bk, D) input dtype
-    bk, d = kb.shape
+    bk = kb.shape[0]
     bq = q_ref.shape[1]
     # this KV head's place among the query heads that read it
     place = gg % group if rope else gg
 
     @pl.when((place == 0) & (qq == 0))
     def _init():
-        dk_scr[:] = jnp.zeros((bk, d), jnp.float32)
+        dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
 
     if rope:
@@ -363,31 +498,16 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref, *rest,
             dkr_scr[:] = jnp.zeros(dkr_scr.shape, jnp.float32)
 
     def _update():
-        vb = v_ref[0]
         q = q_ref[0]                            # (bq, D)
         do = do_ref[0]                          # (bq, Dv)
-        lse = lse_ref[0]                        # (bq, 1) f32
-        dsum = dsum_ref[0]                      # (bq, 1) f32
-        s = _scores(q, kb, (qr_ref[0], kr_ref[0]) if rope else None,
-                    scale)                                       # (bq, bk)
-        if causal:
-            # q-block index is the LAST grid dim here; kv-block is dim 1
-            s = jnp.where(_causal_mask(bq, bk, qq, jk, window), s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # (bk, Dv)
-        dp = jax.lax.dot_general(
-            do, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # (bq, bk)
-        ds = (p * (dp - dsum)).astype(q.dtype)
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        # q-block index is the LAST grid dim here; kv-block is dim 1
+        p, ds = _p_ds(q, kb, v_ref[0], do, lse_ref[0], dsum_ref[0],
+                      (qr_ref[0], kr_ref[0]) if rope else None, scale,
+                      _causal_mask(bq, bk, qq, jk, window) if causal else None)
+        dv_scr[:] += _dot(p, do, (0, 0))                         # (bk, Dv)
+        dk_scr[:] += _dot(ds, q, (0, 0)) * scale
         if rope:
-            dkr_scr[:] += jax.lax.dot_general(
-                ds, qr_ref[0], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+            dkr_scr[:] += _dot(ds, qr_ref[0], (0, 0)) * scale
 
     if causal:
         pl.when(_tile_live(bq, bk, qq, jk, window))(_update)
@@ -405,16 +525,113 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref, *rest,
             dkr_ref[0] = dkr_scr[:].astype(dkr_ref.dtype)
 
 
+def _vmem(shape, index):
+    return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+
 def _flash_backward_impl(q3, k3, v3, do3, lse, dsum, scale, causal=False,
                          window=None, qr3=None, kr3=None):
     """(bh, T, D) q, (bh, T, Dv) dO, (bh // g, T, D | Dv) k | v + (bh, T, 1)
     lse/Δ → (dq, dk, dv), O(T·D) HBM; with the scores' second part (`qr3`
     (bh, T, Dr), `kr3` (bh // g_r, T, Dr)) → (dq, dk, dv, dq_r, dk_r).
 
-    The score tile is recomputed per block pair in both kernels; the only
-    HBM residuals are out/lse from the forward. Blocks are capped at 512 so
-    the (bq, bk) f32 score/probability tiles plus the (block, D) operand
-    tiles fit VMEM alongside the accumulators."""
+    One fused kernel wherever its whole-T accumulators fit `_VMEM_BUDGET`
+    (`_fused_vmem_bytes`: T of 40 thousand at 128-wide K and V in bf16), the
+    two-kernel split beyond. The choice is made from the shapes as the
+    caller is traced, and counted there: `flash_backward_total{path}`."""
+    path = backward_path(q3.shape[1], _kv_widths(k3, v3, kr3),
+                         q3.dtype.itemsize)
+    spans.count("flash_backward_total", path=path)
+    impl = _backward_fused if path == "fused" else _backward_split
+    return impl(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3, kr3)
+
+
+def _band_blocks(causal, bq, bk, nq, window):
+    """(kv block fetched at step kk of q-block j, q block fetched at step qq
+    of kv-block j): the same DMA-elision trick as the forward — a
+    compute-skipped step re-requests a block of the band (bq == bk by
+    construction of _block), so nothing is fetched for it."""
+    if not causal:
+        return (lambda j, kk: kk), (lambda j, qq: qq)
+    return (lambda j, kk: jnp.clip(kk, _first_kv_block(bq, bk, j, window), j),
+            lambda j, qq: jnp.clip(qq, j, _last_q_block(bq, bk, j, nq, window)))
+
+
+def _backward_fused(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3,
+                    kr3):
+    bh, t, d = q3.shape
+    dv = v3.shape[-1]
+    group = bh // k3.shape[0]
+    rope = qr3 is not None
+    bq = _block(t, cap=512)
+    bk = _block(t, cap=512)
+    nq, nk = t // bq, t // bk
+    # the first grid dimension and the query heads under each of its entries:
+    # the KV heads and their group, or K_r's heads and theirs
+    outer = kr3.shape[0] if rope else k3.shape[0]
+    heads = bh // outer
+    # The kv blocks of a q block are walked from the LAST to the first: a
+    # causal row's dead steps (blocks past the diagonal, a fraction of a
+    # microsecond each) then come first and re-request the diagonal block,
+    # and the row ends on a live tile, under which the pipeline fetches the
+    # next row's q, dO and diagonal K/V. Walked upwards the row ends on dead
+    # steps, too short to hide that fetch: 5-6 % of the kernel at 8,192
+    # tokens (PERF.md §6, PR 36). dQ's sum runs over the blocks in that order.
+    band_block, _ = _band_blocks(causal, bq, bk, nq, window)
+    kv_block = lambda j, step: band_block(j, nk - 1 - step)  # noqa: E731
+    q_idx = lambda i, g, j, kk: (i * heads + g, j, 0)  # noqa: E731
+    kv_idx = lambda i, g, j, kk: (  # noqa: E731
+        (i * heads + g) // group, kv_block(j, kk), 0)
+    kv_whole = lambda i, g, j, kk: ((i * heads + g) // group, 0, 0)  # noqa: E731
+
+    in_specs = [_vmem((1, bq, d), q_idx), _vmem((1, bk, d), kv_idx),
+                _vmem((1, bk, dv), kv_idx), _vmem((1, bq, dv), q_idx),
+                _vmem((1, bq, 1), q_idx), _vmem((1, bq, 1), q_idx)]
+    args = (q3, k3, v3, do3, lse, dsum)
+    out_shape = [jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+                 jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+                 jax.ShapeDtypeStruct(v3.shape, v3.dtype)]
+    out_specs = [_vmem((1, bq, d), q_idx), _vmem((1, t, d), kv_whole),
+                 _vmem((1, t, dv), kv_whole)]
+    scratch = [pltpu.VMEM((bq, d), jnp.float32),
+               pltpu.VMEM((t, d), jnp.float32),
+               pltpu.VMEM((t, dv), jnp.float32)]
+    if rope:
+        dr = qr3.shape[-1]
+        in_specs += [_vmem((1, bq, dr), q_idx),
+                     _vmem((1, bk, dr),
+                           lambda i, g, j, kk: (i, kv_block(j, kk), 0))]
+        args += (qr3, kr3)
+        out_shape += [jax.ShapeDtypeStruct(qr3.shape, qr3.dtype),
+                      jax.ShapeDtypeStruct(kr3.shape, kr3.dtype)]
+        out_specs += [_vmem((1, bq, dr), q_idx),
+                      _vmem((1, t, dr), lambda i, g, j, kk: (i, 0, 0))]
+        scratch += [pltpu.VMEM((bq, dr), jnp.float32),
+                    pltpu.VMEM((t, dr), jnp.float32)]
+    return tuple(pl.pallas_call(
+        functools.partial(_dkvq_kernel, scale=scale, nq=nq, nk=nk,
+                          causal=causal, window=window, group=group,
+                          heads=heads, rope=rope),
+        out_shape=out_shape,
+        grid=(outer, heads, nq, nk),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_fused_vmem_bytes(
+                t, _kv_widths(k3, v3, kr3), q3.dtype.itemsize)),
+        interpret=_interpret(),
+        name="flash_dkvq",
+    )(*args))
+
+
+def _backward_split(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3,
+                    kr3):
+    """The path beyond the fused kernel's VMEM: one kernel grids over
+    q-blocks and streams K/V to accumulate dQ, the other grids over kv-blocks
+    and streams Q/dO to accumulate dK and dV, each rebuilding the tile's P
+    and dS, so that only (block, D) accumulators are resident and the
+    sequence length is bound by HBM alone."""
     bh, t, d = q3.shape
     dv = v3.shape[-1]
     bh_kv = k3.shape[0]
@@ -427,40 +644,27 @@ def _flash_backward_impl(q3, k3, v3, do3, lse, dsum, scale, causal=False,
     # of its entries: the KV heads and their group, or K_r's heads and theirs
     outer = kr3.shape[0] if rope else bh_kv
     heads = bh // outer
-
-    if causal:
-        # Same DMA-elision trick as the forward: compute-skipped steps
-        # re-request a block of the band (bq == bk by construction).
-        kv_block = lambda j, kk: jnp.clip(  # noqa: E731
-            kk, _first_kv_block(bq, bk, j, window), j)
-        q_block = lambda j, qq: jnp.clip(  # noqa: E731
-            qq, j, _last_q_block(bq, bk, j, nq, window))
-    else:
-        kv_block = lambda j, kk: kk  # noqa: E731
-        q_block = lambda j, qq: qq  # noqa: E731
+    kv_block, q_block = _band_blocks(causal, bq, bk, nq, window)
     kv_idx = lambda i, j, kk: (i // group, kv_block(j, kk), 0)  # noqa: E731
     q_row_idx = lambda i, j, g, qq: (  # noqa: E731
         i * heads + g, q_block(j, qq), 0)
     q_idx = lambda i, j, kk: (i, j, 0)  # noqa: E731
 
-    def vmem(shape, index):
-        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
-
-    in_specs = [vmem((1, bq, d), q_idx), vmem((1, bk, d), kv_idx),
-                vmem((1, bk, dv), kv_idx), vmem((1, bq, dv), q_idx),
-                vmem((1, bq, 1), q_idx), vmem((1, bq, 1), q_idx)]
+    in_specs = [_vmem((1, bq, d), q_idx), _vmem((1, bk, d), kv_idx),
+                _vmem((1, bk, dv), kv_idx), _vmem((1, bq, dv), q_idx),
+                _vmem((1, bq, 1), q_idx), _vmem((1, bq, 1), q_idx)]
     args = (q3, k3, v3, do3, lse, dsum)
     out_shape = jax.ShapeDtypeStruct((bh, t, d), q3.dtype)
-    out_specs = vmem((1, bq, d), q_idx)
+    out_specs = _vmem((1, bq, d), q_idx)
     scratch = [pltpu.VMEM((bq, d), jnp.float32)]
     if rope:
         dr = qr3.shape[-1]
-        in_specs += [vmem((1, bq, dr), q_idx),
-                     vmem((1, bk, dr),
-                          lambda i, j, kk: (i // heads, kv_block(j, kk), 0))]
+        in_specs += [_vmem((1, bq, dr), q_idx),
+                     _vmem((1, bk, dr),
+                           lambda i, j, kk: (i // heads, kv_block(j, kk), 0))]
         args += (qr3, kr3)
         out_shape = [out_shape, jax.ShapeDtypeStruct((bh, t, dr), qr3.dtype)]
-        out_specs = [out_specs, vmem((1, bq, dr), q_idx)]
+        out_specs = [out_specs, _vmem((1, bq, dr), q_idx)]
         scratch.append(pltpu.VMEM((bq, dr), jnp.float32))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, nk=nk, causal=causal,
@@ -479,21 +683,21 @@ def _flash_backward_impl(q3, k3, v3, do3, lse, dsum, scale, causal=False,
         kv_own = lambda i, j, g, qq: ((i * heads + g) // group, j, 0)  # noqa: E731
     else:
         kv_own = lambda i, j, g, qq: (i, j, 0)  # noqa: E731
-    in_specs = [vmem((1, bk, d), kv_own), vmem((1, bk, dv), kv_own),
-                vmem((1, bq, d), q_row_idx), vmem((1, bq, dv), q_row_idx),
-                vmem((1, bq, 1), q_row_idx), vmem((1, bq, 1), q_row_idx)]
+    in_specs = [_vmem((1, bk, d), kv_own), _vmem((1, bk, dv), kv_own),
+                _vmem((1, bq, d), q_row_idx), _vmem((1, bq, dv), q_row_idx),
+                _vmem((1, bq, 1), q_row_idx), _vmem((1, bq, 1), q_row_idx)]
     args = (k3, v3, q3, do3, lse, dsum)
     out_shape = [jax.ShapeDtypeStruct((bh_kv, t, d), k3.dtype),
                  jax.ShapeDtypeStruct((bh_kv, t, dv), v3.dtype)]
-    out_specs = [vmem((1, bk, d), kv_own), vmem((1, bk, dv), kv_own)]
+    out_specs = [_vmem((1, bk, d), kv_own), _vmem((1, bk, dv), kv_own)]
     scratch = [pltpu.VMEM((bk, d), jnp.float32),
                pltpu.VMEM((bk, dv), jnp.float32)]
     if rope:
         kr_own = lambda i, j, g, qq: (i, j, 0)  # noqa: E731
-        in_specs += [vmem((1, bk, dr), kr_own), vmem((1, bq, dr), q_row_idx)]
+        in_specs += [_vmem((1, bk, dr), kr_own), _vmem((1, bq, dr), q_row_idx)]
         args += (kr3, qr3)
         out_shape.append(jax.ShapeDtypeStruct(kr3.shape, kr3.dtype))
-        out_specs.append(vmem((1, bk, dr), kr_own))
+        out_specs.append(_vmem((1, bk, dr), kr_own))
         scratch.append(pltpu.VMEM((bk, dr), jnp.float32))
     dkv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, nq=nq, causal=causal,

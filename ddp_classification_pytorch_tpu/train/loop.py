@@ -154,8 +154,9 @@ def build_datasets(cfg: Config) -> Tuple[Any, Any]:
         f"dataset {d.dataset!r} has a transform preset but no build branch")
 
 
-# HELP lines of the input pipeline's counters (obs/spans.py) in metrics.prom
-_INPUT_COUNTER_HELP = {
+# HELP lines of the counters kept in obs/spans.py (the input pipeline's, and
+# what the kernels count as the step is traced) in metrics.prom
+_SPAN_COUNTER_HELP = {
     "input_batches_total": "batches the input pipeline handed to a loop",
     "input_starved_total": "of those, batches that were not staged yet when "
                            "the loop asked",
@@ -163,6 +164,8 @@ _INPUT_COUNTER_HELP = {
                                   "the dtype it wrote them in",
     "input_batch_buffers_total": "batches the loader's Python path filled, "
                                  "by whether the buffer was a recycled one",
+    "flash_backward_total": "attention calls traced, by the backward their "
+                            "shapes chose: one fused kernel or the split",
 }
 
 
@@ -432,7 +435,7 @@ class Trainer:
             publish("span_count_total", "spans of this name recorded",
                     {"span": name}, count)
         for (name, labels), n in spans.counters().items():
-            publish(name, _INPUT_COUNTER_HELP.get(name, ""),
+            publish(name, _SPAN_COUNTER_HELP.get(name, ""),
                     {k: str(v) for k, v in labels}, n)
 
     # -------------------------------------------------------------- profile --
@@ -520,13 +523,22 @@ class Trainer:
     def _publish_layer_kinds(self) -> None:
         """The layout the decoder was built with: how many of its layers mix
         tokens by which operator before which feed-forward — a static
-        counter, and the same counts beside `init_state` in the set-up line."""
+        counter, and the same counts beside `init_state` in the set-up line,
+        with the path the attention kernels' backward takes at these sizes."""
+        from ..models.decoder_lm import flash_backward_path
+
         kinds = collections.Counter(self.cfg.model.decoder.layer_kinds())
         for (operator, ffn), n in sorted(kinds.items()):
             self.obs.counter("decoder_layers_total", "layers of the token "
                              "decoder by token mixer and feed-forward",
                              {"operator": operator, "ffn": ffn}).inc(n)
             spans.note(**{f"{operator}_{ffn}": n})
+        path = flash_backward_path(self.cfg.model.decoder, self.cfg.model.dtype,
+                                   self.cfg.model.flash_min_tokens)
+        if path:
+            # what `flash_backward_total{path}` will count once the step is
+            # traced (ops/flash_attention.py), known here from the sizes
+            spans.note(flash_backward=path)
 
     def _publish_moe_load(self, load: np.ndarray) -> None:
         """The logged step's routing, as the step's metrics carry it —

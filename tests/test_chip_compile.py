@@ -124,8 +124,9 @@ def test_flash_attention_compiles_forward_and_backward(one_chip, kernels,
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     text = _compiled_text(fwd_and_vjp, q, q, q)
-    # forward, dQ, and dK/dV are three separate Mosaic calls
-    assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
+    # the forward and the one backward kernel are two Mosaic calls
+    assert text.count("tpu_custom_call") == 2, text.count("tpu_custom_call")
+    assert "flash_dkvq" in text and "flash_dq" not in text
 
 
 # ------------------------------------------------------------ whole steps --
@@ -257,7 +258,7 @@ def test_flash_attention_grouped_heads_and_window_compile(one_chip, kernels, win
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     text = _compiled_text(fwd_and_vjp, q, kv, kv)
-    assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
+    assert text.count("tpu_custom_call") == 2, text.count("tpu_custom_call")
 
 
 def test_sparse_experts_compile_to_grouped_matmul_kernels(one_chip):
@@ -316,7 +317,9 @@ def test_decoder_train_step_fits_one_chip(topo, kernels):
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 0.99 * m.output_size_in_bytes
     text = compiled.as_text()
-    assert "ragged-dot" in text and text.count("tpu_custom_call") >= 12
+    # 4 attention layers x (forward, the fused backward) kernels
+    assert "ragged-dot" in text and text.count("tpu_custom_call") >= 8
+    assert "flash_dkvq" in text and "flash_dq" not in text
 
 
 # ------------------------------- latent attention, the second decoder (PR 32) --
@@ -342,7 +345,7 @@ def test_flash_attention_latent_scores_compile(one_chip, kernels):
         return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*a)
 
     text = _compiled_text(fwd_and_vjp, *args)
-    assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
+    assert text.count("tpu_custom_call") == 2, text.count("tpu_custom_call")
 
 
 def test_latent_decoder_train_step_fits_one_chip(topo, kernels):
@@ -379,8 +382,9 @@ def test_latent_decoder_train_step_fits_one_chip(topo, kernels):
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 0.99 * m.output_size_in_bytes
     text = compiled.as_text()
-    # 6 attention blocks x (forward, dq, dkv) kernels
-    assert "ragged-dot" in text and text.count("tpu_custom_call") >= 18
+    # 6 attention blocks x (forward, the fused backward) kernels
+    assert "ragged-dot" in text and text.count("tpu_custom_call") >= 12
+    assert "flash_dkvq" in text and "flash_dq" not in text
 
 
 # ------------- the short convolution beside 64-wide heads, the third decoder --
@@ -389,7 +393,7 @@ def test_flash_attention_64_wide_heads_compile(one_chip, kernels):
     """LFM2-8B-A1B's attention at its published widths: 32 query heads on 8
     KV heads of 64, 2 rows of 8,192 tokens, full causal. The head is not
     padded to 128: Mosaic takes the (512, 64) blocks of q, k and v as they
-    are in all three kernels."""
+    are in both kernels."""
     _, fa = kernels
     q = jax.ShapeDtypeStruct((2, 8192, 32, 64), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((2, 8192, 8, 64), jnp.bfloat16, sharding=one_chip)
@@ -401,7 +405,7 @@ def test_flash_attention_64_wide_heads_compile(one_chip, kernels):
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     text = _compiled_text(fwd_and_vjp, q, kv, kv)
-    assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
+    assert text.count("tpu_custom_call") == 2, text.count("tpu_custom_call")
 
 
 def test_hybrid_decoder_train_step_fits_one_chip(topo, kernels):
@@ -439,5 +443,7 @@ def test_hybrid_decoder_train_step_fits_one_chip(topo, kernels):
     assert _device_bytes(compiled) < 0.9 * HBM_BYTES
     assert m.alias_size_in_bytes >= 0.99 * m.output_size_in_bytes
     text = compiled.as_text()
-    # one attention block x (forward, dq, dkv); the convolution is plain XLA
-    assert "ragged-dot" in text and text.count("tpu_custom_call") >= 3
+    # one attention block x (forward, the fused backward); the convolution
+    # is plain XLA
+    assert "ragged-dot" in text and text.count("tpu_custom_call") >= 2
+    assert "flash_dkvq" in text and "flash_dq" not in text

@@ -9,6 +9,7 @@ causality, and the counter that shows the layout a run built. CPU, toy sizes."""
 
 import json
 import os
+import re
 import sys
 
 import jax
@@ -404,15 +405,18 @@ def test_factory_refuses_kinds_that_do_not_go_together():
 
 # counters ------------------------------------------------------------------
 
+@pytest.mark.parametrize("extra", [(), ("--flash_min_tokens", "0")],
+                         ids=["dense_op", "flash_kernels"])
 def test_hybrid_decoder_trains_through_cli_train_and_publishes_its_layout(
-        tmp_path, capsys):
+        extra, tmp_path, capsys):
     t = ARCH["seq_len"]
     ids = (np.arange(8 * (t + 1)) * 7 % 50).astype(np.int32)
     path = tmp_path / "train.bin"
     ids.tofile(path)
     argv = cli_argv(ARCH, "--train_dir", str(path), "--batchsize", "8", "--epochs",
                     "2", "--lr", "0.003", "--adam_b2", "0.95", "--platform", "cpu",
-                    "--out", str(tmp_path / "run"), "--log_every", "1", "--remat")
+                    "--out", str(tmp_path / "run"), "--log_every", "1", "--remat",
+                    *extra)
     train_main(argv)   # Trainer, ShardedLoader, DevicePrefetcher, _build_step
     with open(tmp_path / "run" / "history.json") as f:
         losses = json.load(f)["loss"]             # one step an epoch: two steps
@@ -427,3 +431,9 @@ def test_hybrid_decoder_trains_through_cli_train_and_publishes_its_layout(
     out = capsys.readouterr().out
     setup = next(line for line in out.splitlines() if "[trainer] set-up:" in line)
     assert "conv_dense=1 conv_routed=1 gqa_routed=1" in setup, setup
+    # the attention layer's backward, where it reaches the kernels: the path
+    # its shapes choose stands in the set-up line before any step is traced,
+    # and the trace counts it (a process total: other tests add to it)
+    assert ("flash_backward=fused" in setup) == bool(extra), setup
+    if extra:
+        assert re.search(r'flash_backward_total\{path="fused"\} [1-9]', prom), prom

@@ -302,18 +302,23 @@ def test_flash_attention_refuses_parts_that_do_not_go_together():
 @pytest.mark.parametrize("extra,want", [
     ((), "2db75f41754dcf9b660717243bc58cf02cc15a287de6284447b17a837c89910a"),
     (("--flash_min_tokens", "0"),
-     "b65fd5f5eea41e030f799778e7a9a66ab77bd39d0692b7942632cce61ca2e57d"),
+     "9af5b40da91b482e42c0bada90420ab38d6501347b31ef6ed787cf24cc28f7ca"),
 ], ids=["dense_op", "flash_kernels"])
 def test_the_first_decoders_argv_still_builds_the_program_it_built(extra, want):
     """`st21b_ep4_8k`'s argv (its rehearsal sizes) yields the leaves it
     yielded at the parent of the PR that made the layer a description (PR 32)
-    and the lowered step it yielded at PR 34: sha256 of the parameters' paths,
+    and the lowered step it yielded at PR 34 (`dense_op`) and PR 36
+    (`flash_kernels`): sha256 of the parameters' paths,
     shapes and dtypes and of the step's StableHLO text, taken there with this
     same code. PR 34 moved the two step hashes by intent and left the leaves'
     alone: the expert layer's combine became one custom_vjp op whose backward
     is written over the sorted rows (ops/moe.py::_combine), so the program
-    text changed in every routing layer. A change of JAX moves all three: take
-    them again from that commit."""
+    text changed in every routing layer. PR 36 moved the `flash_kernels` hash
+    by intent and left the `dense_op` hash and the leaves' alone: the flash
+    backward became one kernel (ops/flash_attention.py::_dkvq_kernel, one
+    `pallas_call` where `flash_dq` and `flash_dkv` stood), so the program
+    text changed in every attention layer that reaches the kernels. A change
+    of JAX moves all three: take them again from that commit."""
     from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
     from ddp_classification_pytorch_tpu.train.state import create_train_state
     from ddp_classification_pytorch_tpu.train.steps import make_train_step

@@ -157,3 +157,138 @@ def test_vit_with_flash_matches_dense_vit():
     np.testing.assert_allclose(
         np.asarray(flash.apply(vs, x, train=False)),
         np.asarray(dense.apply(vs, x, train=False)), atol=1e-4)
+
+
+# ------------------------------------------- the fused backward (PR 36) --
+
+# T = 256 in blocks of 64: a 4 x 4 grid of tiles, so every case streams.
+# b, heads, kv_heads, d, dv, dr (0: no second part of the scores), and the
+# keyword arguments of the op
+_BACKWARD_CASES = {
+    "non_causal": (2, 2, 2, 32, 32, 0, {}),
+    "causal": (2, 2, 2, 32, 32, 0, {"causal": True}),
+    # keys 80 back: q-block 3 sees kv-blocks 2-3 whole or in part, 1 by its
+    # edge (cols 113-127 of rows 192-206), 0 not at all
+    "window_dead_and_edge_tiles": (2, 2, 2, 32, 32, 0,
+                                   {"causal": True, "window": 80}),
+    "grouped_kv_heads_4": (1, 8, 2, 32, 32, 0, {"causal": True}),
+    "value_narrower_than_scores": (2, 2, 2, 32, 16, 0, {"causal": True}),
+    "latent_one_rope_head_under_two_kv_heads": (
+        2, 4, 2, 16, 24, 8, {"causal": True, "window": 150}),
+}
+
+
+def _backward_counts():
+    """(fused, split) of `flash_backward_total`: process totals."""
+    from ddp_classification_pytorch_tpu.obs import spans
+
+    return tuple(spans.counters().get(
+        ("flash_backward_total", (("path", path),)), 0)
+        for path in ("fused", "split"))
+
+
+@pytest.fixture
+def fa64(monkeypatch):
+    """The module (ops/__init__ re-exports a same-named function) with its
+    blocks shrunk to 64."""
+    import importlib
+
+    fa = importlib.import_module(
+        "ddp_classification_pytorch_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_block", lambda t, cap=1024: 64)
+    return fa
+
+
+@pytest.mark.parametrize("path", ["fused", "split"])
+@pytest.mark.parametrize("case", list(_BACKWARD_CASES) + ["lse_cotangent"])
+def test_backward_matches_the_dense_ops_gradients(case, path, fa64, monkeypatch):
+    """dQ, dK, dV (and dQ_r, dK_r) of the backward against `jax.grad` of the
+    (T, T) op, on the one fused kernel and, with the VMEM budget shrunk so
+    that no whole-T accumulator fits it, on the two-kernel split, which has
+    to agree with the fused kernel to the order of its sums;
+    `flash_backward_total` counts the path taken once for the one attention
+    call traced."""
+    fa = fa64
+    t = 256
+    if case == "lse_cotangent":
+        # ring attention's building block: the cotangent of the row
+        # logsumexp folds into the kernels' Δ
+        b, h, h_kv, d, dv, dr, kw = 2, 2, 2, 32, 32, 0, {"causal": True}
+    else:
+        b, h, h_kv, d, dv, dr, kw = _BACKWARD_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(7), 7)
+    args = [jax.random.normal(ks[0], (b, t, h, d)),
+            jax.random.normal(ks[1], (b, t, h_kv, d)),
+            jax.random.normal(ks[2], (b, t, h_kv, dv))]
+    if dr:
+        args += [jax.random.normal(ks[3], (b, t, h, dr)),
+                 jax.random.normal(ks[4], (b, t, 1, dr))]  # ONE rotary head
+    cot = jax.random.normal(ks[5], (b, t, h, dv))
+    cot_lse = jax.random.normal(ks[6], (b, h, t))
+
+    def dense(q, k, v, *rope):
+        if case != "lse_cotangent":
+            return (attention(q, k, v, **kw, **dict(zip(
+                ("q_rope", "k_rope"), rope))) * cot).sum()
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        return ((attention(q, k, v, causal=True) * cot).sum()
+                + (jax.nn.logsumexp(s, axis=-1) * cot_lse).sum())
+
+    def flash(q, k, v, *rope):
+        if case != "lse_cotangent":
+            return (fa.flash_attention(q, k, v, **kw, **dict(zip(
+                ("q_rope", "k_rope"), rope))) * cot).sum()
+        out, lse = fa.flash_attention_with_lse(q, k, v, causal=True)
+        return (out * cot).sum() + (lse * cot_lse).sum()
+
+    every = tuple(range(len(args)))
+    want = jax.grad(dense, argnums=every)(*args)
+    fused0, split0 = _backward_counts()
+    fused = jax.grad(flash, argnums=every)(*args)
+    assert _backward_counts() == (fused0 + 1, split0)
+    got = fused
+    if path == "split":
+        monkeypatch.setattr(fa, "_VMEM_BUDGET", fa._TILE_VMEM)
+        got = jax.grad(flash, argnums=every)(*args)
+        assert _backward_counts() == (fused0 + 1, split0 + 1)
+        for a, f in zip(got, fused):
+            np.testing.assert_allclose(a, f, rtol=1e-6, atol=1e-6)
+    for a, w in zip(got, want):
+        assert a.shape == w.shape
+        np.testing.assert_allclose(a, w, rtol=2e-5, atol=2e-5)
+
+
+def test_the_fused_backward_is_taken_by_what_its_accumulators_weigh(fa64):
+    """The path is a byte count of the shapes and nothing else: an f32
+    accumulator and a double-buffered output block for the whole T of K, V
+    (and K_r), lanes padded to 128, beside the tiles' 16 MiB."""
+    fa = fa64
+    mib = 2 ** 20
+    # the three decoder cells at 8,192 tokens in bf16, and a ViT's 196
+    assert fa._fused_vmem_bytes(8192, (128, 128, 64), 2) == (24 + 16) * mib
+    assert fa._fused_vmem_bytes(8192, (128, 128), 2) == (16 + 16) * mib
+    assert fa._fused_vmem_bytes(8192, (64, 64), 2) == (16 + 16) * mib
+    assert fa._fused_vmem_bytes(196, (64, 64), 2) < 17 * mib
+    # 128-wide K and V in bf16: fused up to 40 thousand tokens, split beyond
+    assert fa._fused_vmem_bytes(40960, (128, 128), 2) <= fa._VMEM_BUDGET
+    assert fa._fused_vmem_bytes(49152, (128, 128), 2) > fa._VMEM_BUDGET
+
+
+@pytest.mark.parametrize("sizes,dtype,want", [
+    ({}, "bfloat16", "fused"),                        # SmallThinker as published
+    ({"attention": "mla", "rope_dim": 64, "v_head_dim": 128}, "bfloat16", "fused"),
+    ({"head_dim": 64, "conv_layout": (1, 1, 1, 0)}, "float32", "fused"),
+    ({"seq_len": 65536}, "bfloat16", "split"),        # past the VMEM budget
+    ({"seq_len": 512}, "bfloat16", None),             # the dense op's rows
+    ({"conv_layout": (1,)}, "bfloat16", None),        # no attention layer
+], ids=["gqa_8k", "latent_8k", "one_attention_layer_in_four", "rows_of_65536",
+        "rows_of_512", "convolutions_only"])
+def test_the_decoder_names_its_backward_from_its_sizes(sizes, dtype, want):
+    """What the `[trainer] set-up:` line says (`flash_backward=`) before any
+    step is traced: the kernels' own byte count over the decoder's sizes."""
+    from ddp_classification_pytorch_tpu.config import DecoderConfig
+    from ddp_classification_pytorch_tpu.models.decoder_lm import (
+        flash_backward_path)
+
+    assert flash_backward_path(DecoderConfig(**sizes), dtype, 1024) == want
