@@ -16,6 +16,13 @@ TPU-first design decisions (not translations):
   shard_map/pmap path.
 - No Python control flow depends on data; the whole model traces to one XLA
   computation.
+- Every op has a name in a device profile: flax writes the module path into
+  `op_name` (`layer1_block0/Conv_0`, `.../BatchNorm_1`, `bn_stem`, `fc`), and
+  what stands outside every module gets a `jax.named_scope`: `bn` (the ReLU
+  behind a BatchNorm: XLA fuses it into the normalize, and a fusion is named
+  after its root), `residual` (add + ReLU), `pool` (the stem's max-pool), and
+  `head` around the global pool and `fc` (docs/observability.md, Device-side
+  names).
 """
 
 from __future__ import annotations
@@ -24,6 +31,7 @@ import functools
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 ModuleDef = Any
@@ -35,6 +43,12 @@ FEAT_DIMS = {
     "resnet101": 2048,
     "resnet152": 2048,
 }
+
+
+def _relu(y: jnp.ndarray) -> jnp.ndarray:
+    """The ReLU behind a BatchNorm, under the `bn` scope."""
+    with jax.named_scope("bn"):
+        return nn.relu(y)
 
 
 class BasicBlock(nn.Module):
@@ -50,8 +64,7 @@ class BasicBlock(nn.Module):
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         residual = x
         y = self.conv(self.filters, (3, 3), strides=(self.strides, self.strides))(x)
-        y = self.norm()(y)
-        y = nn.relu(y)
+        y = _relu(self.norm()(y))
         y = self.conv(self.filters, (3, 3))(y)
         y = self.norm(scale_init=nn.initializers.ones)(y)
         if residual.shape != y.shape:
@@ -60,7 +73,8 @@ class BasicBlock(nn.Module):
                 strides=(self.strides, self.strides), name="downsample_conv",
             )(x)
             residual = self.norm(name="downsample_bn")(residual)
-        return nn.relu(y + residual)
+        with jax.named_scope("residual"):
+            return nn.relu(y + residual)
 
 
 class Bottleneck(nn.Module):
@@ -76,11 +90,9 @@ class Bottleneck(nn.Module):
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         residual = x
         y = self.conv(self.filters, (1, 1))(x)
-        y = self.norm()(y)
-        y = nn.relu(y)
+        y = _relu(self.norm()(y))
         y = self.conv(self.filters, (3, 3), strides=(self.strides, self.strides))(y)
-        y = self.norm()(y)
-        y = nn.relu(y)
+        y = _relu(self.norm()(y))
         y = self.conv(self.filters * self.expansion, (1, 1))(y)
         y = self.norm(scale_init=nn.initializers.ones)(y)
         if residual.shape != y.shape:
@@ -89,7 +101,8 @@ class Bottleneck(nn.Module):
                 strides=(self.strides, self.strides), name="downsample_conv",
             )(x)
             residual = self.norm(name="downsample_bn")(residual)
-        return nn.relu(y + residual)
+        with jax.named_scope("residual"):
+            return nn.relu(y + residual)
 
 
 class ResNet(nn.Module):
@@ -145,12 +158,13 @@ class ResNet(nn.Module):
             x = conv(self.num_filters, (3, 3), name="conv_stem")(x)
         else:
             x = conv(self.num_filters, (7, 7), strides=(2, 2), name="conv_stem")(x)
-        x = norm(name="bn_stem")(x)
-        x = nn.relu(x)
+        x = _relu(norm(name="bn_stem")(x))
         if not self.cifar_stem:
             # torch MaxPool2d(3, 2, padding=1); flax max_pool pads with -inf,
             # matching torch's border semantics
-            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=[(1, 1), (1, 1)])
+            with jax.named_scope("pool"):
+                x = nn.max_pool(x, (3, 3), strides=(2, 2),
+                                padding=[(1, 1), (1, 1)])
 
         block_cls = nn.remat(self.block_cls) if self.remat else self.block_cls
         for i, n_blocks in enumerate(self.stage_sizes):
@@ -165,9 +179,10 @@ class ResNet(nn.Module):
         # global average pool (adaptive, any input size); f32 output — the
         # pool feeds the f32 head, so rounding the mean back to the compute
         # dtype would only discard mantissa bits in between (dtype audit D6)
-        x = jnp.mean(x, axis=(1, 2), dtype=jnp.float32)
-        if self.num_classes > 0:
-            x = nn.Dense(self.num_classes, dtype=jnp.float32, name="fc")(x)
+        with jax.named_scope("head"):
+            x = jnp.mean(x, axis=(1, 2), dtype=jnp.float32)
+            if self.num_classes > 0:
+                x = nn.Dense(self.num_classes, dtype=jnp.float32, name="fc")(x)
         return x
 
 

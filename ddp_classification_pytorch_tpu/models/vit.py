@@ -15,7 +15,13 @@ TPU-first choices:
 - bf16 compute, f32 params / LayerNorm / softmax accumulators;
 - mean-pool over tokens (no CLS token): pooling commutes with the sharded
   token axis, so the head never needs a gather from shard 0;
-- static shapes end to end; the ring loop is a `lax.fori_loop`.
+- static shapes end to end; the ring loop is a `lax.fori_loop`;
+- every op has a name in a device profile: flax writes the module path into
+  `op_name` (`block3/attn/qkv`, `.../mlp_in`), and what stands outside every
+  module gets a `jax.named_scope`: `patch_embed` (cast, reshape, position
+  add), `ln` (a LayerNorm with the cast behind it), `mlp` (the two matmuls and
+  the GELU between them), `residual`, `head` (token pool and `fc`)
+  (docs/observability.md, Device-side names).
 """
 
 from __future__ import annotations
@@ -23,6 +29,7 @@ from __future__ import annotations
 from typing import Any, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from ..ops.attention import ring_attention
@@ -99,11 +106,15 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = True) -> jnp.ndarray:
         ln_dtype = self.dtype if self.ln_bf16 else jnp.float32
-        y = nn.LayerNorm(dtype=ln_dtype, name="ln1")(x).astype(self.dtype)
-        x = x + MHA(self.dim, self.heads, self.dtype, self.mesh,
-                    self.seq_axis, self.use_flash,
-                    self.flash_min_tokens, name="attn")(y)
-        y = nn.LayerNorm(dtype=ln_dtype, name="ln2")(x).astype(self.dtype)
+        with jax.named_scope("ln"):
+            y = nn.LayerNorm(dtype=ln_dtype, name="ln1")(x).astype(self.dtype)
+        y = MHA(self.dim, self.heads, self.dtype, self.mesh,
+                self.seq_axis, self.use_flash,
+                self.flash_min_tokens, name="attn")(y)
+        with jax.named_scope("residual"):
+            x = x + y
+        with jax.named_scope("ln"):
+            y = nn.LayerNorm(dtype=ln_dtype, name="ln2")(x).astype(self.dtype)
         if self.moe_experts > 0:
             from ..ops.moe import (
                 load_balance_loss,
@@ -148,12 +159,14 @@ class Block(nn.Module):
                         mesh=self.mesh if self.moe_axis else None,
                         axis=self.moe_axis, batch_axis=batch_axis)
         else:
-            y = nn.Dense(4 * self.dim, dtype=self.dtype, name="mlp_in")(y)
-            y = nn.gelu(y)
-            if self.dropout:
-                y = nn.Dropout(self.dropout, deterministic=not train)(y)
-            y = nn.Dense(self.dim, dtype=self.dtype, name="mlp_out")(y)
-        return x + y
+            with jax.named_scope("mlp"):
+                y = nn.Dense(4 * self.dim, dtype=self.dtype, name="mlp_in")(y)
+                y = nn.gelu(y)
+                if self.dropout:
+                    y = nn.Dropout(self.dropout, deterministic=not train)(y)
+                y = nn.Dense(self.dim, dtype=self.dtype, name="mlp_out")(y)
+        with jax.named_scope("residual"):
+            return x + y
 
 
 class ViT(nn.Module):
@@ -183,16 +196,17 @@ class ViT(nn.Module):
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = True) -> jnp.ndarray:
-        x = x.astype(self.dtype)
-        x = nn.Conv(self.dim, (self.patch, self.patch),
-                    strides=(self.patch, self.patch), padding="VALID",
-                    dtype=self.dtype, name="patch_embed")(x)
-        b, h, w, c = x.shape
-        x = x.reshape(b, h * w, c)
-        pos = self.param("pos_embed",
-                         nn.initializers.normal(stddev=0.02),
-                         (1, h * w, self.dim), jnp.float32)
-        x = x + pos.astype(self.dtype)
+        with jax.named_scope("patch_embed"):
+            x = x.astype(self.dtype)
+            x = nn.Conv(self.dim, (self.patch, self.patch),
+                        strides=(self.patch, self.patch), padding="VALID",
+                        dtype=self.dtype, name="patch_embed")(x)
+            b, h, w, c = x.shape
+            x = x.reshape(b, h * w, c)
+            pos = self.param("pos_embed",
+                             nn.initializers.normal(stddev=0.02),
+                             (1, h * w, self.dim), jnp.float32)
+            x = x + pos.astype(self.dtype)
         if self.remat:
             # checkpoint the blocks but keep every matmul (dot) output
             # saved: the ViT's recompute cost is dominated by its matmuls,
@@ -215,13 +229,15 @@ class ViT(nn.Module):
         # ln_final stays f32 even under --ln_bf16: its output feeds only the
         # f32 pool/head, so a bf16 affine here buys no matmul throughput and
         # just rounds the logits' inputs (dtype audit D6)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
+        with jax.named_scope("ln"):
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_final")(x)
         # token mean-pool; shard-friendly (see module doc). f32 output: the
         # pool feeds the f32 head, so rounding the mean back to the compute
         # dtype would only discard mantissa bits in between (dtype audit D6)
-        x = x.mean(axis=1, dtype=jnp.float32)
-        if self.num_classes > 0:
-            x = nn.Dense(self.num_classes, dtype=jnp.float32, name="fc")(x)
+        with jax.named_scope("head"):
+            x = x.mean(axis=1, dtype=jnp.float32)
+            if self.num_classes > 0:
+                x = nn.Dense(self.num_classes, dtype=jnp.float32, name="fc")(x)
         return x
 
 

@@ -58,6 +58,17 @@ from .state import TrainState, state_shardings
 
 Batch = Tuple[jnp.ndarray, jnp.ndarray]  # (images NHWC u8|f32, labels i32)
 
+# Device-side names (`jax.named_scope`) of what a train step does OUTSIDE
+# value_and_grad, where autodiff writes no mark of its own into an op's
+# `op_name`: the uint8 epilogue, the gradient exchange (ZeRO constraints, the
+# sections' pmeans), the non-finite guard (norm, isfinite, the keep-selects),
+# the optimizer update, the metrics. Forward, backward and recomputation have
+# no scope: `jvp(`, `transpose(` and `rematted_computation` in the same path
+# are their names (docs/observability.md, Device-side names).
+STEP_SCOPES = ("step.input", "step.exchange", "step.guard", "step.opt",
+               "step.metrics")
+_INPUT, _EXCHANGE, _GUARD, _OPT, _METRICS = STEP_SCOPES
+
 # fold_in tag deriving the flip stream from the step rng WITHOUT consuming
 # it — the float32 wire's mask/dropout derivations stay bit-identical
 _FLIP_FOLD = 0x464C4950  # "FLIP"
@@ -105,9 +116,10 @@ def _train_flip_enabled(cfg: Config) -> bool:
 def _cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     """Mean softmax-CE — semantics of the reference's LogSoftmax+NLLLoss pair
     (BASELINE/main.py:139,152) in one fused, stable op."""
-    return optax.softmax_cross_entropy_with_integer_labels(
-        logits.astype(jnp.float32), labels
-    ).mean()
+    with jax.named_scope("loss"):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits.astype(jnp.float32), labels
+        ).mean()
 
 
 def _train_metrics(loss, logits, labels) -> Dict[str, jnp.ndarray]:
@@ -436,13 +448,14 @@ def _reduced_grad_section(cfg: Config, mesh: Any, reduce_dtype: Any):
 
         (loss, (new_stats, logits)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, batch_stats)
-        grads = jax.tree_util.tree_map(
-            lambda g: g.astype(reduce_dtype), grads)
-        # per-shard mean-loss grads, so pmean == grad of the global mean
-        grads = jax.lax.pmean(grads, DATA_AXIS)
-        grads = jax.tree_util.tree_map(
-            lambda g, p: g.astype(p.dtype), grads, params)
-        loss = jax.lax.pmean(loss, DATA_AXIS)
+        with jax.named_scope(_EXCHANGE):
+            grads = jax.tree_util.tree_map(
+                lambda g: g.astype(reduce_dtype), grads)
+            # per-shard mean-loss grads, so pmean == grad of the global mean
+            grads = jax.lax.pmean(grads, DATA_AXIS)
+            grads = jax.tree_util.tree_map(
+                lambda g, p: g.astype(p.dtype), grads, params)
+            loss = jax.lax.pmean(loss, DATA_AXIS)
         return loss, new_stats, logits, grads
 
     return shard_map_unchecked(
@@ -561,15 +574,16 @@ def _accum_grad_section(cfg: Config, mesh: Any, grad_accum: int,
 
         loss, new_stats, logits, grads = _scan_microbatches(
             loss_fn, grad_accum, params, batch_stats, images, labels, rng)
-        grads = jax.tree_util.tree_map(
-            lambda g: g.astype(reduce_dtype), grads)
-        # THE deferred reduction: one cross-replica mean of the summed
-        # per-shard mean grads per optimizer step (pmean of per-shard
-        # means == grad of the global mean for equal shards)
-        grads = jax.lax.pmean(grads, DATA_AXIS)
-        grads = jax.tree_util.tree_map(
-            lambda g, p: g.astype(p.dtype), grads, params)
-        loss = jax.lax.pmean(loss, DATA_AXIS)
+        with jax.named_scope(_EXCHANGE):
+            grads = jax.tree_util.tree_map(
+                lambda g: g.astype(reduce_dtype), grads)
+            # THE deferred reduction: one cross-replica mean of the summed
+            # per-shard mean grads per optimizer step (pmean of per-shard
+            # means == grad of the global mean for equal shards)
+            grads = jax.lax.pmean(grads, DATA_AXIS)
+            grads = jax.tree_util.tree_map(
+                lambda g, p: g.astype(p.dtype), grads, params)
+            loss = jax.lax.pmean(loss, DATA_AXIS)
         return loss, new_stats, logits, grads
 
     return shard_map_unchecked(
@@ -640,7 +654,8 @@ def _build_step(tx, base_rng, loss_fn, metrics_fn, chaos=None, flip=False,
         # Outside value_and_grad: images carry no parameter gradient.
         # Runs BEFORE any (K, mb, ...) reshape — the uint8 epilogue audit
         # requires raw pixels to flow straight into convert → /255.
-        images = device_input_epilogue(images, rng, flip=flip)
+        with jax.named_scope(_INPUT):
+            images = device_input_epilogue(images, rng, flip=flip)
         if grad_section is not None:
             loss, new_stats, aux, grads = grad_section(
                 state.params, state.batch_stats, images, labels, rng)
@@ -662,29 +677,35 @@ def _build_step(tx, base_rng, loss_fn, metrics_fn, chaos=None, flip=False,
             # gradient slices land data-sharded (the reduce-scatter half
             # of ZeRO); grads share the params' key paths, so the
             # optimizer sharding rules apply verbatim
-            grads = jax.tree_util.tree_map(
-                jax.lax.with_sharding_constraint, grads,
-                meshlib.opt_shardings(grads, mesh, zero_data=True))
-        grad_norm = optax.global_norm(grads)
-        step_ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope(_EXCHANGE):
+                grads = jax.tree_util.tree_map(
+                    jax.lax.with_sharding_constraint, grads,
+                    meshlib.opt_shardings(grads, mesh, zero_data=True))
+        with jax.named_scope(_GUARD):
+            grad_norm = optax.global_norm(grads)
+            step_ok = jnp.isfinite(loss) & jnp.isfinite(grad_norm)
+        with jax.named_scope(_OPT):
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
 
         def keep(new, old):
             return jax.tree_util.tree_map(
                 lambda n, o: jnp.where(step_ok, n, o), new, old)
 
-        new_state = state.replace(
-            step=state.step + 1,
-            params=keep(new_params, state.params),
-            batch_stats=keep(new_stats, state.batch_stats),
-            opt_state=keep(new_opt, state.opt_state),
-        )
+        with jax.named_scope(_GUARD):
+            new_state = state.replace(
+                step=state.step + 1,
+                params=keep(new_params, state.params),
+                batch_stats=keep(new_stats, state.batch_stats),
+                opt_state=keep(new_opt, state.opt_state),
+            )
         if zero or grad_section is not None:
-            new_state = _constrain_state(new_state, mesh, zero)
-        metrics = metrics_fn(loss, aux, labels)
-        metrics["step_ok"] = step_ok.astype(jnp.float32)
-        metrics["grad_norm"] = grad_norm
+            with jax.named_scope(_EXCHANGE):
+                new_state = _constrain_state(new_state, mesh, zero)
+        with jax.named_scope(_METRICS):
+            metrics = metrics_fn(loss, aux, labels)
+            metrics["step_ok"] = step_ok.astype(jnp.float32)
+            metrics["grad_norm"] = grad_norm
         return new_state, metrics
 
     return jax.jit(step, donate_argnums=0)
@@ -708,10 +729,11 @@ def _arcface_sharded_loss(cfg, model, mesh):
             variables, images, train=True,
             mutable=["batch_stats", "losses"],
             rngs={"dropout": drop_rng}, method="features")
-        loss, t1, t3 = arc_margin_ce_sharded(
-            emb, params["margin"]["weight"], labels, mesh, MODEL_AXIS,
-            batch_axis=batch_axis, s=mc.arc_s, m=mc.arc_m,
-            easy_margin=mc.arc_easy_margin)
+        with jax.named_scope("loss"):
+            loss, t1, t3 = arc_margin_ce_sharded(
+                emb, params["margin"]["weight"], labels, mesh, MODEL_AXIS,
+                batch_axis=batch_axis, s=mc.arc_s, m=mc.arc_m,
+                easy_margin=mc.arc_easy_margin)
         # sown auxiliary penalties (MoE router balance on a ViT backbone)
         # flow into this path too — same contract as the dense step
         aux = sum(jax.tree_util.tree_leaves(mutated.get("losses", {})))
