@@ -16,6 +16,7 @@ import subprocess
 
 import numpy as np
 import pytest
+from tiny import tiny_cfg
 
 import jax
 import jax.numpy as jnp
@@ -342,24 +343,12 @@ def test_trainer_nan_burst_skips_then_sustained_nan_diverges(tmp_path):
     bounded NaN burst is skipped and training continues; an open-ended
     window trips SentinelDiverged once the consecutive streak reaches
     max_bad_steps."""
-    from ddp_classification_pytorch_tpu.config import get_preset
     from ddp_classification_pytorch_tpu.train.loop import Trainer
 
-    cfg = get_preset("baseline")
-    cfg.data.dataset = "synthetic"
+    cfg = tiny_cfg("baseline", tmp_path, epochs=3)
     cfg.data.image_size = 16
-    cfg.data.num_classes = 4
-    cfg.data.synthetic_size = 128
-    cfg.data.batch_size = 32
-    cfg.data.num_workers = 1
-    cfg.model.arch = "resnet18"
-    cfg.model.variant = "cifar"
-    cfg.model.dtype = "float32"
-    cfg.run.epochs = 3
+    cfg.data.synthetic_size = 64
     cfg.run.log_every = 2
-    cfg.run.out_dir = str(tmp_path)
-    cfg.run.write_records = False
-    cfg.run.save_every_epoch = False
     # 4 steps/epoch: a burst at steps 1-2 (epoch 0), then NaN forever
     # from step 6 (mid-epoch 1 onward)
     cfg.run.fault_spec = "nan_loss@step=1..2,nan_loss@step=6.."

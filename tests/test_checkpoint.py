@@ -3,6 +3,8 @@
 import numpy as np
 
 import jax.numpy as jnp
+import pytest
+from tiny import tiny_cfg
 
 from ddp_classification_pytorch_tpu.train.checkpoint import CheckpointManager
 from ddp_classification_pytorch_tpu.train.state import TrainState
@@ -87,7 +89,7 @@ def test_async_failure_surfaces_on_next_save_and_then_clears(tmp_path):
     mgr.wait()
     shutil.rmtree(tmp_path)  # make the next write fail
     mgr.save(_state(1.0), 1)
-    mgr._pending.join()  # let the failure land without consuming it
+    mgr._pending.join(timeout=60)  # let the failure land without consuming it
     import os
 
     os.makedirs(tmp_path, exist_ok=True)
@@ -162,22 +164,9 @@ def test_auto_resume_trainer_e2e(tmp_path):
     """Preemption recovery: a second Trainer with auto_resume picks up the
     latest checkpoint in out_dir and continues from the next epoch — the
     restart command is identical to the start command (scripts/supervise.sh)."""
-    from ddp_classification_pytorch_tpu.config import get_preset
     from ddp_classification_pytorch_tpu.train.loop import Trainer
 
-    cfg = get_preset("baseline")
-    cfg.data.dataset = "synthetic"
-    cfg.data.image_size = 32
-    cfg.data.num_classes = 4
-    cfg.data.synthetic_size = 64
-    cfg.data.batch_size = 32
-    cfg.data.num_workers = 1
-    cfg.model.arch = "resnet18"
-    cfg.model.variant = "cifar"
-    cfg.model.dtype = "float32"
-    cfg.run.epochs = 2
-    cfg.run.out_dir = str(tmp_path)
-    cfg.run.write_records = False
+    cfg = tiny_cfg("baseline", tmp_path, epochs=2)
     cfg.run.auto_resume = True
 
     tr = Trainer(cfg)

@@ -7,6 +7,7 @@ differ), proving the auto-sharded path really does compute DDP semantics.
 
 import numpy as np
 import pytest
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +29,7 @@ def _tiny_cfg():
     cfg.data.image_size = 16
     cfg.data.num_classes = 4
     cfg.data.batch_size = 16
-    cfg.model.arch = "resnet18"
+    cfg.model.arch = "resnet10"
     cfg.model.variant = "cifar"
     cfg.model.dtype = "float32"
     return cfg
@@ -95,14 +96,8 @@ def test_hybrid_mesh_two_tier_layout_and_training():
         meshlib.MeshSpec(4, 2), dcn_data_parallel=2)
     assert dict(mesh.shape) == {"data": 4, "model": 2}
 
-    cfg = get_preset("baseline")
-    cfg.data.dataset = "synthetic"
-    cfg.data.image_size = 32
-    cfg.data.num_classes = 4
-    cfg.data.batch_size = 8
-    cfg.model.arch = "resnet18"
-    cfg.model.variant = "cifar"
-    cfg.model.dtype = "float32"
+    cfg = _tiny_cfg()
+    cfg.data.image_size, cfg.data.batch_size = 32, 8
     with mesh:
         model, tx, state = create_train_state(cfg, mesh, steps_per_epoch=4)
         step = make_train_step(cfg, model, tx)

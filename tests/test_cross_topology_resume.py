@@ -26,6 +26,7 @@ allclose, not bitwise.
 import jax
 import numpy as np
 import pytest
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 
 from ddp_classification_pytorch_tpu.config import get_preset
 from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
@@ -41,7 +42,7 @@ def _cfg(mp: int):
     cfg.data.image_size = SIZE
     cfg.data.num_classes = CLASSES
     cfg.data.batch_size = BATCH
-    cfg.model.arch = "resnet18"
+    cfg.model.arch = "resnet10"
     cfg.model.variant = "cifar"
     cfg.model.dtype = "float32"
     cfg.parallel.model_axis = mp

@@ -7,6 +7,7 @@ modelling code, the expert share, the flash kernels' two-part scores, the
 step's counters, and the first decoder's program, which must not have moved.
 CPU, toy sizes."""
 
+import functools
 import hashlib
 import importlib
 import json
@@ -60,6 +61,13 @@ def cli_config(arch, *extra, dtype="float32"):
     return config_from_args(build_parser().parse_args(argv + list(extra)))
 
 
+@functools.lru_cache(maxsize=None)
+def reference_grad():
+    """The plain reference's loss and gradients at ARCH, compiled ONCE for
+    the three tests that hold a program against it."""
+    return jax.jit(jax.value_and_grad(ref.loss_for(ARCH)))
+
+
 def program(arch, *extra, dtype="float32"):
     cfg = cli_config(arch, *extra, dtype=dtype)
     model = build_model(cfg.model, cfg.data.num_classes)
@@ -82,8 +90,7 @@ def test_program_matches_the_plain_reference_loss_and_every_gradient(extra):
             == {k: v.shape for k, v in flat.items()})
     (loss, (_, aux)), grads = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
         program_tree(flat), {}, tokens, targets, None)
-    want, want_grads = jax.jit(jax.value_and_grad(ref.loss_for(ARCH)))(
-        flat, tokens, targets)
+    want, want_grads = reference_grad()(flat, tokens, targets)
     # float32 against float32: what is left is the order of the sums (the
     # kernels' tiles, the sorted slots): 1e-5 of the loss, 2e-4 of a leaf's
     # largest entry; a bf16 program lies a hundred times further (below)
@@ -115,8 +122,7 @@ def test_bf16_program_lies_further_from_the_reference_and_fp8_further_still():
     control lies further again: an fp8-for-bf16 swap shows."""
     flat = common.make_params(ref.param_spec(ARCH), 5)
     tokens, targets = batch(ARCH, seed=1)
-    want, want_g = jax.jit(jax.value_and_grad(ref.loss_for(ARCH)))(
-        flat, tokens, targets)
+    want, want_g = reference_grad()(flat, tokens, targets)
     _, loss_fn, _ = program(ARCH, dtype="bfloat16")
     got, g = jax.jit(jax.value_and_grad(
         lambda p: loss_fn(p, {}, tokens, targets, None)[0]))(program_tree(flat))

@@ -84,7 +84,8 @@ def test_program_matches_the_plain_reference_loss_and_every_gradient():
     model = build_model(cfg.model, cfg.data.num_classes)
     flat = make_params(ref.param_spec(ARCH), 3)
     tokens, targets = batch(ARCH)
-    init = model.init(jax.random.PRNGKey(0), tokens[:, :8], train=False)["params"]
+    init = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), tokens[:, :8], train=False))["params"]
     assert ({k: v.shape for k, v in flat_tree(init).items()}
             == {k: v.shape for k, v in flat.items()})
     loss_fn, _ = _lm_loss(cfg, model)

@@ -13,10 +13,10 @@ import time
 
 import numpy as np
 import pytest
+from tiny import tiny_cfg
 
 import jax
 
-from ddp_classification_pytorch_tpu.config import get_preset
 from ddp_classification_pytorch_tpu.data.device_prefetch import DevicePrefetcher
 from ddp_classification_pytorch_tpu.data.loader import ShardedLoader
 from ddp_classification_pytorch_tpu.data.synthetic import SyntheticDataset
@@ -300,20 +300,10 @@ def test_overlap_early_break_joins_threads_mid_transfer():
 # ---------------------------------------------------------------- trainer --
 
 def _tiny_cfg(prefetch_depth):
-    cfg = get_preset("baseline")
-    cfg.data.dataset = "synthetic"
-    cfg.data.image_size = 32
-    cfg.data.num_classes = 4
-    cfg.data.synthetic_size = 128
-    cfg.data.batch_size = 32
+    cfg = tiny_cfg("baseline")
+    cfg.data.synthetic_size = 64  # four steps
     cfg.data.num_workers = 2
     cfg.data.device_prefetch = prefetch_depth
-    cfg.model.arch = "resnet18"
-    cfg.model.variant = "cifar"
-    cfg.model.dtype = "float32"
-    cfg.run.epochs = 1
-    cfg.run.write_records = False
-    cfg.run.save_every_epoch = False
     return cfg
 
 

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 
 from ddp_classification_pytorch_tpu.ops.attention import attention
 from ddp_classification_pytorch_tpu.ops.flash_attention import flash_attention
@@ -145,13 +146,14 @@ def test_flash_under_jit_and_vmap_free_shapes():
 
 
 def test_vit_with_flash_matches_dense_vit():
-    """Same params: ViT(use_flash=True) == ViT(use_flash=False)."""
+    """Same params: ViT(use_flash=True) == ViT(use_flash=False), in four
+    blocks: each calls the kernel as the next does."""
     from ddp_classification_pytorch_tpu.models.vit import build_vit
 
     x = jnp.asarray(
         np.random.default_rng(0).normal(size=(2, 64, 64, 3)), jnp.float32)
-    dense = build_vit("vit_t16", num_classes=5, dtype=jnp.float32)
-    flash = build_vit("vit_t16", num_classes=5, dtype=jnp.float32,
+    dense = build_vit("vit_t16_d4", num_classes=5, dtype=jnp.float32)
+    flash = build_vit("vit_t16_d4", num_classes=5, dtype=jnp.float32,
                       use_flash=True)
     vs = dense.init(jax.random.PRNGKey(0), x, train=False)
     np.testing.assert_allclose(

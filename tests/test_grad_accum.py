@@ -19,15 +19,17 @@ wire). What THIS file proves:
   mapping (in-process, same pattern as test_recovery_rc_discipline).
 """
 
+import functools
 import json
 import os
 
 import jax
 import numpy as np
 import pytest
+from test_zero_opt import _assert_trees_close, _dp2_mesh, _run_steps as zero_opt_steps
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 
 from ddp_classification_pytorch_tpu.config import get_preset
-from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BASELINES = os.path.join(REPO, "ddp_classification_pytorch_tpu",
@@ -43,44 +45,14 @@ def _tiny_vit_cfg(grad_accum=1):
     cfg.data.image_size = 32
     cfg.data.num_classes = 4
     cfg.data.batch_size = 32
-    cfg.model.arch = "vit_t16"
+    cfg.model.arch = "vit_t16_d4"
     cfg.model.dtype = "float32"
     cfg.model.dropout = 0.0
     cfg.parallel.grad_accum = grad_accum
     return cfg
 
 
-def _dp2_mesh():
-    return meshlib.make_mesh(meshlib.MeshSpec(2, 1),
-                             devices=jax.devices()[:2])
-
-
-def _run_steps(cfg, mesh, steps=3):
-    from ddp_classification_pytorch_tpu.train.state import create_train_state
-    from ddp_classification_pytorch_tpu.train.steps import make_train_step
-
-    rng = np.random.default_rng(7)
-    images = rng.normal(size=(32, 32, 32, 3)).astype(np.float32)
-    labels = rng.integers(0, 4, 32).astype(np.int32)
-    with mesh:
-        model, tx, state = create_train_state(cfg, mesh, steps_per_epoch=4)
-        step = make_train_step(cfg, model, tx, mesh=mesh)
-        batch = meshlib.make_global_array((images, labels), mesh)
-        losses = []
-        for _ in range(steps):
-            state, metrics = step(state, *batch)
-            losses.append(float(metrics["loss"]))
-    return losses, jax.device_get(state)
-
-
-def _assert_trees_close(a, b, rtol, atol):
-    la, ta = jax.tree_util.tree_flatten_with_path(a)
-    lb, _ = jax.tree_util.tree_flatten_with_path(b)
-    assert len(la) == len(lb)
-    for (path, x), (_, y) in zip(la, lb):
-        np.testing.assert_allclose(
-            np.asarray(x), np.asarray(y), rtol=rtol, atol=atol,
-            err_msg=jax.tree_util.keystr(path))
+_run_steps = functools.partial(zero_opt_steps, rows=32, seed=7)
 
 
 def test_accum4_matches_single_batch_state_for_state():

@@ -11,9 +11,9 @@ import time
 
 import numpy as np
 import pytest
+from tiny import tiny_cfg
 from PIL import Image
 
-from ddp_classification_pytorch_tpu.config import get_preset
 from ddp_classification_pytorch_tpu.obs import spans
 from ddp_classification_pytorch_tpu.train.loop import Trainer
 
@@ -47,23 +47,20 @@ def _load_paths(mark_ns):
             if s.name == "input.load" and s.start_ns >= mark_ns}
 
 
-def test_imagefolder_native_train(image_tree, tmp_path):
-    cfg = get_preset("baseline")
+def _folder_cfg(image_tree, out_dir):
+    cfg = tiny_cfg("baseline", out_dir)
     cfg.data.dataset = "imagefolder"
     cfg.data.train_dir = str(image_tree / "train")
     cfg.data.val_dir = str(image_tree / "val")
     cfg.data.num_classes = 3
     cfg.data.batch_size = 8
-    cfg.data.image_size = 32
     cfg.data.train_crop_size = 40
     cfg.data.num_workers = 2
-    cfg.model.arch = "resnet18"
-    cfg.model.variant = "cifar"
-    cfg.model.dtype = "float32"
-    cfg.run.epochs = 1
-    cfg.run.out_dir = str(tmp_path)
-    cfg.run.write_records = False
-    cfg.run.save_every_epoch = False
+    return cfg
+
+
+def test_imagefolder_native_train(image_tree, tmp_path):
+    cfg = _folder_cfg(image_tree, tmp_path)
 
     tr = Trainer(cfg)
     assert tr.train_loader.batcher is not None, "native dataplane not engaged"
@@ -87,23 +84,8 @@ def test_imagefolder_native_train(image_tree, tmp_path):
 
 
 def test_imagefolder_python_fallback(image_tree, tmp_path):
-    cfg = get_preset("baseline")
-    cfg.data.dataset = "imagefolder"
-    cfg.data.train_dir = str(image_tree / "train")
-    cfg.data.val_dir = str(image_tree / "val")
+    cfg = _folder_cfg(image_tree, tmp_path)
     cfg.data.native_loader = False
-    cfg.data.num_classes = 3
-    cfg.data.batch_size = 8
-    cfg.data.image_size = 32
-    cfg.data.train_crop_size = 40
-    cfg.data.num_workers = 2
-    cfg.model.arch = "resnet18"
-    cfg.model.variant = "cifar"
-    cfg.model.dtype = "float32"
-    cfg.run.epochs = 1
-    cfg.run.out_dir = str(tmp_path)
-    cfg.run.write_records = False
-    cfg.run.save_every_epoch = False
 
     tr = Trainer(cfg)
     assert tr.train_loader.batcher is None

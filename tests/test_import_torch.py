@@ -11,6 +11,7 @@
 
 import numpy as np
 import pytest
+from tiny import zero_variables
 
 import jax
 import jax.numpy as jnp
@@ -79,39 +80,45 @@ def _torch_key_for(flax_path, leaf):
     raise AssertionError(flax_path)
 
 
-@pytest.mark.parametrize("arch", ["resnet18", "resnet50"])
-def test_state_dict_roundtrip_covers_every_leaf(arch):
-    model = getattr(R, arch)(num_classes=7, dtype=jnp.float32)
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 64, 64, 3)), train=False)
-
-    rng = np.random.default_rng(2)
-    state_dict = {}
-    expected = {}
+def _roundtrip(model, image_size, seed, key_for, to_torch, skipped, converter,
+               init_rngs=None):
+    """Every leaf of `model`, drawn anew, written under its torch name and
+    layout (`to_torch(names, arr)` for the layouts that are not the common
+    ones), converted back and merged: each must come back where it was.
+    `skipped` are entries of a real state_dict the converter has to drop."""
+    variables = zero_variables(model, init_rngs or jax.random.PRNGKey(0),
+                               jnp.zeros((1, image_size, image_size, 3)),
+                               train=False)
+    rng = np.random.default_rng(seed)
+    state_dict, expected = dict(skipped), {}
     for coll in ("params", "batch_stats"):
-        flat = jax.tree_util.tree_flatten_with_path(variables[coll])[0]
-        for path, value in flat:
+        for path, value in jax.tree_util.tree_flatten_with_path(variables[coll])[0]:
             names = tuple(p.key for p in path)
-            key = _torch_key_for(names[:-1], names[-1])
             arr = rng.normal(size=value.shape).astype(np.float32)
             expected[(coll,) + names] = arr
-            if names[-1] == "kernel" and arr.ndim == 4:
-                state_dict[key] = arr.transpose(3, 2, 0, 1)  # HWIO → OIHW
+            special = to_torch(names, arr)
+            if special is not None:
+                arr = special
+            elif names[-1] == "kernel" and arr.ndim == 4:
+                arr = arr.transpose(3, 2, 0, 1)  # HWIO → OIHW
             elif names[-1] == "kernel":
-                state_dict[key] = arr.T
-            else:
-                state_dict[key] = arr
-    state_dict["bn1.num_batches_tracked"] = np.int64(5)  # must be skipped
-    state_dict["mean_vector"] = np.zeros(3)  # vestigial buffer, skipped
-
-    converted = convert_resnet_state_dict(state_dict)
-    merged = merge_into_variables(variables, converted)
+                arr = arr.T
+            state_dict[key_for(names[:-1], names[-1])] = arr
+    merged = merge_into_variables(variables, converter(state_dict))
     for coll in ("params", "batch_stats"):
-        flat = jax.tree_util.tree_flatten_with_path(merged[coll])[0]
-        for path, value in flat:
+        for path, value in jax.tree_util.tree_flatten_with_path(merged[coll])[0]:
             names = (coll,) + tuple(p.key for p in path)
-            np.testing.assert_array_equal(
-                np.asarray(value), expected[names], err_msg=str(names))
+            np.testing.assert_array_equal(np.asarray(value), expected[names],
+                                          err_msg=str(names))
+
+
+@pytest.mark.parametrize("arch", ["resnet18", "resnet50"])
+def test_state_dict_roundtrip_covers_every_leaf(arch):
+    _roundtrip(getattr(R, arch)(num_classes=7, dtype=jnp.float32), 64, 2,
+               _torch_key_for, lambda names, arr: None,
+               {"bn1.num_batches_tracked": np.int64(5),  # must be skipped
+                "mean_vector": np.zeros(3)},             # vestigial buffer
+               convert_resnet_state_dict)
 
 
 def test_pretrained_path_loads_into_train_state(tmp_path):
@@ -122,7 +129,7 @@ def test_pretrained_path_loads_into_train_state(tmp_path):
     from ddp_classification_pytorch_tpu.train.state import create_train_state
 
     model = R.resnet18(num_classes=1000, dtype=jnp.float32)
-    variables = model.init(jax.random.PRNGKey(0),
+    variables = zero_variables(model, jax.random.PRNGKey(0),
                            jnp.zeros((1, 64, 64, 3)), train=False)
     rng = np.random.default_rng(3)
     state_dict = {}
@@ -195,7 +202,7 @@ def test_pretrained_without_path_raises():
 
 def test_merge_rejects_shape_mismatch():
     model = R.resnet18(num_classes=7, dtype=jnp.float32)
-    variables = model.init(jax.random.PRNGKey(0),
+    variables = zero_variables(model, jax.random.PRNGKey(0),
                            jnp.zeros((1, 32, 32, 3)), train=False)
     bad = {"params": {"conv_stem": {"kernel": np.zeros((3, 3, 3, 63))}}}
     with pytest.raises(ValueError, match="shape mismatch"):
@@ -235,43 +242,19 @@ def test_vgg_state_dict_roundtrip_covers_every_leaf():
     )
     from ddp_classification_pytorch_tpu.models.vgg import vgg19_bn
 
-    model = vgg19_bn(num_classes=13, dtype=jnp.float32)
-    variables = model.init(
-        {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(1)},
-        jnp.zeros((1, 64, 64, 3)), train=False)
+    def fc1(names, arr):
+        if names[-2:] == ("fc1", "kernel"):
+            o = arr.shape[1]
+            # flax (HWC-flat, O) → torch (O, CHW-flat)
+            return (arr.T.reshape(o, 7, 7, 512).transpose(0, 3, 1, 2)
+                    .reshape(o, -1))
 
-    rng = np.random.default_rng(4)
-    state_dict = {}
-    expected = {}
-    for coll in ("params", "batch_stats"):
-        flat = jax.tree_util.tree_flatten_with_path(variables[coll])[0]
-        for path, value in flat:
-            names = tuple(p.key for p in path)
-            key = _vgg_torch_key(names[:-1], names[-1])
-            arr = rng.normal(size=value.shape).astype(np.float32)
-            expected[(coll,) + names] = arr
-            if names[-1] == "kernel" and arr.ndim == 4:
-                state_dict[key] = arr.transpose(3, 2, 0, 1)  # HWIO → OIHW
-            elif names[-1] == "kernel" and names[-2] == "fc1":
-                o = arr.shape[1]
-                # flax (HWC-flat, O) → torch (O, CHW-flat)
-                state_dict[key] = (arr.T.reshape(o, 7, 7, 512)
-                                   .transpose(0, 3, 1, 2).reshape(o, -1))
-            elif names[-1] == "kernel":
-                state_dict[key] = arr.T
-            else:
-                state_dict[key] = arr
-    state_dict["features.1.num_batches_tracked"] = np.int64(7)  # skipped
-
-    converted = convert_vgg_state_dict(state_dict)
-    merged = merge_into_variables(variables, converted)
-    for coll in ("params", "batch_stats"):
-        flat = jax.tree_util.tree_flatten_with_path(merged[coll])[0]
-        for path, value in flat:
-            names = (coll,) + tuple(p.key for p in path)
-            np.testing.assert_allclose(
-                np.asarray(value), expected[names], atol=1e-6,
-                err_msg=str(names))
+    _roundtrip(vgg19_bn(num_classes=13, dtype=jnp.float32), 64, 4,
+               _vgg_torch_key, fc1,
+               {"features.1.num_batches_tracked": np.int64(7)},
+               convert_vgg_state_dict,
+               init_rngs={"params": jax.random.PRNGKey(0),
+                          "dropout": jax.random.PRNGKey(1)})
 
 
 def test_vgg_fc1_flatten_order_matches_torch():
@@ -339,40 +322,13 @@ def test_tresnet_state_dict_roundtrip_covers_every_leaf():
     )
     from ddp_classification_pytorch_tpu.models.tresnet import tresnet_m
 
-    model = tresnet_m(num_classes=11, dtype=jnp.float32)
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 64, 64, 3)), train=False)
+    def se(names, arr):
+        if names[-1] == "kernel" and len(names) >= 3 and names[-3] == "se":
+            return arr.T[:, :, None, None]  # Dense (I, O) → timm 1×1-conv (O, I, 1, 1)
 
-    rng = np.random.default_rng(6)
-    state_dict = {}
-    expected = {}
-    for coll in ("params", "batch_stats"):
-        flat = jax.tree_util.tree_flatten_with_path(variables[coll])[0]
-        for path, value in flat:
-            names = tuple(p.key for p in path)
-            key = _tresnet_torch_key(names[:-1], names[-1])
-            arr = rng.normal(size=value.shape).astype(np.float32)
-            expected[(coll,) + names] = arr
-            if names[-1] == "kernel" and arr.ndim == 4:
-                state_dict[key] = arr.transpose(3, 2, 0, 1)  # HWIO → OIHW
-            elif (names[-1] == "kernel" and len(names) >= 3
-                    and names[-3] == "se"):
-                # Dense (I, O) → timm 1×1-conv (O, I, 1, 1)
-                state_dict[key] = arr.T[:, :, None, None]
-            elif names[-1] == "kernel":
-                state_dict[key] = arr.T
-            else:
-                state_dict[key] = arr
-    # fixed blur buffers + BN counters must be skipped
-    state_dict["body.layer2.0.conv1.1.filt"] = np.zeros((128, 1, 3, 3))
-    state_dict["body.conv1.1.num_batches_tracked"] = np.int64(3)
-
-    converted = convert_tresnet_state_dict(state_dict)
-    merged = merge_into_variables(variables, converted)
-    for coll in ("params", "batch_stats"):
-        flat = jax.tree_util.tree_flatten_with_path(merged[coll])[0]
-        for path, value in flat:
-            names = (coll,) + tuple(p.key for p in path)
-            np.testing.assert_allclose(
-                np.asarray(value), expected[names], atol=1e-6,
-                err_msg=str(names))
+    _roundtrip(tresnet_m(num_classes=11, dtype=jnp.float32), 64, 6,
+               _tresnet_torch_key, se,
+               # fixed blur buffers + BN counters must be skipped
+               {"body.layer2.0.conv1.1.filt": np.zeros((128, 1, 3, 3)),
+                "body.conv1.1.num_batches_tracked": np.int64(3)},
+               convert_tresnet_state_dict)

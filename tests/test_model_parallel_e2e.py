@@ -2,29 +2,18 @@
 (cfg.parallel.model_axis=2 on the 8-device mesh → data=4 × model=2)."""
 
 import numpy as np
+from tiny import tiny_cfg
 
 
-from ddp_classification_pytorch_tpu.config import get_preset
 from ddp_classification_pytorch_tpu.parallel.mesh import MODEL_AXIS
 from ddp_classification_pytorch_tpu.train.loop import Trainer
 
 
 def test_arcface_model_parallel_trainer(tmp_path):
-    cfg = get_preset("arcface")
-    cfg.data.dataset = "synthetic"
+    cfg = tiny_cfg("arcface", tmp_path)
     cfg.data.image_size = 16
     cfg.data.num_classes = 8  # divisible by model axis
-    cfg.data.synthetic_size = 64
-    cfg.data.batch_size = 16
-    cfg.data.num_workers = 1
-    cfg.model.arch = "resnet18"
-    cfg.model.variant = "cifar"
-    cfg.model.dtype = "float32"
     cfg.parallel.model_axis = 2
-    cfg.run.epochs = 1
-    cfg.run.write_records = False
-    cfg.run.save_every_epoch = False
-    cfg.run.out_dir = str(tmp_path)
 
     tr = Trainer(cfg)
     assert dict(zip(tr.mesh.axis_names, tr.mesh.devices.shape)) == {

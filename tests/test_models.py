@@ -14,10 +14,14 @@ from ddp_classification_pytorch_tpu.models.factory import (
 )
 
 
-def _init_and_apply(model, x, **apply_kw):
-    variables = model.init(jax.random.key(0), x, train=False)
-    out = model.apply(variables, x, train=False, **apply_kw)
-    return variables, out
+def _init_and_apply(model, *inputs, apply_inputs=None):
+    """(variables, output) as shapes and dtypes, which is all these tests
+    assert: traced with eval_shape, nothing is drawn and no forward runs."""
+    def both():
+        variables = model.init(jax.random.key(0), *inputs, train=False)
+        return variables, model.apply(variables, *(apply_inputs or inputs),
+                                      train=False)
+    return jax.eval_shape(both)
 
 
 @pytest.mark.parametrize("factory,feat", [(resnet18, 512), (resnet50, 2048)])
@@ -49,8 +53,9 @@ def test_resnet_classifier_logits():
 def test_batch_stats_update_in_train_mode():
     x = jax.random.normal(jax.random.key(1), (4, 32, 32, 3))
     model = resnet18(num_classes=0, variant="cifar", dtype=jnp.float32)
-    variables = model.init(jax.random.key(0), x, train=False)
-    out, mutated = model.apply(variables, x, train=True, mutable=["batch_stats"])
+    variables = jax.jit(lambda: model.init(jax.random.key(0), x, train=False))()
+    out, mutated = jax.jit(lambda v: model.apply(
+        v, x, train=True, mutable=["batch_stats"]))(variables)
     before = variables["batch_stats"]["bn_stem"]["mean"]
     after = mutated["batch_stats"]["bn_stem"]["mean"]
     assert not jnp.allclose(before, after)
@@ -63,8 +68,9 @@ def test_freeze_bn_no_stat_update():
 
     x = jax.random.normal(jax.random.key(1), (4, 32, 32, 3))
     model = r18(num_classes=0, variant="cifar", dtype=jnp.float32, freeze_bn=True)
-    variables = model.init(jax.random.key(0), x, train=False)
-    _, mutated = model.apply(variables, x, train=True, mutable=["batch_stats"])
+    variables = jax.jit(lambda: model.init(jax.random.key(0), x, train=False))()
+    _, mutated = jax.jit(lambda v: model.apply(
+        v, x, train=True, mutable=["batch_stats"]))(variables)
     before = variables["batch_stats"]["bn_stem"]["mean"]
     after = mutated.get("batch_stats", {}).get("bn_stem", {}).get("mean", before)
     assert jnp.allclose(before, after)
@@ -73,8 +79,7 @@ def test_freeze_bn_no_stat_update():
 def test_vgg19_bn_feature_and_logits():
     x = jnp.zeros((2, 32, 32, 3))
     model = vgg19_bn(num_classes=0, dtype=jnp.float32)
-    variables = model.init(jax.random.key(0), x, train=False)
-    out = model.apply(variables, x, train=False)
+    _, out = _init_and_apply(model, x)
     assert out.shape == (2, 4096)
 
 
@@ -83,8 +88,7 @@ def test_build_model_fc_head():
     model = build_model(cfg, num_classes=11)
     assert isinstance(model, ClassifierModel)
     x = jnp.zeros((2, 64, 64, 3))
-    variables = model.init(jax.random.key(0), x, train=False)
-    out = model.apply(variables, x, train=False)
+    _, out = _init_and_apply(model, x)
     assert out.shape == (2, 11)
 
 
@@ -94,10 +98,9 @@ def test_build_model_arcface_head():
     assert isinstance(model, ArcFaceModel)
     x = jnp.zeros((2, 64, 64, 3))
     labels = jnp.zeros((2,), jnp.int32)
-    variables = model.init(jax.random.key(0), x, labels, train=False)
-    out = model.apply(variables, x, labels, train=False)
+    _, out = _init_and_apply(model, x, labels)
     assert out.shape == (2, 11)
-    scores = model.apply(variables, x, None, train=False)
+    _, scores = _init_and_apply(model, x, labels, apply_inputs=(x, None))
     assert scores.shape == (2, 11)
 
 
@@ -106,7 +109,6 @@ def test_build_model_nested_head():
     model = build_model(cfg, num_classes=11)
     assert isinstance(model, NestedModel)
     x = jnp.zeros((2, 32, 32, 3))
-    variables = model.init(jax.random.key(0), x, train=False)
     mask = jnp.ones((1, FEAT_DIMS["resnet18"]))
-    out = model.apply(variables, x, mask, train=False)
+    _, out = _init_and_apply(model, x, apply_inputs=(x, mask))
     assert out.shape == (2, 11)

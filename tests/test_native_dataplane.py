@@ -362,7 +362,7 @@ def test_library_is_keyed_by_source_hash_not_mtime(tmp_path, monkeypatch):
         'extern "C" int dp_load_batch() { return -1; }\n')  # no dp_has_png
     stale_lib = tmp_path / "libdataplane.so"
     subprocess.run(["g++", "-shared", "-fPIC", "-o", str(stale_lib),
-                    str(stale_src)], check=True)
+                    str(stale_src)], check=True, timeout=120)
     future = time.time() + 3600
     os.utime(stale_lib, (future, future))
 

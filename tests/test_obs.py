@@ -34,7 +34,7 @@ def test_counter_concurrent_increments():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=60)
     assert c.value == 8000
 
 
@@ -156,7 +156,7 @@ def test_write_prom_atomic_under_concurrent_reads(tmp_path):
             assert len(lines) == 1 and float(lines[0].split()[1]) >= 0
     finally:
         stop.set()
-        t.join()
+        t.join(timeout=60)
     assert not [p for p in os.listdir(tmp_path) if p.endswith(".tmp")]
 
 

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 
 from ddp_classification_pytorch_tpu.ops.pipeline import gpipe, _stage_apply
 from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
@@ -86,7 +87,7 @@ def _pp_cfg(mp=2, micro=2):
     from ddp_classification_pytorch_tpu.config import get_preset
 
     cfg = get_preset("baseline")
-    cfg.model.arch = "vit_t16"
+    cfg.model.arch = "vit_t16_d4"
     cfg.model.dtype = "float32"
     cfg.data.image_size = 64  # 16 tokens
     cfg.data.num_classes = 4
@@ -107,8 +108,8 @@ def test_gpipe_vit_forward_matches_single_stage():
     mesh_seq = meshlib.make_mesh(meshlib.MeshSpec(len(jax.devices()), 1))
     x = jnp.asarray(
         np.random.default_rng(0).normal(size=(8, 64, 64, 3)), jnp.float32)
-    pp = GPipeViT("vit_t16", 4, mesh_pp, 2, dtype=jnp.float32)
-    seq = GPipeViT("vit_t16", 4, mesh_seq, 2, dtype=jnp.float32)
+    pp = GPipeViT("vit_t16_d4", 4, mesh_pp, 2, dtype=jnp.float32)
+    seq = GPipeViT("vit_t16_d4", 4, mesh_seq, 2, dtype=jnp.float32)
     vs = pp.init(jax.random.PRNGKey(0), x)
     out_pp = jax.jit(lambda v, x: pp.apply(v, x, train=False))(vs, x)
     out_seq = seq.apply(vs, x, train=False)
@@ -157,7 +158,7 @@ def test_pipeline_flag_rejects_unsupported_configs():
     cfg.arch = "resnet50"
     with _pytest.raises(ValueError, match="requires a ViT"):
         build_model(cfg, 4, mesh=mesh, pipeline_microbatches=2)
-    cfg.arch = "vit_t16"
+    cfg.arch = "vit_t16_d4"
     cfg.head = "nested"
     with _pytest.raises(ValueError, match="head='fc' or 'arcface'"):
         build_model(cfg, 4, mesh=mesh, pipeline_microbatches=2)

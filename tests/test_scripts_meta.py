@@ -29,7 +29,7 @@ def _shell_scripts():
 def test_shell_scripts_parse():
     assert _shell_scripts(), "scripts/*.sh disappeared"
     for path in _shell_scripts():
-        p = subprocess.run(["bash", "-n", path], capture_output=True)
+        p = subprocess.run(["bash", "-n", path], capture_output=True, timeout=30)
         assert p.returncode == 0, (path, p.stderr.decode())
 
 

@@ -87,7 +87,7 @@ def test_concurrent_requests_bit_identical_to_direct_predict(sv):
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(timeout=60)
         preds = [f.result(timeout=30) for f in futures]
     finally:
         engine.drain()
@@ -270,7 +270,7 @@ def test_swap_racing_drain_never_mixes_params_in_a_batch(sv):
         engine.drain()
     finally:
         stop.set()
-        t.join()
+        t.join(timeout=60)
     preds = [f.result(timeout=30) for f in futures]
     assert preds, "no request was ever accepted"
     for p in preds:

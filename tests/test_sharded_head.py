@@ -10,7 +10,9 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 
+from ddp_classification_pytorch_tpu.config import get_preset
 from ddp_classification_pytorch_tpu.ops.arcface import arc_margin_logits
 from ddp_classification_pytorch_tpu.ops.sharded_head import arc_margin_ce_sharded
 from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
@@ -74,11 +76,22 @@ def test_sharded_ce_rejects_indivisible_classes():
         arc_margin_ce_sharded(feats, weight, labels, mesh, meshlib.MODEL_AXIS)
 
 
+def _arcface_cfg(sharded_ce: bool):
+    cfg = get_preset("arcface")
+    cfg.data.image_size = 32
+    cfg.data.num_classes = 16
+    cfg.data.batch_size = 8
+    cfg.model.arch = "resnet10"
+    cfg.model.variant = "cifar"
+    cfg.model.dtype = "float32"
+    cfg.parallel.arcface_sharded_ce = sharded_ce
+    return cfg
+
+
 def test_arcface_sharded_step_matches_dense_step():
     """Full train-step equivalence: the partial-FC step (flag on) and the
     dense step produce the same loss/metrics from identical initial state
     on a data×model mesh."""
-    from ddp_classification_pytorch_tpu.config import get_preset
     from ddp_classification_pytorch_tpu.train.state import create_train_state
     from ddp_classification_pytorch_tpu.train.steps import make_train_step
 
@@ -89,14 +102,7 @@ def test_arcface_sharded_step_matches_dense_step():
 
     results = {}
     for name, flag in (("dense", False), ("sharded", True)):
-        cfg = get_preset("arcface")
-        cfg.data.image_size = 32
-        cfg.data.num_classes = 16
-        cfg.data.batch_size = 8
-        cfg.model.arch = "resnet18"
-        cfg.model.variant = "cifar"
-        cfg.model.dtype = "float32"
-        cfg.parallel.arcface_sharded_ce = flag
+        cfg = _arcface_cfg(flag)
         with mesh:
             model, tx, state = create_train_state(cfg, mesh, steps_per_epoch=4)
             step = make_train_step(cfg, model, tx, mesh=mesh)
@@ -113,14 +119,13 @@ def test_arcface_sharded_step_matches_dense_step():
 def test_sharded_ce_flag_without_model_axis_raises():
     """--sharded_ce with no model axis must fail loudly, not silently run
     the dense (B, C) path it exists to avoid."""
-    from ddp_classification_pytorch_tpu.config import get_preset
     from ddp_classification_pytorch_tpu.train.state import create_train_state
     from ddp_classification_pytorch_tpu.train.steps import make_train_step
 
     cfg = get_preset("arcface")
     cfg.data.image_size = 32
     cfg.data.num_classes = 16
-    cfg.model.arch = "resnet18"
+    cfg.model.arch = "resnet10"
     cfg.model.variant = "cifar"
     cfg.parallel.arcface_sharded_ce = True
     mesh = meshlib.make_mesh(meshlib.MeshSpec(len(jax.devices()), 1))
@@ -136,7 +141,6 @@ def test_arcface_sharded_eval_matches_dense_eval():
     """Partial-FC eval (m=0 → s·cosθ scores, valid-masked) must produce the
     same loss_sum/top-k counts as the dense eval step, including a
     wrap-padded final batch."""
-    from ddp_classification_pytorch_tpu.config import get_preset
     from ddp_classification_pytorch_tpu.train.state import create_train_state
     from ddp_classification_pytorch_tpu.train.steps import make_eval_step
 
@@ -148,14 +152,7 @@ def test_arcface_sharded_eval_matches_dense_eval():
 
     results = {}
     for name, flag in (("dense", False), ("sharded", True)):
-        cfg = get_preset("arcface")
-        cfg.data.image_size = 32
-        cfg.data.num_classes = 16
-        cfg.data.batch_size = 8
-        cfg.model.arch = "resnet18"
-        cfg.model.variant = "cifar"
-        cfg.model.dtype = "float32"
-        cfg.parallel.arcface_sharded_ce = flag
+        cfg = _arcface_cfg(flag)
         with mesh:
             model, tx, state = create_train_state(cfg, mesh, steps_per_epoch=4)
             ev = make_eval_step(cfg, model, mesh=mesh)

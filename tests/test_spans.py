@@ -12,8 +12,7 @@ import time
 from collections import Counter, defaultdict
 
 import pytest
-
-from test_train_loop import tiny_cfg as train_loop_cfg
+from tiny import tiny_cfg as base_cfg
 
 from ddp_classification_pytorch_tpu.obs import spans
 from ddp_classification_pytorch_tpu.train.loop import Trainer
@@ -195,10 +194,9 @@ def test_module_imports_only_the_standard_library():
 
 # ---------------------------------------------- the trainer's own spans --
 def tiny_cfg(out_dir):
-    cfg = train_loop_cfg("baseline", epochs=1)
+    cfg = base_cfg("baseline", out_dir)
     cfg.data.image_size = 16
-    cfg.data.synthetic_size = 192  # six steps of 32
-    cfg.run.out_dir = str(out_dir)
+    cfg.data.synthetic_size = 96  # six steps of 16: two log syncs at log_every 4
     return cfg
 
 
@@ -280,7 +278,7 @@ def test_every_step_has_one_span_of_each_stage_with_the_same_step(
         assert asm.thread == (stager or main)
         assert len({load.thread, asm.thread, wait.thread}) == (3 if stager else 2)
         assert load.ids["epoch"] == wait.ids["epoch"] == disp.ids["epoch"] == 0
-        assert asm.ids["rows"] == 32 and asm.ids["bytes"] == 32 * 16 * 16 * 3 + 32 * 4
+        assert asm.ids["rows"] == 16 and asm.ids["bytes"] == 16 * 16 * 16 * 3 + 16 * 4
         assert wait.ids["starved"] in (0, 1)
     epoch = [s for s in got if s.name == "train.epoch"]
     assert len(epoch) == 1 and epoch[0].ids == {"epoch": 0} and epoch[0].parent is None
@@ -297,7 +295,8 @@ def test_every_step_has_one_span_of_each_stage_with_the_same_step(
     if depth == 0:
         assert starved == steps  # no staged queue: the loop waits for every batch
     else:
-        assert by["train.input_wait"][0].ids["starved"] == 1  # nothing staged at the start
+        # the stager threads may stage batch 0 before the loop's first look
+        assert by["train.input_wait"][0].ids["starved"] in (0, 1)
 
 
 def test_the_val_loaders_spans_say_so(trainer):

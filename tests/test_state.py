@@ -17,6 +17,7 @@ Three things are held here:
 import jax
 import numpy as np
 import pytest
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 
 from ddp_classification_pytorch_tpu.cli.train import build_parser, config_from_args
 from ddp_classification_pytorch_tpu.config import get_preset
@@ -26,7 +27,7 @@ from ddp_classification_pytorch_tpu.train.state import create_train_state
 from ddp_classification_pytorch_tpu.utils import cache as progcache
 
 
-def _image_cfg(workload="baseline", arch="resnet18", mp=1, zero_opt="auto"):
+def _image_cfg(workload="baseline", arch="resnet10", mp=1, zero_opt="auto"):
     cfg = get_preset(workload)
     cfg.data.image_size = 32
     cfg.data.num_classes = 64
@@ -54,7 +55,7 @@ CASES = {
     "resnet": lambda: _image_cfg(),
     "arcface": lambda: _image_cfg("arcface"),
     "nested": lambda: _image_cfg("nested"),
-    "vit": lambda: _image_cfg(arch="vit_t16"),
+    "vit": lambda: _image_cfg(arch="vit_t16_d4"),
     "decoder_lm": _decoder_cfg,
 }
 

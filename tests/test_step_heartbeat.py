@@ -13,6 +13,7 @@ in a subprocess.
 import os
 import subprocess
 import sys
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

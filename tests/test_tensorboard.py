@@ -5,6 +5,7 @@ real third-party parser."""
 import struct
 
 import pytest
+from tiny import tiny_cfg
 
 from ddp_classification_pytorch_tpu.utils.tensorboard import (
     SummaryWriter,
@@ -80,23 +81,9 @@ def test_third_party_reader_cross_validation(tmp_path):
 
 
 def test_trainer_writes_tb_events(tmp_path):
-    from ddp_classification_pytorch_tpu.config import get_preset
     from ddp_classification_pytorch_tpu.train.loop import Trainer
 
-    cfg = get_preset("baseline")
-    cfg.data.dataset = "synthetic"
-    cfg.data.image_size = 32
-    cfg.data.num_classes = 4
-    cfg.data.synthetic_size = 32
-    cfg.data.batch_size = 32
-    cfg.data.num_workers = 1
-    cfg.model.arch = "resnet18"
-    cfg.model.variant = "cifar"
-    cfg.model.dtype = "float32"
-    cfg.run.epochs = 1
-    cfg.run.out_dir = str(tmp_path)
-    cfg.run.write_records = False
-    cfg.run.save_every_epoch = False
+    cfg = tiny_cfg("baseline", tmp_path)
     cfg.run.tensorboard = True
     Trainer(cfg).run()
     tb_files = list((tmp_path / "tb").iterdir())

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 
 from ddp_classification_pytorch_tpu.config import get_preset
 from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
@@ -33,7 +34,7 @@ def _cfg(mp: int, pp: int):
     cfg.data.image_size = SIZE
     cfg.data.num_classes = CLASSES
     cfg.data.batch_size = BATCH
-    cfg.model.arch = "vit_t16"
+    cfg.model.arch = "vit_t16_d4"
     cfg.model.dtype = "float32"
     cfg.model.dropout = 0.0
     cfg.parallel.model_axis = mp
@@ -98,7 +99,7 @@ def test_gpipe_arcface_inference_scores_match_dense_head():
 
     mesh = meshlib.make_mesh(meshlib.MeshSpec(4, 1, 2), jax.devices()[:8])
     with mesh:
-        model = GPipeArcFaceViT("vit_t16", 11, mesh, microbatches=2,
+        model = GPipeArcFaceViT("vit_t16_d4", 11, mesh, microbatches=2,
                                 dtype=jnp.float32, axis_name="pipe")
         v = model.init(jax.random.PRNGKey(3), jnp.zeros((1, SIZE, SIZE, 3)))
         x = jnp.asarray(np.random.default_rng(5).normal(

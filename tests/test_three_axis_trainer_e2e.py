@@ -12,32 +12,24 @@ actually train.
 import jax
 import numpy as np
 import pytest
+from tiny import tiny_cfg
 
-from ddp_classification_pytorch_tpu.config import get_preset
 from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
 from ddp_classification_pytorch_tpu.train.loop import Trainer
 
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 devices")
 def test_trainer_runs_and_resumes_on_three_axis_mesh(tmp_path):
-    cfg = get_preset("arcface")
-    cfg.data.dataset = "synthetic"
-    cfg.data.synthetic_size = 64
-    cfg.data.image_size = 32
+    cfg = tiny_cfg("arcface", tmp_path, epochs=2)
     cfg.data.num_classes = 16
-    cfg.data.batch_size = 16
-    cfg.data.num_workers = 1
-    cfg.model.arch = "vit_t16"
-    cfg.model.dtype = "float32"
+    cfg.model.arch = "vit_t16_d4"
+    cfg.model.variant = ""
     cfg.model.dropout = 0.0
     cfg.parallel.data_axis = 2
     cfg.parallel.model_axis = 2
     cfg.parallel.pipeline_stages = 2
     cfg.parallel.pipeline_microbatches = 2
     cfg.parallel.arcface_sharded_ce = True
-    cfg.run.epochs = 2
-    cfg.run.out_dir = str(tmp_path)
-    cfg.run.write_records = False
     cfg.run.auto_resume = True
 
     tr = Trainer(cfg)

@@ -34,7 +34,8 @@ Two tiers, because cross-backend f32 determinism sets a noise floor:
    schedule-indexing difference fails at ~1e-6.
 2. The full-model tests run real conv nets, where torch-CPU and XLA-CPU
    reduction orders differ at ~1e-6 per step and training amplifies that
-   ~40x/step (measured: losses agree 7e-7 at step 0, 2.5e-3 by step 5).
+   ~40x/step (measured: losses agree 7e-7 at step 0, 2.5e-3 by step 5 of the six
+   this file once ran).
    Their tolerances are therefore SEMANTIC-level (2e-2): they catch a BN
    momentum-convention swap (~9x running-stat error), a wrong lr actually
    applied (warmup/decay overlay), train-vs-eval BN mode mixups, and
@@ -66,7 +67,13 @@ torch = pytest.importorskip("torch")
 
 from torch_resnet_oracle import make_torch_resnet, randomize_  # noqa: E402
 
-N_STEPS = 6
+# Four steps are the fewest at which every planted difference is outside
+# the tolerances below (read by planting each on the flax side, PR 41): no
+# warm-up fails losses, parameters and statistics from 3 steps on, a BN frozen
+# on one side only fails all three at once, and a decay that does not fire
+# (what counting epochs from the warm-up's end gives: lr 0.01 at step 3, not
+# 0.001) fails the parameters at 4, the first step after the warm-up.
+N_STEPS = 4
 BATCH = 16
 CLASSES = 7
 SIZE = 64
@@ -242,7 +249,7 @@ def test_sgd_bn_warmup_dynamics_match_torch(oracle_pth):
     torch_losses, tmodel = _run_torch(sd, xs, ys, freeze_bn=False)
 
     # per-step loss trajectory: pins training-mode BN normalization + the
-    # lr actually applied each iteration (warmup AND the step-2/4 decays);
+    # lr actually applied each iteration (warmup AND the step-2 decay);
     # tolerance is the measured chaos floor x margin (see module docstring)
     np.testing.assert_allclose(flax_losses, torch_losses, rtol=2e-2,
                                err_msg=f"{flax_losses} vs {torch_losses}")
@@ -254,7 +261,7 @@ def test_sgd_bn_warmup_dynamics_match_torch(oracle_pth):
     _assert_trees_close(state.params["backbone"], converted["params"],
                         rtol=2e-2, atol=1e-3, what="params")
     # running stats: the running mean tracks the drifting activations, so
-    # its absolute floor is higher (measured 7e-3 after 6 steps) — still
+    # its absolute floor is higher (measured 7e-3 after 6 steps, less after 4) — still
     # far below the ~0.5-scale error a 0.1-vs-0.9 momentum mixup produces
     _assert_trees_close(state.batch_stats["backbone"],
                         converted["batch_stats"],

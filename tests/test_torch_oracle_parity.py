@@ -14,6 +14,7 @@ these tests.
 
 import numpy as np
 import pytest
+from tiny import zero_variables
 
 import jax
 import jax.numpy as jnp
@@ -48,12 +49,12 @@ def _forward_pair(make_oracle, make_flax, converter, image_size, seed,
         ref = tmodel(torch.from_numpy(x)).numpy()
 
     fmodel = make_flax()
-    variables = fmodel.init(init_rngs or jax.random.PRNGKey(0),
+    variables = zero_variables(fmodel, init_rngs or jax.random.PRNGKey(0),
                             jnp.zeros((1, image_size, image_size, 3)),
                             train=False)
     merged = merge_into_variables(variables, converter(tmodel.state_dict()))
-    out = fmodel.apply(merged, jnp.asarray(x.transpose(0, 2, 3, 1)),
-                       train=False)
+    out = jax.jit(lambda v, x: fmodel.apply(v, x, train=False))(
+        merged, jnp.asarray(x.transpose(0, 2, 3, 1)))
     return np.asarray(out), ref
 
 
@@ -92,7 +93,7 @@ def test_feature_extractor_matches_torch_prepool():
         ref = h.mean(dim=(2, 3)).numpy()
 
     fmodel = R.resnet18(num_classes=0, dtype=jnp.float32)
-    variables = fmodel.init(jax.random.PRNGKey(0), jnp.zeros((1, 64, 64, 3)),
+    variables = zero_variables(fmodel, jax.random.PRNGKey(0), jnp.zeros((1, 64, 64, 3)),
                             train=False)
     converted = convert_resnet_state_dict(tmodel.state_dict(), include_fc=False)
     merged = merge_into_variables(variables, converted)

@@ -21,11 +21,15 @@ def test_space_to_depth_roundtrip():
 def test_tresnet_forward_shapes_and_stats():
     model = tresnet_m(num_classes=10, dtype=jnp.float32)
     x = jnp.zeros((2, 64, 64, 3), jnp.float32)
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
-    assert "batch_stats" in variables
 
-    logits, mutated = model.apply(
-        variables, x, train=True, mutable=["batch_stats"])
+    def init_then_train_pass():
+        variables = model.init(jax.random.PRNGKey(0), x, train=False)
+        return variables, model.apply(variables, x, train=True,
+                                      mutable=["batch_stats"])
+
+    # one jitted program, not a few hundred ops compiled one by one
+    variables, (logits, mutated) = jax.jit(init_then_train_pass)()
+    assert "batch_stats" in variables
     assert logits.shape == (2, 10)
 
     # train-mode pass must update the running stats
@@ -33,15 +37,18 @@ def test_tresnet_forward_shapes_and_stats():
     after = jax.tree_util.tree_leaves(mutated["batch_stats"])
     assert any(not np.allclose(a, b) for a, b in zip(before, after))
 
-    eval_logits = model.apply(variables, x, train=False)
+    # of the eval pass only the shape is asserted: a trace gives it
+    eval_logits = jax.eval_shape(lambda v: model.apply(v, x, train=False),
+                                 variables)
     assert eval_logits.shape == (2, 10)
 
 
 def test_tresnet_feature_mode():
     model = tresnet_m(num_classes=0, dtype=jnp.float32)
     x = jnp.zeros((2, 64, 64, 3), jnp.float32)
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
-    feats = model.apply(variables, x, train=False)
+    # shapes are what is asserted: a trace gives them, nothing runs
+    feats = jax.eval_shape(lambda: model.apply(
+        model.init(jax.random.PRNGKey(0), x, train=False), x, train=False))
     assert feats.shape == (2, 2048)  # stage-4 bottleneck: 512 · expansion 4
 
 
@@ -57,7 +64,9 @@ def test_tresnet_train_step_runs():
     cfg.data.num_classes = 4
     cfg.data.batch_size = 16
 
-    mesh = meshlib.make_mesh()
+    # two devices: the step's program is the same on eight, and every device
+    # more draws and updates all 29 M parameters again
+    mesh = meshlib.make_mesh(meshlib.MeshSpec(2, 1), jax.devices()[:2])
     with mesh:
         model, tx, state = create_train_state(cfg, mesh, steps_per_epoch=4)
         step = make_train_step(cfg, model, tx)
@@ -82,7 +91,8 @@ def test_tresnet_odd_stage_dims_forward():
     from ddp_classification_pytorch_tpu.models.tresnet import tresnet_m
 
     model = tresnet_m(num_classes=3, dtype=jnp.float32)
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 36, 36, 3)), train=False)
-    out = model.apply(variables, jnp.zeros((2, 36, 36, 3)), train=False)
+    # the crash was a shape error at trace time: a trace is the whole test
+    out = jax.eval_shape(lambda: model.apply(
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 36, 36, 3)), train=False),
+        jnp.zeros((2, 36, 36, 3)), train=False))
     assert out.shape == (2, 3)

@@ -12,6 +12,7 @@ crops — quantization happens pre-normalize in both modes.
 
 import numpy as np
 import pytest
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 from PIL import Image
 
 import jax
@@ -45,7 +46,7 @@ def _tiny_cfg(input_dtype: str):
     cfg.data.num_classes = 4
     cfg.data.batch_size = 16
     cfg.data.input_dtype = input_dtype
-    cfg.model.arch = "resnet18"
+    cfg.model.arch = "resnet10"
     cfg.model.variant = "cifar"
     cfg.model.dtype = "float32"
     return cfg

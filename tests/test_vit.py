@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 
 from ddp_classification_pytorch_tpu.config import get_preset
 from ddp_classification_pytorch_tpu.models.factory import build_model, feat_dim_for
@@ -20,7 +21,7 @@ from ddp_classification_pytorch_tpu.train.steps import make_eval_step, make_trai
 
 def _vit_cfg(head="fc", mp=1):
     cfg = get_preset("baseline")
-    cfg.model.arch = "vit_t16"
+    cfg.model.arch = "vit_t16_d4"
     cfg.model.dtype = "float32"
     cfg.model.head = head
     cfg.data.image_size = 64  # (64/16)² = 16 tokens; divisible by mp ≤ 8
@@ -31,12 +32,12 @@ def _vit_cfg(head="fc", mp=1):
 
 
 def test_vit_feature_and_logit_shapes():
-    model = build_vit("vit_t16", num_classes=0, dtype=jnp.float32)
+    model = build_vit("vit_t16_d4", num_classes=0, dtype=jnp.float32)
     x = jnp.zeros((2, 64, 64, 3))
     vs = model.init(jax.random.PRNGKey(0), x, train=False)
     feats = model.apply(vs, x, train=False)
     assert feats.shape == (2, 192)
-    clf = build_vit("vit_t16", num_classes=7, dtype=jnp.float32)
+    clf = build_vit("vit_t16_d4", num_classes=7, dtype=jnp.float32)
     vs = clf.init(jax.random.PRNGKey(0), x, train=False)
     assert clf.apply(vs, x, train=False).shape == (2, 7)
 
@@ -46,15 +47,16 @@ def test_vit_feat_dim_registry():
     assert feat_dim_for(cfg.model) == 192
 
 
-@pytest.mark.parametrize("mp", [2, 4])
-def test_vit_train_step_sequence_parallel(mp):
-    """Full jitted train step with dp×sp mesh; loss finite and decreasing-ish."""
+def _three_losses(mp):
+    """Three steps on one fixed batch over data x model = (8 / mp) x mp, the
+    step built as the Trainer builds it (`mesh=`: its output shardings are
+    the state's own)."""
     cfg = _vit_cfg(mp=mp)
     mesh = meshlib.make_mesh(
         meshlib.MeshSpec(len(jax.devices()) // mp, mp))
     with mesh:
         model, tx, state = create_train_state(cfg, mesh, steps_per_epoch=4)
-        step = make_train_step(cfg, model, tx)
+        step = make_train_step(cfg, model, tx, mesh=mesh)
         rng = np.random.default_rng(0)
         images = jax.device_put(
             rng.normal(size=(8, 64, 64, 3)).astype(np.float32),
@@ -66,8 +68,25 @@ def test_vit_train_step_sequence_parallel(mp):
         for _ in range(3):
             state, metrics = step(state, images, labels)
             losses.append(float(metrics["loss"]))
+    return losses
+
+
+@pytest.fixture(scope="module")
+def data_parallel_losses():
+    return _three_losses(1)
+
+
+@pytest.mark.parametrize("mp", [2, 4])
+def test_vit_train_step_sequence_parallel(mp, data_parallel_losses):
+    """Full jitted train step with dp×sp mesh; loss finite and decreasing-ish,
+    and the same as with no ring at all: the ring moves tokens, not results.
+    (Without `mesh=` the compiler picks the returned state's shardings, and
+    on data=2 x model=4 the program compiled for THAT state reads 2.477 for
+    the second loss where every other mesh reads 2.308: PERF.md section 7.)"""
+    losses = _three_losses(mp)
     assert all(np.isfinite(l) for l in losses)
     assert losses[-1] < losses[0]  # memorizes a fixed batch within 3 steps
+    np.testing.assert_allclose(losses, data_parallel_losses, rtol=1e-4)
 
 
 def test_vit_sequence_parallel_matches_single_device():
@@ -125,7 +144,7 @@ def test_flash_min_tokens_autopick(monkeypatch):
     x = jnp.zeros((2, 64, 64, 3))  # 16 tokens
     for floor, expect_flash in [(1024, False), (0, True), (16, True)]:
         calls.clear()
-        model = build_vit("vit_t16", num_classes=0, dtype=jnp.float32,
+        model = build_vit("vit_t16_d4", num_classes=0, dtype=jnp.float32,
                           use_flash=True, flash_min_tokens=floor)
         vs = model.init(jax.random.PRNGKey(0), x, train=False)
         model.apply(vs, x, train=False)
@@ -136,7 +155,7 @@ def test_flash_min_tokens_config_plumbs_to_model():
     from ddp_classification_pytorch_tpu.models.factory import build_backbone
 
     cfg = get_preset("baseline")
-    cfg.model.arch = "vit_t16"
+    cfg.model.arch = "vit_t16_d4"
     cfg.model.flash_attention = True
     cfg.model.flash_min_tokens = 512
     vit = build_backbone(cfg.model, 10)
@@ -153,7 +172,7 @@ def test_ln_bf16_stays_close_to_f32_recipe():
                     jnp.float32)
 
     def logits(ln_bf16, dtype):
-        model = build_vit("vit_t16", num_classes=7, dtype=dtype,
+        model = build_vit("vit_t16_d4", num_classes=7, dtype=dtype,
                           ln_bf16=ln_bf16)
         v = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)),
                        train=False)
@@ -178,7 +197,7 @@ def test_vit_remat_checkpoint_dots_gradients_match():
     y = jnp.asarray([1, 3], jnp.int32)
 
     def grads_for(remat):
-        model = build_vit("vit_t16", num_classes=5, dtype=jnp.float32,
+        model = build_vit("vit_t16_d4", num_classes=5, dtype=jnp.float32,
                           remat=remat)
         v = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)),
                        train=False)
@@ -188,7 +207,7 @@ def test_vit_remat_checkpoint_dots_gradients_match():
             return optax.softmax_cross_entropy_with_integer_labels(
                 logits, y).mean()
 
-        return jax.grad(loss)(v["params"])
+        return jax.jit(jax.grad(loss))(v["params"])  # one program, not an op at a time
 
     for a, b in zip(jax.tree_util.tree_leaves(grads_for(False)),
                     jax.tree_util.tree_leaves(grads_for(True))):

@@ -25,6 +25,7 @@ import os
 import jax
 import numpy as np
 import pytest
+import tiny  # noqa: F401  (registers resnet10 and vit_t16_d4)
 
 from ddp_classification_pytorch_tpu.config import get_preset
 from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
@@ -37,7 +38,7 @@ def _tiny_cfg(**parallel):
     cfg.data.image_size = 32
     cfg.data.num_classes = 4
     cfg.data.batch_size = 16
-    cfg.model.arch = "resnet18"
+    cfg.model.arch = "resnet10"
     cfg.model.variant = "cifar"
     cfg.model.dtype = "float32"
     for k, v in parallel.items():
@@ -50,16 +51,16 @@ def _dp2_mesh():
                              devices=jax.devices()[:2])
 
 
-def _run_steps(cfg, mesh, steps=3):
+def _run_steps(cfg, mesh, steps=3, rows=16, seed=3):
     """3 steps of the real train step on a fixed seeded batch (the
     test_two_process_run_matches_single_process pins); returns (losses,
-    full host copy of the final state)."""
+    full host copy of the final state). test_grad_accum.py runs it too."""
     from ddp_classification_pytorch_tpu.train.state import create_train_state
     from ddp_classification_pytorch_tpu.train.steps import make_train_step
 
-    rng = np.random.default_rng(3)
-    images = rng.normal(size=(16, 32, 32, 3)).astype(np.float32)
-    labels = rng.integers(0, 4, 16).astype(np.int32)
+    rng = np.random.default_rng(seed)
+    images = rng.normal(size=(rows, 32, 32, 3)).astype(np.float32)
+    labels = rng.integers(0, 4, rows).astype(np.int32)
     with mesh:
         model, tx, state = create_train_state(cfg, mesh, steps_per_epoch=4)
         step = make_train_step(cfg, model, tx, mesh=mesh)
