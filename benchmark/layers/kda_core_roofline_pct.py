@@ -1,0 +1,28 @@
+"""Kimi delta attention: the recurrence's share of its roofline — the least
+time the chip could take for it (benchmark/flops `kda_core_bound_s`: the
+larger of the chunked form's FLOPs at a NOMINAL chunk of 64 over the bf16
+peak and of q, k, v, g, beta in and o out, once, over the HBM's bandwidth;
+forward x 3, every KDA layer; the bytes bind) over the time of the op events
+under `kda.core` (forward, rematerialized forward and backward; union of
+intervals, layers/_scope_members.py). Counted from shapes alone, whatever
+chunk or kernel the program computes with, so a later kernel is read against
+the same work. A program without the scope, or a configuration without the
+count, gives None."""
+
+import importlib
+
+from benchmark.layers import _scope_members, _scoped_ops
+
+
+def read(ctx):
+    flops = importlib.import_module(f"benchmark.flops.{ctx['config']['flops']}")
+    if not hasattr(flops, "kda_core_bound_s"):
+        return None
+    ms = _scope_members.scope_ms(ctx, "kda.core")
+    if ms is None:
+        return None
+    bound = flops.kda_core_bound_s(
+        ctx["arch"], ctx["batch"] // ctx["chips"],
+        _scoped_ops.peak(ctx, "bf16_flops_per_s"),
+        _scoped_ops.peak(ctx, "hbm_bytes_per_s"))
+    return 100.0 * bound / (ms * 1e-3)

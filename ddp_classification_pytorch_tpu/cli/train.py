@@ -90,9 +90,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "decoder_lm (a token decoder: next-token training "
                         "as per-position classification; sizes in the "
                         "'decoder' group: per layer grouped-query or latent "
-                        "attention or a gated short convolution, dense / "
-                        "routed / shared feed-forward, softmax or sigmoid "
-                        "router, a multi-token-prediction module, a tied or "
+                        "attention, a gated short convolution or Kimi delta "
+                        "attention, dense / routed / shared feed-forward, "
+                        "softmax or sigmoid router with or without a group "
+                        "limit, a multi-token-prediction module, a tied or "
                         "untied head; defaults = the published "
                         "SmallThinker-21BA3B-Instruct)")
     m.add_argument("--flash_attention", action="store_true",
@@ -147,6 +148,8 @@ def build_parser() -> argparse.ArgumentParser:
                        ("attention", str), ("q_rank", int), ("kv_rank", int),
                        ("rope_dim", int), ("v_head_dim", int),
                        ("rope_pairing", str), ("qk_norm", int),
+                       ("out_gate", int), ("conv_kernel", int),
+                       ("n_group", int), ("topk_group", int),
                        ("dense_layers", int),
                        ("dense_width", int), ("activation", str),
                        ("router", str), ("router_scale", float),
@@ -155,11 +158,12 @@ def build_parser() -> argparse.ArgumentParser:
                        ("mtp_layers", int), ("mtp_weight", float),
                        ("tied_embeddings", int)):
         dec.add_argument(f"--{flag}", type=kind, default=None)
-    for flag in ("rope_layout", "window_layout", "conv_layout"):
+    for flag in ("rope_layout", "window_layout", "conv_layout", "kda_layout"):
         dec.add_argument(f"--{flag}", default=None,
                          help="comma-separated 0/1 per layer, repeated to "
                               "the depth (e.g. 0,1,1,1); conv_layout: 1 = the "
-                              "gated short convolution in attention's place")
+                              "gated short convolution in attention's place; "
+                              "kda_layout: 1 = Kimi delta attention there")
 
     a = p.add_argument_group("arcface")
     a.add_argument("--arc_s", type=float, default=-1.0)

@@ -73,15 +73,17 @@ class DecoderConfig:
     models/decoder_lm.py) — the one place they live; the CLI fills it.
     Defaults are the published SmallThinker-21BA3B-Instruct config.json
     (PowerInfer, arXiv:2507.20984). The layer is described by data: the
-    token mixer per layer (`conv_layout`: the configured attention, or the
-    gated short convolution of LFM2, LiquidAI's `lfm2` / `lfm2_moe`), the
-    attention kind and its QK-norm, the feed-forward kind per layer
-    (`dense_layers` leading dense ones, then routed experts with or without
-    a shared expert), the router's scoring and tap, the activation, the
-    rotary pairing, a head of its own or tied to the embedding, and a
-    multi-token-prediction module after the last layer. Three published
-    layers are its fixed points: SmallThinker's, the DeepSeek-V3 layer as
-    JoyAI-LLM-Flash configures it, and LFM2-8B-A1B's.
+    token mixer per layer, one of three kinds (`conv_layout`: the gated short
+    convolution of LFM2, LiquidAI's `lfm2` / `lfm2_moe`; `kda_layout`: Kimi
+    delta attention, a recurrence over the row, arXiv:2510.26692; else the
+    configured attention), the attention kind, its QK-norm and its output
+    gate, the feed-forward kind per layer (`dense_layers` leading dense ones,
+    then routed experts with or without a shared expert), the router's
+    scoring, group limit and tap, the activation, the rotary pairing, a head
+    of its own or tied to the embedding, and a multi-token-prediction module
+    after the last layer. Four published layers are its fixed points:
+    SmallThinker's, the DeepSeek-V3 layer as JoyAI-LLM-Flash configures it,
+    LFM2-8B-A1B's, and Ling-3.0-flash's (inclusionAI, `bailing_hybrid`).
 
     A deployment that spreads a layer's experts and the vocabulary's rows
     over several chips gives each chip its share: `experts_held` experts
@@ -113,7 +115,8 @@ class DecoderConfig:
     head_block: int = 2048
     # attention: "gqa" = grouped-query heads of `head_dim`, rotary over the
     # whole head; "mla" = latent attention (DeepSeek-V2/V3): queries through
-    # a rank-`q_rank` bottleneck, keys and values from a rank-`kv_rank`
+    # a rank-`q_rank` bottleneck (0: straight from the normed input, no
+    # bottleneck), keys and values from a rank-`kv_rank`
     # latent, scores over `head_dim` dims without position + `rope_dim`
     # rotary dims whose key part is ONE head shared by every query head,
     # values of `v_head_dim`
@@ -124,11 +127,22 @@ class DecoderConfig:
     v_head_dim: int = 0              # 0 = head_dim
     rope_pairing: str = "half"       # "half": i with i + D/2 | "interleaved": 2i with 2i + 1
     qk_norm: int = 0                 # 1: RMSNorm on every "gqa" query and key head before the rotary embedding
-    # the token mixer, a 0/1 list repeated to the depth like the two above:
-    # 1 = the gated short convolution (LFM2: [B | C | X] = h W_in, a causal
-    # depthwise convolution of 3 taps (`decoder_lm.CONV_TAPS`) over B * X, gated by C,
-    # then W_out) stands where attention stands; 0 = the attention above
+    out_gate: int = 0                # 1: each attention head's output times sigmoid(h w_g), one gate a head, before W_o
+    # the token mixer, two 0/1 lists repeated to the depth like the two
+    # above; a layer neither marks runs the attention above.
+    # conv_layout 1 = the gated short convolution (LFM2: [B | C | X] = h W_in,
+    # a causal depthwise convolution of `conv_kernel` taps over B * X, gated
+    # by C, then W_out) stands where attention stands.
+    # kda_layout 1 = Kimi delta attention (models/decoder_lm.py, ops/kda.py):
+    # q, k, v through a causal depthwise convolution of `conv_kernel` taps
+    # and SiLU, q and k L2-normed, `num_heads` states of head_dim x head_dim
+    # carried along the row by the gated delta rule with a per-channel decay
+    # whose log lies in (-5, 0), in chunks of 64 tokens, 8 heads at a time
+    # (ops/kda.py's LOWER_BOUND, CHUNK, HEAD_GROUP: constants until a second
+    # published value exists); a gated per-head RMSNorm on the output
     conv_layout: Sequence[int] = (0,)
+    kda_layout: Sequence[int] = (0,)
+    conv_kernel: int = 3             # taps (LFM2 conv_L_cache 3; Ling short_conv_kernel_size 4)
     # feed-forward: the first `dense_layers` layers are one gated MLP of
     # `dense_width`; the others route over the experts
     dense_layers: int = 0
@@ -140,6 +154,12 @@ class DecoderConfig:
     router: str = "softmax"
     router_scale: float = 1.0
     router_eps: float = 0.0          # "sigmoid": added to the chosen scores' sum (LFM2: 1e-6)
+    # "sigmoid" with n_group > 1 (DeepSeek-V3's group-limited choice): the
+    # experts stand in `n_group` groups, a group's score is the sum of its
+    # two largest score + bias, and the top-k is taken inside the
+    # `topk_group` best groups only
+    n_group: int = 1
+    topk_group: int = 1
     router_tap: str = "pre"          # reads the layer's normed input: "pre" attention | "post"
     shared_experts: int = 0          # experts every token takes (width x this many)
     # multi-token prediction (DeepSeek-V3 eq. 21-25): 0 or 1 extra layer that
@@ -170,10 +190,11 @@ class DecoderConfig:
 
     def layer_kinds(self) -> tuple:
         """(operator, ffn) of each of the `num_layers` layers: operator
-        "conv" or the attention kind, ffn "dense" | "routed"."""
-        return tuple(("conv" if conv else self.attention,
+        "conv", "kda" or the attention kind, ffn "dense" | "routed"."""
+        return tuple(("conv" if conv else "kda" if kda else self.attention,
                       "dense" if i < self.dense_layers else "routed")
-                     for i, conv in enumerate(self.layout(self.conv_layout)))
+                     for i, (conv, kda) in enumerate(zip(
+                         self.layout(self.conv_layout), self.layout(self.kda_layout))))
 
 
 @dataclass

@@ -2,25 +2,50 @@
 position over the vocabulary.
 
 ONE layer module, described by data (`config.DecoderConfig`, which the CLI
-fills — no table of variants, no second model file). Three published layers
+fills — no table of variants, no second model file). Four published layers
 are its fixed points: SmallThinker-21BA3B-Instruct (PowerInfer,
 arXiv:2507.20984; the defaults), the DeepSeek-V3 layer (arXiv:2412.19437
-§2.1-2.2) as JoyAI-LLM-Flash configures it, and LFM2-8B-A1B (LiquidAI,
+§2.1-2.2) as JoyAI-LLM-Flash configures it, LFM2-8B-A1B (LiquidAI,
 `lfm2_moe`: most layers mix tokens by a gated short convolution and not by
-attention). With x (B, T, C), every projection without bias:
+attention) and Ling-3.0-flash (inclusionAI, `bailing_hybrid`: most layers
+carry a state along the row by Kimi delta attention, arXiv:2510.26692 §3).
+With x (B, T, C), every projection without bias:
 
     h  = RMSNorm(x)                        input norm
-    a  = layers with conv_layout = 1: the gated short convolution —
+    a  = the token mixer, one of three kinds per layer:
+         layers with conv_layout = 1: the gated short convolution —
                                            [B | C | X] = h W_in   (C, 3C)
                                            z   = B * X
                                            c_t = Σ_j w[j] * z_{t−(L−1)+j}
-                                             depthwise over L = CONV_TAPS = 3
-                                             taps, causal (z_{<0} = 0), no
-                                             bias, no activation
+                                             depthwise over L = conv_kernel
+                                             taps (LFM2: 3), causal
+                                             (z_{<0} = 0), no bias, no
+                                             activation
                                            a   = (C * c) W_out
                                            (it crosses document boundaries
                                            inside a packed row, as attention
                                            does)
+         layers with kda_layout = 1: Kimi delta attention, H heads of
+         d = head_dim —                    q~, k~, v~ = h W_q, h W_k, h W_v
+                                           q, k, v = SiLU(conv(·)): depthwise,
+                                             causal, conv_kernel taps (Ling: 4)
+                                           q, k L2-normed per head (eps 1e-6)
+                                           g_t = −5 ·
+                                             sigmoid(exp(A_log) (h_t W_f + dt_bias))
+                                             ∈ (−5, 0)^d: the log of the
+                                             per-channel decay, A_log a head
+                                           β_t = sigmoid(h_t w_β), one a head
+                                           S_t = (I − β_t k_t k_tᵀ) Diag(exp g_t)
+                                                 S_{t−1} + β_t k_t v_tᵀ
+                                             S (d, d) a head, 0 at the row's
+                                             start, carried over documents
+                                           o_t = S_tᵀ q_t / sqrt(d)
+                                           a = [RMSNorm_d(o) · sigmoid(h w_g)]
+                                             over the heads, W_o (one scale
+                                             of d for all heads, one gate a
+                                             head); no rotary embedding;
+                                           in chunks of 64 tokens, 8 heads
+                                           at a time (ops/kda.py's constants)
          the others: attention(h) W_o      "gqa": H query heads on H_kv KV
                                            heads of head_dim; with qk_norm an
                                            RMSNorm (one scale of head_dim for
@@ -32,6 +57,8 @@ attention). With x (B, T, C), every projection without bias:
                                            "mla": latent attention —
                                            c_q = RMSNorm(h W_qa);  q = c_q W_qb
                                              → H x [q_nope head_dim | q_rope rope_dim]
+                                             (q_rank = 0: q = h W_q, no
+                                             bottleneck and no norm)
                                            [c | k_r] = h W_kva     k_r: ONE head
                                            [k_nope | v] = RMSNorm(c) W_kvb
                                            rotary on q_rope and k_r only;
@@ -39,6 +66,9 @@ attention). With x (B, T, C), every projection without bias:
                                            / sqrt(head_dim + rope_dim)
                                            mask: causal, and where
                                            window_layout = 1 also j > i − window
+                                           with out_gate each head's output
+                                           times sigmoid(h w_g), one gate a
+                                           head, before W_o
     x1 = x + a
     u  = RMSNorm(x1)
     y  = layers < dense_layers: W_down(act(W_gate u) · W_up u), one gated MLP
@@ -49,7 +79,9 @@ attention). With x (B, T, C), every projection without bias:
 The router's logits r = t W_r are taken from t = h (router_tap "pre": before
 attention, as SmallThinker places it) or t = u ("post"); "softmax" scoring
 chooses the top-k of r and weighs them by their softmax, "sigmoid" chooses
-the top-k of sigmoid(r) + bias and weighs by the chosen sigmoid(r),
+the top-k of sigmoid(r) + bias — with n_group > 1 inside the topk_group best
+of n_group groups of experts only, a group scored by the sum of its two
+largest — and weighs by the chosen sigmoid(r),
 renormalised (over their sum + router_eps) and times router_scale
 (ops/moe.py::route_top_k). The bias is a
 leaf that selection alone reads: its gradient is zero and no rule moves it
@@ -74,14 +106,16 @@ layer (ops/moe.py::sparse_moe) computes its own experts' part, and under a
 `model` mesh axis > 1 the banks shard over it and a psum completes the sum.
 A shared expert is held by every chip and counted once.
 
-TPU-first: bf16 matmuls with f32 accumulation, f32 params, norms, router and
-softmax; attention through the Pallas flash kernels (window band, grouped KV
-heads, the latent scores' shared rotary key: ops/flash_attention.py) wherever
-they tile T, else the dense op; `hidden` stops before the head so the train
-step can take head and loss in row blocks (ops/lm_head.py).
+TPU-first: bf16 matmuls with f32 accumulation, f32 params, norms, router,
+softmax, decays and recurrent state; attention through the Pallas flash
+kernels (window band, grouped KV heads, the latent scores' shared rotary
+key: ops/flash_attention.py) wherever they tile T, else the dense op;
+`hidden` stops before the head so the train step can take head and loss in
+row blocks (ops/lm_head.py).
 
 Device scopes (`jax.named_scope`, docs/observability.md): `attn`, `conv`
-(with `conv.in`, `conv.mix`, `conv.out` inside it), `ffn`,
+(with `conv.in`, `conv.mix`, `conv.out` inside it), `kda` (with `kda.in`,
+`kda.core`, `kda.out` inside it), `ffn`,
 `moe.route` / `.dispatch` / `.experts` / `.combine` / `.shared`, `mtp`
 (outermost, around the whole module), `lm_head`.
 """
@@ -94,15 +128,13 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..config import DecoderConfig
 from ..ops.attention import attention, flash_supported
 from ..ops.flash_attention import backward_path, flash_attention
+from ..ops.kda import LOWER_BOUND, kda_chunked
 from ..ops.moe import GATE_ACTIVATIONS, sparse_moe
-
-# taps of the gated short convolution (LFM2's published `conv_L_cache`); a
-# constant until a second published length exists
-CONV_TAPS = 3
 
 
 def rotate_half(x: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -172,6 +204,15 @@ class Head(nn.Module):
         return _logits(h, kernel, self.dtype)
 
 
+def _causal_taps(z: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """A depthwise causal convolution of z (B, T, C) with the taps w (L, C)
+    as shifted multiply-adds: tap j reads position t − (L − 1) + j, z shifted
+    down the row, zeros before the row's start."""
+    taps, t = w.shape[0], z.shape[1]
+    return sum(w[j] * jnp.pad(z, ((0, 0), (taps - 1 - j, 0), (0, 0)))[:, :t]
+               for j in range(taps))
+
+
 def _takes_kernels(t: int, flash_min_tokens: int) -> bool:
     """Rows of `t` tokens go through the flash kernels: where they tile T and
     beat the dense op (ModelConfig.flash_min_tokens); else the (T, T) op."""
@@ -182,9 +223,9 @@ def flash_backward_path(cfg: DecoderConfig, dtype,
                         flash_min_tokens: int) -> Optional[str]:
     """Which backward the attention layers' kernels take at the configured
     row length ("fused" | "split", ops/flash_attention.py::backward_path);
-    None where no layer reaches the kernels (convolutions only, or rows the
+    None where no layer reaches the kernels (no attention layer, or rows the
     dense op takes)."""
-    if (all(op == "conv" for op, _ in cfg.layer_kinds())
+    if (all(op != cfg.attention for op, _ in cfg.layer_kinds())
             or not _takes_kernels(cfg.seq_len, flash_min_tokens)):
         return None
     widths = ((cfg.head_dim, cfg.value_dim, cfg.rope_dim)
@@ -201,7 +242,7 @@ class DecoderLayer(nn.Module):
     expert_axis: Optional[str] = None
     flash_min_tokens: int = 1024
     routed: bool = True     # False: one dense gated MLP (a leading layer)
-    conv: bool = False      # True: the gated short convolution, no attention
+    mixer: str = "attn"     # "attn" | "conv" | "kda" (DecoderConfig.layer_kinds)
 
     def _gated_mlp(self, u, width: int, prefix: str):
         """W_down(act(W_gate u) · W_up u): the dense layer's feed-forward
@@ -237,13 +278,17 @@ class DecoderLayer(nn.Module):
             if self.rope:
                 q, k = rotary(q), rotary(k)
             return q, k, v, ()
-        # latent attention: queries through a normed bottleneck; one normed
-        # latent gives every head its position-free key and its value, and
-        # one rotary key head serves all query heads
+        # latent attention: queries through a normed bottleneck (or, with
+        # q_rank 0, straight from h); one normed latent gives every head its
+        # position-free key and its value, and one rotary key head serves all
+        # query heads
         heads, hd, dr = c.num_heads, c.head_dim, c.rope_dim
-        cq = RMSNorm(c.rms_eps, name="q_norm")(
-            _dense(c.q_rank, self.dtype, "q_a")(h)).astype(self.dtype)
-        q = _dense(heads * (hd + dr), self.dtype, "q_b")(cq)
+        if c.q_rank:
+            cq = RMSNorm(c.rms_eps, name="q_norm")(
+                _dense(c.q_rank, self.dtype, "q_a")(h)).astype(self.dtype)
+            q = _dense(heads * (hd + dr), self.dtype, "q_b")(cq)
+        else:
+            q = _dense(heads * (hd + dr), self.dtype, "q")(h)
         q = q.reshape(b, t, heads, hd + dr)
         kv = _dense(c.kv_rank + dr, self.dtype, "kv_a")(h)
         ckv = RMSNorm(c.rms_eps, name="kv_norm")(
@@ -259,22 +304,71 @@ class DecoderLayer(nn.Module):
         """LFM2's token mixer: h (B, T, C) → (B, T, C). `W_in` is ONE matmul
         of 3C columns; the taps are shifted multiply-adds in f32 over the
         gate product as the compute dtype holds it; plain XLA."""
-        dim, taps = h.shape[-1], CONV_TAPS
+        dim, taps = h.shape[-1], self.cfg.conv_kernel
         with jax.named_scope("conv.in"):
             gate_b, gate_c, xs = jnp.split(
                 _dense(3 * dim, self.dtype, "conv_in")(h), 3, axis=-1)
         with jax.named_scope("conv.mix"):
             w = self.param("conv_taps", nn.initializers.lecun_normal(),
                            (taps, dim), jnp.float32)
-            z = (gate_b * xs).astype(jnp.float32)
-            t = z.shape[1]
-            # tap j reads position t − (L − 1) + j: z shifted down the row,
-            # zeros before the row's start
-            mixed = sum(w[j] * jnp.pad(z, ((0, 0), (taps - 1 - j, 0), (0, 0)))[:, :t]
-                        for j in range(taps))
+            mixed = _causal_taps((gate_b * xs).astype(jnp.float32), w)
             y = (gate_c.astype(jnp.float32) * mixed).astype(self.dtype)
         with jax.named_scope("conv.out"):
             return _dense(dim, self.dtype, "conv_out")(y)
+
+    def _kda(self, h):
+        """Kimi delta attention's block: h (B, T, C) → (B, T, C). q, k, v
+        through a depthwise causal convolution of `conv_kernel` taps and
+        SiLU, q and k L2-normed per head, the per-channel log decay g and β
+        from h, the recurrence in chunks (ops/kda.py), a per-head RMSNorm
+        gated by one sigmoid a head, W_o; no rotary embedding. Plain XLA."""
+        c = self.cfg
+        b, t, dim = h.shape
+        heads, hd, taps = c.num_heads, c.head_dim, c.conv_kernel
+        f32 = jnp.float32
+
+        def project(name, width=heads * hd):
+            return _dense(width, self.dtype, f"kda_{name}")(h)
+
+        def taps_of(name):
+            return self.param(f"kda_taps_{name}", nn.initializers.lecun_normal(),
+                              (taps, heads * hd), f32)
+
+        @jax.checkpoint     # keeps the projections; the rest is elementwise
+        def prepare(xq, xk, xv, xf, xb, wq, wk, wv, a_log, dt_bias):
+            def branch(x, w):
+                return jax.nn.silu(_causal_taps(x.astype(f32), w)).reshape(
+                    b, t, heads, hd)
+
+            def unit(x):    # L2 norm over the head's dims
+                return x * jax.lax.rsqrt(
+                    jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+
+            g = LOWER_BOUND * jax.nn.sigmoid(
+                jnp.exp(a_log)[:, None]
+                * (xf.astype(f32) + dt_bias).reshape(b, t, heads, hd))
+            return ((unit(branch(xq, wq)) * hd ** -0.5).astype(self.dtype),
+                    unit(branch(xk, wk)).astype(self.dtype),
+                    branch(xv, wv).astype(self.dtype), g,
+                    jax.nn.sigmoid(xb.astype(f32)))
+
+        with jax.named_scope("kda.in"):
+            q, k, v, g, beta = prepare(
+                project("q"), project("k"), project("v"), project("f"),
+                project("beta", heads), taps_of("q"), taps_of("k"), taps_of("v"),
+                self.param("kda_a_log", nn.initializers.zeros, (heads,), f32),
+                self.param("kda_dt_bias", nn.initializers.constant(-4.0),
+                           (heads * hd,), f32))
+        with jax.named_scope("kda.core"):
+            # named for --remat's policy (DecoderLM.setup): the layer's
+            # recomputed forward keeps o and does not walk the states again
+            o = checkpoint_name(
+                kda_chunked(q, k, v, g, beta, dtype=self.dtype), "kda_out")
+        with jax.named_scope("kda.out"):
+            gate = jax.nn.sigmoid(_dense(heads, self.dtype, "kda_gate")(h).astype(f32))
+            o = RMSNorm(c.rms_eps, name="kda_norm")(o) * gate[..., None]
+            return _dense(dim, self.dtype, "kda_o")(
+                o.astype(self.dtype).reshape(b, t, -1))
 
     def _attention(self, h):
         b, t, dim = h.shape
@@ -283,6 +377,10 @@ class DecoderLayer(nn.Module):
                 else attention)
         a = core(q, k, v, causal=True, window=self.window,
                  **(dict(q_rope=rope[0], k_rope=rope[1]) if rope else {}))
+        if self.cfg.out_gate:   # one sigmoid a head on the head's output
+            gate = jax.nn.sigmoid(_dense(self.cfg.num_heads, self.dtype, "o_gate")(
+                h).astype(jnp.float32))
+            a = (a.astype(jnp.float32) * gate[..., None]).astype(self.dtype)
         return _dense(dim, self.dtype, "o")(a.reshape(b, t, -1))
 
     @nn.compact
@@ -292,8 +390,9 @@ class DecoderLayer(nn.Module):
         h32 = RMSNorm(c.rms_eps, name="norm_in")(x)
         if self.routed and c.router_tap == "pre":
             logits = self._router_logits(h32)
-        with jax.named_scope("conv" if self.conv else "attn"):
-            mix = self._short_conv if self.conv else self._attention
+        with jax.named_scope(self.mixer):
+            mix = {"attn": self._attention, "conv": self._short_conv,
+                   "kda": self._kda}[self.mixer]
             x = x + mix(h32.astype(self.dtype))
         u32 = RMSNorm(c.rms_eps, name="norm_post")(x)
         u = u32.astype(self.dtype)
@@ -308,6 +407,8 @@ class DecoderLayer(nn.Module):
                          eps=c.router_eps,
                          bias=self.param("router_bias", nn.initializers.zeros,
                                          (c.num_experts,), jnp.float32))
+            if c.n_group > 1:
+                route.update(n_group=c.n_group, topk_group=c.topk_group)
         init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
         w_gate = self.param("w_gate", init, (c.held, dim, c.expert_width), jnp.float32)
@@ -374,19 +475,23 @@ class DecoderLM(nn.Module):
                               embedding_init=nn.initializers.normal(0.02),
                               name="embed")
         # --remat recomputes a layer in its backward pass but for the flash
-        # kernels' output and logsumexp (117 MB a layer at 2 x 8,192 tokens):
-        # saving them spares a second run of the forward kernel
+        # kernels' output and logsumexp (117 MB a layer at 2 x 8,192 tokens)
+        # and the KDA recurrence's output (134 MB a layer at 8,192 tokens):
+        # saving them spares a second run of the forward kernel, and of the
+        # walk over the chunks' states
         layer = (nn.remat(DecoderLayer, policy=jax.checkpoint_policies
-                          .save_only_these_names("flash_out", "flash_lse"))
+                          .save_only_these_names("flash_out", "flash_lse", "kda_out"))
                  if self.remat else DecoderLayer)
 
         def build(i: int, name: str):
+            mixer = ("conv" if c.conv_layout[i % len(c.conv_layout)] else
+                     "kda" if c.kda_layout[i % len(c.kda_layout)] else "attn")
             return layer(c, bool(c.rope_layout[i % len(c.rope_layout)]),
                          c.window if c.window_layout[i % len(c.window_layout)]
                          else None,
                          self.dtype, self.mesh, self.expert_axis,
-                         self.flash_min_tokens, i >= c.dense_layers,
-                         bool(c.conv_layout[i % len(c.conv_layout)]), name=name)
+                         self.flash_min_tokens, i >= c.dense_layers, mixer,
+                         name=name)
 
         self.layers = [build(i, f"layer{i}") for i in range(c.num_layers)]
         self.norm_final = RMSNorm(c.rms_eps, name="norm_final")

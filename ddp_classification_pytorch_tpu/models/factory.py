@@ -111,8 +111,9 @@ def build_decoder_lm(cfg: ModelConfig, num_classes: int,
     for key, allowed in kinds.items():
         if getattr(dc, key) not in allowed:
             raise ValueError(f"decoder {key}={getattr(dc, key)!r}: one of {allowed}")
-    if dc.attention == "mla" and not (dc.q_rank and dc.kv_rank and dc.rope_dim):
-        raise ValueError("latent attention needs --q_rank, --kv_rank and --rope_dim")
+    if dc.attention == "mla" and not (dc.kv_rank and dc.rope_dim):
+        raise ValueError("latent attention needs --kv_rank and --rope_dim "
+                         "(--q_rank 0: queries without a bottleneck)")
     if not 0 <= dc.dense_layers <= dc.num_layers or (dc.dense_layers
                                                      and not dc.dense_width):
         raise ValueError(f"{dc.dense_layers} dense layers of width "
@@ -120,8 +121,23 @@ def build_decoder_lm(cfg: ModelConfig, num_classes: int,
     if dc.qk_norm and dc.attention != "gqa":
         raise ValueError("--qk_norm norms grouped-query heads; latent "
                          "attention norms its two latents already")
-    if any(v not in (0, 1) for v in dc.conv_layout):
-        raise ValueError(f"conv_layout {tuple(dc.conv_layout)} is 0/1 per layer")
+    for key in ("conv_layout", "kda_layout"):
+        if any(v not in (0, 1) for v in getattr(dc, key)):
+            raise ValueError(f"{key} {tuple(getattr(dc, key))} is 0/1 per layer")
+    if any(conv and kda for conv, kda in zip(dc.layout(dc.conv_layout),
+                                             dc.layout(dc.kda_layout))):
+        raise ValueError("conv_layout and kda_layout mark the same layer")
+    if any(op == "kda" for op, _ in dc.layer_kinds()):
+        from ..ops.kda import chunk_of
+
+        chunk_of(dc.seq_len)   # refuses a row that is not whole chunks
+    if dc.n_group > 1 and (dc.router != "sigmoid" or dc.num_experts % dc.n_group
+                           or not 1 <= dc.topk_group <= dc.n_group
+                           or dc.top_k > dc.topk_group * (dc.num_experts // dc.n_group)):
+        raise ValueError(
+            f"n_group {dc.n_group} / topk_group {dc.topk_group}: a sigmoid "
+            f"router's {dc.num_experts} experts in equal groups, the kept "
+            f"groups holding at least top_k {dc.top_k}")
     if dc.mtp_layers not in (0, 1):
         raise ValueError("multi-token prediction is built at depth 0 or 1, "
                          f"got mtp_layers={dc.mtp_layers}")

@@ -171,7 +171,7 @@ def moe_mlp(
 
 def route_top_k(logits: jnp.ndarray, top_k: int, *, scoring: str = "softmax",
                 bias: Optional[jnp.ndarray] = None, scale: float = 1.0,
-                eps: float = 0.0):
+                eps: float = 0.0, n_group: int = 1, topk_group: int = 1):
     """(N, E) f32 router logits → (expert ids (N, k) i32, weights (N, k)
     f32). "softmax": the k largest logits and the softmax over those k (=
     the full softmax renormalised over the chosen). "sigmoid" (DeepSeek-V3):
@@ -179,7 +179,10 @@ def route_top_k(logits: jnp.ndarray, top_k: int, *, scoring: str = "softmax",
     the bias (E,) steers selection and nothing else, its gradient is zero —
     and the weights are the chosen experts' UNBIASED scores, renormalised
     over the chosen (their sum + `eps`: LFM2 publishes 1e-6, DeepSeek-V3
-    none) and times `scale`."""
+    none) and times `scale`. With `n_group` > 1 the choice is group-limited
+    (DeepSeek-V3's `noaux_tc`): the experts stand in `n_group` groups of
+    E / n_group, a group's score is the sum of its two largest s + bias, and
+    the k largest are taken inside the `topk_group` best groups only."""
     logits = logits.astype(jnp.float32)
     if scoring == "softmax":
         vals, idx = jax.lax.top_k(logits, top_k)
@@ -188,6 +191,8 @@ def route_top_k(logits: jnp.ndarray, top_k: int, *, scoring: str = "softmax",
         raise ValueError(f"unknown router scoring {scoring!r}")
     scores = jax.nn.sigmoid(logits)
     biased = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    if n_group > 1:
+        biased = _group_limited(biased, n_group, topk_group)
     # the k largest by k passes of argmax (ties to the lower id, as top_k's),
     # each pass reading its expert's unbiased score through the same one-hot
     # mask: no sort, and no gather whose transpose is a scatter-add into
@@ -204,6 +209,23 @@ def route_top_k(logits: jnp.ndarray, top_k: int, *, scoring: str = "softmax",
     # no `+ 0.0` in the program of a router that publishes no epsilon
     weights = scale * chosen / (total + eps if eps else total)
     return idx.astype(jnp.int32), weights
+
+
+def _group_limited(biased: jnp.ndarray, n_group: int, topk_group: int):
+    """(N, E) selection scores with every expert outside the `topk_group`
+    best of the `n_group` groups at −inf. A group's score is the sum of its
+    two largest entries; like the choice below, by passes of argmax under a
+    one-hot mask: no sort, nothing to transpose."""
+    n, e = biased.shape
+    per = jax.lax.stop_gradient(biased).reshape(n, n_group, e // n_group)
+    first = jnp.max(per, axis=-1)
+    hit = jax.nn.one_hot(jnp.argmax(per, axis=-1), per.shape[-1], dtype=bool)
+    score = first + jnp.max(jnp.where(hit, -jnp.inf, per), axis=-1)   # (N, groups)
+    keep = jnp.zeros(score.shape, bool)
+    for _ in range(topk_group):
+        hit = jax.nn.one_hot(jnp.argmax(score, axis=-1), n_group, dtype=bool)
+        keep, score = keep | hit, jnp.where(hit, -jnp.inf, score)
+    return jnp.where(keep[:, :, None], per, -jnp.inf).reshape(n, e)
 
 
 @jax.custom_vjp

@@ -41,12 +41,18 @@ CONFIGS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
     # attention layer, the head tied to the embedding
     pytest.param("lfm2_8b_a1b.json", 507820288, 0.9 * HBM_BYTES, 1,
                  id="hybrid_decoder"),
+    # Ling-3.0-flash (PR 42): six Kimi-delta-attention layers (plain XLA: the
+    # chunked recurrence) and one latent-attention layer without a query
+    # bottleneck, 1 row of 8,192 tokens. State 13.15 GB: held, as the latent
+    # decoder is, against what the chip's allocator hands out
+    pytest.param("ling_3_0_flash.json", 822036416, 0.95 * 16_909_336_064, 1,
+                 id="delta_decoder"),
 ])
 def test_train_step_fits_one_chip(topo, kernels, config, parameters,
                                   byte_limit, attention_blocks):
-    """A benchmark cell's step as `cli.train` builds it (2 rows of 8,192
-    tokens, --remat, the head in row blocks) lowers, compiles and fits, with
-    the compiler's memory count printed."""
+    """A benchmark cell's step as `cli.train` builds it (the cell's rows of
+    8,192 tokens, --remat, the head in row blocks) lowers, compiles and fits,
+    with the compiler's memory count printed."""
     with open(os.path.join(CONFIGS, config)) as f:
         conf = json.load(f)
     cfg = config_from_args(build_parser().parse_args(

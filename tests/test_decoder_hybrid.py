@@ -54,8 +54,6 @@ def cli_argv(arch, *extra, dtype="float32", tied=1):
             dtype, "--optimizer", "adam", "--head_block", "16",
             "--tied_embeddings", str(tied), *KINDS]
     for key, value in arch.items():
-        if key == "conv_kernel":  # the reference's key; the program's CONV_TAPS
-            continue
         argv += [f"--{key}", ",".join(map(str, value)) if isinstance(value, list)
                  else str(value)]
     return argv + list(extra)
@@ -384,9 +382,10 @@ def test_analytic_counts_match_the_published_8b_a1b():
     # the argv builds the arch
     dc = config_from_args(build_parser().parse_args(
         CONF["argv"] + ["--dataset", "tokens"])).model.decoder
-    cut = dict(cut)
-    taps = cut.pop("conv_kernel")   # the reference's key: no option of the program
-    assert taps == decoder_lm.CONV_TAPS == CONF["conv_L_cache"]
+    # the taps: the published length, which is the field's default (the
+    # configuration's argv does not name it)
+    assert cut["conv_kernel"] == dc.conv_kernel == CONF["conv_L_cache"] == 3
+    assert "--conv_kernel" not in CONF["argv"]
     assert {k: (list(getattr(dc, k)) if isinstance(v, list) else getattr(dc, k))
             for k, v in cut.items()} == cut
     assert (dc.qk_norm, dc.tied_embeddings, dc.router, dc.router_tap) == (
