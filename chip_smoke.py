@@ -15,7 +15,8 @@ batch 128, uint8 input wire; weights random from a seed):
            bank) answering a few HTTP POSTs
   kernels  each Pallas kernel family once at real shapes, forward and
            backward, compiled (the lowered program holds the Mosaic custom
-           call) and compared with the repo's jax.numpy references
+           call) and compared with the repo's jax.numpy references (the
+           delta rule's kernels: with the same chunks in plain XLA)
 
 `--chips 4` runs ONLY the data-parallel arm and what it is compared with:
 ResNet-50 DP=4 at global batch 512 (ZeRO-1 auto-on, bf16 gradient wire), then
@@ -33,6 +34,7 @@ The last stdout line is the contract's JSON object and nothing else.
 from __future__ import annotations
 
 import argparse
+import functools
 import glob
 import json
 import math
@@ -56,7 +58,9 @@ BUDGET_S = 1140.0                                  # the contract allows 1200
 
 # Tolerances, stated here once.
 # Kernels vs jax.numpy references on bf16 operands with f32 accumulation:
-# max|a-b| / max|b| per compared array.
+# max|a-b| / max|b| per compared array (the delta rule's kernels against the
+# same chunks in plain XLA, neither the other's reference: 5.7e-3 at most, on
+# dg, on the chip in PR 43).
 KERNEL_REL_TOL = 2e-2
 # DP=4 (bf16 gradient wire, sharded BN reductions) vs one chip (no wire), same
 # global batch/seed: |loss_dp4 - loss_1| per step. Step 0 differs only by
@@ -550,6 +554,34 @@ def kernels_child(platform: str, tiny: bool) -> None:
             lambda q, k, v, c_=causal: fa.flash_attention(q, k, v, causal=c_),
             lambda q, k, v, c_=causal: attention(q, k, v, causal=c_),
             args, 3))
+    # Kimi delta attention's recurrence (ops/kda.py) at the width of
+    # `ling3_ep64_8k`'s layers: the three kernels against the same chunks in
+    # plain XLA (`_grouped`, the path every narrower head takes), both over
+    # operands of `dt`
+    kda = importlib.import_module(f"{PKG}.ops.kda")
+    b, t, h, d = (1, 128, 2, 128) if tiny else (1, 8192, 32, 128)
+    kq, kk, kv, kf, kb, kg, key = jax.random.split(key, 7)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    args = ((unit(jax.random.normal(kq, (b, t, h, d))) * d ** -0.5).astype(dt),
+            unit(jax.random.normal(kk, (b, t, h, d))).astype(dt),
+            jax.random.normal(kv, (b, t, h, d), dt),
+            kda.LOWER_BOUND * jax.nn.sigmoid(
+                2.0 * jax.random.normal(kf, (b, t, h, d)) - 2.0),
+            jax.nn.sigmoid(jax.random.normal(kb, (b, t, h))),
+            jax.random.normal(kg, (b, t, h, d)))
+    name = f"kda {(b, t, h, d)} {jnp.dtype(dt).name}"
+    if not kda.takes_kernel(t, d, d):
+        print(f"[kernels] {name}: FAIL these shapes would take plain XLA",
+              flush=True)
+        cases.append({"name": name, "mosaic_calls": 0, "ok": False,
+                      "error": "falls through to plain XLA"})
+    else:
+        cases.append(run_case(
+            name, functools.partial(kda.kda_chunked, dtype=dt),
+            functools.partial(kda._grouped, dtype=dt), args, 5))
     peak = (dev.memory_stats() or {}).get("peak_bytes_in_use", "n/a")
     print(f"[kernels] peak_bytes_in_use={peak}", flush=True)
     print("KERNELS_JSON " + json.dumps({"device": device, "cases": cases}),

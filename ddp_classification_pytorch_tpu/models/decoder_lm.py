@@ -44,8 +44,9 @@ With x (B, T, C), every projection without bias:
                                              over the heads, W_o (one scale
                                              of d for all heads, one gate a
                                              head); no rotary embedding;
-                                           in chunks of 64 tokens, 8 heads
-                                           at a time (ops/kda.py's constants)
+                                           in chunks of 64 tokens (ops/kda.py:
+                                           its kernels at 128-wide heads, else
+                                           plain XLA, 8 heads at a time)
          the others: attention(h) W_o      "gqa": H query heads on H_kv KV
                                            heads of head_dim; with qk_norm an
                                            RMSNorm (one scale of head_dim for
@@ -133,7 +134,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..config import DecoderConfig
 from ..ops.attention import attention, flash_supported
 from ..ops.flash_attention import backward_path, flash_attention
-from ..ops.kda import LOWER_BOUND, kda_chunked
+from ..ops.kda import LOWER_BOUND, kda_chunked, takes_kernel
 from ..ops.moe import GATE_ACTIVATIONS, sparse_moe
 
 
@@ -233,6 +234,15 @@ def flash_backward_path(cfg: DecoderConfig, dtype,
     return backward_path(cfg.seq_len, widths, jnp.dtype(dtype).itemsize)
 
 
+def kda_core_path(cfg: DecoderConfig) -> Optional[str]:
+    """What the delta layers' recurrence runs as at the configured sizes
+    ("kernel" | "xla", ops/kda.py::takes_kernel on what `_kda` hands over);
+    None where no layer is one."""
+    if all(op != "kda" for op, _ in cfg.layer_kinds()):
+        return None
+    return "kernel" if takes_kernel(cfg.seq_len, cfg.head_dim, cfg.head_dim) else "xla"
+
+
 class DecoderLayer(nn.Module):
     cfg: DecoderConfig
     rope: bool
@@ -320,8 +330,9 @@ class DecoderLayer(nn.Module):
         """Kimi delta attention's block: h (B, T, C) → (B, T, C). q, k, v
         through a depthwise causal convolution of `conv_kernel` taps and
         SiLU, q and k L2-normed per head, the per-channel log decay g and β
-        from h, the recurrence in chunks (ops/kda.py), a per-head RMSNorm
-        gated by one sigmoid a head, W_o; no rotary embedding. Plain XLA."""
+        from h, the recurrence in chunks (ops/kda.py: its kernels where the
+        head's tiles are whole, else plain XLA), a per-head RMSNorm gated by
+        one sigmoid a head, W_o; no rotary embedding."""
         c = self.cfg
         b, t, dim = h.shape
         heads, hd, taps = c.num_heads, c.head_dim, c.conv_kernel

@@ -524,21 +524,27 @@ class Trainer:
         """The layout the decoder was built with: how many of its layers mix
         tokens by which operator before which feed-forward — a static
         counter, and the same counts beside `init_state` in the set-up line,
-        with the path the attention kernels' backward takes at these sizes."""
-        from ..models.decoder_lm import flash_backward_path
+        with the path the attention kernels' backward takes at these sizes
+        and whether the delta layers' recurrence takes its kernels."""
+        from ..models.decoder_lm import flash_backward_path, kda_core_path
 
-        kinds = collections.Counter(self.cfg.model.decoder.layer_kinds())
+        dc = self.cfg.model.decoder
+        kinds = collections.Counter(dc.layer_kinds())
         for (operator, ffn), n in sorted(kinds.items()):
             self.obs.counter("decoder_layers_total", "layers of the token "
                              "decoder by token mixer and feed-forward",
                              {"operator": operator, "ffn": ffn}).inc(n)
             spans.note(**{f"{operator}_{ffn}": n})
-        path = flash_backward_path(self.cfg.model.decoder, self.cfg.model.dtype,
+        path = flash_backward_path(dc, self.cfg.model.dtype,
                                    self.cfg.model.flash_min_tokens)
         if path:
             # what `flash_backward_total{path}` will count once the step is
             # traced (ops/flash_attention.py), known here from the sizes
             spans.note(flash_backward=path)
+        core = kda_core_path(dc)
+        if core:
+            # the predicate `ops/kda.py::kda_chunked` dispatches on
+            spans.note(kda_core=core)
 
     def _publish_moe_load(self, load: np.ndarray) -> None:
         """The logged step's routing, as the step's metrics carry it —

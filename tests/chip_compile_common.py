@@ -63,14 +63,17 @@ def one_chip(topo):
 
 @pytest.fixture
 def kernels(monkeypatch):
-    """Both kernel modules with interpret mode steered off (ops/__init__
-    re-exports a function named like the flash module, hence importlib)."""
+    """The kernel modules with interpret mode steered off (ops/__init__
+    re-exports a function named like the flash module, hence importlib);
+    returns the first two, the delta rule's (ops/kda.py) is steered only."""
     pk = importlib.import_module(
         "ddp_classification_pytorch_tpu.ops.pallas_kernels")
     fa = importlib.import_module(
         "ddp_classification_pytorch_tpu.ops.flash_attention")
+    kda = importlib.import_module("ddp_classification_pytorch_tpu.ops.kda")
     monkeypatch.setattr(pk, "_interpret", lambda: False)
     monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
     return pk, fa
 
 

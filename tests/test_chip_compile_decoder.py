@@ -82,3 +82,9 @@ def test_train_step_fits_one_chip(topo, kernels, config, parameters,
     assert "ragged-dot" in text
     assert text.count("tpu_custom_call") >= 2 * attention_blocks
     assert "flash_dkvq" in text and "flash_dq" not in text
+    # a delta layer's recurrence is three more: the forward walk, and in the
+    # backward the walk that keeps the chunks' states and the reverse walk
+    delta = sum(cfg.model.decoder.layout(cfg.model.decoder.kda_layout))
+    for name in ("kda_fwd", "kda_states", "kda_bwd"):
+        assert (name in text) == bool(delta), name
+    assert text.count("tpu_custom_call") >= 2 * attention_blocks + 3 * delta

@@ -32,7 +32,8 @@ PHASE_SAYS = {
               "dataplane: native", "compile_s="),
     "serve": ("serve_cold:", "warm boot hit the AOT bank",
               "POST /predict answered", "compile_s="),
-    "kernels": ("[kernels] bn_leaky_relu", "[kernels] flash_attention"),
+    "kernels": ("[kernels] bn_leaky_relu", "[kernels] flash_attention",
+                "[kernels] kda (1, 128, 2, 128) float32"),
 }
 
 

@@ -77,18 +77,18 @@ def reference_grad():
     return jax.jit(jax.value_and_grad(ref.loss_for(ARCH)))
 
 
-def mixer_inputs(t, heads=2, d=8, seed=0, lower=-5.0, shift=-2.0):
+def mixer_inputs(t, heads=2, d=8, seed=0, lower=-5.0, shift=-2.0, rows=2):
     """q, k (unit length), v, the log decay g in (lower, 0) and beta."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
 
     def unit(x):
         return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
 
-    q = unit(jax.random.normal(ks[0], (2, t, heads, d))) * d ** -0.5
-    k = unit(jax.random.normal(ks[1], (2, t, heads, d)))
-    v = jax.random.normal(ks[2], (2, t, heads, d))
-    g = lower * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], (2, t, heads, d)) + shift)
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (2, t, heads)))
+    q = unit(jax.random.normal(ks[0], (rows, t, heads, d))) * d ** -0.5
+    k = unit(jax.random.normal(ks[1], (rows, t, heads, d)))
+    v = jax.random.normal(ks[2], (rows, t, heads, d))
+    g = lower * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], (rows, t, heads, d)) + shift)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (rows, t, heads)))
     return q, k, v, g, beta
 
 
@@ -134,12 +134,15 @@ def test_chunked_op_is_the_token_by_token_recurrence_forward_and_every_gradient(
     assert float(jnp.abs(reach[:, 8]).max()) > 0 == float(jnp.abs(reach[:, 41:]).max())
 
 
+@pytest.mark.parametrize("d", [8, 128], ids=["xla", "kernels"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
-def test_the_lower_bound_on_every_channel_for_a_whole_chunk_stays_finite(dtype):
+def test_the_lower_bound_on_every_channel_for_a_whole_chunk_stays_finite(dtype, d):
     """g = -5 everywhere: a chunk's cumulative decay is exp(-320), under
     float32's range; the sub-chunks keep every factor inside it, forward and
-    backward, and the result is still the recurrence's."""
-    q, k, v, g, beta = mixer_inputs(64, seed=1)
+    backward, in plain XLA and inside the kernels, and the result is still
+    the recurrence's."""
+    q, k, v, g, beta = mixer_inputs(64, d=d, seed=1, rows=1)
+    assert kda.takes_kernel(64, d, d) == (d == 128)
     g = jnp.full_like(g, -5.0)
 
     def f(*a):
@@ -150,7 +153,10 @@ def test_the_lower_bound_on_every_channel_for_a_whole_chunk_stays_finite(dtype):
         q, k, v, g, beta)
     assert all(bool(jnp.isfinite(x).all()) for x in (out, *grads))
     assert float(jnp.abs(grads[3]).max()) > 0
-    tol = 2e-4 if dtype == jnp.float32 else 3e-2
+    # a sub-chunk's last row stands at exp(-80) against its own column: of a
+    # unit k over 128 channels the smallest leave float32's normal range there
+    # (2.7e-4 of 4.3e-2, in `_chunked` and in the kernels alike)
+    tol = (2e-4 if d == 8 else 5e-4) if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(out, ref.kda_recurrence(q, k, v, g, beta),
                                rtol=tol, atol=tol)
 
@@ -185,6 +191,115 @@ def test_the_heads_go_eight_at_a_time_or_the_most_that_divides_them(heads, steps
     lengths = re.findall(r"length=(\d+)", str(jax.make_jaxpr(kda_chunked)(*args)))
     # the chunks' own scan (one chunk here), inside the map's where there is one
     assert lengths == ["1"] + [str(steps)] * bool(steps)
+
+
+# (a') the kernels (interpret mode here, as the flash kernels' tests) ----------
+
+@functools.lru_cache(maxsize=None)
+def kernels_at(t, dtype):
+    """(inputs, weight, (o, five gradients)) of the kernel path at 128-wide
+    heads: 1 row of `t` tokens, 2 heads."""
+    args = mixer_inputs(t, d=128, seed=t, rows=1)
+    weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    assert kda.takes_kernel(t, 128, 128)
+    return args, weight, jax.jit(out_and_grads(
+        functools.partial(kda_chunked, dtype=jnp.dtype(dtype)), weight))(*args)
+
+
+def out_and_grads(f, weight):
+    return lambda *a: (f(*a), jax.grad(lambda *b: jnp.sum(weight * f(*b)),
+                                       argnums=(0, 1, 2, 3, 4))(*a))
+
+
+def worst(got, want):
+    """The largest gap of (o, gradients), each over its own largest entry."""
+    got, want = [got[0], *got[1]], [want[0], *want[1]]
+    return max(float(jnp.abs(a - b).max() / jnp.abs(b).max()) for a, b in zip(got, want))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t", [64, 128, 256])
+def test_kernels_are_the_token_by_token_recurrence_forward_and_every_gradient(t, dtype):
+    """One chunk, two and four: the state leaves VMEM for no chunk boundary,
+    the reverse walk carries dS back over them. float32 operands: the order
+    of the sums is what is left; bf16 operands: their rounding."""
+    args, weight, got = kernels_at(t, dtype)
+    want = jax.jit(out_and_grads(ref.kda_recurrence, weight))(*args)
+    assert all(bool(jnp.isfinite(x).all()) for x in (got[0], *got[1]))
+    assert worst(got, want) < (5e-5 if dtype == "float32" else 2e-2)
+    assert got[0].dtype == jnp.float32 and [x.dtype for x in got[1]] == [
+        x.dtype for x in args]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_kernels_and_the_chunks_in_plain_xla_agree(dtype):
+    """`_chunked`, the path every other shape takes, is the kernels' second
+    oracle: the same arithmetic at the same operand dtypes, so in float32 they
+    differ by the order of the sums, in bf16 by roundings of the same size as
+    either's distance from the recurrence."""
+    args, weight, got = kernels_at(128, dtype)
+    xla = jax.jit(out_and_grads(functools.partial(
+        kda._grouped, dtype=jnp.dtype(dtype)), weight))(*args)
+    assert worst(got, xla) < (5e-5 if dtype == "float32" else 2e-2)
+    # dg sums differences of products: without the references' own gradient
+    # the kernels' lies half again as far from the recurrence's as the chunks'
+    # in XLA (1.7 % | 1.2 %), with it nearer (0.8 %)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(weight * ref.kda_recurrence(*a)),
+                            argnums=3))(*args)
+    assert (float(jnp.linalg.norm(got[1][3] - want))
+            <= float(jnp.linalg.norm(xla[1][3] - want)) or dtype == "float32")
+
+
+@pytest.mark.parametrize("t,dk,dv,chunk,kernel", [
+    (8192, 128, 128, 64, True), (64, 128, 128, 64, True), (256, 256, 128, 64, True),
+    (128, 8, 8, 64, False),       # the tests' heads
+    (128, 16, 16, 64, False),     # the rehearsal arch's
+    (128, 64, 128, 64, False), (128, 128, 64, 64, False),
+    (48, 128, 128, 64, False),    # one shorter chunk
+    (128, 128, 128, 32, False),   # another chunk length: the tests' own
+], ids=str)
+def test_the_shapes_decide_which_rows_take_the_kernels(t, dk, dv, chunk, kernel):
+    assert kda.takes_kernel(t, dk, dv, chunk) == kernel
+    args = [jax.ShapeDtypeStruct((1, t, 2, d), jnp.float32) for d in (dk, dk, dv, dk)] + [
+        jax.ShapeDtypeStruct((1, t, 2), jnp.float32)]
+    text = str(jax.make_jaxpr(functools.partial(kda_chunked, chunk=chunk))(*args))
+    assert ("name=kda_fwd" in text) == kernel == ("scan" not in text)
+
+
+@pytest.mark.parametrize("t", [72, 8200])
+def test_a_row_that_is_not_whole_chunks_is_refused_at_128_wide_heads_too(t):
+    with pytest.raises(ValueError, match="chunk"):
+        kda.takes_kernel(t, 128, 128)
+    with pytest.raises(ValueError, match="chunk"):
+        kda_chunked(*mixer_inputs(t, d=128, rows=1))
+
+
+def test_the_kernels_backward_keeps_the_five_inputs_and_nothing_else():
+    """What `jax.checkpoint` keeps of `_chunked`: no state, no chunk matrix."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    args = mixer_inputs(128, d=128, rows=1)
+    kept = saved_residuals(kda_chunked, *args)
+    assert [(a.shape, why) for a, why in kept] == [
+        (x.shape, f"from the argument {name}")
+        for name, x in zip(("q", "k", "v", "g", "beta"), args)], kept
+
+
+def test_under_remat_a_layer_walks_the_row_once_forward_and_twice_backward():
+    """`--remat` keeps `kda_out` by name (DecoderLM.setup), so the layer's
+    recomputed forward does not hold the forward kernel again (PR 42: 65 ms a
+    step): the gradient's program has `kda_fwd` once (the forward pass),
+    `kda_states` once and `kda_bwd` once."""
+    arch = dict(ARCH, num_layers=1, dense_layers=1, kda_layout=[1], num_heads=1,
+                head_dim=128, seq_len=64)
+    _, loss_fn, _ = program(arch, "--remat", "--num_kv_heads", "1")
+    params = jax.eval_shape(lambda: program_tree(
+        common.make_params(ref.param_spec(arch), 0)))
+    tokens = jax.ShapeDtypeStruct((1, 64), jnp.int32)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p, x, y: loss_fn(p, {}, x, y, None)[0]))(params, tokens, tokens))
+    assert re.findall(r"name=(kda_fwd|kda_states|kda_bwd)\b", text) == [
+        "kda_fwd", "kda_states", "kda_bwd"]
 
 
 # (b) the program against the reference ---------------------------------------
@@ -434,6 +549,7 @@ def test_delta_decoder_trains_through_cli_train_and_publishes_its_layout(
     out = capsys.readouterr().out
     setup = next(line for line in out.splitlines() if "[trainer] set-up:" in line)
     assert "kda_dense=1 kda_routed=1 mla_routed=1" in setup, setup
+    assert "kda_core=xla" in setup, setup      # 16-wide heads: no kernel
 
 
 # the two readers ---------------------------------------------------------------
