@@ -94,7 +94,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "attention, dense / routed / shared feed-forward, "
                         "softmax or sigmoid router with or without a group "
                         "limit, a multi-token-prediction module, a tied or "
-                        "untied head; defaults = the published "
+                        "untied head, the stack run --loops times with an "
+                        "exit gate; defaults = the published "
                         "SmallThinker-21BA3B-Instruct)")
     m.add_argument("--flash_attention", action="store_true",
                    help="ViT: Pallas streaming attention kernel for the "
@@ -156,7 +157,8 @@ def build_parser() -> argparse.ArgumentParser:
                        ("router_eps", float),
                        ("router_tap", str), ("shared_experts", int),
                        ("mtp_layers", int), ("mtp_weight", float),
-                       ("tied_embeddings", int)):
+                       ("tied_embeddings", int), ("loops", int),
+                       ("sandwich_norm", int), ("exit_beta", float)):
         dec.add_argument(f"--{flag}", type=kind, default=None)
     for flag in ("rope_layout", "window_layout", "conv_layout", "kda_layout"):
         dec.add_argument(f"--{flag}", default=None,

@@ -81,9 +81,13 @@ class DecoderConfig:
     then routed experts with or without a shared expert), the router's
     scoring, group limit and tap, the activation, the rotary pairing, a head
     of its own or tied to the embedding, and a multi-token-prediction module
-    after the last layer. Four published layers are its fixed points:
-    SmallThinker's, the DeepSeek-V3 layer as JoyAI-LLM-Flash configures it,
-    LFM2-8B-A1B's, and Ling-3.0-flash's (inclusionAI, `bailing_hybrid`).
+    after the last layer. `loops` > 1 runs the whole stack that many times on
+    its own output with the same weights (a looped language model,
+    arXiv:2510.25741: `sandwich_norm`, an exit gate, a loss weighted over the
+    passes). Five published models are its fixed points: SmallThinker's, the
+    DeepSeek-V3 layer as JoyAI-LLM-Flash configures it, LFM2-8B-A1B's,
+    Ling-3.0-flash's (inclusionAI, `bailing_hybrid`) and Ouro-2.6B
+    (ByteDance, `ouro`).
 
     A deployment that spreads a layer's experts and the vocabulary's rows
     over several chips gives each chip its share: `experts_held` experts
@@ -169,6 +173,19 @@ class DecoderConfig:
     # 1: the head is the embedding transposed (no `lm_head` leaf; the
     # table's gradient sums the lookup's scatter-add and the head's matmul)
     tied_embeddings: int = 0
+    # a looped model (Ouro, `total_ut_steps`): the stack of `num_layers`
+    # layers runs `loops` times with the same weights, the final norm closes
+    # every pass and its output is what the next pass starts from; the head
+    # and a one-unit exit gate (leaf `exit_gate`) read every pass, and the
+    # training loss is the passes' cross-entropies weighted by the gate's
+    # exit distribution p, less `exit_beta` x the entropy of p
+    # (train/steps.py::_lm_loss). Evaluation and the step's top-k counts read
+    # the last pass. Dense layers only, no prediction module
+    loops: int = 1
+    # 1: a second RMSNorm on each sub-layer's OUTPUT before the residual add
+    # (`norm_mix_out`, `norm_ffn_out`), beside the one on its input
+    sandwich_norm: int = 0
+    exit_beta: float = 0.05          # read only where loops > 1
 
     @property
     def held(self) -> int:

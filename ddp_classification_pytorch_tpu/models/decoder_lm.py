@@ -2,13 +2,15 @@
 position over the vocabulary.
 
 ONE layer module, described by data (`config.DecoderConfig`, which the CLI
-fills — no table of variants, no second model file). Four published layers
+fills — no table of variants, no second model file). Five published models
 are its fixed points: SmallThinker-21BA3B-Instruct (PowerInfer,
 arXiv:2507.20984; the defaults), the DeepSeek-V3 layer (arXiv:2412.19437
 §2.1-2.2) as JoyAI-LLM-Flash configures it, LFM2-8B-A1B (LiquidAI,
 `lfm2_moe`: most layers mix tokens by a gated short convolution and not by
-attention) and Ling-3.0-flash (inclusionAI, `bailing_hybrid`: most layers
-carry a state along the row by Kimi delta attention, arXiv:2510.26692 §3).
+attention), Ling-3.0-flash (inclusionAI, `bailing_hybrid`: most layers
+carry a state along the row by Kimi delta attention, arXiv:2510.26692 §3)
+and Ouro-2.6B (ByteDance, `ouro`, arXiv:2510.25741: the whole stack runs
+`loops` times with the same weights, below).
 With x (B, T, C), every projection without bias:
 
     h  = RMSNorm(x)                        input norm
@@ -70,12 +72,12 @@ With x (B, T, C), every projection without bias:
                                            with out_gate each head's output
                                            times sigmoid(h w_g), one gate a
                                            head, before W_o
-    x1 = x + a
+    x1 = x + a                             with sandwich_norm: x + RMSNorm(a)
     u  = RMSNorm(x1)
     y  = layers < dense_layers: W_down(act(W_gate u) · W_up u), one gated MLP
          the others: Σ_{e ∈ chosen} g_e · W_down^e(act(W_gate^e u) · W_up^e u)
                      + (shared_experts > 0) the same unit on every token
-    x2 = x1 + y
+    x2 = x1 + y                            with sandwich_norm: x1 + RMSNorm(y)
 
 The router's logits r = t W_r are taken from t = h (router_tap "pre": before
 attention, as SmallThinker places it) or t = u ("post"); "softmax" scoring
@@ -101,6 +103,24 @@ output before the final norm and `targets` the row shifted by one,
 
 (the step, train/steps.py::_lm_loss, adds mtp_weight x that loss).
 
+With loops = R > 1 (Ouro's `total_ut_steps`) the stack is walked R times,
+the SAME leaves at every pass, positions 0..T−1 in every pass:
+
+    h(0) = Emb(tokens)
+    h(t) = RMSNorm_f(layers(h(t−1)))       t = 1..R: the final norm closes
+                                           every pass, and its output is what
+                                           the head and the gate read AND
+                                           what pass t + 1 starts from
+    λ(t) = sigmoid(h(t) · w_g + b_g)       the exit gate, one unit a token
+    S(0) = 1,  S(t) = S(t−1) (1 − λ(t))
+    p(t) = λ(t) S(t−1) for t < R,  p(R) = S(R−1)        Σ_t p(t) = 1
+
+`hidden` hands on all R normed states; the step (`_lm_loss`) minimises
+mean[Σ_t p(t) CE(h(t) W_head, target) − exit_beta · H(p)] with the gradient
+through p too; logits, evaluation and the top-k counts are pass R's. The
+passes are ONE `lax.scan` whose body is the stack (`LOOP_TRACED`): the program
+holds the layers once, --remat's saved names stack over the passes.
+
 This chip may hold a share of each layer (`experts_held`, `first_expert`,
 a slice of the vocabulary): the router keeps its full width, the expert
 layer (ops/moe.py::sparse_moe) computes its own experts' part, and under a
@@ -118,7 +138,8 @@ Device scopes (`jax.named_scope`, docs/observability.md): `attn`, `conv`
 (with `conv.in`, `conv.mix`, `conv.out` inside it), `kda` (with `kda.in`,
 `kda.core`, `kda.out` inside it), `ffn`,
 `moe.route` / `.dispatch` / `.experts` / `.combine` / `.shared`, `mtp`
-(outermost, around the whole module), `lm_head`.
+(outermost, around the whole module), `lm_head`, `loop` (outermost, around
+the R passes of a looped stack).
 """
 
 from __future__ import annotations
@@ -185,6 +206,25 @@ def head_kernel(params, cfg: DecoderConfig) -> jnp.ndarray:
     if cfg.tied_embeddings:
         return params["embed"]["embedding"].T
     return params["lm_head"]["kernel"]
+
+
+# how the passes of a looped stack are traced: "scan" = one `lax.scan` over
+# the passes whose body is the stack, the parameters broadcast (`_looped`)
+LOOP_TRACED = "scan"
+
+
+def exit_distribution(gate, states: jnp.ndarray) -> jnp.ndarray:
+    """The exit gate's distribution over the passes: `gate` the `exit_gate`
+    leaves (kernel (C, 1), bias (1,)), `states` (R, ..., C) the normed states
+    of the R passes → p (R, ...) f32, Σ over R = 1: p(t) = λ(t) Π_{s<t} (1 −
+    λ(s)) with λ = sigmoid(h · w + b), and the last pass takes what is left."""
+    lam = jax.nn.sigmoid(
+        jnp.einsum("r...c,c->r...", states.astype(jnp.float32),
+                   gate["kernel"][:, 0], precision=jax.lax.Precision.HIGHEST)
+        + gate["bias"][0])
+    stay = jnp.cumprod(1.0 - lam, axis=0)           # S(1..R)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])   # S(0..R−1)
+    return jnp.concatenate([(lam * before)[:-1], before[-1:]])
 
 
 def _logits(h: jnp.ndarray, kernel: jnp.ndarray, dtype) -> jnp.ndarray:
@@ -401,15 +441,22 @@ class DecoderLayer(nn.Module):
         h32 = RMSNorm(c.rms_eps, name="norm_in")(x)
         if self.routed and c.router_tap == "pre":
             logits = self._router_logits(h32)
+        def out_norm(y, name):
+            # the sandwich: a second norm, on the sub-layer's output
+            if not c.sandwich_norm:
+                return y
+            return RMSNorm(c.rms_eps, name=name)(y).astype(self.dtype)
+
         with jax.named_scope(self.mixer):
             mix = {"attn": self._attention, "conv": self._short_conv,
                    "kda": self._kda}[self.mixer]
-            x = x + mix(h32.astype(self.dtype))
+            x = x + out_norm(mix(h32.astype(self.dtype)), "norm_mix_out")
         u32 = RMSNorm(c.rms_eps, name="norm_post")(x)
         u = u32.astype(self.dtype)
         if not self.routed:
             with jax.named_scope("ffn"):
-                return x + self._gated_mlp(u, c.dense_width, "ffn").astype(x.dtype), None
+                y = out_norm(self._gated_mlp(u, c.dense_width, "ffn"), "norm_ffn_out")
+                return x + y.astype(x.dtype), None
         if c.router_tap != "pre":
             logits = self._router_logits(u32)
         route = None
@@ -443,7 +490,7 @@ class DecoderLayer(nn.Module):
             with jax.named_scope("moe.shared"):
                 y = y + self._gated_mlp(
                     u, c.shared_experts * c.expert_width, "shared")
-        return x + y.astype(x.dtype), load
+        return x + out_norm(y, "norm_ffn_out").astype(x.dtype), load
 
 
 class MTPModule(nn.Module):
@@ -467,11 +514,22 @@ class MTPModule(nn.Module):
         return RMSNorm(self.eps, name="norm_final")(x).astype(self.dtype), load
 
 
+def _one_pass(mdl: "DecoderLM", x: jnp.ndarray, _):
+    """One pass of a looped stack, the body of `_looped`'s scan: the layers,
+    then the final norm → (what the next pass starts from, what the head and
+    the gate read): the same normed states."""
+    for layer in mdl.layers:
+        x = layer(x)[0]
+    out = mdl.norm_final(x).astype(mdl.dtype)
+    return out, out
+
+
 class DecoderLM(nn.Module):
     """tokens (B, T) i32 → logits (B, T, V) f32; `hidden` → the final-normed
-    states (B, T, C) and the token-slot loads of the held experts, one row a
-    routing layer (`DecoderConfig.moe_layer_names`) — what the row-blocked
-    head and the step's metrics take."""
+    states (B, T, C) — of a looped stack (R, B, T, C), one set a pass — and
+    the token-slot loads of the held experts, one row a routing layer
+    (`DecoderConfig.moe_layer_names`) — what the row-blocked head and the
+    step's metrics take."""
 
     cfg: DecoderConfig
     dtype: Any = jnp.bfloat16
@@ -512,6 +570,20 @@ class DecoderLM(nn.Module):
             # its layer continues the layouts: index = the depth
             self.mtp = MTPModule(functools.partial(build, c.num_layers),
                                  c.rms_eps, self.dtype, name="mtp")
+        if c.loops > 1:
+            # read by the step (`exit_distribution`), from the leaves
+            self.exit_gate = nn.Dense(1, name="exit_gate")
+
+    def _looped(self, x: jnp.ndarray) -> jnp.ndarray:
+        """x (B, T, C) → the R passes' normed states (R, B, T, C): one scan
+        over the passes, the parameters broadcast (the same leaves at every
+        pass; their gradient sums over the passes)."""
+        if self.is_initializing():   # one plain pass makes every leaf
+            return jnp.stack([_one_pass(self, x, None)[1]] * self.cfg.loops)
+        with jax.named_scope("loop"):
+            return nn.scan(_one_pass, variable_broadcast="params",
+                           split_rngs={"params": False},
+                           length=self.cfg.loops)(self, x, None)[1]
 
     def hidden(self, tokens: jnp.ndarray, train: bool = True,
                targets: Optional[jnp.ndarray] = None):
@@ -519,6 +591,8 @@ class DecoderLM(nn.Module):
         prediction module also its states: (h, loads, h_mtp), the module's
         loads in the last row."""
         x = self.embed(tokens).astype(self.dtype)
+        if self.cfg.loops > 1:   # dense layers only: no loads
+            return self._looped(x), jnp.zeros((0, self.cfg.held), jnp.int32)
         loads = []
         for layer in self.layers:
             x, load = layer(x)
@@ -537,6 +611,10 @@ class DecoderLM(nn.Module):
         # init has to reach the prediction module's leaves too
         targets = tokens if self.is_initializing() else None
         h = self.hidden(tokens, train, targets)[0]
+        if self.cfg.loops > 1:
+            h = h[-1]   # the last pass is the one served
+            if self.is_initializing():
+                self.exit_gate(h)
         with jax.named_scope("lm_head"):
             if self.cfg.tied_embeddings:
                 return _logits(h, self.embed.embedding.T, self.dtype)

@@ -141,6 +141,12 @@ def build_decoder_lm(cfg: ModelConfig, num_classes: int,
     if dc.mtp_layers not in (0, 1):
         raise ValueError("multi-token prediction is built at depth 0 or 1, "
                          f"got mtp_layers={dc.mtp_layers}")
+    if dc.loops < 1 or (dc.loops > 1 and (dc.mtp_layers
+                                          or dc.dense_layers != dc.num_layers)):
+        raise ValueError(
+            f"--loops {dc.loops}: the stack runs 1 or more times, and a "
+            "looped stack is built of dense layers (--dense_layers = "
+            "--num_layers) without a prediction module")
     mp = mesh.shape.get(MODEL_AXIS, 1) if mesh is not None else 1
     return DecoderLM(dc, dtype=jnp.dtype(cfg.dtype), remat=cfg.remat,
                      mesh=mesh if mp > 1 else None,

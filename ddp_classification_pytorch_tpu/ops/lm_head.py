@@ -24,8 +24,11 @@ def blocked_cross_entropy(h: jnp.ndarray, kernel: jnp.ndarray,
     """h (N, C) rows, kernel (C, V), targets (N,) → (Σ cross-entropy, count
     of rows whose target is the largest logit, count within the largest 3),
     all f32. `weights` (N,) scales each row's three contributions (0/1 for
-    the loader's wrap-padding). The matmul runs in `dtype` with f32
-    accumulation; softmax and the loss are f32."""
+    the loader's wrap-padding); `weights` (N, K) gives K sets of sums, each
+    of the three (K,), one a column (a looped decoder: the exit distribution
+    beside the passes' indicators). The weights may be traced and
+    differentiated: their gradient is the row's cross-entropy. The matmul
+    runs in `dtype` with f32 accumulation; softmax and the loss are f32."""
     n, c = h.shape
     block = min(block, n)
     if n % block:
@@ -40,15 +43,20 @@ def blocked_cross_entropy(h: jnp.ndarray, kernel: jnp.ndarray,
         at = jnp.take_along_axis(logits, tb[:, None], axis=-1)       # (b, 1)
         ce = jax.nn.logsumexp(logits, axis=-1) - at[:, 0]
         above = jnp.sum(logits > at, axis=-1)    # logits ranked over the target
-        return (jnp.sum(wb * ce), jnp.sum(wb * (above < 1)),
-                jnp.sum(wb * (above < 3)))
+
+        def total(x):   # Σ over the block's rows, per column of the weights
+            return (jnp.sum(wb * x) if wb.ndim == 1
+                    else jnp.sum(wb * x[:, None], axis=0))
+
+        return total(ce), total(above < 1), total(above < 3)
 
     def body(carry, xs):
         return jax.tree_util.tree_map(jnp.add, carry, one(*xs)), None
 
-    zero = jnp.zeros((), jnp.float32)
+    zero = jnp.zeros(weights.shape[1:], jnp.float32)
     sums, _ = jax.lax.scan(
         body, (zero, zero, zero),
         (h.reshape(n // block, block, c), targets.reshape(n // block, block),
-         weights.astype(jnp.float32).reshape(n // block, block)))
+         weights.astype(jnp.float32).reshape(n // block, block,
+                                             *weights.shape[1:])))
     return sums

@@ -525,8 +525,11 @@ class Trainer:
         tokens by which operator before which feed-forward — a static
         counter, and the same counts beside `init_state` in the set-up line,
         with the path the attention kernels' backward takes at these sizes
-        and whether the delta layers' recurrence takes its kernels."""
-        from ..models.decoder_lm import flash_backward_path, kda_core_path
+        and whether the delta layers' recurrence takes its kernels; of a
+        looped stack also how often a step applies a layer, and how its
+        passes are traced."""
+        from ..models.decoder_lm import (LOOP_TRACED, flash_backward_path,
+                                         kda_core_path)
 
         dc = self.cfg.model.decoder
         kinds = collections.Counter(dc.layer_kinds())
@@ -535,6 +538,12 @@ class Trainer:
                              "decoder by token mixer and feed-forward",
                              {"operator": operator, "ffn": ffn}).inc(n)
             spans.note(**{f"{operator}_{ffn}": n})
+        self.obs.counter("decoder_layer_applications_total", "layers a step "
+                         "runs: the layers built x the passes of the stack "
+                         "(--loops)").inc(dc.loops * dc.num_layers)
+        if dc.loops > 1:
+            spans.note(loops=dc.loops, sandwich=dc.sandwich_norm,
+                       passes=LOOP_TRACED)
         path = flash_backward_path(dc, self.cfg.model.dtype,
                                    self.cfg.model.flash_min_tokens)
         if path:
@@ -713,13 +722,16 @@ class Trainer:
                 last = {**train_m, **val_m, "epoch_time": time.time() - t0}
                 self._epochs_counter.inc()
                 self._loss_gauge.set(last.get("loss", 0.0))
-                for part in ("loss_main", "loss_mtp"):
-                    if part in last:  # a decoder with a prediction module
+                for part in sorted(last):
+                    # a decoder with a prediction module (train_loss =
+                    # loss_main + mtp_weight x loss_mtp), or a looped one
+                    # (train_loss = Σ_t exit_p<t> x loss_ut<t> over the
+                    # targets, less exit_beta x the entropy of p)
+                    if part.startswith(("loss_", "exit_p")):
                         self.obs.gauge(
                             f"train_{part}", "mean of the step's metric "
                             f"`{part}` over the last completed epoch "
-                            "(train_loss = loss_main + mtp_weight x loss_mtp)"
-                        ).set(last[part])
+                            "(docs/observability.md)").set(last[part])
                 if "val_top1" in last:
                     self._val_top1_gauge.set(last["val_top1"])
                 self._epoch_seconds_gauge.set(last["epoch_time"])

@@ -323,7 +323,9 @@ def _lm_sums(cfg: Config, model: Any, params, tokens, targets, train: bool,
     `mtp` (a configuration with a prediction module) a fifth value: the
     module's Σ cross-entropy against the token after next, through the same
     head and the same embedding, the row's last position weighted 0. A tied
-    model's head is its embedding transposed (`head_kernel`)."""
+    model's head is its embedding transposed (`head_kernel`). Of a looped
+    stack the LAST pass is read (what it serves; its training loss over all
+    the passes: `_exit_sums`)."""
     from ..models.decoder_lm import head_kernel
     from ..ops.lm_head import blocked_cross_entropy
 
@@ -338,6 +340,8 @@ def _lm_sums(cfg: Config, model: Any, params, tokens, targets, train: bool,
     hidden, load, *h_mtp = model.apply(
         {"params": params}, tokens, train=train, method="hidden",
         **({"targets": targets} if mtp else {}))
+    if cfg.model.decoder.loops > 1:
+        hidden = hidden[-1]
     with jax.named_scope("lm_head"):
         ce, t1, t3 = head_sums(hidden, targets, weights)
     if not mtp:
@@ -351,6 +355,35 @@ def _lm_sums(cfg: Config, model: Any, params, tokens, targets, train: bool,
     return ce, t1, t3, load, ce_mtp
 
 
+def _exit_sums(cfg: Config, model: Any, params, tokens, targets):
+    """A looped decoder's training sums over the N = B·T targets: with p
+    (R, N) the exit gate's distribution over the R passes
+    (models/decoder_lm.py::exit_distribution) → (Σ_t Σ_n p CE, Σ_n H(p), each
+    pass's unweighted Σ CE (R,), the last pass's top-1 and top-3 counts, each
+    pass's Σ p (R,)). The R x N rows go through the head ONCE, `weights` = p
+    beside the passes' indicators; p is differentiated (through the head's
+    sums: a row's cross-entropy; and through H), H(p) = −Σ_t p log max(p,
+    1e-9)."""
+    from ..models.decoder_lm import exit_distribution, head_kernel
+    from ..ops.lm_head import blocked_cross_entropy
+
+    dc = cfg.model.decoder
+    states, _ = model.apply({"params": params}, tokens, train=True,
+                            method="hidden")                 # (R, B, T, C)
+    r, n = states.shape[0], targets.size
+    with jax.named_scope("exit"):
+        p = exit_distribution(params["exit_gate"], states).reshape(r, n)
+        entropy = -jnp.sum(p * jnp.log(jnp.maximum(p, 1e-9)))
+        of_pass = jnp.repeat(jnp.eye(r, dtype=jnp.float32), n, axis=0)
+        with jax.named_scope("lm_head"):
+            ce, t1, t3 = blocked_cross_entropy(
+                states.reshape(r * n, -1), head_kernel(params, dc),
+                jnp.tile(targets.reshape(-1), r), dc.head_block,
+                jnp.dtype(cfg.model.dtype),
+                weights=jnp.concatenate([p.reshape(-1, 1), of_pass], axis=1))
+    return ce[0], entropy, ce[1:], t1[r], t3[r], p.sum(axis=1)
+
+
 def _lm_loss(cfg: Config, model: Any):
     """Loss/metrics pair of the token decoder (models/decoder_lm.py): mean
     next-token cross-entropy over every position, head and loss in row
@@ -360,11 +393,32 @@ def _lm_loss(cfg: Config, model: Any):
     loss_main + mtp_weight · loss_mtp, the second the mean over the T − 1
     positions that have a token after next; `loss` is the total and
     `loss_main` / `loss_mtp` stand beside it.
+    A looped stack (`decoder.loops` = R > 1) minimises the mean over the
+    targets of Σ_t p(t) CE(t) − exit_beta · H(p) (`_exit_sums`); `loss` is
+    that objective, `loss_ut1..R` each pass's unweighted mean cross-entropy,
+    `exit_p1..R` the mean of p(t); top-1 and top-3 are the last pass's.
     The step's metrics also carry `moe_load` (L, e): the token-slots each
     held expert took in each routing layer — the loop's gauges and the
     benchmark's imbalance metric read it; nothing in the step depends on it."""
     dc = cfg.model.decoder
     mtp = bool(dc.mtp_layers)
+
+    def looped_loss_fn(params, batch_stats, tokens, targets, rng):
+        ce, entropy, ce_ut, t1, t3, p = _exit_sums(cfg, model, params, tokens,
+                                                   targets)
+        n = targets.size
+        return ((ce - dc.exit_beta * entropy) / n,
+                (batch_stats, (t1, t3, ce_ut / n, p / n)))
+
+    def looped_metrics_fn(loss, aux, labels):
+        t1, t3, ce_ut, p = aux
+        return {"loss": loss,
+                **{f"loss_ut{i + 1}": ce_ut[i] for i in range(dc.loops)},
+                **{f"exit_p{i + 1}": p[i] for i in range(dc.loops)},
+                "top1": t1 / labels.size, "top3": t3 / labels.size}
+
+    if dc.loops > 1:
+        return looped_loss_fn, looped_metrics_fn
 
     def loss_fn(params, batch_stats, tokens, targets, rng):
         ce, t1, t3, load, *ce_mtp = _lm_sums(cfg, model, params, tokens,

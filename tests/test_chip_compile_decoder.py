@@ -47,6 +47,11 @@ CONFIGS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
     # decoder is, against what the chip's allocator hands out
     pytest.param("ling_3_0_flash.json", 822036416, 0.95 * 16_909_336_064, 1,
                  id="delta_decoder"),
+    # Ouro-2.6B (PR 45): four dense layers walked four times by ONE scan whose
+    # body is the stack (8 kernel calls in the text, 32 layer-kernel runs a
+    # step), both 49,152-row tables, 1 row of 8,192 tokens
+    pytest.param("ouro_2_6b.json", 406884353, 0.9 * HBM_BYTES, 4,
+                 id="looped_decoder"),
 ])
 def test_train_step_fits_one_chip(topo, kernels, config, parameters,
                                   byte_limit, attention_blocks):
@@ -79,12 +84,17 @@ def test_train_step_fits_one_chip(topo, kernels, config, parameters,
     assert m.alias_size_in_bytes >= 0.99 * m.output_size_in_bytes
     text = compiled.as_text()
     # each attention block is two kernels: forward, the fused backward
-    assert "ragged-dot" in text
+    dc = cfg.model.decoder
+    assert ("ragged-dot" in text) == (dc.dense_layers < dc.num_layers)
     assert text.count("tpu_custom_call") >= 2 * attention_blocks
     assert "flash_dkvq" in text and "flash_dq" not in text
     # a delta layer's recurrence is three more: the forward walk, and in the
     # backward the walk that keeps the chunks' states and the reverse walk
-    delta = sum(cfg.model.decoder.layout(cfg.model.decoder.kda_layout))
+    delta = sum(dc.layout(dc.kda_layout))
     for name in ("kda_fwd", "kda_states", "kda_bwd"):
         assert (name in text) == bool(delta), name
     assert text.count("tpu_custom_call") >= 2 * attention_blocks + 3 * delta
+    if dc.loops > 1:
+        # the passes are one loop: the program holds the stack once, not
+        # once a pass
+        assert text.count("tpu_custom_call") < 2 * attention_blocks * dc.loops
