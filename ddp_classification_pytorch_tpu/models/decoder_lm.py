@@ -48,7 +48,19 @@ With x (B, T, C), every projection without bias:
                                              head); no rotary embedding;
                                            in chunks of 64 tokens (ops/kda.py:
                                            its kernels at 128-wide heads, else
-                                           plain XLA, 8 heads at a time)
+                                           plain XLA, 8 heads at a time). At
+                                           128-wide heads and rows of whole
+                                           256-token blocks nothing between
+                                           the projections and W_o leaves
+                                           their layout, (B, T, H·d): taps,
+                                           SiLU, norms and decay are one
+                                           fused op (ops/kda_prepare.py), the
+                                           recurrence's kernels read and write
+                                           it (`kda_flat`), the gated norm is
+                                           one fused op (ops/
+                                           kda_gated_norm.py); any other shape:
+                                           `kda_prepare_xla` and `RMSNorm` over
+                                           (B, T, H, d), in plain XLA
          the others: attention(h) W_o      "gqa": H query heads on H_kv KV
                                            heads of head_dim; with qk_norm an
                                            RMSNorm (one scale of head_dim for
@@ -135,8 +147,11 @@ key: ops/flash_attention.py) wherever they tile T, else the dense op;
 row blocks (ops/lm_head.py).
 
 Device scopes (`jax.named_scope`, docs/observability.md): `attn`, `conv`
-(with `conv.in`, `conv.mix`, `conv.out` inside it), `kda` (with `kda.in`,
-`kda.core`, `kda.out` inside it), `ffn`,
+(with `conv.in`, `conv.mix`, `conv.out` inside it), `kda` (with `kda.in`:
+the five projections and the input side, the kernels `kda_prepare_fwd` /
+`kda_prepare_bwd` or plain XLA; `kda.core`: the recurrence, `kda_fwd` /
+`kda_states` / `kda_bwd` or plain XLA; `kda.out`: the gate, the per-head norm,
+`kda_gated_norm_fwd` / `kda_gated_norm_bwd` or plain XLA, and W_o), `ffn`,
 `moe.route` / `.dispatch` / `.experts` / `.combine` / `.shared`, `mtp`
 (outermost, around the whole module), `lm_head`, `loop` (outermost, around
 the R passes of a looped stack).
@@ -155,7 +170,9 @@ from jax.ad_checkpoint import checkpoint_name
 from ..config import DecoderConfig
 from ..ops.attention import attention, flash_supported
 from ..ops.flash_attention import backward_path, flash_attention
-from ..ops.kda import LOWER_BOUND, kda_chunked, takes_kernel
+from ..ops import kda_prepare
+from ..ops.kda import LOWER_BOUND, kda_chunked, kda_flat, takes_kernel
+from ..ops.kda_gated_norm import kda_gated_norm
 from ..ops.moe import GATE_ACTIVATIONS, sparse_moe
 
 
@@ -194,6 +211,15 @@ class RMSNorm(nn.Module):
         x = x.astype(jnp.float32)
         ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
         return x * jax.lax.rsqrt(ms + self.eps) * scale       # f32
+
+
+class NormScale(nn.Module):
+    """`RMSNorm`'s leaf without its arithmetic: `scale` (d) under the norm's
+    name, for a fused op that takes the scale as an operand."""
+
+    @nn.compact
+    def __call__(self, d: int) -> jnp.ndarray:
+        return self.param("scale", nn.initializers.ones, (d,), jnp.float32)
 
 
 def _dense(features: int, dtype, name: str) -> nn.Dense:
@@ -281,6 +307,41 @@ def kda_core_path(cfg: DecoderConfig) -> Optional[str]:
     if all(op != "kda" for op, _ in cfg.layer_kinds()):
         return None
     return "kernel" if takes_kernel(cfg.seq_len, cfg.head_dim, cfg.head_dim) else "xla"
+
+
+def kda_prepare_path(cfg: DecoderConfig) -> Optional[str]:
+    """What stands between the delta layers' projections and the recurrence
+    at the configured sizes: "kernel", the fused op in the projections' own
+    layout (ops/kda_prepare.py::takes_kernel: the recurrence on its kernels,
+    the row whole blocks, four taps), or "xla", `kda_prepare_xla`; None where
+    no layer is one."""
+    if kda_core_path(cfg) is None:
+        return None
+    return ("kernel" if kda_prepare.takes_kernel(
+        cfg.seq_len, cfg.head_dim, cfg.conv_kernel) else "xla")
+
+
+@functools.partial(jax.checkpoint, static_argnums=(10, 11))
+def kda_prepare_xla(xq, xk, xv, xf, xb, wq, wk, wv, a_log, dt_bias, heads, dtype):
+    """The delta layer's input side in plain XLA: the projections' outputs
+    (B, T, H·d) and (B, T, H) → q, k, v (B, T, H, d) in `dtype`, g (B, T, H, d)
+    and β (B, T, H) float32. Keeps the projections; the rest is elementwise.
+    What every shape the fused op does not take runs, and its reference."""
+    f32 = jnp.float32
+    b, t, _ = xq.shape
+    hd = xq.shape[-1] // heads
+
+    def branch(x, w):
+        return jax.nn.silu(_causal_taps(x.astype(f32), w)).reshape(b, t, heads, hd)
+
+    def unit(x):    # L2 norm over the head's dims
+        return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+
+    g = LOWER_BOUND * jax.nn.sigmoid(
+        jnp.exp(a_log)[:, None] * (xf.astype(f32) + dt_bias).reshape(b, t, heads, hd))
+    return ((unit(branch(xq, wq)) * hd ** -0.5).astype(dtype),
+            unit(branch(xk, wk)).astype(dtype), branch(xv, wv).astype(dtype), g,
+            jax.nn.sigmoid(xb.astype(f32)))
 
 
 class DecoderLayer(nn.Module):
@@ -372,7 +433,9 @@ class DecoderLayer(nn.Module):
         SiLU, q and k L2-normed per head, the per-channel log decay g and β
         from h, the recurrence in chunks (ops/kda.py: its kernels where the
         head's tiles are whole, else plain XLA), a per-head RMSNorm gated by
-        one sigmoid a head, W_o; no rotary embedding."""
+        one sigmoid a head, W_o; no rotary embedding. Where the input side's
+        fused op takes the shapes (`kda_prepare_path`), q, k, v, g and o stay
+        (B, T, H·d) from the projections to W_o; else (B, T, H, d)."""
         c = self.cfg
         b, t, dim = h.shape
         heads, hd, taps = c.num_heads, c.head_dim, c.conv_kernel
@@ -385,41 +448,31 @@ class DecoderLayer(nn.Module):
             return self.param(f"kda_taps_{name}", nn.initializers.lecun_normal(),
                               (taps, heads * hd), f32)
 
-        @jax.checkpoint     # keeps the projections; the rest is elementwise
-        def prepare(xq, xk, xv, xf, xb, wq, wk, wv, a_log, dt_bias):
-            def branch(x, w):
-                return jax.nn.silu(_causal_taps(x.astype(f32), w)).reshape(
-                    b, t, heads, hd)
-
-            def unit(x):    # L2 norm over the head's dims
-                return x * jax.lax.rsqrt(
-                    jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
-
-            g = LOWER_BOUND * jax.nn.sigmoid(
-                jnp.exp(a_log)[:, None]
-                * (xf.astype(f32) + dt_bias).reshape(b, t, heads, hd))
-            return ((unit(branch(xq, wq)) * hd ** -0.5).astype(self.dtype),
-                    unit(branch(xk, wk)).astype(self.dtype),
-                    branch(xv, wv).astype(self.dtype), g,
-                    jax.nn.sigmoid(xb.astype(f32)))
-
+        fused = kda_prepare.takes_kernel(t, hd, taps)
         with jax.named_scope("kda.in"):
-            q, k, v, g, beta = prepare(
-                project("q"), project("k"), project("v"), project("f"),
-                project("beta", heads), taps_of("q"), taps_of("k"), taps_of("v"),
-                self.param("kda_a_log", nn.initializers.zeros, (heads,), f32),
-                self.param("kda_dt_bias", nn.initializers.constant(-4.0),
-                           (heads * hd,), f32))
+            xs = [project(name) for name in "qkvf"] + [project("beta", heads)]
+            small = (taps_of("q"), taps_of("k"), taps_of("v"),
+                     self.param("kda_a_log", nn.initializers.zeros, (heads,), f32),
+                     self.param("kda_dt_bias", nn.initializers.constant(-4.0),
+                                (heads * hd,), f32))
+            if fused:   # q, k, v, g stay (B, T, H·d), as the kernels read them
+                q, k, v, g, beta = kda_prepare.kda_prepare(*xs, *small)
+            else:
+                q, k, v, g, beta = kda_prepare_xla(*xs, *small, heads, self.dtype)
         with jax.named_scope("kda.core"):
             # named for --remat's policy (DecoderLM.setup): the layer's
             # recomputed forward keeps o and does not walk the states again
-            o = checkpoint_name(
-                kda_chunked(q, k, v, g, beta, dtype=self.dtype), "kda_out")
+            core = kda_flat if fused else kda_chunked
+            o = checkpoint_name(core(q, k, v, g, beta, dtype=self.dtype), "kda_out")
         with jax.named_scope("kda.out"):
             gate = jax.nn.sigmoid(_dense(heads, self.dtype, "kda_gate")(h).astype(f32))
-            o = RMSNorm(c.rms_eps, name="kda_norm")(o) * gate[..., None]
-            return _dense(dim, self.dtype, "kda_o")(
-                o.astype(self.dtype).reshape(b, t, -1))
+            if fused:   # o too stays (B, T, H·d), as W_o reads it
+                y = kda_gated_norm(o, gate, NormScale(name="kda_norm")(hd),
+                                   eps=c.rms_eps, dtype=self.dtype)
+            else:
+                y = (RMSNorm(c.rms_eps, name="kda_norm")(o) * gate[..., None]
+                     ).astype(self.dtype).reshape(b, t, -1)
+            return _dense(dim, self.dtype, "kda_o")(y)
 
     def _attention(self, h):
         b, t, dim = h.shape

@@ -61,7 +61,13 @@ What runs where is read from the shapes (`takes_kernel`; no flag):
   any exponent: A and P do not depend on them, but without it what a row's
   and a column's factor round apart would run on to the chunk's first token
   (dg 1.7 % from the recurrence's for 0.9 %). Interpret mode off the TPU, as
-  the flash kernels.
+  the flash kernels. The kernels read and write (B, T, H · d), a head's dims
+  side by side along the lanes: the projections' own layout. `kda_flat` is
+  their entry in that layout (the model's: ops/kda_prepare.py hands q, k, v,
+  g over in it, ops/kda_gated_norm.py takes o in it, and no (B, T, H, d)
+  array, another tiling on the TPU, stands between); `kda_chunked`, the
+  (B, T, H, d) entry of the tests and of every shape the input side's fused
+  op does not take, reshapes at its two edges.
 - anything else (narrower heads, a row shorter than a chunk, another chunk
   length): `_chunked` in plain XLA, forward and backward through autodiff,
   under `jax.checkpoint` (its backward builds the chunks' matrices and walks
@@ -126,9 +132,12 @@ def kda_chunked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
                 head_group: int = HEAD_GROUP):
     """q, k (B, T, H, d_k), v (B, T, H, d_v), g (B, T, H, d_k) the log of the
     per-channel decay (≤ 0, and ≥ LOWER_BOUND), beta (B, T, H) → o (B, T, H,
-    d_v) float32: the kernels where `takes_kernel` says so, else `_grouped`."""
+    d_v) float32: the kernels where `takes_kernel` says so (through
+    `kda_flat`, a reshape each way), else `_grouped`."""
     if takes_kernel(k.shape[1], k.shape[-1], v.shape[-1], chunk):
-        return _kernels(q, k, v, g, beta, jnp.dtype(dtype))
+        b, t = k.shape[:2]
+        return kda_flat(*(x.reshape(b, t, -1) for x in (q, k, v, g)), beta,
+                        dtype=dtype).reshape(v.shape)
     return _grouped(q, k, v, g, beta, chunk=chunk, dtype=dtype,
                     head_group=head_group)
 
@@ -563,8 +572,10 @@ def _call(kernel, name, ins, outs, interpret, reverse=False):
 
 
 def _operands(q, k, v, g, beta):
-    b, t, h, _ = k.shape
-    return [(x.reshape(b, t, -1), "lanes") for x in (q, k, v, g.astype(_F32))] + [
+    """q, k, v, g (B, T, H · d) as the projections and `ops/kda_prepare.py`
+    make them (a head's dims side by side along the lanes: no other layout
+    stands on the kernel path), beta (B, T, H)."""
+    return [(x, "lanes") for x in (q, k, v, g.astype(_F32))] + [
         (jnp.moveaxis(beta.astype(_F32), 2, 1)[..., None], "beta")]
 
 
@@ -573,17 +584,16 @@ def _operands(q, k, v, g, beta):
 # `interpret` is in the key: the tests steer it
 @functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
 def _forward(q, k, v, g, beta, *, dtype, interpret):
-    b, t, h, dv = v.shape
     (o,) = _call(functools.partial(_walk_kernel, dtype=dtype, keep=False), "kda_fwd",
                  _operands(q, k, v, g, beta),
-                 [(jax.ShapeDtypeStruct((b, t, h * dv), _F32), "lanes")], interpret)
-    return o.reshape(v.shape)
+                 [(jax.ShapeDtypeStruct(v.shape, _F32), "lanes")], interpret)
+    return o
 
 
 @functools.partial(jax.jit, static_argnames=("dtype", "interpret"))
 def _backward(q, k, v, g, beta, do, *, dtype, interpret):
-    b, t, h, dk = k.shape
-    dv, nt = v.shape[-1], t // CHUNK
+    b, t, h = beta.shape
+    dk, dv, nt = k.shape[-1] // h, v.shape[-1] // h, t // CHUNK
     ins = _operands(q, k, v, g, beta)
     kept = _call(functools.partial(_walk_kernel, dtype=dtype, keep=True), "kda_states",
                  ins, [(jax.ShapeDtypeStruct((b, h, nt, r, w), _F32), "chunk")
@@ -592,12 +602,10 @@ def _backward(q, k, v, g, beta, do, *, dtype, interpret):
     # the five gradients have the five operands' shapes and dtypes
     dq, dk_, dv_, dg, dbeta = _call(
         functools.partial(_reverse_kernel, dtype=dtype), "kda_bwd",
-        ins + [(do.astype(_F32).reshape(b, t, -1), "lanes")] + [
-            (x, "chunk") for x in kept],
+        ins + [(do.astype(_F32), "lanes")] + [(x, "chunk") for x in kept],
         [(jax.ShapeDtypeStruct(x.shape, x.dtype), kind) for x, kind in ins],
         interpret, reverse=True)
-    return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
-            dg.reshape(g.shape).astype(g.dtype),
+    return (dq, dk_, dv_, dg.astype(g.dtype),
             jnp.moveaxis(dbeta[..., 0], 1, 2).astype(beta.dtype))
 
 
@@ -615,3 +623,15 @@ def _kernels_bwd(dtype, res, do):
 
 
 _kernels.defvjp(_kernels_fwd, _kernels_bwd)
+
+
+def kda_flat(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
+             beta: jnp.ndarray, *, dtype=jnp.bfloat16):
+    """The kernels' own entry: q, k, g (B, T, H · d_k), v (B, T, H · d_v), beta
+    (B, T, H) → o (B, T, H · d_v) float32; the gradients come back in the
+    same layout. Only where `takes_kernel` says so."""
+    h = beta.shape[-1]
+    if not takes_kernel(k.shape[1], k.shape[-1] // h, v.shape[-1] // h):
+        raise ValueError(f"rows of {k.shape[1]} tokens, heads {k.shape[-1] // h} and "
+                         f"{v.shape[-1] // h} wide: not the kernels' (takes_kernel)")
+    return _kernels(q, k, v, g, beta, jnp.dtype(dtype))

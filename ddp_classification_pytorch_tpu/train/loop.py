@@ -525,11 +525,11 @@ class Trainer:
         tokens by which operator before which feed-forward — a static
         counter, and the same counts beside `init_state` in the set-up line,
         with the path the attention kernels' backward takes at these sizes
-        and whether the delta layers' recurrence takes its kernels; of a
-        looped stack also how often a step applies a layer, and how its
-        passes are traced."""
+        and whether the delta layers' recurrence and their input side take
+        their kernels; of a looped stack also how often a step applies a
+        layer, and how its passes are traced."""
         from ..models.decoder_lm import (LOOP_TRACED, flash_backward_path,
-                                         kda_core_path)
+                                         kda_core_path, kda_prepare_path)
 
         dc = self.cfg.model.decoder
         kinds = collections.Counter(dc.layer_kinds())
@@ -552,8 +552,9 @@ class Trainer:
             spans.note(flash_backward=path)
         core = kda_core_path(dc)
         if core:
-            # the predicate `ops/kda.py::kda_chunked` dispatches on
-            spans.note(kda_core=core)
+            # the predicates `ops/kda.py::kda_chunked` and the layer's input
+            # side (`DecoderLayer._kda`) dispatch on
+            spans.note(kda_core=core, kda_prepare=kda_prepare_path(dc))
 
     def _publish_moe_load(self, load: np.ndarray) -> None:
         """The logged step's routing, as the step's metrics carry it —

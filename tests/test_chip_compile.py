@@ -2,6 +2,8 @@
 v5e (rules and fixtures: chip_compile_common.py; the whole steps are in
 test_chip_compile_resnet.py and test_chip_compile_decoder.py)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -113,3 +115,34 @@ def test_sparse_experts_compile_to_grouped_matmul_kernels(one_chip):
     # grouped, not dense: far under 16 experts x every slot
     dense = 3 * 2 * 98304 * 3 * 2560 * 768 * 16
     assert compiled.cost_analysis()["flops"] < dense / 8
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("side", ["input", "output"])
+def test_kda_sides_compile_forward_and_backward(one_chip, kernels, side, dtype):
+    """Ling-3.0-flash's delta layer around its recurrence, at the published
+    widths: 32 heads of 128 over 8,192 tokens in (B, T, H·d), blocks of
+    (256, 1,024); a head's norm a lane reduction inside one tile. The input
+    side reads the 16 rows before a block for its taps and keeps their sums
+    in a resident block; the output side's gate rides (B, H, T, 1)."""
+    from ddp_classification_pytorch_tpu.ops import kda_gated_norm, kda_prepare
+
+    def arg(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    assert kda_prepare.takes_kernel(8192, 128, 4)
+    if side == "input":
+        op = kda_prepare.kda_prepare
+        args = ([arg((1, 8192, 4096), dtype)] * 4 + [arg((1, 8192, 32), dtype)]
+                + [arg((4, 4096))] * 3 + [arg((32,)), arg((4096,))])
+    else:
+        op = functools.partial(kda_gated_norm.kda_gated_norm, eps=1e-6, dtype=dtype)
+        args = [arg((1, 8192, 4096)), arg((1, 8192, 32)), arg((128,))]
+
+    def loss(*a):
+        return sum(jnp.sum(x.astype(jnp.float32)) for x in jax.tree_util.tree_leaves(op(*a)))
+
+    text = _compiled_text(jax.value_and_grad(loss, argnums=tuple(range(len(args)))), *args)
+    assert text.count("tpu_custom_call") == 2, text.count("tpu_custom_call")
+    name = {"input": "kda_prepare", "output": "kda_gated_norm"}[side]
+    assert f"{name}_fwd" in text and f"{name}_bwd" in text

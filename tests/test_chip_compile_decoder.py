@@ -89,11 +89,14 @@ def test_train_step_fits_one_chip(topo, kernels, config, parameters,
     assert text.count("tpu_custom_call") >= 2 * attention_blocks
     assert "flash_dkvq" in text and "flash_dq" not in text
     # a delta layer's recurrence is three more: the forward walk, and in the
-    # backward the walk that keeps the chunks' states and the reverse walk
+    # backward the walk that keeps the chunks' states and the reverse walk;
+    # its input side (taps, SiLU, norms, decay) two, forward and backward, and
+    # its output side (the gated per-head norm) two
     delta = sum(dc.layout(dc.kda_layout))
-    for name in ("kda_fwd", "kda_states", "kda_bwd"):
+    for name in ("kda_fwd", "kda_states", "kda_bwd", "kda_prepare_fwd",
+                 "kda_prepare_bwd", "kda_gated_norm_fwd", "kda_gated_norm_bwd"):
         assert (name in text) == bool(delta), name
-    assert text.count("tpu_custom_call") >= 2 * attention_blocks + 3 * delta
+    assert text.count("tpu_custom_call") >= 2 * attention_blocks + 7 * delta
     if dc.loops > 1:
         # the passes are one loop: the program holds the stack once, not
         # once a pass

@@ -30,9 +30,11 @@ from ddp_classification_pytorch_tpu.cli.train import (  # noqa: E402
     config_from_args,
     main as train_main,
 )
+from ddp_classification_pytorch_tpu.models import decoder_lm  # noqa: E402
 from ddp_classification_pytorch_tpu.models.factory import build_model  # noqa: E402
-from ddp_classification_pytorch_tpu.ops import kda  # noqa: E402
+from ddp_classification_pytorch_tpu.ops import kda, kda_prepare  # noqa: E402
 from ddp_classification_pytorch_tpu.ops.kda import kda_chunked  # noqa: E402
+from ddp_classification_pytorch_tpu.ops.kda_gated_norm import kda_gated_norm  # noqa: E402
 from ddp_classification_pytorch_tpu.ops.moe import route_top_k, sparse_moe  # noqa: E402
 from ddp_classification_pytorch_tpu.train.steps import _lm_loss  # noqa: E402
 from test_decoder_lm import batch, flat_tree, program_tree  # noqa: E402
@@ -274,32 +276,168 @@ def test_a_row_that_is_not_whole_chunks_is_refused_at_128_wide_heads_too(t):
         kda_chunked(*mixer_inputs(t, d=128, rows=1))
 
 
-def test_the_kernels_backward_keeps_the_five_inputs_and_nothing_else():
-    """What `jax.checkpoint` keeps of `_chunked`: no state, no chunk matrix."""
+def prepare_inputs(t, heads=2, dtype=jnp.float32, rows=2, seed=0):
+    """The projections' outputs x_q, x_k, x_v, x_f (B, T, H · 128) and x_b
+    (B, T, H) in `dtype`, the three tap tables, A_log and dt_bias."""
+    width = heads * 128
+    ks = jax.random.split(jax.random.PRNGKey(seed), 10)
+    xs = [(1.5 * jax.random.normal(ks[i], (rows, t, width))).astype(dtype)
+          for i in range(4)] + [jax.random.normal(ks[4], (rows, t, heads)).astype(dtype)]
+    return (*xs, *(0.5 * jax.random.normal(ks[5 + i], (4, width)) for i in range(3)),
+            0.5 * jax.random.normal(ks[8], (heads,)),
+            jax.random.normal(ks[9], (width,)) - 1.0)
+
+
+@pytest.mark.parametrize("op", ["recurrence", "prepare"])
+def test_the_kernels_backward_keeps_the_ops_inputs_and_nothing_else(op):
+    """What `jax.checkpoint` keeps of `_chunked` and of `kda_prepare_xla`: no
+    state, no chunk matrix, no tap, no norm."""
     from jax._src.ad_checkpoint import saved_residuals
 
-    args = mixer_inputs(128, d=128, rows=1)
-    kept = saved_residuals(kda_chunked, *args)
-    assert [(a.shape, why) for a, why in kept] == [
-        (x.shape, f"from the argument {name}")
-        for name, x in zip(("q", "k", "v", "g", "beta"), args)], kept
+    if op == "recurrence":     # the kernels' own entry: (B, T, H · d)
+        q, k, v, g, beta = mixer_inputs(128, d=128, rows=1)
+        args = (*(x.reshape(1, 128, -1) for x in (q, k, v, g)), beta)
+        f, names = kda.kda_flat, ("q", "k", "v", "g", "beta")
+    else:
+        args = prepare_inputs(64)
+        f, names = kda_prepare.kda_prepare, ("xq", "xk", "xv", "xf", "xb", "wq", "wk",
+                                            "wv", "a_log", "dt_bias")
+    kept = [(a.shape, why) for a, why in saved_residuals(f, *args)]
+    if op == "prepare":     # β's sigmoid is plain XLA and keeps what it likes
+        kept = [x for x in kept if x[0] != args[4].shape]
+    assert kept == [(x.shape, f"from the argument {name}")
+                    for name, x in zip(names, args) if name != "xb"], kept
+
+
+# (a'') the input side: taps, SiLU, norms and decay as one op -------------------
+
+@functools.lru_cache(maxsize=None)
+def prepare_in_xla(t, dtype):
+    """(inputs, weights, (five outputs, ten gradients)) of `kda_prepare_xla`,
+    its outputs in the kernels' layout."""
+    args = prepare_inputs(t, dtype=jnp.dtype(dtype))
+    weights = [jax.random.normal(jax.random.PRNGKey(20 + i), args[min(i, 4)].shape)
+               for i in range(5)]
+
+    def xla(*a):
+        outs = decoder_lm.kda_prepare_xla(*a, 2, jnp.dtype(dtype))
+        return tuple(x.reshape(2, t, -1) for x in outs)
+
+    return args, weights, jax.jit(outs_and_grads(xla, weights))(*args)
+
+
+def outs_and_grads(f, weights):
+    def loss(*a):
+        return sum(jnp.sum(w * o.astype(jnp.float32)) for w, o in zip(weights, f(*a)))
+    return lambda *a: (f(*a), jax.grad(loss, argnums=tuple(range(10)))(*a))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t,rows", [(64, 32), (128, 64), (256, 128)])
+def test_fused_input_side_is_prepare_in_plain_xla_forward_and_every_gradient(
+        t, rows, dtype):
+    """Two blocks of rows a row: the taps read across the block's boundary
+    forward, their transpose backward; two rows of a batch: the second sees
+    nothing of the first; two heads: each its own norm. float32 inputs: the
+    order of the sums is what is left; bf16: one rounding of an output."""
+    args, weights, want = prepare_in_xla(t, dtype)
+    assert kda_prepare.takes_kernel(t, 128, 4, rows)
+    got = jax.jit(outs_and_grads(
+        functools.partial(kda_prepare.kda_prepare, rows=rows), weights))(*args)
+    names = ("q", "k", "v", "g", "beta", "xq", "xk", "xv", "xf", "xb", "wq", "wk", "wv",
+             "a_log", "dt_bias")
+    for name, a, b in zip(names, (*got[0], *got[1]), (*want[0], *want[1])):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert bool(jnp.isfinite(a).all()), name
+        gap = float(jnp.abs(a - b).max() / jnp.abs(b).max())
+        assert gap < (5e-6 if dtype == "float32" else 8e-3), (name, gap)
+    # a row's first tokens see zeros before them, not the row before: the
+    # second row's outputs do not move with the first row's inputs
+    moved = jax.jit(functools.partial(kda_prepare.kda_prepare, rows=rows))(
+        *(x.at[0].set(0) for x in args[:5]), *args[5:])
+    for a, b in zip(moved[:3], got[0][:3]):
+        np.testing.assert_array_equal(a[1], b[1])
+        assert float(jnp.abs(a[0].astype(jnp.float32)).max()) == 0.0
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t,rows", [(64, 32), (256, 128)])
+def test_fused_output_side_is_the_gated_norm_in_plain_xla_forward_and_every_gradient(
+        t, rows, dtype):
+    """The per-head RMSNorm and its gate over (B, T, H · d) against `RMSNorm`'s
+    arithmetic over (B, T, H, d): two blocks of rows, two rows, two heads."""
+    ks = jax.random.split(jax.random.PRNGKey(t), 4)
+    o = 2.0 * jax.random.normal(ks[0], (2, t, 256))
+    gate = jax.nn.sigmoid(jax.random.normal(ks[1], (2, t, 2)))
+    scale = 1.0 + 0.3 * jax.random.normal(ks[2], (128,))
+    weight = jax.random.normal(ks[3], (2, t, 256))
+
+    def xla(o, gate, scale):
+        x = o.reshape(2, t, 2, 128)
+        y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + 1e-6) * scale
+        return (y * gate[..., None]).astype(dtype).reshape(2, t, -1)
+
+    def both(f):
+        return jax.jit(lambda *a: (f(*a), jax.grad(
+            lambda *b: jnp.sum(weight * f(*b).astype(jnp.float32)), argnums=(0, 1, 2))(*a))
+        )(o, gate, scale)
+
+    got = both(functools.partial(kda_gated_norm, eps=1e-6, dtype=jnp.dtype(dtype),
+                                 rows=rows))
+    want = both(xla)
+    for name, a, b in zip(("y", "o", "gate", "scale"), (got[0], *got[1]),
+                          (want[0], *want[1])):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert float(jnp.abs(a - b).max() / jnp.abs(b).max()) < 2e-6, name
+
+
+@pytest.mark.parametrize("arch,path", [
+    ({"head_dim": 128, "seq_len": 8192}, "kernel"),
+    ({"head_dim": 128, "seq_len": 64}, "kernel"),        # one shorter block
+    ({"head_dim": 128, "seq_len": 512}, "kernel"),
+    ({"head_dim": 128, "seq_len": 320}, "xla"),          # whole chunks, not whole blocks
+    ({"head_dim": 128, "seq_len": 48}, "xla"),           # the recurrence in `_grouped`
+    ({"head_dim": 64, "seq_len": 8192}, "xla"),
+    ({"head_dim": 256, "seq_len": 8192}, "xla"),         # a head over two lane tiles
+    ({"head_dim": 16, "seq_len": 128}, "xla"),           # the rehearsal arch's
+    ({"head_dim": 128, "seq_len": 8192, "conv_kernel": 3}, "xla"),
+    ({"head_dim": 128, "seq_len": 8192, "kda_layout": [0]}, None),
+], ids=str)
+def test_the_shapes_decide_whether_the_input_side_takes_its_kernels(arch, path):
+    dc = config_from_args(build_parser().parse_args(
+        cli_argv(dict(ARCH, v_head_dim=arch["head_dim"], **arch)))).model.decoder
+    assert decoder_lm.kda_prepare_path(dc) == path
+    core = decoder_lm.kda_core_path(dc)
+    assert (core is None) == (path is None) and (path != "kernel" or core == "kernel")
 
 
 def test_under_remat_a_layer_walks_the_row_once_forward_and_twice_backward():
     """`--remat` keeps `kda_out` by name (DecoderLM.setup), so the layer's
     recomputed forward does not hold the forward kernel again (PR 42: 65 ms a
     step): the gradient's program has `kda_fwd` once (the forward pass),
-    `kda_states` once and `kda_bwd` once."""
-    arch = dict(ARCH, num_layers=1, dense_layers=1, kda_layout=[1], num_heads=1,
+    `kda_states` once and `kda_bwd` once; the input side's forward kernel
+    twice (the pass and its recomputation: q, k, v, g are what the backward's
+    walks read) and its backward once, and the output side's the same. No
+    (B, T, H, d) value stands anywhere in the layer, forward or backward."""
+    arch = dict(ARCH, num_layers=1, dense_layers=1, kda_layout=[1], num_heads=2,
                 head_dim=128, seq_len=64)
-    _, loss_fn, _ = program(arch, "--remat", "--num_kv_heads", "1")
+    _, loss_fn, _ = program(arch, "--remat", "--num_kv_heads", "2")
     params = jax.eval_shape(lambda: program_tree(
         common.make_params(ref.param_spec(arch), 0)))
     tokens = jax.ShapeDtypeStruct((1, 64), jnp.int32)
     text = str(jax.make_jaxpr(jax.grad(
         lambda p, x, y: loss_fn(p, {}, x, y, None)[0]))(params, tokens, tokens))
-    assert re.findall(r"name=(kda_fwd|kda_states|kda_bwd)\b", text) == [
-        "kda_fwd", "kda_states", "kda_bwd"]
+    kernels = (r"name=(kda_prepare_fwd|kda_prepare_bwd|kda_fwd|kda_states|kda_bwd|"
+               r"kda_gated_norm_fwd|kda_gated_norm_bwd)\b")
+    assert re.findall(kernels, text) == [
+        "kda_prepare_fwd", "kda_fwd", "kda_gated_norm_fwd",          # the pass
+        "kda_prepare_fwd", "kda_gated_norm_fwd",                     # again, but for o
+        "kda_gated_norm_bwd", "kda_states", "kda_bwd", "kda_prepare_bwd"]
+    # the layer through, forward and backward: the projections make
+    # (B, T, H · d), W_o reads it, and every op between keeps it
+    assert "[1,64,256]" in text and "[1,64,2,128]" not in text
 
 
 # (b) the program against the reference ---------------------------------------
@@ -332,6 +470,33 @@ def test_program_matches_the_plain_reference_loss_and_every_gradient():
             assert float(jnp.abs(got[name]).max()) == 0.0, name
     load = metrics_fn(loss, aux, targets)["moe_load"]
     assert load.shape == (2, ARCH["experts_held"])
+
+
+def test_program_on_the_kernel_path_matches_the_plain_reference_too():
+    """One dense delta layer at 128-wide heads: the input side's fused op, the
+    recurrence's kernels and the output side's fused op hand (B, T, H · d) to
+    each other (`kda_prepare=kernel`), and loss and every gradient are the
+    float32 reference's, which knows nothing of layouts."""
+    arch = dict(ARCH, num_layers=1, dense_layers=1, kda_layout=[1], num_heads=2,
+                head_dim=128, seq_len=64)
+    model, loss_fn, _ = program(arch, "--remat", "--num_kv_heads", "2")
+    assert decoder_lm.kda_prepare_path(model.cfg) == "kernel"
+    flat = common.make_params(ref.param_spec(arch), 7)
+    # the norm's scale and the taps are ones and small at seeded weights: move them
+    flat = {k: (v + 0.3 * jax.random.normal(jax.random.PRNGKey(len(k)), v.shape)
+                if k.endswith(("kda_norm/scale", "kda_a_log")) else v)
+            for k, v in flat.items()}
+    tokens, targets = batch(arch)
+    (loss, _), grads = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
+        program_tree(flat), {}, tokens, targets, None)
+    want, want_grads = jax.jit(jax.value_and_grad(ref.loss_for(arch)))(
+        flat, tokens, targets)
+    assert abs(float(loss) - float(want)) < 1e-5 * abs(float(want))
+    got = flat_tree(grads)
+    assert set(got) == set(want_grads)
+    for name, g in want_grads.items():
+        scale = float(jnp.abs(g).max()) + 1e-12
+        assert float(jnp.abs(got[name] - g).max()) < 2e-4 * scale, name
 
 
 def test_bf16_program_lies_further_from_the_reference_and_fp8_further_still():
@@ -550,6 +715,7 @@ def test_delta_decoder_trains_through_cli_train_and_publishes_its_layout(
     setup = next(line for line in out.splitlines() if "[trainer] set-up:" in line)
     assert "kda_dense=1 kda_routed=1 mla_routed=1" in setup, setup
     assert "kda_core=xla" in setup, setup      # 16-wide heads: no kernel
+    assert "kda_prepare=xla" in setup, setup
 
 
 # the two readers ---------------------------------------------------------------
