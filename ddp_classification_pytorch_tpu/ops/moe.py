@@ -29,26 +29,40 @@ decoder, models/decoder_lm.py): dense dispatch of top-6 of 64 would cost
 10.7x the active FLOPs. It routes over the router's full width, sorts the
 token-slots by expert, runs grouped matmuls (`jax.lax.ragged_dot`: one
 kernel over the ragged groups on the TPU) over the experts HELD HERE only,
-and combines. Dropless: the slot buffer has the static worst-case length
-(every slot on a held expert), so uneven loads lose nothing. Two spaces,
-both of S = k·N rows: the SLOTS in choice-major order (slot j·N + n is token
-n's j-th choice, so (S, C) ↔ (k, N, C) is a free reshape) and the ROWS sorted
-by expert that the grouped matmuls work on; `order` takes a row to its slot,
-`inv` a slot to its row. Gathers cross between them, never scatters. From an
-N-row source to the rows: the dispatch forward (`_dispatch_rows`: u[order %
-N]), again when remat recomputes it, and the combine backward (`_combine`:
-the output's cotangent g[order % N], whose products with the weights and
-with y stay among the rows). From the S-row buffer to the slots, the dearer
-kind: the combine forward (y[inv]) and the dispatch backward (d_rows[inv]);
-the combine's weight gradients cross as S scalars. It is told
+and combines. Two spaces: the S = k·N SLOTS in choice-major order (slot
+j·N + n is token n's j-th choice, so (S, C) ↔ (k, N, C) is a free reshape)
+and the ROWS sorted by expert that the grouped matmuls work on; `order`
+takes a row to its slot, `inv` a slot to its row. Gathers cross between
+them, never scatters. From an N-row source to the rows: the dispatch forward
+(`_dispatch_rows`: u[order % N]), again when remat recomputes it, and the
+combine backward (`_combine`: the output's cotangent g[order % N], whose
+products with the weights and with y stay among the rows). From the row
+buffer to the slots, the dearer kind: the combine forward (y[inv]) and the
+dispatch backward (d_rows[inv]); the combine's weight gradients cross as S
+scalars.
+
+Dropless, whatever the load. How many rows the buffer has is chosen from the
+shapes (`slot_bound`): `SLOT_BOUND_FACTOR` times what a uniform router would
+send to the experts held here, at most S. Holding a quarter of the experts
+or more, that is S, the static worst case (every slot on a held expert), and
+the code is one path. Holding fewer, `_bounded_experts` asks the counted
+`load` on the device (`lax.cond`): a step and layer whose load fits works on
+the first `bound` sorted rows, which hold every held expert's whole group
+(`order` sorts the others last); one whose load does not fit walks the live
+rows in windows of `bound`, as many as the load needs, and adds the windows'
+results. The same terms either way (within a window in today's order), no
+slot is dropped, and no branch holds a row buffer of S rows: the two
+slot-space results stay (S, C), in both. It is told
 which experts it holds (`first_expert`, the banks' leading dimension), so
 one chip of an expert-parallel layout runs it without the exchange and a
-`model` axis > 1 runs the same function per shard with the psum above.
+`model` axis > 1 runs the same function per shard with the psum above (each
+shard asks its own load: the psum stands outside the `cond`).
 The ViT's split-FFN path above still dispatches densely (ROADMAP D6).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -228,15 +242,44 @@ def _group_limited(biased: jnp.ndarray, n_group: int, topk_group: int):
     return jnp.where(keep[:, :, None], per, -jnp.inf).reshape(n, e)
 
 
+# How many sorted rows the experts held here work on, as a multiple of what a
+# uniform router would send them. The largest counted load a routing layer has
+# shown is 2.1 x that share (2,160 slots for 1,024 in `ling3_ep64_8k`, 16,900
+# for 8,192 in `joyai_ep16_8k`, seeded routers with a selection bias; PERF.md
+# §5), `lfm2_ep4_8k`'s heaviest seed 1.3 x. A load past the bound is not
+# dropped: that step and layer walks its rows in windows (`_bounded_experts`).
+SLOT_BOUND_FACTOR = 4
+
+
+def slot_bound(slots: int, held: int, experts: int) -> int:
+    """Rows of the sorted buffer for `slots` = k·N token-slots routed over
+    `experts`, of which `held` are here: `SLOT_BOUND_FACTOR` uniform shares,
+    up to whole tiles of 128 rows, and at most `slots` (= no bound: every
+    slot may fall on a held expert)."""
+    share = -(-SLOT_BOUND_FACTOR * slots * held // experts)
+    return min(slots, -(-share // 128) * 128)
+
+
+def _at_slots(rows, inv):
+    """rows[inv]: for every slot, its sorted row. A bounded buffer is shorter
+    than `inv` reaches: a slot that is `mine` lies inside it; the others
+    point past its end, are clipped here and masked by the caller, as the
+    rows past the last group are."""
+    if rows.shape[0] == inv.shape[0]:
+        return rows[inv]
+    return jnp.take(rows, inv, axis=0, mode="clip")
+
+
 @jax.custom_vjp
 def _dispatch_rows(u, order, inv, mine):
-    """The token's row of u (N, C) for every sorted slot: slot s belongs to
+    """The token's row of u (N, C) for every sorted row: slot s belongs to
     token s % N (slots are laid out choice-major, s = j·N + n, so that
     (S, C) ↔ (k, N, C) is a free reshape). `order` is a permutation of the
-    S = k·N slots and `inv` its inverse, so the transpose is a gather too
-    (no scatter-add): d_u[n] = Σ_j d_rows[inv[j·N + n]] over the slots
-    `mine` — the rows of the others lie past the last group, where the
-    grouped matmuls' transposes leave whatever the buffer held."""
+    S = k·N slots, or its first rows, and `inv` the permutation's inverse, so
+    the transpose is a gather too (no scatter-add): d_u[n] = Σ_j d_rows[inv[
+    j·N + n]] over the slots `mine` — the rows of the others lie past the
+    last group, where the grouped matmuls' transposes leave whatever the
+    buffer held."""
     return u[order % u.shape[0]]
 
 
@@ -246,7 +289,7 @@ def _dispatch_fwd(u, order, inv, mine):
 
 def _dispatch_bwd(res, g):
     inv, mine, n = res
-    g = jnp.where(mine[:, None], g[inv], jnp.zeros((), g.dtype))
+    g = jnp.where(mine[:, None], _at_slots(g, inv), jnp.zeros((), g.dtype))
     return g.reshape(-1, n, g.shape[-1]).sum(axis=0), None, None, None
 
 
@@ -256,9 +299,10 @@ _dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 @jax.custom_vjp
 def _combine(y, w, order, inv, mine):
     """out[n] = Σ_j w[j, n] · y[inv[j·N + n]] (N, C) f32 over the slots
-    `mine`: the sorted rows y (S, C) weighted back onto their tokens. w
-    (k, N) f32 is zero where not `mine`; the mask stays all the same, since
-    those slots' rows lie past the last group and were never written.
+    `mine`: the sorted rows y weighted back onto their tokens; `order` has y's
+    length. w (k, N) f32 is zero where not `mine`; the mask stays all the
+    same, since those slots' rows lie past the last group and were never
+    written.
 
     The backward stays among the sorted rows: the token's cotangent for every
     sorted row is one gather from the N-row g, g_tok = g[order % N]; d_y is
@@ -267,7 +311,7 @@ def _combine(y, w, order, inv, mine):
     Nothing of shape (k, N, C) is built, and nothing reads the forward's
     y[inv]: under remat it is not computed a second time."""
     k, n = w.shape
-    slots = jnp.where(mine[:, None], y[inv], jnp.zeros((), y.dtype))
+    slots = jnp.where(mine[:, None], _at_slots(y, inv), jnp.zeros((), y.dtype))
     return (w[..., None] * slots.reshape(k, n, -1).astype(jnp.float32)).sum(axis=0)
 
 
@@ -277,12 +321,12 @@ def _combine_fwd(y, w, order, inv, mine):
 
 def _combine_bwd(res, g):
     y, w, order, inv, mine = res
-    g_tok = g[order % w.shape[1]]                         # (S, C) f32
+    g_tok = g[order % w.shape[1]]                         # (rows, C) f32
     d_y = (w.reshape(-1)[order][:, None] * g_tok).astype(y.dtype)
     # a row past the last group may hold anything, NaN too: its dot is
     # dropped here, and its d_y above is 0 · g
     dots = (g_tok * y.astype(jnp.float32)).sum(axis=-1)
-    d_w = jnp.where(mine, dots[inv], 0.0).reshape(w.shape)
+    d_w = jnp.where(mine, _at_slots(dots, inv), 0.0).reshape(w.shape)
     return d_y, d_w, None, None, None
 
 
@@ -293,20 +337,15 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 GATE_ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
 
-def _sparse_experts(u, logits, w_gate, w_up, w_down, *, top_k, first_expert,
-                    dtype, activation="relu", route=None):
-    n, _ = u.shape
-    held = w_gate.shape[0]
-    with jax.named_scope("moe.route"):
-        idx, w = route_top_k(logits, top_k, **(route or {}))
+def _experts_on_rows(u, w, order, inv, mine, load, w_gate, w_up, w_down, *,
+                     dtype, activation):
+    """The tokens u (N, C) through the held experts and back, weighted by
+    the router's w (N, k): (N, C) f32. `order` is the sorted rows to work on:
+    all S, the first `bound`, or a window of them (`inv`, `mine` and `load`
+    then in the window's terms); `load` says where each expert's group ends
+    among them."""
+    n, top_k = w.shape
     with jax.named_scope("moe.dispatch"):
-        local = idx.T.reshape(-1) - first_expert          # (S,) S = k·N
-        mine = (local >= 0) & (local < held)
-        key = jnp.where(mine, local, held)                # others sort last
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inv = jnp.argsort(order).astype(jnp.int32)
-        load = (key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :]
-                ).sum(axis=0, dtype=jnp.int32)            # slots per expert
         rows = _dispatch_rows(u.astype(dtype), order, inv, mine)
     with jax.named_scope("moe.experts"):
         def grouped(x, bank):
@@ -323,7 +362,119 @@ def _sparse_experts(u, logits, w_gate, w_up, w_down, *, top_k, first_expert,
                     * both[:, width:], w_down.astype(dtype))
     with jax.named_scope("moe.combine"):
         w = jnp.where(mine.reshape(top_k, n), w.T, 0.0)
-        out = _combine(y, w, order, inv, mine)
+        return _combine(y, w, order, inv, mine)
+
+
+def _window(first, rows, u, w, order, inv, mine, load, *banks, **kw):
+    """`_experts_on_rows` on the sorted rows [first, first + rows) alone:
+    each expert's group cut to its part inside the window, and of the slots
+    `mine` those whose row lies there. Windows that tile the live rows add up
+    to the whole layer, every slot in exactly one. (The last window of a
+    buffer that is no whole number of them starts early; the rows it repeats
+    are computed and, their slots not being its own, weigh nothing.)"""
+    start = jnp.minimum(first, order.shape[0] - rows)
+    ends = jnp.cumsum(load)
+    part = (jnp.clip(ends, start, start + rows)
+            - jnp.clip(ends - load, start, start + rows))
+    inside = mine & (inv >= first) & (inv < start + rows)
+    return _experts_on_rows(
+        u, w, jax.lax.dynamic_slice(order, (start,), (rows,)), inv - start,
+        inside, part, *banks, **kw)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _bounded_experts(bound, dtype, activation, u, w, order, inv, mine, load,
+                     w_gate, w_up, w_down):
+    """`_experts_on_rows` with a buffer of `bound` sorted rows, whatever the
+    load: one `cond` on the device. Where the counted load fits, the first
+    `bound` rows hold every held expert's whole group (`order` sorts the
+    others last) and are the layer. Where it does not, the live rows are
+    walked in windows of `bound` (`_window`), as many as the load needs, and
+    the windows' results added: no slot dropped, no array of S rows of the
+    model's width in either branch but the two slot-space gathers' results.
+
+    A `custom_vjp` so that each branch's residuals stay inside the branch:
+    differentiated as it stands, the forward `cond` would hand out both
+    branches' residuals, zeros for the branch not taken, in every layer of
+    every step, and the loop would keep every window's. The backward holds a
+    `cond` of its own and rebuilds the taken branch's forward inside it from
+    the inputs (what `--remat` does for the layer anyway)."""
+    return _bounded_fwd(bound, dtype, activation, u, w, order, inv, mine, load,
+                        w_gate, w_up, w_down)[0]
+
+
+def _branches(bound, order, inv, mine, load, **kw):
+    """(fits, window): the layer on the first `bound` sorted rows, and on the
+    i-th window of `bound` of them, as functions of (u, w, *banks)."""
+    def fits(u, w, *banks):
+        return _experts_on_rows(u, w, order[:bound], inv, mine, load, *banks, **kw)
+
+    def window(i, u, w, *banks):
+        return _window(i * bound, bound, u, w, order, inv, mine, load, *banks, **kw)
+
+    return fits, window
+
+
+def _bounded_fwd(bound, dtype, activation, u, w, order, inv, mine, load, *banks):
+    fits, window = _branches(bound, order, inv, mine, load, dtype=dtype,
+                             activation=activation)
+
+    def windows(*a):
+        return jax.lax.fori_loop(0, -(-load.sum() // bound),
+                                 lambda i, out: out + window(i, *a),
+                                 jnp.zeros(u.shape, jnp.float32))
+
+    out = jax.lax.cond(load.sum() <= bound, fits, windows, u, w, *banks)
+    return out, (u, w, order, inv, mine, load, *banks)
+
+
+def _bounded_bwd(bound, dtype, activation, res, g):
+    u, w, order, inv, mine, load, *banks = res
+    fits, window = _branches(bound, order, inv, mine, load, dtype=dtype,
+                             activation=activation)
+
+    def pull(layer, g, *a):
+        return jax.vjp(layer, *a)[1](g)
+
+    def windows(g, *a):
+        # the windows' gradients added in float32, then as `fits` hands them
+        like = jax.eval_shape(functools.partial(pull, fits), g, *a)
+        total = jax.lax.fori_loop(
+            0, -(-load.sum() // bound),
+            lambda i, acc: jax.tree_util.tree_map(
+                lambda t, d: t + d.astype(jnp.float32), acc,
+                pull(functools.partial(window, i), g, *a)),
+            jax.tree_util.tree_map(lambda d: jnp.zeros(d.shape, jnp.float32), like))
+        return jax.tree_util.tree_map(lambda t, d: t.astype(d.dtype), total, like)
+
+    d_u, d_w, *d_banks = jax.lax.cond(
+        load.sum() <= bound, functools.partial(pull, fits), windows, g, u, w, *banks)
+    return (d_u, d_w, None, None, None, None, *d_banks)
+
+
+_bounded_experts.defvjp(_bounded_fwd, _bounded_bwd)
+
+
+def _sparse_experts(u, logits, w_gate, w_up, w_down, *, top_k, first_expert,
+                    dtype, activation="relu", route=None):
+    held = w_gate.shape[0]
+    with jax.named_scope("moe.route"):
+        idx, w = route_top_k(logits, top_k, **(route or {}))
+    with jax.named_scope("moe.dispatch"):
+        local = idx.T.reshape(-1) - first_expert          # (S,) S = k·N
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)                # others sort last
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inv = jnp.argsort(order).astype(jnp.int32)
+        load = (key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :]
+                ).sum(axis=0, dtype=jnp.int32)            # slots per expert
+    bound = slot_bound(key.shape[0], held, logits.shape[-1])
+    if bound == key.shape[0]:
+        out = _experts_on_rows(u, w, order, inv, mine, load, w_gate, w_up, w_down,
+                               dtype=dtype, activation=activation)
+    else:
+        out = _bounded_experts(bound, dtype, activation, u, w, order, inv, mine,
+                               load, w_gate, w_up, w_down)
     return out, load
 
 

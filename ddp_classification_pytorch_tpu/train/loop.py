@@ -52,6 +52,7 @@ from ..data.synthetic import SyntheticDataset
 from ..data.transforms import build_transform
 from ..obs import spans
 from ..obs.registry import Registry
+from ..ops.moe import slot_bound
 from ..ops.nested import best_k
 from ..parallel import fleet as fleetlib
 from ..parallel import mesh as meshlib
@@ -555,6 +556,19 @@ class Trainer:
             # the predicates `ops/kda.py::kda_chunked` and the layer's input
             # side (`DecoderLayer._kda`) dispatch on
             spans.note(kda_core=core, kda_prepare=kda_prepare_path(dc))
+        if dc.moe_layer_names():
+            # the sorted rows a routing layer keeps / the slots it routes
+            bound, slots = self._moe_bound()
+            spans.note(moe_bound=f"{bound}/{slots}")
+
+    def _moe_bound(self) -> Tuple[int, int]:
+        """(sorted rows a routing layer works on while its load fits, token-
+        slots k·N it routes in a step): ops/moe.py::slot_bound at the step's
+        shapes."""
+        dc = self.cfg.model.decoder
+        slots = (self.cfg.data.batch_size * jax.process_count() * dc.seq_len
+                 * dc.top_k)
+        return slot_bound(slots, dc.held, dc.num_experts), slots
 
     def _publish_moe_load(self, load: np.ndarray) -> None:
         """The logged step's routing, as the step's metrics carry it —
@@ -562,6 +576,10 @@ class Trainer:
         layer (`DecoderConfig.moe_layer_names`: the layer's index, or "mtp"
         for the prediction module's)."""
         dc = self.cfg.model.decoder
+        # which path `ops/moe.py::_sparse_experts` took, from the load it asked
+        # on the device (under a mesh: the shards' loads together against
+        # their bounds together)
+        bound, slots = self._moe_bound()
         for name, row in zip(dc.moe_layer_names(), load):
             layer = {"layer": name}
             self.obs.gauge("moe_expert_load_max", "token-slots of the "
@@ -570,8 +588,14 @@ class Trainer:
             self.obs.gauge("moe_expert_load_mean", "mean token-slots of a "
                            "held expert in the logged step",
                            layer).set(float(row.mean()))
-        routed = float(self.cfg.data.batch_size * jax.process_count()
-                       * dc.seq_len * dc.top_k * len(load))
+            fits = bool(row.sum() <= bound)
+            for path, took in (("bounded", fits), ("full", not fits)):
+                self.obs.counter("moe_slot_bound_total", "logged steps in "
+                                 "which the layer's load on held experts fit "
+                                 "the bounded sorted-row buffer / was walked "
+                                 "in several windows of it",
+                                 dict(layer, path=path)).inc(float(took))
+        routed = float(slots * len(load))
         for held, n in (("true", float(load.sum())),
                         ("false", routed - float(load.sum()))):
             self.obs.counter("moe_slots_routed_total", "token-slots the "

@@ -17,7 +17,10 @@ from chip_compile_common import (  # noqa: F401
     topo,
 )
 
+from hlo_text import conditionals
+
 from ddp_classification_pytorch_tpu.cli.train import build_parser, config_from_args
+from ddp_classification_pytorch_tpu.ops.moe import slot_bound
 from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
 from ddp_classification_pytorch_tpu.train.steps import make_train_step
 
@@ -86,6 +89,25 @@ def test_train_step_fits_one_chip(topo, kernels, config, parameters,
     # each attention block is two kernels: forward, the fused backward
     dc = cfg.model.decoder
     assert ("ragged-dot" in text) == (dc.dense_layers < dc.num_layers)
+    # holding under a quarter of the router's experts, a routing layer's
+    # sorted rows are bounded (ops/moe.py::slot_bound: 4,096 of 65,536 in
+    # Ling's cell, 32,768 of 131,072 in JoyAI's): one `conditional` forward
+    # and one backward a layer, the ragged-dot kernels in both branches of
+    # each (the one window, the walk over windows), and of the worst-case S
+    # rows no float32 array and none of the experts' width in either
+    slots = cfg.data.batch_size * dc.seq_len * dc.top_k
+    bounded = slot_bound(slots, dc.held, dc.num_experts) < slots
+    conds = conditionals(text)
+    assert len(conds) == 2 * len(dc.moe_layer_names()) * bounded
+    for results, branches in conds:
+        assert f"[{slots}," not in results, results
+        assert sorted(" while(" in b for b in branches) == [False, True]
+        for branch in branches:
+            assert "ragged-dot" in branch
+            for worst_case in (f"[{slots},{dc.expert_width}]",
+                               f"[{slots},{2 * dc.expert_width}]",
+                               f"f32[{slots},{dc.hidden_size}]"):
+                assert worst_case not in branch, worst_case
     assert text.count("tpu_custom_call") >= 2 * attention_blocks
     assert "flash_dkvq" in text and "flash_dq" not in text
     # a delta layer's recurrence is three more: the forward walk, and in the

@@ -4,6 +4,7 @@ it takes nothing from the program), the expert share, the sparse dispatch,
 the window / grouped-head flash kernels, the layout lists and `--dataset
 tokens` through `cli.train`. CPU, toy sizes."""
 
+import functools
 import importlib
 import json
 import os
@@ -18,6 +19,8 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+from hlo_text import conditionals  # noqa: E402
+
 from benchmark.reference import joyai_llm_flash as ref_sigmoid  # noqa: E402
 from benchmark.reference import smallthinker as ref  # noqa: E402
 from benchmark.reference.common import make_params  # noqa: E402
@@ -28,7 +31,8 @@ from ddp_classification_pytorch_tpu.cli.train import (  # noqa: E402
 )
 from ddp_classification_pytorch_tpu.models.factory import build_model  # noqa: E402
 from ddp_classification_pytorch_tpu.ops.attention import attention  # noqa: E402
-from ddp_classification_pytorch_tpu.ops.moe import sparse_moe  # noqa: E402
+from ddp_classification_pytorch_tpu.ops import moe  # noqa: E402
+from ddp_classification_pytorch_tpu.ops.moe import slot_bound, sparse_moe  # noqa: E402
 from ddp_classification_pytorch_tpu.train.steps import _lm_loss  # noqa: E402
 
 # ops/__init__ re-exports a function named like the module
@@ -187,12 +191,10 @@ def test_sparse_dispatch_equals_dense_evaluation_and_drops_no_slot(case):
         np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
 
 
-def test_rows_past_the_last_group_are_never_read(monkeypatch):
-    """On the chip the grouped-matmul kernels write only the rows of their
-    groups: in the slot buffer's tail (slots of experts held elsewhere) the
-    outputs, and the transposes' row cotangents, are whatever the buffer
-    held. Here that tail is poisoned with NaN, forward and backward; values
-    and gradients still have to equal the dense evaluation."""
+def poisoned_ragged_dot(monkeypatch, seen):
+    """`jax.lax.ragged_dot` as the chip's kernels leave it: only the rows of
+    the groups are written, forward and in the transposes; the buffer's tail
+    is NaN here. Every call that RUNS puts its buffer's rows on `seen`."""
     real = jax.lax.ragged_dot
 
     def poison(x, gs):
@@ -201,6 +203,7 @@ def test_rows_past_the_last_group_are_never_read(monkeypatch):
 
     @jax.custom_vjp
     def ragged(x, w, gs):
+        jax.debug.callback(lambda: seen.append(x.shape[0]))
         return poison(real(x, w, gs), gs)
 
     def fwd(x, w, gs):
@@ -216,6 +219,85 @@ def test_rows_past_the_last_group_are_never_read(monkeypatch):
     monkeypatch.setattr(
         jax.lax, "ragged_dot",
         lambda x, w, gs, preferred_element_type=None: ragged(x, w, gs))
+
+
+def slots_on_held(n, experts, first, loads, key):
+    """Router logits (n, experts) that send exactly loads[e] token-slots to
+    expert first + e: its first loads[e] tokens choose it, no other does."""
+    logits = jax.random.normal(key, (n, experts))
+    for e, load in enumerate(loads):
+        logits = logits.at[:, first + e].set(
+            jnp.where(jnp.arange(n) < load, 9.0, -9.0))
+    return logits
+
+
+@functools.partial(jax.jit, static_argnames=("top_k", "first", "traced_as"))
+def sparse_step(u, logits, cot, *w, top_k, first, traced_as):
+    """Value, load and the five gradients of `sparse_moe`; `traced_as` names
+    what was patched while it was traced (one trace a shape and a name: the
+    cases below share them)."""
+    def loss(u, logits, *w):
+        y, load = sparse_moe(u, logits, *w, top_k=top_k, first_expert=first,
+                             dtype=jnp.float32)
+        return (y * cot).sum(), load
+
+    return jax.value_and_grad(loss, argnums=range(5), has_aux=True)(u, logits, *w)
+
+
+SEEN = []   # the rows of every grouped matmul that ran (poisoned_ragged_dot)
+
+
+@pytest.mark.parametrize("n,top_k,loads,windows", [
+    (256, 2, (0, 0), 1), (256, 2, (60, 40), 1), (256, 2, (200, 56), 1),
+    (256, 2, (200, 57), 2), (256, 2, (256, 256), 2), (200, 3, (200, 200), 2),
+], ids=["no_slot", "under_the_bound", "exactly_at_it", "one_slot_over", "every_slot_here",
+        "a_last_window_that_starts_early"])
+def test_a_bounded_buffer_takes_what_fits_and_drops_nothing(n, top_k, loads, windows,
+                                                            monkeypatch):
+    """Holding under a quarter of the experts (2 of 16: 512 slots for a bound
+    of 256 sorted rows, or 600 for 384), the counted load picks the branch on
+    the device: one window of `bound` rows where it fits, as many as it needs
+    where it does not. Values and all five gradients equal the dense
+    evaluation and the one-path code (the constant patched until bound = S)
+    in either, and every grouped matmul ran on `bound` rows: two a window
+    forward, two more where the backward rebuilds it. Rows between the last
+    group and a window's end are NaN here and read by nothing."""
+    experts, held, first = 16, 2, 5
+    bound = slot_bound(top_k * n, held, experts)
+    assert bound < top_k * n and windows == max(1, -(-sum(loads) // bound))
+    ks = jax.random.split(jax.random.PRNGKey(13), 4)
+    u, w = jax.random.normal(ks[0], (n, 16)), banks(ks[1], held)
+    logits = slots_on_held(n, experts, first, loads, ks[2])
+    cot = jax.random.normal(ks[3], u.shape)
+    args, kw = (u, logits, cot, *w), dict(top_k=top_k, first=first)
+
+    SEEN.clear()
+    poisoned_ragged_dot(monkeypatch, SEEN)
+    (got, load), got_grads = sparse_step(*args, **kw, traced_as="poisoned")
+    jax.effects_barrier()
+    assert load.tolist() == list(loads)
+    assert SEEN == [bound] * 4 * windows, SEEN
+    monkeypatch.undo()
+    monkeypatch.setattr(moe, "SLOT_BOUND_FACTOR", 8)      # 8 x 2/16: no bound
+    (full, _), full_grads = sparse_step(*args, **kw, traced_as="one_path")
+    want, want_grads = jax.jit(jax.value_and_grad(
+        lambda u, l, *w: (dense_mixture(u, l, w, top_k, first) * cot).sum(),
+        argnums=range(5)))(u, logits, *w)
+    np.testing.assert_allclose(got, want, rtol=1e-4)
+    np.testing.assert_allclose(got, full, rtol=1e-6)
+    for g, f, r in zip(got_grads, full_grads, want_grads):
+        assert bool(jnp.isfinite(g).all())
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(g, f, rtol=1e-5, atol=1e-5)
+
+
+def test_rows_past_the_last_group_are_never_read(monkeypatch):
+    """On the chip the grouped-matmul kernels write only the rows of their
+    groups: in the slot buffer's tail (slots of experts held elsewhere) the
+    outputs, and the transposes' row cotangents, are whatever the buffer
+    held. Here that tail is poisoned with NaN, forward and backward; values
+    and gradients still have to equal the dense evaluation."""
+    poisoned_ragged_dot(monkeypatch, [])
     n, experts, held, first, top_k = 64, 8, 4, 2, 2
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     u, w = jax.random.normal(ks[0], (n, 16)), banks(ks[1], held)
@@ -293,6 +375,101 @@ def test_the_combines_backward_stays_among_the_sorted_rows(case):
     assert not spread, "the output's cotangent is broadcast over the choices"
 
 
+@pytest.mark.parametrize("held", [2, 4], ids=["an_eighth_held", "a_quarter_held"])
+def test_each_branch_keeps_its_residuals_to_itself(held):
+    """Under remat a bounded routing layer is two `conditional`s, the
+    forward's and the backward's (which rebuilds the taken branch's forward
+    inside it); what they hand out is token-space and bank-space only, no
+    array of zeros for the branch not taken. Inside either branch, the one
+    window and the loop over windows, nothing has S rows but the slot-space
+    gather's result. Holding a quarter of the experts the program has no
+    `cond` at all. Counted in the program the CPU's compiler leaves."""
+    n, c, width, experts, first, top_k = 192, 24, 10, 16, 5, 3   # no two alike
+    slots, bound = top_k * n, slot_bound(top_k * n, held, experts)
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    u, logits = jax.random.normal(ks[0], (n, c)), jax.random.normal(ks[1], (n, experts))
+    w, cot = banks(ks[2], held, c=c, width=width), jax.random.normal(ks[3], (n, c))
+
+    @jax.checkpoint
+    def layer(u, logits, *w):
+        return u + sparse_moe(u, logits, *w, top_k=top_k, first_expert=first,
+                              dtype=jnp.float32)[0]
+
+    lowered = jax.jit(jax.value_and_grad(lambda *a: (layer(*a) * cot).sum(),
+                                         argnums=range(5))).lower(u, logits, *w)
+    if held == 4:
+        assert bound == slots and "stablehlo.case" not in lowered.as_text()
+        return
+    assert (bound, slots) == (384, 576)
+    conds = conditionals(lowered.compile().as_text())
+    assert len(conds) == 2, len(conds)
+    for results, branches in conds:
+        assert f"[{slots}," not in results, results
+        assert sorted(" while(" in b for b in branches) == [False, True]
+        for branch in branches:
+            assert f"[{bound},{2 * width}]" in branch        # the census sees them
+            for wide in (width, 2 * width):
+                assert f"[{slots},{wide}]" not in branch, wide
+            # (S, C) there: the slot-space gather's result and its mask, no more
+            made = [op for dims, op in re.findall(r"= \w+\[([\d,]*)\]\S* ([\w\-]+)\(", branch)
+                    if [d for d in dims.split(",") if d != "1"] == [str(slots), str(c)]]
+            assert 1 <= made.count("gather") <= 2, made
+            assert set(made) <= {"gather", "bitcast", "broadcast", "select"}, made
+
+
+def test_a_model_axis_of_eight_bounds_each_shards_buffer():
+    """All 16 experts over a `model` axis of 8: a shard holds 2 of 16, so the
+    `shard_map` body takes the bound (the unsharded whole, holding all, has
+    none), each shard on its own counted load, the psum outside the `cond`."""
+    from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
+
+    ks = jax.random.split(jax.random.PRNGKey(17), 3)
+    u, logits = jax.random.normal(ks[0], (128, 16)), jax.random.normal(ks[1], (128, 16))
+    w = banks(ks[2], 16)
+    mesh = meshlib.make_mesh(meshlib.MeshSpec(1, 8, 1))
+
+    def sharded(u, logits, *w):
+        return sparse_moe(u, logits, *w, top_k=2, dtype=jnp.float32, mesh=mesh,
+                          axis="model")
+
+    assert slot_bound(256, 2, 16) == 128
+    assert jax.jit(sharded).lower(u, logits, *w).as_text().count("stablehlo.case") == 1
+    got, load = jax.jit(sharded)(u, logits, *w)
+    whole, whole_load = sparse_moe(u, logits, *w, top_k=2, dtype=jnp.float32)
+    np.testing.assert_allclose(got, whole, rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(load, whole_load)
+
+
+def test_the_decoders_step_is_the_same_step_with_bounded_buffers(monkeypatch):
+    """At the model's level (every other model test holds a quarter of its
+    experts or more): 2 of 16 held, 512 slots a layer for a bound of 256; loss,
+    `moe_load` and every gradient leaf against the same step with the constant
+    patched until bound = S."""
+    arch = dict(ARCH, num_layers=1, num_experts=16, experts_held=2, first_expert=5,
+                seq_len=128)
+    cfg = cli_config(arch, "--remat")
+    model = build_model(cfg.model, cfg.data.num_classes)
+    params = program_tree(make_params(ref.param_spec(arch), 3))
+    tokens, targets = batch(arch)
+    loss_fn, _ = _lm_loss(cfg, model)
+
+    def step():
+        fn = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))
+        assert ("stablehlo.case" in fn.lower(params, {}, tokens, targets, None).as_text()) \
+            == (moe.SLOT_BOUND_FACTOR == 4)
+        (loss, (_, (_, _, load))), grads = fn(params, {}, tokens, targets, None)
+        return loss, load, flat_tree(grads)
+
+    loss, load, grads = step()
+    assert 0 < int(load.sum(axis=1).max()) <= slot_bound(512, 2, 16) == 256
+    monkeypatch.setattr(moe, "SLOT_BOUND_FACTOR", 8)
+    want_loss, want_load, want = step()
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    np.testing.assert_array_equal(load, want_load)
+    for name, g in want.items():
+        np.testing.assert_allclose(grads[name], g, rtol=1e-4, atol=1e-6, err_msg=name)
+
+
 # (d) ----------------------------------------------------------------------
 
 @pytest.mark.parametrize("heads,kv_heads,window", [
@@ -365,9 +542,32 @@ def test_layer_4k_has_no_rotary_term_and_a_full_mask():
                                rtol=1e-5, atol=1e-6)
 
 
+def test_the_trainer_counts_which_buffer_each_layer_took():
+    """`moe_slot_bound_total{layer, path}` from the logged step's `moe_load`
+    against the bound the op itself asks: 2 rows of 128 tokens, top-2, 2 of
+    16 held: 256 sorted rows for 512 slots."""
+    from types import SimpleNamespace
+
+    from ddp_classification_pytorch_tpu.obs.registry import Registry
+    from ddp_classification_pytorch_tpu.train.loop import Trainer
+
+    cfg = cli_config(dict(ARCH, num_experts=16, experts_held=2, seq_len=128),
+                     "--batchsize", "2")
+    stub = SimpleNamespace(cfg=cfg, obs=Registry())
+    stub._moe_bound = lambda: Trainer._moe_bound(stub)
+    assert stub._moe_bound() == (256, 512)
+    for load in ([[0, 0], [200, 56], [200, 57], [100, 30]],
+                 [[3, 4], [256, 1], [512, 0], [0, 256]]):
+        Trainer._publish_moe_load(stub, np.array(load))
+    text = stub.obs.expose()
+    for layer, bounded, full in (("0", 2, 0), ("1", 1, 1), ("2", 0, 2), ("3", 2, 0)):
+        assert f'moe_slot_bound_total{{layer="{layer}",path="bounded"}} {bounded}\n' in text
+        assert f'moe_slot_bound_total{{layer="{layer}",path="full"}} {full}\n' in text
+
+
 # (f) ----------------------------------------------------------------------
 
-def test_tokens_dataset_trains_through_cli_train(tmp_path):
+def test_tokens_dataset_trains_through_cli_train(tmp_path, capsys):
     from ddp_classification_pytorch_tpu.data.tokens import TokenDataset
 
     t = ARCH["seq_len"]
@@ -395,3 +595,8 @@ def test_tokens_dataset_trains_through_cli_train(tmp_path):
     prom = (tmp_path / "run" / "metrics.prom").read_text()
     assert 'moe_expert_load_max{layer="3"}' in prom
     assert 'moe_slots_routed_total{held="true"}' in prom
+    # 4 of 8 held: the sorted rows are the dropless worst case, every step
+    assert 'moe_slot_bound_total{layer="3",path="bounded"} 2' in prom
+    setup = next(line for line in capsys.readouterr().out.splitlines()
+                 if "[trainer] set-up:" in line)
+    assert "moe_bound=512/512" in setup, setup
