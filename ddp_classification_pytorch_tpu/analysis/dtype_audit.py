@@ -583,7 +583,7 @@ def dtype_registry() -> List[DtypeCase]:
     waiver entry if it trades precision."""
     cases: List[DtypeCase] = []
     for spec in build_registry():
-        train = spec.name.startswith(("train_step", "shard_map_train"))
+        train = spec.name.startswith("train_step")
         waivers = (frozenset({WAIVER_BF16_WIRE})
                    if spec.name == "train_step_bf16_reduce" else frozenset())
         cases.append(DtypeCase(spec.name, spec.build, train=train,

@@ -1,11 +1,11 @@
 """Jaxpr/HLO audit of every jitted step factory.
 
 The registry below names each hot-path program the framework runs (train,
-eval, nested-eval, PLC-predict, top-k serve predict, the explicit-collective
-shard_map step) together with the invariants its factory promises. The audit
-lowers each to a jaxpr (and, where donation is promised, all the way to a
-compiled executable) on synthetic avals of a tiny config and checks the
-*program*, not the source text:
+eval, nested-eval, PLC-predict, top-k serve predict) together with the
+invariants its factory promises. The audit lowers each to a jaxpr (and,
+where donation is promised, all the way to a compiled executable) on
+synthetic avals of a tiny config and checks the *program*, not the source
+text:
 
 - **donation** — inputs declared donated must actually be aliased in the
   executable's `input_output_alias` table. An unaliased donated buffer means
@@ -22,7 +22,7 @@ compiled executable) on synthetic avals of a tiny config and checks the
   primitives: a collective in a program some hosts skip (eval_every, serve)
   is exactly the desync that hangs a pod's control collectives
   (parallel/fleet.py). Train-path entries that legitimately use collectives
-  (shard_map DDP) opt out via `allow_collectives`.
+  (the train step's shard_map sections) opt out via `allow_collectives`.
 
 Entries trace/compile in a fraction of the real model's cost (resnet18,
 32 px, batch 8) — invariants are shape/dtype/program-structure properties,
@@ -312,9 +312,9 @@ class AuditContext:
     """Tiny-config model/state cache shared by every registry entry.
 
     One resnet18/cifar-stem f32 state for the fc-head entries, one for the
-    nested head, one axis-named DDP model for the shard_map entry — built
-    lazily so `--passes lint` never touches the backend, and cached so the
-    test suite's module-scoped audit pays each init exactly once."""
+    nested head — built lazily so `--passes lint` never touches the
+    backend, and cached so the test suite's module-scoped audit pays each
+    init exactly once."""
 
     def __init__(self, arch: str = "resnet18", image_size: int = 32,
                  num_classes: int = 8, batch: int = 8):
@@ -608,30 +608,6 @@ def _build_train_accum(ctx: AuditContext):
                 batch_sharded(ctx.labels(), mesh))
 
 
-def _build_shard_map_train(ctx: AuditContext):
-    from ..parallel.collectives import build_ddp_model, make_shard_map_train_step
-    from ..train.schedule import build_optimizer
-    from ..train.state import TrainState
-
-    cfg = ctx.tiny_cfg("baseline")
-    if "ddp" not in ctx._cache:
-        model = build_ddp_model(cfg)
-        p_rng, d_rng = jax.random.split(jax.random.PRNGKey(cfg.run.seed))
-        h = ctx.image_size
-        variables = model.init({"params": p_rng, "dropout": d_rng},
-                               jnp.zeros((2, h, h, 3)), train=False)
-        tx = build_optimizer(cfg.optim, 4)
-        state = TrainState(step=jnp.zeros((), jnp.int32),
-                           params=variables["params"],
-                           batch_stats=variables.get("batch_stats", {}),
-                           opt_state=tx.init(variables["params"]))
-        ctx._cache["ddp"] = (model, tx, state)
-    model, tx, state = ctx._cache["ddp"]
-    fn = make_shard_map_train_step(cfg, model, tx, ctx.mesh)
-    # the shard_map path is the float32 reference program (no epilogue)
-    return fn, (state, ctx.images(jnp.float32), ctx.labels())
-
-
 def build_registry() -> List[StepSpec]:
     """Every jitted step program the framework runs, with its invariants.
     Ordered cheap-to-expensive so a red CLI run fails fast.
@@ -639,10 +615,10 @@ def build_registry() -> List[StepSpec]:
     NOTE: a new jitted step factory MUST be registered here — it is then
     donation/epilogue/callback-audited automatically, AND wrapped into the
     dtype pass's contract cells by `dtype_audit.dtype_registry()` (D1–D6
-    at the f32-pinned audit precision; name-prefix `train_step`/
-    `shard_map_train` turns on the D2 master-weights contract). A NEW
-    PRECISION KNOB additionally needs an explicit `#<knob>` cell (plus a
-    `WAIVER_REASONS` entry if it trades precision) in `dtype_registry()`.
+    at the f32-pinned audit precision; name-prefix `train_step` turns on
+    the D2 master-weights contract). A NEW PRECISION KNOB additionally needs
+    an explicit `#<knob>` cell (plus a `WAIVER_REASONS` entry if it trades
+    precision) in `dtype_registry()`.
     The `lint_jit_sites` guard (tests/conftest.py) fails on any
     `jax.jit` site in train/steps.py that is not reachable from a
     registered factory."""
@@ -753,13 +729,6 @@ def build_registry() -> List[StepSpec]:
             donate=(0,),
             uint8_input=True,
             allow_collectives=True,  # the once-per-K pmean IS this program
-        ),
-        StepSpec(
-            name="shard_map_train_step",
-            factory="ddp_classification_pytorch_tpu.parallel.collectives:make_shard_map_train_step",
-            build=_build_shard_map_train,
-            donate=(0,),
-            allow_collectives=True,  # explicit pmean/psum IS this program
         ),
     ]
 

@@ -1,0 +1,121 @@
+"""What the token decoder says about itself to whoever runs it.
+
+The training loop asks `models/factory.py::model_report` and gets this for
+`decoder_lm`: the inputs the model is initialised on, the row length its token
+file is cut at, the notes and static counters of what a run built, the
+counters of a logged step's routing, and which of an epoch's metrics are
+gauges. Every name and help text `docs/observability.md` lists for the decoder
+is written here; the predicates the notes come from are the ones the layer
+itself dispatches on (`decoder_lm.py`, `ops/moe.py`).
+"""
+
+from __future__ import annotations
+
+import collections
+from typing import Any, Dict, List, Mapping
+
+import jax.numpy as jnp
+import numpy as np
+
+from ..config import ModelConfig
+from ..ops.moe import slot_bound
+from .decoder_lm import (LOOP_TRACED, flash_backward_path, kda_core_path,
+                         kda_prepare_path)
+from .factory import ModelReport
+
+
+class DecoderReport(ModelReport):
+    def __init__(self, cfg: ModelConfig):
+        self.cfg = cfg
+        self.bound = self.slots = 0     # of a routing layer: set by `built`
+
+    def init_inputs(self, image_size: int) -> Any:
+        """Token ids; parameters do not depend on T, so a few positions do."""
+        return jnp.zeros((2, min(self.cfg.decoder.seq_len, 8)), jnp.int32)
+
+    def token_row_length(self) -> int:
+        return self.cfg.decoder.seq_len
+
+    def built(self, rows: int, registry) -> Dict[str, Any]:
+        """The layout the decoder was built with, for a step of `rows` rows:
+        how many of its layers mix tokens by which operator before which
+        feed-forward — a static counter in `registry`, and the same counts as
+        notes for the set-up line, with the path the attention kernels'
+        backward takes at these sizes and whether the delta layers'
+        recurrence and their input side take their kernels; of a looped stack
+        also how often a step applies a layer, and how its passes are traced;
+        of a routing one the sorted rows a layer keeps / the slots it routes."""
+        dc = self.cfg.decoder
+        notes: Dict[str, Any] = {}
+        kinds = collections.Counter(dc.layer_kinds())
+        for (operator, ffn), n in sorted(kinds.items()):
+            registry.counter("decoder_layers_total", "layers of the token "
+                             "decoder by token mixer and feed-forward",
+                             {"operator": operator, "ffn": ffn}).inc(n)
+            notes[f"{operator}_{ffn}"] = n
+        registry.counter("decoder_layer_applications_total", "layers a step "
+                         "runs: the layers built x the passes of the stack "
+                         "(--loops)").inc(dc.loops * dc.num_layers)
+        if dc.loops > 1:
+            notes.update(loops=dc.loops, sandwich=dc.sandwich_norm,
+                         passes=LOOP_TRACED)
+        path = flash_backward_path(dc, self.cfg.dtype,
+                                   self.cfg.flash_min_tokens)
+        if path:
+            # what `flash_backward_total{path}` will count once the step is
+            # traced (ops/flash_attention.py), known here from the sizes
+            notes["flash_backward"] = path
+        core = kda_core_path(dc)
+        if core:
+            # the predicates `ops/kda.py::kda_chunked` and the layer's input
+            # side (`DecoderLayer._kda`) dispatch on
+            notes.update(kda_core=core, kda_prepare=kda_prepare_path(dc))
+        if dc.moe_layer_names():
+            # token-slots k·N a routing layer routes in a step, and the sorted
+            # rows it works on while its load fits: ops/moe.py::slot_bound at
+            # the step's shapes
+            self.slots = rows * dc.seq_len * dc.top_k
+            self.bound = slot_bound(self.slots, dc.held, dc.num_experts)
+            notes["moe_bound"] = f"{self.bound}/{self.slots}"
+        return notes
+
+    def logged_step(self, metrics: Mapping[str, Any], registry) -> None:
+        """The logged step's routing, as the step's metrics carry it —
+        `moe_load` (L, e): token-slots each held expert took in each routing
+        layer (`DecoderConfig.moe_layer_names`: the layer's index, or "mtp"
+        for the prediction module's)."""
+        if "moe_load" not in metrics:
+            return
+        load = np.asarray(metrics["moe_load"])
+        for name, row in zip(self.cfg.decoder.moe_layer_names(), load):
+            layer = {"layer": name}
+            registry.gauge("moe_expert_load_max", "token-slots of the "
+                           "busiest held expert in the logged step",
+                           layer).set(float(row.max()))
+            registry.gauge("moe_expert_load_mean", "mean token-slots of a "
+                           "held expert in the logged step",
+                           layer).set(float(row.mean()))
+            # which path `ops/moe.py::_sparse_experts` took, from the load it
+            # asked on the device (under a mesh: the shards' loads together
+            # against their bounds together)
+            fits = bool(row.sum() <= self.bound)
+            for path, took in (("bounded", fits), ("full", not fits)):
+                registry.counter("moe_slot_bound_total", "logged steps in "
+                                 "which the layer's load on held experts fit "
+                                 "the bounded sorted-row buffer / was walked "
+                                 "in several windows of it",
+                                 dict(layer, path=path)).inc(float(took))
+        routed = float(self.slots * len(load))
+        for held, n in (("true", float(load.sum())),
+                        ("false", routed - float(load.sum()))):
+            registry.counter("moe_slots_routed_total", "token-slots the "
+                             "routers of the logged steps sent to experts "
+                             "held here / elsewhere", {"held": held}).inc(n)
+
+    def epoch_gauges(self, metrics: Mapping[str, float]) -> List[str]:
+        """Of an epoch's means, those published as `train_<name>`: the parts
+        of a decoder with a prediction module (train_loss = loss_main +
+        mtp_weight x loss_mtp), or of a looped one (train_loss = Σ_t
+        exit_p<t> x loss_ut<t> over the targets, less exit_beta x the
+        entropy of p)."""
+        return sorted(k for k in metrics if k.startswith(("loss_", "exit_p")))

@@ -265,3 +265,36 @@ def build_model(cfg: ModelConfig, num_classes: int,
             classifier=NetClassifier(num_classes),
         )
     raise ValueError(f"unknown head {cfg.head!r}")
+
+
+class ModelReport:
+    """What a model says about itself to the loop that trains it
+    (`model_report`). An image model: the images it is initialised on, and
+    nothing else — no token rows, no notes, no counters, no gauges."""
+
+    def init_inputs(self, image_size: int) -> Any:
+        return jnp.zeros((2, image_size, image_size, 3), jnp.float32)
+
+    def token_row_length(self) -> int:
+        raise ValueError("dataset 'tokens' feeds a model that reads token "
+                         "rows (--model decoder_lm)")
+
+    def built(self, rows: int, registry) -> dict:
+        """Notes for the set-up line on what was built for steps of `rows`
+        rows; its static counters go into `registry`."""
+        return {}
+
+    def logged_step(self, metrics, registry) -> None:
+        """What a logged step's metrics show, into `registry`."""
+
+    def epoch_gauges(self, metrics) -> list:
+        """Names of an epoch's mean metrics published as `train_<name>`."""
+        return []
+
+
+def model_report(cfg: ModelConfig) -> ModelReport:
+    if cfg.arch == "decoder_lm":
+        from .decoder_report import DecoderReport
+
+        return DecoderReport(cfg)
+    return ModelReport()

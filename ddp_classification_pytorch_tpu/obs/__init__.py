@@ -12,9 +12,8 @@ Three planes, one package:
   work that really ran (set-up phases, the input pipeline's stages, the
   step loop), always on, bounded; read by `Trainer._write_prom`
   (`span_seconds_total{span=…}`) and by the benchmark's per-layer readers.
-- `obs.events` — the machine-readable event plane (`events.jsonl`),
-  promoted from `scenario/events.py` (which remains as a compat
-  re-export). `emit()` stays env-gated and unconditionally cheap.
+- `obs.events` — the machine-readable event plane (`events.jsonl`).
+  `emit()` stays env-gated and unconditionally cheap.
 
 Everything here is host-side bookkeeping: no instrument ever syncs a
 device value or appears inside a jitted program (`analysis/lint.py`

@@ -56,10 +56,6 @@ S5 invariant replays these):
                           queue_depth, fill_ratio
     replica_retire        replica                    retired replica excused
                                                      from future S3 adoption
-
-Historically this lived at `scenario/events.py`; it was promoted here so
-non-scenario subsystems emit through the same spine without reaching into
-the scenario package. `scenario.events` remains a compat re-export.
 """
 
 from __future__ import annotations

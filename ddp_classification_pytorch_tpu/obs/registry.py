@@ -27,6 +27,7 @@ torn mix (pinned by tests/test_obs.py).
 
 from __future__ import annotations
 
+import math
 import os
 import re
 import threading
@@ -63,8 +64,12 @@ def _fmt_labels(labels: Tuple[Tuple[str, str], ...], extra: str = "") -> str:
 
 
 def _fmt_value(v: float) -> str:
-    # integers render bare (counter conventions); floats keep repr precision
+    # integers render bare (counter conventions); floats keep repr precision;
+    # a non-finite value as the exposition format spells it (a diverged loss
+    # gauge must show in the scrape file, not end it)
     f = float(v)
+    if not math.isfinite(f):
+        return "NaN" if math.isnan(f) else ("+Inf" if f > 0 else "-Inf")
     return str(int(f)) if f == int(f) and abs(f) < 1e15 else repr(f)
 
 

@@ -12,7 +12,7 @@ relaunches a host the chaos plan SIGKILLed once the survivors re-form
 around its absence; and on completion runs the analyzer gate
 (`scripts/lint.sh`). Every transition lands in the shared `events.jsonl` —
 the supervisor's own record plus what the trainer/serve processes emit
-through `scenario.events.emit` — which the invariant checker then replays.
+through `obs.events.emit` — which the invariant checker then replays.
 
 When `serve.max_replicas > replicas` the supervisor also runs the
 autoscaler loop: it aggregates the replicas' /metrics.json gauges (sum of

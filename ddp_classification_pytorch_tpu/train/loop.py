@@ -33,7 +33,6 @@ TPU-first details the reference has no analogue for:
 
 from __future__ import annotations
 
-import collections
 import os
 import time
 from typing import Any, Dict, Optional, Tuple
@@ -50,9 +49,9 @@ from ..data import native as native_mod
 from ..data.native import NativeBatcher
 from ..data.synthetic import SyntheticDataset
 from ..data.transforms import build_transform
+from ..models.factory import model_report
 from ..obs import spans
 from ..obs.registry import Registry
-from ..ops.moe import slot_bound
 from ..ops.nested import best_k
 from ..parallel import fleet as fleetlib
 from ..parallel import mesh as meshlib
@@ -112,7 +111,7 @@ def build_datasets(cfg: Config) -> Tuple[Any, Any]:
     if d.dataset == "tokens":
         from ..data.tokens import TokenDataset
 
-        seq_len = cfg.model.decoder.seq_len
+        seq_len = model_report(cfg.model).token_row_length()
         return (TokenDataset(d.train_dir, seq_len),
                 TokenDataset(d.val_dir or d.train_dir, seq_len))
     preset = dataset_transform_preset(d)
@@ -303,8 +302,10 @@ class Trainer:
             # creeps into set-up shows here before it shows in seconds
             n1, s1 = progcache.compiled()
             spans.note(compiles=n1 - n0, compile_s=round(s1 - s0, 3))
-            if cfg.model.arch == "decoder_lm":
-                self._publish_layer_kinds()
+            # what the model says it was built as, and its static counters
+            self.report = model_report(cfg.model)
+            spans.note(**self.report.built(
+                cfg.data.batch_size * jax.process_count(), self.obs))
 
         with phase("build_steps"):
             self.train_step = make_train_step(cfg, self.model, self.tx,
@@ -499,8 +500,7 @@ class Trainer:
             eta.maybe_log(epoch, step,
                           **{k: float(v) for k, v in metrics.items()
                              if v.ndim == 0})
-        if "moe_load" in metrics:
-            self._publish_moe_load(np.asarray(metrics["moe_load"]))
+        self.report.logged_step(metrics, self.obs)
         # flush is a device round-trip too, so reaching here is proof the
         # backend is answering — heartbeat it. It also raises
         # SentinelDiverged on a sustained-NaN streak (pod mode: noted as
@@ -520,87 +520,6 @@ class Trainer:
         # refresh the scrape file on the same cadence (atomic rewrite; host 0
         # only)
         self._write_prom()
-
-    def _publish_layer_kinds(self) -> None:
-        """The layout the decoder was built with: how many of its layers mix
-        tokens by which operator before which feed-forward — a static
-        counter, and the same counts beside `init_state` in the set-up line,
-        with the path the attention kernels' backward takes at these sizes
-        and whether the delta layers' recurrence and their input side take
-        their kernels; of a looped stack also how often a step applies a
-        layer, and how its passes are traced."""
-        from ..models.decoder_lm import (LOOP_TRACED, flash_backward_path,
-                                         kda_core_path, kda_prepare_path)
-
-        dc = self.cfg.model.decoder
-        kinds = collections.Counter(dc.layer_kinds())
-        for (operator, ffn), n in sorted(kinds.items()):
-            self.obs.counter("decoder_layers_total", "layers of the token "
-                             "decoder by token mixer and feed-forward",
-                             {"operator": operator, "ffn": ffn}).inc(n)
-            spans.note(**{f"{operator}_{ffn}": n})
-        self.obs.counter("decoder_layer_applications_total", "layers a step "
-                         "runs: the layers built x the passes of the stack "
-                         "(--loops)").inc(dc.loops * dc.num_layers)
-        if dc.loops > 1:
-            spans.note(loops=dc.loops, sandwich=dc.sandwich_norm,
-                       passes=LOOP_TRACED)
-        path = flash_backward_path(dc, self.cfg.model.dtype,
-                                   self.cfg.model.flash_min_tokens)
-        if path:
-            # what `flash_backward_total{path}` will count once the step is
-            # traced (ops/flash_attention.py), known here from the sizes
-            spans.note(flash_backward=path)
-        core = kda_core_path(dc)
-        if core:
-            # the predicates `ops/kda.py::kda_chunked` and the layer's input
-            # side (`DecoderLayer._kda`) dispatch on
-            spans.note(kda_core=core, kda_prepare=kda_prepare_path(dc))
-        if dc.moe_layer_names():
-            # the sorted rows a routing layer keeps / the slots it routes
-            bound, slots = self._moe_bound()
-            spans.note(moe_bound=f"{bound}/{slots}")
-
-    def _moe_bound(self) -> Tuple[int, int]:
-        """(sorted rows a routing layer works on while its load fits, token-
-        slots k·N it routes in a step): ops/moe.py::slot_bound at the step's
-        shapes."""
-        dc = self.cfg.model.decoder
-        slots = (self.cfg.data.batch_size * jax.process_count() * dc.seq_len
-                 * dc.top_k)
-        return slot_bound(slots, dc.held, dc.num_experts), slots
-
-    def _publish_moe_load(self, load: np.ndarray) -> None:
-        """The logged step's routing, as the step's metrics carry it —
-        `load` (L, e): token-slots each held expert took in each routing
-        layer (`DecoderConfig.moe_layer_names`: the layer's index, or "mtp"
-        for the prediction module's)."""
-        dc = self.cfg.model.decoder
-        # which path `ops/moe.py::_sparse_experts` took, from the load it asked
-        # on the device (under a mesh: the shards' loads together against
-        # their bounds together)
-        bound, slots = self._moe_bound()
-        for name, row in zip(dc.moe_layer_names(), load):
-            layer = {"layer": name}
-            self.obs.gauge("moe_expert_load_max", "token-slots of the "
-                           "busiest held expert in the logged step",
-                           layer).set(float(row.max()))
-            self.obs.gauge("moe_expert_load_mean", "mean token-slots of a "
-                           "held expert in the logged step",
-                           layer).set(float(row.mean()))
-            fits = bool(row.sum() <= bound)
-            for path, took in (("bounded", fits), ("full", not fits)):
-                self.obs.counter("moe_slot_bound_total", "logged steps in "
-                                 "which the layer's load on held experts fit "
-                                 "the bounded sorted-row buffer / was walked "
-                                 "in several windows of it",
-                                 dict(layer, path=path)).inc(float(took))
-        routed = float(slots * len(load))
-        for held, n in (("true", float(load.sum())),
-                        ("false", routed - float(load.sum()))):
-            self.obs.counter("moe_slots_routed_total", "token-slots the "
-                             "routers of the logged steps sent to experts "
-                             "held here / elsewhere", {"held": held}).inc(n)
 
     def train_epoch(self, epoch: int, eta: Optional[EtaLogger] = None) -> Dict[str, float]:
         self.train_loader.set_epoch(epoch)
@@ -747,16 +666,11 @@ class Trainer:
                 last = {**train_m, **val_m, "epoch_time": time.time() - t0}
                 self._epochs_counter.inc()
                 self._loss_gauge.set(last.get("loss", 0.0))
-                for part in sorted(last):
-                    # a decoder with a prediction module (train_loss =
-                    # loss_main + mtp_weight x loss_mtp), or a looped one
-                    # (train_loss = Σ_t exit_p<t> x loss_ut<t> over the
-                    # targets, less exit_beta x the entropy of p)
-                    if part.startswith(("loss_", "exit_p")):
-                        self.obs.gauge(
-                            f"train_{part}", "mean of the step's metric "
-                            f"`{part}` over the last completed epoch "
-                            "(docs/observability.md)").set(last[part])
+                for part in self.report.epoch_gauges(last):
+                    self.obs.gauge(
+                        f"train_{part}", "mean of the step's metric "
+                        f"`{part}` over the last completed epoch "
+                        "(docs/observability.md)").set(last[part])
                 if "val_top1" in last:
                     self._val_top1_gauge.set(last["val_top1"])
                 self._epoch_seconds_gauge.set(last["epoch_time"])

@@ -25,7 +25,7 @@ import optax
 from flax import struct
 
 from ..config import Config
-from ..models.factory import build_model
+from ..models.factory import build_model, model_report
 from ..parallel import mesh as meshlib
 from .schedule import build_optimizer
 
@@ -72,12 +72,7 @@ def create_train_state(
 
     def init_variables(rng):
         p_rng, d_rng = jax.random.split(rng)
-        if cfg.model.arch == "decoder_lm":
-            # token ids; parameters do not depend on T, so a few positions do
-            inputs = [jnp.zeros((2, min(cfg.model.decoder.seq_len, 8)), jnp.int32)]
-        else:
-            h = w = cfg.data.image_size
-            inputs = [jnp.zeros((2, h, w, 3), jnp.float32)]
+        inputs = [model_report(cfg.model).init_inputs(cfg.data.image_size)]
         if cfg.model.head == "arcface":
             inputs.append(jnp.zeros((2,), jnp.int32))  # labels
         elif cfg.model.head == "nested":

@@ -235,10 +235,9 @@ def test_rc_lint_passes_catalogued_patterns():
 def test_registry_names_every_step_program():
     names = {s.name for s in build_registry()}
     assert names == {"train_step", "eval_step", "nested_eval_step",
-                     "plc_predict", "topk_predict", "shard_map_train_step",
-                     "train_step_survivor",
-                     # the bf16-wire gradient-reduction variant of the
-                     # shard_map train step (--grad_reduce_dtype bfloat16)
+                     "plc_predict", "topk_predict", "train_step_survivor",
+                     # the bf16-wire gradient-reduction variant: a shard_map
+                     # section of the train step (--grad_reduce_dtype bfloat16)
                      "train_step_bf16_reduce",
                      # the same eval-family programs traced under the
                      # composed dp×tp mesh (sharded audit satellites)
@@ -265,12 +264,11 @@ def test_self_audit_repo_is_clean(audit):
 
 def test_train_steps_donation_fully_aliased(audit):
     """The MFU item's donation audit: every donated state byte is aliased
-    in BOTH train-step executables — no buffer round-trips HBM."""
-    for name in ("train_step", "shard_map_train_step"):
-        don = audit.specs[name].evidence["donation"]
-        assert don["donated_bytes"] > 10_000_000, (name, don)  # real state
-        assert don["donation_coverage"] == 1.0, (name, don)
-        assert don["unaliased"] == [], (name, don)
+    in the train step's executable — no buffer round-trips HBM."""
+    don = audit.specs["train_step"].evidence["donation"]
+    assert don["donated_bytes"] > 10_000_000, don  # real state
+    assert don["donation_coverage"] == 1.0, don
+    assert don["unaliased"] == [], don
 
 
 def test_step_factories_lint_clean():

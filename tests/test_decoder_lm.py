@@ -546,20 +546,18 @@ def test_the_trainer_counts_which_buffer_each_layer_took():
     """`moe_slot_bound_total{layer, path}` from the logged step's `moe_load`
     against the bound the op itself asks: 2 rows of 128 tokens, top-2, 2 of
     16 held: 256 sorted rows for 512 slots."""
-    from types import SimpleNamespace
-
+    from ddp_classification_pytorch_tpu.models.factory import model_report
     from ddp_classification_pytorch_tpu.obs.registry import Registry
-    from ddp_classification_pytorch_tpu.train.loop import Trainer
 
     cfg = cli_config(dict(ARCH, num_experts=16, experts_held=2, seq_len=128),
                      "--batchsize", "2")
-    stub = SimpleNamespace(cfg=cfg, obs=Registry())
-    stub._moe_bound = lambda: Trainer._moe_bound(stub)
-    assert stub._moe_bound() == (256, 512)
+    report, obs = model_report(cfg.model), Registry()
+    assert report.built(cfg.data.batch_size, obs)["moe_bound"] == "256/512"
+    assert (report.bound, report.slots) == (256, 512)
     for load in ([[0, 0], [200, 56], [200, 57], [100, 30]],
                  [[3, 4], [256, 1], [512, 0], [0, 256]]):
-        Trainer._publish_moe_load(stub, np.array(load))
-    text = stub.obs.expose()
+        report.logged_step({"moe_load": np.array(load)}, obs)
+    text = obs.expose()
     for layer, bounded, full in (("0", 2, 0), ("1", 1, 1), ("2", 0, 2), ("3", 2, 0)):
         assert f'moe_slot_bound_total{{layer="{layer}",path="bounded"}} {bounded}\n' in text
         assert f'moe_slot_bound_total{{layer="{layer}",path="full"}} {full}\n' in text

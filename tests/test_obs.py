@@ -160,18 +160,24 @@ def test_write_prom_atomic_under_concurrent_reads(tmp_path):
     assert not [p for p in os.listdir(tmp_path) if p.endswith(".tmp")]
 
 
+@pytest.mark.parametrize("value,text", [
+    (float("nan"), "NaN"), (float("inf"), "+Inf"), (float("-inf"), "-Inf")])
+def test_a_non_finite_gauge_shows_in_the_scrape_file(tmp_path, value, text):
+    """A diverged loss is set on `train_loss` at the log cadence: the scrape
+    file must show it as the exposition format spells it, beside the other
+    families, and not raise out of `write_prom` into the step loop."""
+    reg = Registry()
+    reg.gauge("train_loss", "mean train loss").set(value)
+    reg.counter("train_steps_total", "steps").inc(3)
+    assert f"train_loss {text}\n" in reg.expose()
+    path = str(tmp_path / "metrics.prom")
+    reg.write_prom(path)
+    with open(path) as f:
+        body = f.read()
+    assert f"train_loss {text}\n" in body and "train_steps_total 3\n" in body
+
+
 # ------------------------------------------------------------- event plane --
-
-def test_scenario_events_is_compat_reexport():
-    """The promotion must keep every historical `scenario.events` name
-    bound to the SAME objects — env-gated emitters registered against one
-    module must be visible through the other."""
-    from ddp_classification_pytorch_tpu.scenario import events as compat
-
-    for name in ("ENV_EVENTS", "ENV_SOURCE", "EventLog", "emit",
-                 "read_events", "write_event"):
-        assert getattr(compat, name) is getattr(obs_events, name), name
-
 
 def test_emit_gated_and_readable(tmp_path, monkeypatch):
     path = str(tmp_path / "events.jsonl")

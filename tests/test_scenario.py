@@ -15,7 +15,7 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
-from ddp_classification_pytorch_tpu.scenario import events as ev
+from ddp_classification_pytorch_tpu.obs import events as ev
 from ddp_classification_pytorch_tpu.scenario.invariants import (
     check_invariants,
     check_restarts_log,
