@@ -90,8 +90,9 @@ def build_parser() -> argparse.ArgumentParser:
                         "decoder_lm (a token decoder: next-token training "
                         "as per-position classification; sizes in the "
                         "'decoder' group: per layer grouped-query or latent "
-                        "attention, a gated short convolution or Kimi delta "
-                        "attention, dense / routed / shared feed-forward, "
+                        "attention, a gated short convolution, Kimi delta "
+                        "attention or Gated DeltaNet, a share of the heads, "
+                        "dense / routed / shared feed-forward, "
                         "softmax or sigmoid router with or without a group "
                         "limit, a multi-token-prediction module, a tied or "
                         "untied head, the stack run --loops times with an "
@@ -136,36 +137,39 @@ def build_parser() -> argparse.ArgumentParser:
     dec = p.add_argument_group(
         "decoder", "sizes of --model decoder_lm (config.DecoderConfig); "
         "--num_classes follows --vocab_size")
+    # in config.DecoderConfig's own order, which says what each value means
     for flag, kind in (("vocab_size", int), ("hidden_size", int),
                        ("num_layers", int), ("num_heads", int),
                        ("num_kv_heads", int), ("head_dim", int),
                        ("expert_width", int), ("num_experts", int),
                        ("experts_held", int), ("first_expert", int),
-                       ("top_k", int), ("window", int), ("seq_len", int),
-                       ("head_block", int), ("rope_theta", float),
-                       ("rms_eps", float),
-                       # the kinds of layer (config.DecoderConfig says what
-                       # each value means)
+                       ("top_k", int), ("window", int), ("rope_theta", float),
+                       ("rms_eps", float), ("seq_len", int),
+                       ("head_block", int),
                        ("attention", str), ("q_rank", int), ("kv_rank", int),
                        ("rope_dim", int), ("v_head_dim", int),
                        ("rope_pairing", str), ("qk_norm", int),
-                       ("out_gate", int), ("conv_kernel", int),
+                       ("out_gate", int), ("gdn_key_dim", int),
+                       ("gdn_value_dim", int), ("conv_kernel", int),
+                       ("heads_held", int), ("dense_layers", int),
+                       ("dense_width", int),
+                       ("activation", str), ("router", str),
+                       ("router_scale", float), ("router_eps", float),
                        ("n_group", int), ("topk_group", int),
-                       ("dense_layers", int),
-                       ("dense_width", int), ("activation", str),
-                       ("router", str), ("router_scale", float),
-                       ("router_eps", float),
                        ("router_tap", str), ("shared_experts", int),
                        ("mtp_layers", int), ("mtp_weight", float),
                        ("tied_embeddings", int), ("loops", int),
-                       ("sandwich_norm", int), ("exit_beta", float)):
+                       ("sandwich_norm", int), ("pre_norm", int),
+                       ("exit_beta", float)):
         dec.add_argument(f"--{flag}", type=kind, default=None)
-    for flag in ("rope_layout", "window_layout", "conv_layout", "kda_layout"):
+    for flag in ("rope_layout", "window_layout", "conv_layout", "kda_layout",
+                 "gdn_layout"):
         dec.add_argument(f"--{flag}", default=None,
                          help="comma-separated 0/1 per layer, repeated to "
                               "the depth (e.g. 0,1,1,1); conv_layout: 1 = the "
                               "gated short convolution in attention's place; "
-                              "kda_layout: 1 = Kimi delta attention there")
+                              "kda_layout: 1 = Kimi delta attention there; "
+                              "gdn_layout: 1 = Gated DeltaNet there")
 
     a = p.add_argument_group("arcface")
     a.add_argument("--arc_s", type=float, default=-1.0)

@@ -73,27 +73,31 @@ class DecoderConfig:
     models/decoder_lm.py) — the one place they live; the CLI fills it.
     Defaults are the published SmallThinker-21BA3B-Instruct config.json
     (PowerInfer, arXiv:2507.20984). The layer is described by data: the
-    token mixer per layer, one of three kinds (`conv_layout`: the gated short
+    token mixer per layer, one of four kinds (`conv_layout`: the gated short
     convolution of LFM2, LiquidAI's `lfm2` / `lfm2_moe`; `kda_layout`: Kimi
-    delta attention, a recurrence over the row, arXiv:2510.26692; else the
-    configured attention), the attention kind, its QK-norm and its output
-    gate, the feed-forward kind per layer (`dense_layers` leading dense ones,
+    delta attention, a recurrence over the row, arXiv:2510.26692;
+    `gdn_layout`: Gated DeltaNet, the same recurrence with one decay a head,
+    arXiv:2412.06464; else the configured attention), the attention kind,
+    its QK-norm and its output gate, where the block's norms stand
+    (`pre_norm`, `sandwich_norm`), the feed-forward kind per layer (`dense_layers` leading dense ones,
     then routed experts with or without a shared expert), the router's
     scoring, group limit and tap, the activation, the rotary pairing, a head
     of its own or tied to the embedding, and a multi-token-prediction module
     after the last layer. `loops` > 1 runs the whole stack that many times on
     its own output with the same weights (a looped language model,
     arXiv:2510.25741: `sandwich_norm`, an exit gate, a loss weighted over the
-    passes). Five published models are its fixed points: SmallThinker's, the
+    passes). Six published models are its fixed points: SmallThinker's, the
     DeepSeek-V3 layer as JoyAI-LLM-Flash configures it, LFM2-8B-A1B's,
-    Ling-3.0-flash's (inclusionAI, `bailing_hybrid`) and Ouro-2.6B
-    (ByteDance, `ouro`).
+    Ling-3.0-flash's (inclusionAI, `bailing_hybrid`), Ouro-2.6B (ByteDance,
+    `ouro`) and Olmo-Hybrid-7B's (allenai, `olmo_hybrid`).
 
-    A deployment that spreads a layer's experts and the vocabulary's rows
-    over several chips gives each chip its share: `experts_held` experts
-    starting at `first_expert` (the router keeps its full width
-    `num_experts` and its `top_k`), and `vocab_size` rows of the vocabulary
-    (embedding, head, loss and token ids are over the slice)."""
+    A deployment that spreads a layer's experts, its heads and the
+    vocabulary's rows over several chips gives each chip its share:
+    `experts_held` experts starting at `first_expert` (the router keeps its
+    full width `num_experts` and its `top_k`), `heads_held` of the `num_heads`
+    heads of every "gqa" and Gated DeltaNet layer, and `vocab_size`
+    rows of the vocabulary (embedding, head, loss and token ids are over the
+    slice)."""
 
     vocab_size: int = 151936
     hidden_size: int = 2560
@@ -130,7 +134,10 @@ class DecoderConfig:
     rope_dim: int = 0
     v_head_dim: int = 0              # 0 = head_dim
     rope_pairing: str = "half"       # "half": i with i + D/2 | "interleaved": 2i with 2i + 1
-    qk_norm: int = 0                 # 1: RMSNorm on every "gqa" query and key head before the rotary embedding
+    # "gqa" queries and keys normed before the rotary embedding: 1 = an
+    # RMSNorm on every head (one scale of head_dim for all heads); 2 = ONE
+    # RMSNorm over the whole projection, all heads' dims together (Olmo's)
+    qk_norm: int = 0
     out_gate: int = 0                # 1: each attention head's output times sigmoid(h w_g), one gate a head, before W_o
     # the token mixer, two 0/1 lists repeated to the depth like the two
     # above; a layer neither marks runs the attention above.
@@ -144,9 +151,28 @@ class DecoderConfig:
     # whose log lies in (-5, 0), in chunks of 64 tokens, 8 heads at a time
     # (ops/kda.py's LOWER_BOUND, CHUNK, HEAD_GROUP: constants until a second
     # published value exists); a gated per-head RMSNorm on the output
+    # gdn_layout 1 = Gated DeltaNet (models/decoder_lm.py, ops/gdn.py): q, k,
+    # v through taps and SiLU as above, q and k L2-normed, `num_heads` states
+    # of gdn_key_dim x gdn_value_dim carried by the gated delta rule with ONE
+    # decay a head and token, its log -exp(A_log) softplus(. + dt_bias)
+    # unbounded, beta in (0, 2); a per-head RMSNorm on the output gated by
+    # SiLU of a full-width projection
     conv_layout: Sequence[int] = (0,)
     kda_layout: Sequence[int] = (0,)
-    conv_kernel: int = 3             # taps (LFM2 conv_L_cache 3; Ling short_conv_kernel_size 4)
+    gdn_layout: Sequence[int] = (0,)
+    gdn_key_dim: int = 0             # of a Gated DeltaNet head's q and k (Olmo-Hybrid: 96)
+    gdn_value_dim: int = 0           # of its v and o (Olmo-Hybrid: 192)
+    conv_kernel: int = 3             # taps (LFM2 conv_L_cache 3; Ling short_conv_kernel_size 4; Olmo-Hybrid linear_conv_kernel_dim 4)
+    # the chip's share of the heads of every "gqa" and Gated DeltaNet layer:
+    # `heads_held` of `num_heads` in each projection, tap and per-head leaf
+    # and in W_o's rows; what the absent heads would add to the mixer's
+    # output is left out. Which of the layer's heads they are is the
+    # deployment's to say: no arithmetic here reads it (unlike `first_expert`,
+    # which offsets the router's indices).
+    # 0 = all of them and no share: the mixers replicated over any mesh axis.
+    # > 0 under a `model` mesh axis > 1: the held heads are split evenly over
+    # it and one psum completes W_o's sum (and a whole-width QK-norm's)
+    heads_held: int = 0
     # feed-forward: the first `dense_layers` layers are one gated MLP of
     # `dense_width`; the others route over the experts
     dense_layers: int = 0
@@ -185,11 +211,24 @@ class DecoderConfig:
     # 1: a second RMSNorm on each sub-layer's OUTPUT before the residual add
     # (`norm_mix_out`, `norm_ffn_out`), beside the one on its input
     sandwich_norm: int = 0
+    # 0: no RMSNorm on a sub-layer's INPUT (`norm_in`, `norm_post` absent);
+    # with sandwich_norm 1 that is Olmo's reordered block, x + RMSNorm(f(x))
+    pre_norm: int = 1
     exit_beta: float = 0.05          # read only where loops > 1
 
     @property
     def held(self) -> int:
         return self.experts_held or self.num_experts
+
+    @property
+    def heads(self) -> int:
+        """Query heads a mixer computes here."""
+        return self.heads_held or self.num_heads
+
+    @property
+    def kv_heads(self) -> int:
+        """KV heads of the held query heads (whole groups: models/factory.py)."""
+        return self.num_kv_heads * self.heads // self.num_heads
 
     def layout(self, which: Sequence[int]) -> tuple:
         """A layout list repeated to the depth."""
@@ -207,11 +246,13 @@ class DecoderConfig:
 
     def layer_kinds(self) -> tuple:
         """(operator, ffn) of each of the `num_layers` layers: operator
-        "conv", "kda" or the attention kind, ffn "dense" | "routed"."""
-        return tuple(("conv" if conv else "kda" if kda else self.attention,
+        "conv", "kda", "gdn" or the attention kind, ffn "dense" | "routed"."""
+        return tuple(("conv" if conv else "kda" if kda else "gdn" if gdn
+                      else self.attention,
                       "dense" if i < self.dense_layers else "routed")
-                     for i, (conv, kda) in enumerate(zip(
-                         self.layout(self.conv_layout), self.layout(self.kda_layout))))
+                     for i, (conv, kda, gdn) in enumerate(zip(
+                         self.layout(self.conv_layout), self.layout(self.kda_layout),
+                         self.layout(self.gdn_layout))))
 
 
 @dataclass

@@ -2,19 +2,21 @@
 position over the vocabulary.
 
 ONE layer module, described by data (`config.DecoderConfig`, which the CLI
-fills — no table of variants, no second model file). Five published models
+fills — no table of variants, no second model file). Six published models
 are its fixed points: SmallThinker-21BA3B-Instruct (PowerInfer,
 arXiv:2507.20984; the defaults), the DeepSeek-V3 layer (arXiv:2412.19437
 §2.1-2.2) as JoyAI-LLM-Flash configures it, LFM2-8B-A1B (LiquidAI,
 `lfm2_moe`: most layers mix tokens by a gated short convolution and not by
 attention), Ling-3.0-flash (inclusionAI, `bailing_hybrid`: most layers
-carry a state along the row by Kimi delta attention, arXiv:2510.26692 §3)
-and Ouro-2.6B (ByteDance, `ouro`, arXiv:2510.25741: the whole stack runs
-`loops` times with the same weights, below).
+carry a state along the row by Kimi delta attention, arXiv:2510.26692 §3),
+Ouro-2.6B (ByteDance, `ouro`, arXiv:2510.25741: the whole stack runs
+`loops` times with the same weights, below) and Olmo-Hybrid-7B (allenai,
+`olmo_hybrid`: three Gated DeltaNet layers, arXiv:2412.06464, to one of
+attention, dense, the block's norms on the sub-layers' OUTPUTS only).
 With x (B, T, C), every projection without bias:
 
-    h  = RMSNorm(x)                        input norm
-    a  = the token mixer, one of three kinds per layer:
+    h  = RMSNorm(x)                        input norm (pre_norm 0: h = x)
+    a  = the token mixer, one of four kinds per layer:
          layers with conv_layout = 1: the gated short convolution —
                                            [B | C | X] = h W_in   (C, 3C)
                                            z   = B * X
@@ -61,11 +63,31 @@ With x (B, T, C), every projection without bias:
                                            kda_gated_norm.py); any other shape:
                                            `kda_prepare_xla` and `RMSNorm` over
                                            (B, T, H, d), in plain XLA
+         layers with gdn_layout = 1: Gated DeltaNet, H heads of d_k =
+         gdn_key_dim, d_v = gdn_value_dim — q, k, v as above (taps, SiLU, L2
+                                           norms, q times d_k^−½)
+                                           β_t = 2 sigmoid(h_t W_b) ∈ (0, 2)
+                                           g_t = −exp(A_log) ·
+                                             softplus(h_t W_a + dt_bias) ≤ 0,
+                                             unbounded: ONE decay a head,
+                                             A_log and dt_bias a head
+                                           S_t = e^{g_t} S_{t−1} + β_t k_t
+                                             (v_t − e^{g_t} S_{t−1}ᵀ k_t)ᵀ
+                                             S (d_k, d_v) a head
+                                           o_t = S_tᵀ q_t
+                                           a = [RMSNorm_{d_v}(o) · SiLU(h W_g)]
+                                             W_o: one scale of d_v for all
+                                             heads, a gate as wide as o; in
+                                             chunks of 64 tokens, plain XLA,
+                                             5 of 15 heads at a time
+                                             (ops/gdn.py)
          the others: attention(h) W_o      "gqa": H query heads on H_kv KV
-                                           heads of head_dim; with qk_norm an
-                                           RMSNorm (one scale of head_dim for
-                                           all heads) on every q and k head
-                                           first; layers with
+                                           heads of head_dim; with qk_norm 1
+                                           an RMSNorm (one scale of head_dim
+                                           for all heads) on every q and k
+                                           head first, with qk_norm 2 ONE
+                                           RMSNorm over the whole q and the
+                                           whole k projection; layers with
                                            rope_layout = 1 rotate q and k over
                                            the whole head, the others carry no
                                            position at all
@@ -85,7 +107,7 @@ With x (B, T, C), every projection without bias:
                                            times sigmoid(h w_g), one gate a
                                            head, before W_o
     x1 = x + a                             with sandwich_norm: x + RMSNorm(a)
-    u  = RMSNorm(x1)
+    u  = RMSNorm(x1)                       (pre_norm 0: u = x1)
     y  = layers < dense_layers: W_down(act(W_gate u) · W_up u), one gated MLP
          the others: Σ_{e ∈ chosen} g_e · W_down^e(act(W_gate^e u) · W_up^e u)
                      + (shared_experts > 0) the same unit on every token
@@ -137,7 +159,16 @@ This chip may hold a share of each layer (`experts_held`, `first_expert`,
 a slice of the vocabulary): the router keeps its full width, the expert
 layer (ops/moe.py::sparse_moe) computes its own experts' part, and under a
 `model` mesh axis > 1 the banks shard over it and a psum completes the sum.
-A shared expert is held by every chip and counted once.
+A shared expert is held by every chip and counted once. Of a "gqa" or a
+Gated DeltaNet layer it may hold a share of the heads too (`heads_held`):
+that many heads' columns of every projection, tap and per-head leaf and their
+rows of W_o (WHICH heads of the published layer they are is the deployment's
+to say and a checkpoint loader's to read; the program is the same); what the absent heads would add to the mixer's
+output is left out and the partial sum goes through the output norm to the
+next layer; a whole-width QK-norm's mean square runs over the held columns.
+Under a `model` mesh axis > 1 a SHARE is split evenly over it (`_over_heads`: a shard_map whose psum completes W_o's sum and the QK-norm's
+sum of squares, so that the shards together ARE the layer of the held heads);
+a layer that holds every head (`heads_held` 0) is replicated over the axis.
 
 TPU-first: bf16 matmuls with f32 accumulation, f32 params, norms, router,
 softmax, decays and recurrent state; attention through the Pallas flash
@@ -151,7 +182,10 @@ Device scopes (`jax.named_scope`, docs/observability.md): `attn`, `conv`
 the five projections and the input side, the kernels `kda_prepare_fwd` /
 `kda_prepare_bwd` or plain XLA; `kda.core`: the recurrence, `kda_fwd` /
 `kda_states` / `kda_bwd` or plain XLA; `kda.out`: the gate, the per-head norm,
-`kda_gated_norm_fwd` / `kda_gated_norm_bwd` or plain XLA, and W_o), `ffn`,
+`kda_gated_norm_fwd` / `kda_gated_norm_bwd` or plain XLA, and W_o), `gdn`
+(with `gdn.in`: the five projections, taps, SiLU, L2 norms, g and β;
+`gdn.core`: the recurrence; `gdn.out`: the gate, the per-head norm and W_o),
+`ffn`,
 `moe.route` / `.dispatch` / `.experts` / `.combine` / `.shared`, `mtp`
 (outermost, around the whole module), `lm_head`, `loop` (outermost, around
 the R passes of a looped stack).
@@ -160,6 +194,7 @@ the R passes of a looped stack).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -170,10 +205,11 @@ from jax.ad_checkpoint import checkpoint_name
 from ..config import DecoderConfig
 from ..ops.attention import attention, flash_supported
 from ..ops.flash_attention import backward_path, flash_attention
-from ..ops import kda_prepare
+from ..ops import gdn, kda_prepare
 from ..ops.kda import LOWER_BOUND, kda_chunked, kda_flat, takes_kernel
 from ..ops.kda_gated_norm import kda_gated_norm
 from ..ops.moe import GATE_ACTIVATIONS, sparse_moe
+from ..utils.compat import shard_map_unchecked
 
 
 def rotate_half(x: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -201,6 +237,15 @@ def rotate_interleaved(x: jnp.ndarray, theta: float) -> jnp.ndarray:
 _ROTARY = {"half": rotate_half, "interleaved": rotate_interleaved}
 
 
+def _rms(x: jnp.ndarray, scale: jnp.ndarray, eps: float, mean_square=None):
+    """x / sqrt(mean square + eps) · scale in f32; `mean_square` where it is
+    not the last axis's own (a norm over columns other shards hold too)."""
+    x = x.astype(jnp.float32)
+    if mean_square is None:
+        mean_square = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(mean_square + eps) * scale       # f32
+
+
 class RMSNorm(nn.Module):
     eps: float
 
@@ -208,9 +253,7 @@ class RMSNorm(nn.Module):
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
-        x = x.astype(jnp.float32)
-        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(ms + self.eps) * scale       # f32
+        return _rms(x, scale, self.eps)
 
 
 class NormScale(nn.Module):
@@ -222,8 +265,46 @@ class NormScale(nn.Module):
         return self.param("scale", nn.initializers.ones, (d,), jnp.float32)
 
 
+class Kernel(nn.Module):
+    """`nn.Dense`'s leaf without its matmul: `kernel` (rows, cols) under the
+    projection's name, for a mixer whose heads may be split over a mesh axis
+    (`_over_heads` hands each shard its columns or rows)."""
+
+    @nn.compact
+    def __call__(self, rows: int, cols: int) -> jnp.ndarray:
+        return self.param("kernel", nn.initializers.lecun_normal(), (rows, cols),
+                          jnp.float32)
+
+
 def _dense(features: int, dtype, name: str) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=dtype, name=name)
+
+
+def _project(x: jnp.ndarray, kernel: jnp.ndarray, dtype) -> jnp.ndarray:
+    """x (.., rows) kernel (rows, cols) in `dtype`, as `_dense` computes it."""
+    return jnp.dot(x.astype(dtype), kernel.astype(dtype))
+
+
+def _over_heads(core, h: jnp.ndarray, leaves: dict, kinds: dict, mesh, axis):
+    """`core(h, leaves, psum, shards)` → the mixer's output (B, T, C): of the
+    heads whose leaves it is handed, through `psum`. Without a mesh axis that
+    is all of them and `psum` the identity. Under one, a shard_map hands each
+    of the axis's `shards` its heads — `kinds` says how a leaf is cut: "cols"
+    (.., H·d) by columns, "rows" (H·d, C) by rows, "heads" (H..,) on its one
+    axis, "all" whole — and `psum` sums over the axis."""
+    n = mesh.shape[axis] if (mesh is not None and axis) else 1
+    if n <= 1:
+        return core(h, leaves, lambda x: x, 1)
+    from jax.sharding import PartitionSpec as P
+    from ..parallel.mesh import DATA_AXIS
+
+    cut = {"cols": P(None, axis), "rows": P(axis, None), "heads": P(axis), "all": P()}
+    dp = mesh.shape.get(DATA_AXIS, 1)
+    rows = P(DATA_AXIS if dp > 1 and h.shape[0] % dp == 0 else None, None, None)
+    return shard_map_unchecked(
+        lambda h_, leaves_: core(h_, leaves_, lambda x: jax.lax.psum(x, axis), n),
+        mesh=mesh, in_specs=(rows, {k: cut[kinds[k]] for k in leaves}),
+        out_specs=rows)(h, leaves)
 
 
 def head_kernel(params, cfg: DecoderConfig) -> jnp.ndarray:
@@ -309,6 +390,14 @@ def kda_core_path(cfg: DecoderConfig) -> Optional[str]:
     return "kernel" if takes_kernel(cfg.seq_len, cfg.head_dim, cfg.head_dim) else "xla"
 
 
+def gdn_core_path(cfg: DecoderConfig) -> Optional[str]:
+    """What the Gated DeltaNet layers' recurrence runs as (ops/gdn.py:
+    "xla" at every shape); None where no layer is one."""
+    if all(op != "gdn" for op, _ in cfg.layer_kinds()):
+        return None
+    return gdn.CORE_PATH
+
+
 def kda_prepare_path(cfg: DecoderConfig) -> Optional[str]:
     """What stands between the delta layers' projections and the recurrence
     at the configured sizes: "kernel", the fused op in the projections' own
@@ -321,6 +410,19 @@ def kda_prepare_path(cfg: DecoderConfig) -> Optional[str]:
         cfg.seq_len, cfg.head_dim, cfg.conv_kernel) else "xla")
 
 
+def _unit(x: jnp.ndarray) -> jnp.ndarray:
+    """x over its L2 norm along the last axis (a head's dims), eps 1e-6
+    inside the root."""
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
+
+
+def _tapped(x: jnp.ndarray, w: jnp.ndarray, heads: int) -> jnp.ndarray:
+    """SiLU of the causal taps w over a projection's output x (B, T, H·d), in
+    f32, split into heads → (B, T, H, d)."""
+    b, t, _ = x.shape
+    return jax.nn.silu(_causal_taps(x.astype(jnp.float32), w)).reshape(b, t, heads, -1)
+
+
 @functools.partial(jax.checkpoint, static_argnums=(10, 11))
 def kda_prepare_xla(xq, xk, xv, xf, xb, wq, wk, wv, a_log, dt_bias, heads, dtype):
     """The delta layer's input side in plain XLA: the projections' outputs
@@ -331,17 +433,38 @@ def kda_prepare_xla(xq, xk, xv, xf, xb, wq, wk, wv, a_log, dt_bias, heads, dtype
     b, t, _ = xq.shape
     hd = xq.shape[-1] // heads
 
-    def branch(x, w):
-        return jax.nn.silu(_causal_taps(x.astype(f32), w)).reshape(b, t, heads, hd)
-
-    def unit(x):    # L2 norm over the head's dims
-        return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + 1e-6)
-
     g = LOWER_BOUND * jax.nn.sigmoid(
         jnp.exp(a_log)[:, None] * (xf.astype(f32) + dt_bias).reshape(b, t, heads, hd))
-    return ((unit(branch(xq, wq)) * hd ** -0.5).astype(dtype),
-            unit(branch(xk, wk)).astype(dtype), branch(xv, wv).astype(dtype), g,
-            jax.nn.sigmoid(xb.astype(f32)))
+    return ((_unit(_tapped(xq, wq, heads)) * hd ** -0.5).astype(dtype),
+            _unit(_tapped(xk, wk, heads)).astype(dtype),
+            _tapped(xv, wv, heads).astype(dtype), g, jax.nn.sigmoid(xb.astype(f32)))
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """Gated DeltaNet's A ~ U(1, 16), as its log (flash-linear-attention)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """softplus⁻¹(dt), dt log-uniform in [1e-3, 0.1] (flash-linear-attention)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3), math.log(0.1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+@functools.partial(jax.checkpoint, static_argnums=(10,))
+def gdn_prepare(xq, xk, xv, xa, xb, wq, wk, wv, a_log, dt_bias, dtype):
+    """Gated DeltaNet's input side in plain XLA: the projections' outputs
+    (B, T, H·d_k), (B, T, H·d_v) and (B, T, H) → q, k (B, T, H, d_k) and v
+    (B, T, H, d_v) in `dtype`, g and β (B, T, H) float32. Keeps the
+    projections; the rest is elementwise."""
+    f32 = jnp.float32
+    heads = xa.shape[-1]
+    g = -jnp.exp(a_log) * jax.nn.softplus(xa.astype(f32) + dt_bias)
+    q = _unit(_tapped(xq, wq, heads))
+    return ((q * q.shape[-1] ** -0.5).astype(dtype),
+            _unit(_tapped(xk, wk, heads)).astype(dtype),
+            _tapped(xv, wv, heads).astype(dtype), g,
+            2.0 * jax.nn.sigmoid(xb.astype(f32)))
 
 
 class DecoderLayer(nn.Module):
@@ -353,7 +476,7 @@ class DecoderLayer(nn.Module):
     expert_axis: Optional[str] = None
     flash_min_tokens: int = 1024
     routed: bool = True     # False: one dense gated MLP (a leading layer)
-    mixer: str = "attn"     # "attn" | "conv" | "kda" (DecoderConfig.layer_kinds)
+    mixer: str = "attn"     # "attn" | "conv" | "kda" | "gdn" (DecoderConfig.layer_kinds)
 
     def _gated_mlp(self, u, width: int, prefix: str):
         """W_down(act(W_gate u) · W_up u): the dense layer's feed-forward
@@ -371,24 +494,11 @@ class DecoderLayer(nn.Module):
                               precision=jax.lax.Precision.HIGHEST)
 
     def _qkv(self, h):
-        """→ q, k, v (B, T, heads, ·) and the scores' second part (q_rope,
-        k_rope) or ()."""
+        """Latent attention's → q, k, v (B, T, heads, ·) and the scores' second
+        part (q_rope, k_rope)."""
         c = self.cfg
         b, t, _ = h.shape
         rotary = functools.partial(_ROTARY[c.rope_pairing], theta=c.rope_theta)
-        if c.attention == "gqa":
-            q = _dense(c.num_heads * c.head_dim, self.dtype, "q")(h)
-            k = _dense(c.num_kv_heads * c.head_dim, self.dtype, "k")(h)
-            v = _dense(c.num_kv_heads * c.head_dim, self.dtype, "v")(h)
-            q = q.reshape(b, t, c.num_heads, c.head_dim)
-            k = k.reshape(b, t, c.num_kv_heads, c.head_dim)
-            v = v.reshape(b, t, c.num_kv_heads, c.head_dim)
-            if c.qk_norm:   # over the head's dims, one scale for all heads
-                q = RMSNorm(c.rms_eps, name="q_head_norm")(q).astype(self.dtype)
-                k = RMSNorm(c.rms_eps, name="k_head_norm")(k).astype(self.dtype)
-            if self.rope:
-                q, k = rotary(q), rotary(k)
-            return q, k, v, ()
         # latent attention: queries through a normed bottleneck (or, with
         # q_rank 0, straight from h); one normed latent gives every head its
         # position-free key and its value, and one rotary key head serves all
@@ -474,13 +584,119 @@ class DecoderLayer(nn.Module):
                      ).astype(self.dtype).reshape(b, t, -1)
             return _dense(dim, self.dtype, "kda_o")(y)
 
+    def _share_axis(self):
+        """(mesh, axis) a share of the heads is split over: the experts' axis
+        where `heads_held` is set; else (None, None): a layer that holds every
+        head is replicated over any axis, as its other leaves are."""
+        return (self.mesh, self.expert_axis) if self.cfg.heads_held else (None, None)
+
+    def _gdn(self, h):
+        """Gated DeltaNet's block (ops/gdn.py has the equations): h (B, T, C)
+        → (B, T, C), of the heads held here (`DecoderConfig.heads`). Its
+        leaves are declared here at those heads' widths and the arithmetic is
+        `core`, which `_over_heads` runs on all of them or, where a share is
+        split over a mesh axis, on each shard's."""
+        c = self.cfg
+        dim, heads, dk, dv = h.shape[-1], c.heads, c.gdn_key_dim, c.gdn_value_dim
+        f32, lecun = jnp.float32, nn.initializers.lecun_normal()
+        leaves, kinds = {}, {}
+        for name, width in (("q", dk), ("k", dk), ("v", dv), ("gate", dv),
+                            ("a", 1), ("beta", 1)):
+            leaves[name] = Kernel(name=f"gdn_{name}")(dim, heads * width)
+            kinds[name] = "cols"
+        for name, width in (("q", dk), ("k", dk), ("v", dv)):
+            leaves[f"taps_{name}"] = self.param(
+                f"gdn_taps_{name}", lecun, (c.conv_kernel, heads * width), f32)
+            kinds[f"taps_{name}"] = "cols"
+        leaves["a_log"] = self.param("gdn_a_log", _a_log_init, (heads,), f32)
+        leaves["dt_bias"] = self.param("gdn_dt_bias", _dt_bias_init, (heads,), f32)
+        kinds.update(a_log="heads", dt_bias="heads")
+        leaves["norm"], kinds["norm"] = NormScale(name="gdn_norm")(dv), "all"
+        leaves["o"], kinds["o"] = Kernel(name="gdn_o")(heads * dv, dim), "rows"
+
+        def core(h, w, psum, shards):
+            b, t, _ = h.shape
+            with jax.named_scope("gdn.in"):
+                xs = [_project(h, w[n], self.dtype) for n in ("q", "k", "v", "a", "beta")]
+                q, k, v, g, beta = gdn_prepare(
+                    *xs, w["taps_q"], w["taps_k"], w["taps_v"], w["a_log"],
+                    w["dt_bias"], self.dtype)
+            with jax.named_scope("gdn.core"):
+                # named for --remat's policy, as `kda_out` is
+                o = checkpoint_name(
+                    gdn.gdn_chunked(q, k, v, g, beta, dtype=self.dtype), "gdn_out")
+            with jax.named_scope("gdn.out"):
+                gate = jax.nn.silu(_project(h, w["gate"], self.dtype).astype(f32))
+                y = (_rms(o, w["norm"], c.rms_eps) * gate.reshape(o.shape)
+                     ).astype(self.dtype).reshape(b, t, -1)
+                return psum(_project(y, w["o"], self.dtype))
+
+        return _over_heads(core, h, leaves, kinds, *self._share_axis())
+
+    def _grouped_attention(self, h):
+        """Grouped-query attention: h (B, T, C) → (B, T, C), of the heads held
+        here (`DecoderConfig.heads`: all of them, or `heads_held`). The leaves
+        are W_q, W_k, W_v's columns and W_o's rows of those heads and the
+        arithmetic is `core`, which `_over_heads` runs on all of them or,
+        where a share is split over a mesh axis, on each shard's. A
+        whole-width QK-norm's mean square runs over the held columns (all
+        shards')."""
+        c = self.cfg
+        dim, hd = h.shape[-1], c.head_dim
+        leaves = {"q": Kernel(name="q")(dim, c.heads * hd),
+                  "k": Kernel(name="k")(dim, c.kv_heads * hd),
+                  "v": Kernel(name="v")(dim, c.kv_heads * hd),
+                  "o": Kernel(name="o")(c.heads * hd, dim)}
+        kinds = dict(q="cols", k="cols", v="cols", o="rows")
+        if c.qk_norm == 2:
+            leaves.update(q_norm=NormScale(name="q_norm")(c.heads * hd),
+                          k_norm=NormScale(name="k_norm")(c.kv_heads * hd))
+            kinds.update(q_norm="heads", k_norm="heads")
+        elif c.qk_norm:
+            leaves.update(q_norm=NormScale(name="q_head_norm")(hd),
+                          k_norm=NormScale(name="k_head_norm")(hd))
+            kinds.update(q_norm="all", k_norm="all")
+        if c.out_gate:
+            leaves["o_gate"], kinds["o_gate"] = Kernel(name="o_gate")(dim, c.heads), "cols"
+        rotary = functools.partial(_ROTARY[c.rope_pairing], theta=c.rope_theta)
+
+        def core(h, w, psum, shards):
+            b, t, _ = h.shape
+            q, k, v = (_project(h, w[n], self.dtype) for n in "qkv")
+            if c.qk_norm == 2:
+                def whole(x, scale):   # the mean over every shard's columns
+                    total = psum(jnp.sum(jnp.square(x.astype(jnp.float32)), -1,
+                                         keepdims=True))
+                    return _rms(x, scale, c.rms_eps, total / (x.shape[-1] * shards)
+                                ).astype(self.dtype)
+
+                q, k = whole(q, w["q_norm"]), whole(k, w["k_norm"])
+            q, k, v = (x.reshape(b, t, -1, hd) for x in (q, k, v))
+            if c.qk_norm == 1:
+                q = _rms(q, w["q_norm"], c.rms_eps).astype(self.dtype)
+                k = _rms(k, w["k_norm"], c.rms_eps).astype(self.dtype)
+            if self.rope:
+                q, k = rotary(q), rotary(k)
+            attend = (flash_attention if _takes_kernels(t, self.flash_min_tokens)
+                      else attention)
+            a = attend(q, k, v, causal=True, window=self.window)
+            if c.out_gate:
+                gate = jax.nn.sigmoid(_project(h, w["o_gate"], self.dtype)
+                                      .astype(jnp.float32))
+                a = (a.astype(jnp.float32) * gate[..., None]).astype(self.dtype)
+            return psum(_project(a.reshape(b, t, -1), w["o"], self.dtype))
+
+        return _over_heads(core, h, leaves, kinds, *self._share_axis())
+
     def _attention(self, h):
+        if self.cfg.attention == "gqa":
+            return self._grouped_attention(h)
         b, t, dim = h.shape
-        q, k, v, rope = self._qkv(h)
+        q, k, v, (q_rope, k_rope) = self._qkv(h)
         core = (flash_attention if _takes_kernels(t, self.flash_min_tokens)
                 else attention)
         a = core(q, k, v, causal=True, window=self.window,
-                 **(dict(q_rope=rope[0], k_rope=rope[1]) if rope else {}))
+                 q_rope=q_rope, k_rope=k_rope)
         if self.cfg.out_gate:   # one sigmoid a head on the head's output
             gate = jax.nn.sigmoid(_dense(self.cfg.num_heads, self.dtype, "o_gate")(
                 h).astype(jnp.float32))
@@ -491,7 +707,14 @@ class DecoderLayer(nn.Module):
     def __call__(self, x: jnp.ndarray):
         c = self.cfg
         b, t, dim = x.shape
-        h32 = RMSNorm(c.rms_eps, name="norm_in")(x)
+        def in_norm(y, name):
+            # pre_norm 0: no norm on a sub-layer's input (Olmo's block norms
+            # the outputs only)
+            if not c.pre_norm:
+                return y.astype(jnp.float32)
+            return RMSNorm(c.rms_eps, name=name)(y)
+
+        h32 = in_norm(x, "norm_in")
         if self.routed and c.router_tap == "pre":
             logits = self._router_logits(h32)
         def out_norm(y, name):
@@ -502,9 +725,9 @@ class DecoderLayer(nn.Module):
 
         with jax.named_scope(self.mixer):
             mix = {"attn": self._attention, "conv": self._short_conv,
-                   "kda": self._kda}[self.mixer]
+                   "kda": self._kda, "gdn": self._gdn}[self.mixer]
             x = x + out_norm(mix(h32.astype(self.dtype)), "norm_mix_out")
-        u32 = RMSNorm(c.rms_eps, name="norm_post")(x)
+        u32 = in_norm(x, "norm_post")
         u = u32.astype(self.dtype)
         if not self.routed:
             with jax.named_scope("ffn"):
@@ -598,16 +821,19 @@ class DecoderLM(nn.Module):
                               name="embed")
         # --remat recomputes a layer in its backward pass but for the flash
         # kernels' output and logsumexp (117 MB a layer at 2 x 8,192 tokens)
-        # and the KDA recurrence's output (134 MB a layer at 8,192 tokens):
-        # saving them spares a second run of the forward kernel, and of the
-        # walk over the chunks' states
+        # and the delta layers' recurrence's output (KDA's 134 MB a layer at
+        # 8,192 tokens, Gated DeltaNet's 94 MB at 15 heads of 192): saving
+        # them spares a second run of the forward kernel, and of the walk
+        # over the chunks' states
         layer = (nn.remat(DecoderLayer, policy=jax.checkpoint_policies
-                          .save_only_these_names("flash_out", "flash_lse", "kda_out"))
+                          .save_only_these_names("flash_out", "flash_lse", "kda_out",
+                                                 "gdn_out"))
                  if self.remat else DecoderLayer)
 
         def build(i: int, name: str):
             mixer = ("conv" if c.conv_layout[i % len(c.conv_layout)] else
-                     "kda" if c.kda_layout[i % len(c.kda_layout)] else "attn")
+                     "kda" if c.kda_layout[i % len(c.kda_layout)] else
+                     "gdn" if c.gdn_layout[i % len(c.gdn_layout)] else "attn")
             return layer(c, bool(c.rope_layout[i % len(c.rope_layout)]),
                          c.window if c.window_layout[i % len(c.window_layout)]
                          else None,

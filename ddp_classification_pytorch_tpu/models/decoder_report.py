@@ -19,8 +19,8 @@ import numpy as np
 
 from ..config import ModelConfig
 from ..ops.moe import slot_bound
-from .decoder_lm import (LOOP_TRACED, flash_backward_path, kda_core_path,
-                         kda_prepare_path)
+from .decoder_lm import (LOOP_TRACED, flash_backward_path, gdn_core_path,
+                         kda_core_path, kda_prepare_path)
 from .factory import ModelReport
 
 
@@ -41,8 +41,10 @@ class DecoderReport(ModelReport):
         how many of its layers mix tokens by which operator before which
         feed-forward — a static counter in `registry`, and the same counts as
         notes for the set-up line, with the path the attention kernels'
-        backward takes at these sizes and whether the delta layers'
-        recurrence and their input side take their kernels; of a looped stack
+        backward takes at these sizes, whether the delta layers' recurrence
+        and their input side take their kernels, what a Gated DeltaNet
+        layer's recurrence runs as and the share of the heads held; of a
+        looped stack
         also how often a step applies a layer, and how its passes are traced;
         of a routing one the sorted rows a layer keeps / the slots it routes."""
         dc = self.cfg.decoder
@@ -70,6 +72,13 @@ class DecoderReport(ModelReport):
             # the predicates `ops/kda.py::kda_chunked` and the layer's input
             # side (`DecoderLayer._kda`) dispatch on
             notes.update(kda_core=core, kda_prepare=kda_prepare_path(dc))
+        if core := gdn_core_path(dc):
+            notes["gdn_core"] = core
+        if dc.heads_held:
+            registry.counter("decoder_heads_held", "query heads a grouped-"
+                             "query or Gated DeltaNet layer computes here: "
+                             "--heads_held of --num_heads").inc(dc.heads)
+            notes["heads"] = f"{dc.heads}/{dc.num_heads}"
         if dc.moe_layer_names():
             # token-slots k·N a routing layer routes in a step, and the sorted
             # rows it works on while its load fits: ops/moe.py::slot_bound at

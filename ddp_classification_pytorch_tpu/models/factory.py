@@ -121,16 +121,44 @@ def build_decoder_lm(cfg: ModelConfig, num_classes: int,
     if dc.qk_norm and dc.attention != "gqa":
         raise ValueError("--qk_norm norms grouped-query heads; latent "
                          "attention norms its two latents already")
-    for key in ("conv_layout", "kda_layout"):
+    if dc.qk_norm not in (0, 1, 2):
+        raise ValueError(f"--qk_norm {dc.qk_norm}: 0 = none, 1 = every head, "
+                         "2 = the whole projection")
+    if not dc.pre_norm and not dc.sandwich_norm:
+        raise ValueError("--pre_norm 0 without --sandwich_norm 1 leaves a "
+                         "sub-layer no norm at all")
+    mixers = ("conv_layout", "kda_layout", "gdn_layout")
+    for key in mixers:
         if any(v not in (0, 1) for v in getattr(dc, key)):
             raise ValueError(f"{key} {tuple(getattr(dc, key))} is 0/1 per layer")
-    if any(conv and kda for conv, kda in zip(dc.layout(dc.conv_layout),
-                                             dc.layout(dc.kda_layout))):
-        raise ValueError("conv_layout and kda_layout mark the same layer")
-    if any(op == "kda" for op, _ in dc.layer_kinds()):
+    if any(sum(marks) > 1 for marks in zip(*(dc.layout(getattr(dc, key))
+                                             for key in mixers))):
+        raise ValueError(f"{', '.join(mixers)}: two of them mark the same layer")
+    operators = {op for op, _ in dc.layer_kinds()}
+    if operators & {"kda", "gdn"}:
         from ..ops.kda import chunk_of
 
         chunk_of(dc.seq_len)   # refuses a row that is not whole chunks
+    if "gdn" in operators and not (dc.gdn_key_dim > 0 and dc.gdn_value_dim > 0):
+        raise ValueError("Gated DeltaNet layers need --gdn_key_dim and "
+                         "--gdn_value_dim")
+    mp = mesh.shape.get(MODEL_AXIS, 1) if mesh is not None else 1
+    if dc.heads_held:
+        # ROADMAP R13 (a) stands open for the three below: their projections
+        # are not cut by heads here (a latent's up-projection, KDA's fused
+        # input side and kernels, a convolution without heads)
+        refused = operators & {"mla", "kda", "conv"}
+        if refused:
+            raise ValueError(
+                f"--heads_held {dc.heads_held}: a share of the heads is built "
+                f"for 'gqa' and Gated DeltaNet layers, not for {sorted(refused)}")
+        group = dc.num_heads // dc.num_kv_heads
+        if (dc.heads_held > dc.num_heads or dc.heads_held % mp
+                or (dc.heads_held // mp) % group):
+            raise ValueError(
+                f"{dc.heads_held} of {dc.num_heads} heads over {mp} shard(s): a "
+                f"share is whole groups of {group} query head(s) on one KV "
+                f"head, the same number a shard")
     if dc.n_group > 1 and (dc.router != "sigmoid" or dc.num_experts % dc.n_group
                            or not 1 <= dc.topk_group <= dc.n_group
                            or dc.top_k > dc.topk_group * (dc.num_experts // dc.n_group)):
@@ -147,7 +175,6 @@ def build_decoder_lm(cfg: ModelConfig, num_classes: int,
             f"--loops {dc.loops}: the stack runs 1 or more times, and a "
             "looped stack is built of dense layers (--dense_layers = "
             "--num_layers) without a prediction module")
-    mp = mesh.shape.get(MODEL_AXIS, 1) if mesh is not None else 1
     return DecoderLM(dc, dtype=jnp.dtype(cfg.dtype), remat=cfg.remat,
                      mesh=mesh if mp > 1 else None,
                      expert_axis=MODEL_AXIS if mp > 1 else None,

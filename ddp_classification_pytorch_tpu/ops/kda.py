@@ -68,8 +68,9 @@ What runs where is read from the shapes (`takes_kernel`; no flag):
   array, another tiling on the TPU, stands between); `kda_chunked`, the
   (B, T, H, d) entry of the tests and of every shape the input side's fused
   op does not take, reshapes at its two edges.
-- anything else (narrower heads, a row shorter than a chunk, another chunk
-  length): `_chunked` in plain XLA, forward and backward through autodiff,
+- any other shape of the per-channel decay (narrower heads, a row shorter
+  than a chunk, another chunk length): `_chunked` in plain XLA, forward and
+  backward through autodiff,
   under `jax.checkpoint` (its backward builds the chunks' matrices and walks
   the states again from the five inputs, which are all it keeps), HEAD_GROUP
   heads at a time. A, P and the solve are taken for all chunks at once (a
@@ -77,6 +78,14 @@ What runs where is read from the shapes (`takes_kernel`; no flag):
   matmul with the later sub-chunks masked is a third less text and, measured
   on the chip, 9 % more step), the three lines with S_0 are a `lax.scan`.
   The kernels' second oracle (the first is the token-by-token recurrence).
+
+A decay that is ONE number a head and token (Gated DeltaNet) is not this
+file's: broadcast over the channels it would do d_k exponentials for one and,
+unbounded, break the range argument above. It has its own entry,
+`ops/gdn.py::gdn_chunked`, which takes from here what does not depend on the
+decay's shape: the chunk (`chunk_of`), the grouping of the heads (`_grouped`),
+a unit triangle's exact inverse (`_unit_triangle_inverse`) and the walk over
+the chunks' states (`_walk`).
 """
 
 from __future__ import annotations
@@ -143,13 +152,14 @@ def kda_chunked(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
 
 
 def _grouped(q, k, v, g, beta, *, chunk=CHUNK, dtype=jnp.bfloat16,
-             head_group=HEAD_GROUP):
-    """`_chunked` under `jax.checkpoint`, the heads `head_group` at a time (or
-    the most that divides them): one group's matrices and states are all that
-    stands, at that many times the steps."""
+             head_group=HEAD_GROUP, core=None):
+    """`core` (`_chunked`; ops/gdn.py hands its own) under `jax.checkpoint`,
+    the heads `head_group` at a time (or the most that divides them): one
+    group's matrices and states are all that stands, at that many times the
+    steps."""
     h = k.shape[2]
     group = math.gcd(head_group, h)
-    one = jax.checkpoint(functools.partial(_chunked, chunk=chunk, dtype=dtype))
+    one = jax.checkpoint(functools.partial(core or _chunked, chunk=chunk, dtype=dtype))
     if group == h:
         return one(q, k, v, g, beta)
 
@@ -206,12 +216,8 @@ def _chunked(q, k, v, g, beta, *, chunk, dtype):
                        jnp.concatenate(solved, axis=-2))
         # the sub-chunk's own unit triangle, inverted exactly:
         # (I + X)(I + X²)(I + X⁴)… with X = −A_ii, nilpotent of order sub
-        x = -a[..., i * sub:]
-        inv = jnp.eye(sub, dtype=f32) + x
-        for _ in range(max((sub - 1).bit_length() - 1, 0)):  # X², X⁴, …: to X^(sub−1)
-            x = jnp.matmul(x, x, precision=hi)
-            inv = inv + jnp.matmul(inv, x, precision=hi)
-        solved.append(jnp.matmul(inv, r, precision=hi))
+        solved.append(jnp.matmul(_unit_triangle_inverse(a[..., i * sub:]), r,
+                                 precision=hi))
     wu = jnp.concatenate(solved, axis=-2)                  # (B, H, NT, C, d_k + d_v)
     p = jnp.concatenate(p_rows, axis=-2)                   # (B, H, NT, C, C)
 
@@ -227,6 +233,34 @@ def _chunked(q, k, v, g, beta, *, chunk, dtype):
           whole(k * jnp.exp(last - big)).astype(dtype),
           jnp.moveaxis(jnp.exp(last[..., 0, 0, :]), 2, 0))
 
+    return _walk(xs, dtype)
+
+
+def _unit_triangle_inverse(a):
+    """(I + A)⁻¹ of strictly lower-triangular A (..., n, n), exactly: with
+    X = −A, nilpotent of order n, (I + X)(I + X²)(I + X⁴)… up to X^(n−1), in
+    float32 at the highest matmul precision."""
+    n, hi = a.shape[-1], jax.lax.Precision.HIGHEST
+    x = -a
+    inv = jnp.eye(n, dtype=jnp.float32) + x
+    for _ in range(max((n - 1).bit_length() - 1, 0)):   # X², X⁴, …: to X^(n−1)
+        x = jnp.matmul(x, x, precision=hi)
+        inv = inv + jnp.matmul(inv, x, precision=hi)
+    return inv
+
+
+def _walk(xs, dtype):
+    """The three lines with S_0, the chunks in order: `xs` = (W, U', Q ⊙ Γ, P,
+    K ⊙ Γ_C / Γ, Γ_C), each (NT, B, H, ...) with C rows a chunk but the last,
+    the chunk's whole decay (NT, B, H, d_k) → o (B, T, H, d_v) float32. The
+    state (B, H, d_k, d_v) float32 starts at zero; matmul operands in `dtype`."""
+    f32 = jnp.float32
+    nt, b, h, c, dk = xs[0].shape
+
+    def mm(eq, x, y):
+        return jnp.einsum(eq, x.astype(dtype), y.astype(dtype),
+                          preferred_element_type=f32)
+
     def step(state, x):
         w_c, u_c, q_c, p_c, k_c, decay = x
         u = u_c - mm("bhcd,bhde->bhce", w_c, state)
@@ -234,8 +268,8 @@ def _chunked(q, k, v, g, beta, *, chunk, dtype):
         state = decay[..., None] * state + mm("bhcd,bhce->bhde", k_c, u)
         return state, o
 
-    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, v.shape[-1]), f32), xs)
-    return jnp.moveaxis(o, 0, 2).reshape(b, h, t, -1).transpose(0, 2, 1, 3)
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, xs[1].shape[-1]), f32), xs)
+    return jnp.moveaxis(o, 0, 2).reshape(b, h, nt * c, -1).transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------
