@@ -78,9 +78,9 @@ With x (B, T, C), every projection without bias:
                                            a = [RMSNorm_{d_v}(o) · SiLU(h W_g)]
                                              W_o: one scale of d_v for all
                                              heads, a gate as wide as o; in
-                                             chunks of 64 tokens, plain XLA,
-                                             5 of 15 heads at a time
-                                             (ops/gdn.py)
+                                             chunks of 64 tokens: kernels or
+                                             plain XLA by the shapes (ops/
+                                             gdn.py, `gdn_core=` at set-up)
          the others: attention(h) W_o      "gqa": H query heads on H_kv KV
                                            heads of head_dim; with qk_norm 1
                                            an RMSNorm (one scale of head_dim
@@ -184,8 +184,8 @@ the five projections and the input side, the kernels `kda_prepare_fwd` /
 `kda_states` / `kda_bwd` or plain XLA; `kda.out`: the gate, the per-head norm,
 `kda_gated_norm_fwd` / `kda_gated_norm_bwd` or plain XLA, and W_o), `gdn`
 (with `gdn.in`: the five projections, taps, SiLU, L2 norms, g and β;
-`gdn.core`: the recurrence; `gdn.out`: the gate, the per-head norm and W_o),
-`ffn`,
+`gdn.core`: the recurrence, `gdn_fwd` / `gdn_states` / `gdn_bwd` or plain
+XLA; `gdn.out`: the gate, the per-head norm and W_o), `ffn`,
 `moe.route` / `.dispatch` / `.experts` / `.combine` / `.shared`, `mtp`
 (outermost, around the whole module), `lm_head`, `loop` (outermost, around
 the R passes of a looped stack).
@@ -391,11 +391,11 @@ def kda_core_path(cfg: DecoderConfig) -> Optional[str]:
 
 
 def gdn_core_path(cfg: DecoderConfig) -> Optional[str]:
-    """What the Gated DeltaNet layers' recurrence runs as (ops/gdn.py:
-    "xla" at every shape); None where no layer is one."""
+    """What the Gated DeltaNet layers' recurrence runs as at the configured
+    sizes ("kernel" | "xla", ops/gdn.py::takes_kernel); None without one."""
     if all(op != "gdn" for op, _ in cfg.layer_kinds()):
         return None
-    return gdn.CORE_PATH
+    return "kernel" if gdn.takes_kernel(cfg.seq_len, cfg.gdn_key_dim, cfg.gdn_value_dim) else "xla"
 
 
 def kda_prepare_path(cfg: DecoderConfig) -> Optional[str]:
@@ -620,7 +620,7 @@ class DecoderLayer(nn.Module):
                 xs = [_project(h, w[n], self.dtype) for n in ("q", "k", "v", "a", "beta")]
                 q, k, v, g, beta = gdn_prepare(
                     *xs, w["taps_q"], w["taps_k"], w["taps_v"], w["a_log"],
-                    w["dt_bias"], self.dtype)
+                    w["dt_bias"], f32 if gdn.takes_kernel(t, dk, dv) else self.dtype)
             with jax.named_scope("gdn.core"):
                 # named for --remat's policy, as `kda_out` is
                 o = checkpoint_name(

@@ -81,11 +81,11 @@ What runs where is read from the shapes (`takes_kernel`; no flag):
 
 A decay that is ONE number a head and token (Gated DeltaNet) is not this
 file's: broadcast over the channels it would do d_k exponentials for one and,
-unbounded, break the range argument above. It has its own entry,
-`ops/gdn.py::gdn_chunked`, which takes from here what does not depend on the
-decay's shape: the chunk (`chunk_of`), the grouping of the heads (`_grouped`),
-a unit triangle's exact inverse (`_unit_triangle_inverse`) and the walk over
-the chunks' states (`_walk`).
+unbounded, break the range argument above. It has its own entry and kernels,
+`ops/gdn.py` (`gdn_fwd`, `gdn_states`, `gdn_bwd`), which take from here what
+does not know the decay's shape: `chunk_of`, `_grouped`, `_walk`, a unit
+triangle's exact inverse (`_unit_triangle_inverse`; in the kernels `_inverse`
+and `_solve`) and the kernels' small parts (`_mm`, `_masks`, `_each`).
 """
 
 from __future__ import annotations

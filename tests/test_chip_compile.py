@@ -146,3 +146,29 @@ def test_kda_sides_compile_forward_and_backward(one_chip, kernels, side, dtype):
     assert text.count("tpu_custom_call") == 2, text.count("tpu_custom_call")
     name = {"input": "kda_prepare", "output": "kda_gated_norm"}[side]
     assert f"{name}_fwd" in text and f"{name}_bwd" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_gdn_recurrence_compiles_forward_and_backward(one_chip, kernels, dtype):
+    """Olmo-Hybrid-7B's recurrence at the cell's sizes: 15 heads of 96 x 192
+    over 8,192 tokens, tokens-minor (B, H, d, T) blocks of 5 heads x 128
+    tokens (two chunks, each visited by two grid steps: a traced lane roll, a
+    select on the way out), g and beta a head's tokens along the lanes; the
+    backward's first walk keeps 0.53 GB of states."""
+    from ddp_classification_pytorch_tpu.ops import gdn
+
+    def arg(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    assert gdn.takes_kernel(8192, 96, 192)
+    args = ([arg((1, 8192, 15, 96), dtype)] * 2 + [arg((1, 8192, 15, 192), dtype)]
+            + [arg((1, 8192, 15))] * 2)
+
+    def loss(*a):
+        return jnp.sum(gdn.gdn_chunked(*a, dtype=dtype))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3, text.count("tpu_custom_call")
+    assert all(name in text for name in ("gdn_fwd", "gdn_states", "gdn_bwd"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9

@@ -55,9 +55,9 @@ CONFIGS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
     # step), both 49,152-row tables, 1 row of 8,192 tokens
     pytest.param("ouro_2_6b.json", 406884353, 0.9 * HBM_BYTES, 4,
                  id="looped_decoder"),
-    # Olmo-Hybrid-7B (PR 49): three Gated DeltaNet layers (plain XLA: the
-    # scalar-decay recurrence in chunks, 5 of the 15 held heads at a time)
-    # and one attention layer at 15 of 30 heads, a QK-norm over the whole
+    # Olmo-Hybrid-7B (PR 49): three Gated DeltaNet layers (since PR 51 the
+    # scalar-decay recurrence on its kernels, 5 of the 15 held heads a grid
+    # step) and one attention layer at 15 of 30 heads, a QK-norm over the whole
     # projection, norms on the sub-layers' outputs only, 1 row of 8,192
     # tokens. State 12.26 GB: held against what the chip's allocator hands out
     pytest.param("olmo_hybrid_7b.json", 766241946, 0.95 * 16_909_336_064, 1,
@@ -126,10 +126,13 @@ def test_train_step_fits_one_chip(topo, kernels, config, parameters,
                  "kda_prepare_bwd", "kda_gated_norm_fwd", "kda_gated_norm_bwd"):
         assert (name in text) == bool(delta), name
     assert text.count("tpu_custom_call") >= 2 * attention_blocks + 7 * delta
-    if sum(dc.layout(dc.gdn_layout)):
-        # a Gated DeltaNet layer's recurrence has no kernel (ops/gdn.py): the
-        # attention block's two are all there are
-        assert text.count("tpu_custom_call") == 2 * attention_blocks
+    # a Gated DeltaNet layer's recurrence is three too (ops/gdn.py), and all
+    # it has: under --remat the forward walk runs once, its output saved by name
+    gated = sum(dc.layout(dc.gdn_layout))
+    for name in ("gdn_fwd", "gdn_states", "gdn_bwd"):
+        assert (name in text) == bool(gated), name
+    if gated:
+        assert text.count("tpu_custom_call") == 2 * attention_blocks + 3 * gated
     if dc.loops > 1:
         # the passes are one loop: the program holds the stack once, not
         # once a pass

@@ -157,7 +157,22 @@ def test_a_row_is_whole_chunks_or_one_shorter_chunk_as_chunk_of_says(t, ok):
 @pytest.mark.parametrize("heads,group", [(15, 5), (30, 6), (32, 8), (3, 3), (7, 7), (11, 1)])
 def test_the_heads_go_the_most_at_a_time_that_divides_them_within_eight(heads, group):
     assert gdn.head_group_of(heads) == group
-    assert gdn.CORE_PATH == "xla"
+
+
+@pytest.mark.parametrize("sizes,path", [
+    ({"gdn_key_dim": 96, "gdn_value_dim": 192, "seq_len": 8192}, "kernel"),   # published
+    ({"gdn_key_dim": 128, "gdn_value_dim": 128, "seq_len": 64}, "kernel"),
+    ({}, "xla"),                                                  # the tests' widths
+    ({"gdn_key_dim": 96, "gdn_value_dim": 192, "seq_len": 48}, "xla"),    # a shorter chunk
+    ({"gdn_key_dim": 32, "gdn_value_dim": 192, "seq_len": 8192}, "xla"),
+    ({"gdn_layout": [0]}, None),
+], ids=str)
+def test_the_configured_sizes_decide_what_the_set_up_line_says_of_the_recurrence(
+        sizes, path):
+    """`gdn_core=` is `ops/gdn.py::takes_kernel` at the configured sizes: no
+    flag, environment variable or configuration key chooses."""
+    dc = program(dict(ARCH, **sizes))[0].model.decoder
+    assert decoder_lm.gdn_core_path(dc) == path
 
 
 def test_bf16_operands_stay_near_the_recurrence():
@@ -455,7 +470,8 @@ def test_hybrid_decoder_trains_through_cli_train_and_publishes_what_it_adds(
     with open(os.path.join(ROOT, "docs", "observability.md")) as f:
         doc = f.read()
     for name in ("decoder_heads_held", "`gdn`", "`gdn.in`", "`gdn.core`", "`gdn.out`",
-                 "gdn_core=xla", "heads=15/30", "gdn_device_ms", "gdn_core_roofline_pct"):
+                 "gdn_core=xla", "gdn_core=kernel", "gdn_fwd", "gdn_states", "gdn_bwd",
+                 "heads=15/30", "gdn_device_ms", "gdn_core_roofline_pct"):
         assert name in doc, name
 
 

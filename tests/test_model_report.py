@@ -13,6 +13,7 @@ import ast
 import os
 
 import pytest
+import test_decoder_gdn as gated
 import test_decoder_hybrid as conv
 import test_decoder_kda as kda
 import test_decoder_latent as latent
@@ -82,6 +83,19 @@ CAPTURED = {
         'decoder_layers_total{ffn="dense",operator="kda"} 1\n'
         'decoder_layers_total{ffn="routed",operator="kda"} 1\n'
         'decoder_layers_total{ffn="routed",operator="mla"} 1\n', []),
+    # (PR 51) Gated DeltaNet's recurrence: plain XLA at the tests' widths, its
+    # kernels at the published ones (8,192 x 96 x 192; `ops/gdn.py::takes_kernel`)
+    "gdn": (
+        from_argv(gated.cli_argv(gated.ARCH)),
+        "gdn_dense=3 gqa_dense=1 gdn_core=xla", 4,
+        'decoder_layers_total{ffn="dense",operator="gdn"} 3\n'
+        'decoder_layers_total{ffn="dense",operator="gqa"} 1\n', []),
+    "gdn_published": (
+        from_argv(gated.cli_argv(dict(gated.ARCH, gdn_key_dim=96, gdn_value_dim=192,
+                                      seq_len=8192))),
+        "gdn_dense=3 gqa_dense=1 flash_backward=fused gdn_core=kernel", 4,
+        'decoder_layers_total{ffn="dense",operator="gdn"} 3\n'
+        'decoder_layers_total{ffn="dense",operator="gqa"} 1\n', []),
     "looped": (
         from_argv(looped.cli_argv(looped.ARCH)),
         "gqa_dense=2 loops=3 sandwich=1 passes=scan", 6,
