@@ -103,9 +103,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "unsharded path")
     m.add_argument("--flash_min_tokens", type=int, default=-1,
                    help="auto-pick floor: below this token count "
-                        "--flash_attention uses XLA's fused dense attention "
-                        "instead of the kernel (default 1024, the measured "
-                        "v5e crossover region; 0 = kernel always)")
+                        "--flash_attention does not take the streaming "
+                        "kernels (a ViT's row then takes the whole-row "
+                        "kernel pair where it fits, else the dense op; "
+                        "default 1024; 0 = the streaming kernels always)")
     m.add_argument("--ln_bf16", action="store_true",
                    help="ViT: LayerNorms in bf16 instead of f32 (bandwidth "
                         "experiment; no chip reading yet, ROADMAP S4)")

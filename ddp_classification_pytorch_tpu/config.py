@@ -301,17 +301,26 @@ class ModelConfig:
     # Switch-style router load-balance penalty weight (ops/moe.py::
     # load_balance_loss, sown per block, summed into the training loss)
     moe_aux_weight: float = 0.01
-    # ViT family: use the Pallas streaming flash-attention kernel for the
-    # unsharded attention path (ops/flash_attention.py); the ring-sharded
-    # path consumes each visiting KV shard with it too
+    # ViT family: use the Pallas streaming flash-attention kernels for the
+    # unsharded attention path (ops/flash_attention.py) from
+    # `flash_min_tokens` tokens on; the ring-sharded path consumes each
+    # visiting KV shard with them too. Without it a ViT's row takes the
+    # whole-row kernel pair where it fits VMEM (ops/rows_attention.py; chosen
+    # by shape, models/vit.py::attention_path), else the dense op
     flash_attention: bool = False
     # Auto-pick floor for the unsharded path: below this token count,
-    # --flash_attention routes to XLA's fused dense attention instead of the
-    # kernel (measured on v5e: flash wins from ~2048 tokens, dense is
-    # equal-or-better in the hundreds — docs/performance.md knob #4).
-    # 0 = always use the kernel. The ring path ignores this floor: there the
-    # kernel's job is keeping the per-shard score tile unmaterialized, which
-    # matters at any length.
+    # --flash_attention does not take the streaming kernels (a ViT's row then
+    # takes the whole-row pair or the dense op, the decoder's the dense op).
+    # Read on the chip at ViT-B/16's 196 tokens, batch 128 (PR 52, one traced
+    # run each, `vit_attn_device_ms` of `step_device_ms`): the dense op 117.7
+    # of 171.0 ms, the streaming kernels forced (`--flash_min_tokens 0`) 95.6
+    # of 149.9, the whole-row pair 41.3 of 96.7: at a row that fits VMEM the
+    # streaming kernels are never the ones to ask for. Where the floor
+    # belongs between 1,100 tokens (the pair's bound) and 8,192 (the
+    # decoder's rows, streamed) has no reading (ROADMAP S7).
+    # 0 = always use the streaming kernels. The ring path ignores this floor:
+    # there the kernel's job is keeping the per-shard score tile
+    # unmaterialized, which matters at any length.
     flash_min_tokens: int = 1024
     # ViT only: run the LayerNorms in the compute dtype (bf16) instead of
     # f32 — a bandwidth experiment for the HBM-bound ViT step (VERDICT r3

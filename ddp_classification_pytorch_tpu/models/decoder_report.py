@@ -36,7 +36,8 @@ class DecoderReport(ModelReport):
     def token_row_length(self) -> int:
         return self.cfg.decoder.seq_len
 
-    def built(self, rows: int, registry) -> Dict[str, Any]:
+    def built(self, rows: int, registry,
+              image_size: int = 0) -> Dict[str, Any]:
         """The layout the decoder was built with, for a step of `rows` rows:
         how many of its layers mix tokens by which operator before which
         feed-forward — a static counter in `registry`, and the same counts as

@@ -45,8 +45,11 @@ def build_backbone(cfg: ModelConfig, num_classes: int = 0,
     """Backbone emitting features (num_classes=0) or logits.
 
     `mesh` (when its 'model' axis is >1) switches the ViT family to
-    sequence-parallel ring attention with tokens sharded over that axis;
-    the CNN zoos ignore it (their parallelism is batch/class sharding)."""
+    sequence-parallel ring attention with tokens sharded over that axis, and
+    on any mesh of more than one device tells its attention to wrap its
+    Pallas call in a shard_map over the batch (models/vit.py::
+    attention_path); the CNN zoos ignore it (their parallelism is
+    batch/class sharding)."""
     dtype = jnp.dtype(cfg.dtype)
     if cfg.moe_experts and cfg.arch not in _vit.VIT_CONFIGS:
         raise ValueError(
@@ -74,7 +77,8 @@ def build_backbone(cfg: ModelConfig, num_classes: int = 0,
         seq = MODEL_AXIS if (mp > 1 and not cfg.moe_experts) else None
         return _vit.build_vit(
             cfg.arch, num_classes=num_classes, dtype=dtype,
-            dropout=cfg.dropout, mesh=mesh if (seq or moe_axis) else None,
+            dropout=cfg.dropout,
+            mesh=mesh if mesh is not None and mesh.size > 1 else None,
             seq_axis=seq, remat=cfg.remat, use_flash=cfg.flash_attention,
             moe_experts=cfg.moe_experts, moe_top_k=cfg.moe_top_k,
             moe_axis=moe_axis, flash_min_tokens=cfg.flash_min_tokens,
@@ -306,9 +310,10 @@ class ModelReport:
         raise ValueError("dataset 'tokens' feeds a model that reads token "
                          "rows (--model decoder_lm)")
 
-    def built(self, rows: int, registry) -> dict:
+    def built(self, rows: int, registry, image_size: int = 0) -> dict:
         """Notes for the set-up line on what was built for steps of `rows`
-        rows; its static counters go into `registry`."""
+        rows (of `image_size` pixels a side, where the model reads images);
+        its static counters go into `registry`."""
         return {}
 
     def logged_step(self, metrics, registry) -> None:
@@ -319,9 +324,34 @@ class ModelReport:
         return []
 
 
-def model_report(cfg: ModelConfig) -> ModelReport:
+class ViTReport(ModelReport):
+    """A ViT also says which attention core its blocks take at the step's
+    shapes: what `vit_attention_total{path}` counts once the step is traced
+    (models/vit.py::attention_path, the rule `MHA` itself dispatches on)."""
+
+    def __init__(self, cfg: ModelConfig, mesh: Optional[Any],
+                 pipelined: bool):
+        # a pipelined stack builds its blocks bare, inside its own shard_map
+        # (models/pipeline_vit.py): no mesh, no streaming kernels
+        self.vit = build_backbone(cfg, mesh=None if pipelined else mesh)
+        self.use_flash = self.vit.use_flash and not pipelined
+
+    def built(self, rows: int, registry, image_size: int = 0) -> dict:
+        v = self.vit
+        path, _ = _vit.attention_path(
+            rows, (image_size // v.patch) ** 2, v.heads, v.dim // v.heads,
+            v.dtype, v.mesh, v.seq_axis, self.use_flash, v.flash_min_tokens)
+        return {"vit_attention": path}
+
+
+def model_report(cfg: ModelConfig, mesh: Optional[Any] = None,
+                 pipeline_microbatches: int = 0) -> ModelReport:
+    """The report of the model `build_model` builds from the same
+    arguments."""
     if cfg.arch == "decoder_lm":
         from .decoder_report import DecoderReport
 
         return DecoderReport(cfg)
+    if cfg.arch in _vit.VIT_CONFIGS:
+        return ViTReport(cfg, mesh, pipeline_microbatches > 0)
     return ModelReport()

@@ -32,7 +32,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import ring_attention
+from ..obs import spans
+from ..ops.attention import batch_axes, flash_supported, ring_attention
+from ..ops.rows_attention import rows_attention, rows_supported
 
 # name → (patch, dim, depth, heads). feat dim == dim (backbone contract).
 VIT_CONFIGS = {
@@ -43,11 +45,37 @@ VIT_CONFIGS = {
 FEAT_DIMS = {name: dim for name, (_, dim, _, _) in VIT_CONFIGS.items()}
 
 
+def attention_path(b: int, t: int, heads: int, d: int, dtype: Any,
+                   mesh: Optional[Any] = None, seq_axis: Optional[str] = None,
+                   use_flash: bool = False, flash_min_tokens: int = 0):
+    """("ring" | "flash" | "rows" | "dense", batch axes of the mesh): which
+    attention core `MHA` runs on `b` rows of `t` tokens, from what it can
+    observe. Tokens sharded over `seq_axis` ring; `use_flash` from
+    `flash_min_tokens` on streams (ops/flash_attention.py); a row that fits
+    VMEM whole with heads that tile the lanes takes the whole-row kernel pair
+    (ops/rows_attention.py::rows_supported), under a mesh of several devices
+    inside a shard_map over the batch when `b` divides its axes; everything
+    else (a toy's handful of heads, the 2-row init batch on a mesh) is the
+    dense op."""
+    if seq_axis is not None:
+        return "ring", ()
+    if use_flash and t >= flash_min_tokens and flash_supported(t):
+        return "flash", ()
+    axes = batch_axes(mesh, None, b) if mesh is not None else ()
+    if rows_supported(t, heads, d, jnp.dtype(dtype).itemsize) and (
+            mesh is None or mesh.size == 1 or axes):
+        return "rows", axes
+    return "dense", ()
+
+
 class MHA(nn.Module):
-    """Multi-head self-attention over (B, T, C) tokens; ring-parallel when a
-    mesh axis is configured (mesh/seq_axis are static module attrs);
-    `use_flash` switches the unsharded path to the Pallas streaming kernel
-    (ops/flash_attention.py)."""
+    """Multi-head self-attention over (B, T, C) tokens. The core is chosen by
+    `attention_path` as the module is traced and counted there
+    (`vit_attention_total{path}`): ring-parallel when a mesh axis is
+    configured (mesh/seq_axis are static module attrs), the Pallas streaming
+    kernels with `use_flash` from `flash_min_tokens` tokens on, the whole-row
+    kernel pair on the projection's own layout where the row fits VMEM, else
+    the dense op."""
 
     dim: int
     heads: int
@@ -55,9 +83,9 @@ class MHA(nn.Module):
     mesh: Optional[Any] = None
     seq_axis: Optional[str] = None
     use_flash: bool = False
-    # unsharded-path auto-pick: below this (static) token count the dense
-    # XLA op is used even when use_flash is set (0 = kernel always). The
-    # ring path is exempt — see ModelConfig.flash_min_tokens.
+    # unsharded-path auto-pick: below this (static) token count the
+    # streaming kernels are not used even when use_flash is set (0 = always).
+    # The ring path is exempt — see ModelConfig.flash_min_tokens.
     flash_min_tokens: int = 0
 
     @nn.compact
@@ -65,17 +93,27 @@ class MHA(nn.Module):
         b, t, _ = x.shape
         d = self.dim // self.heads
         qkv = nn.Dense(3 * self.dim, dtype=self.dtype, name="qkv")(x)
-        qkv = qkv.reshape(b, t, 3, self.heads, d)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        use_flash = self.use_flash and (
-            self.seq_axis is not None or t >= self.flash_min_tokens)
-        # ring_attention owns the whole dispatch: sharded token axis → ring
-        # (with the flash kernel consuming each visiting KV shard when
-        # use_flash), unsharded → direct flash or dense.
-        out = ring_attention(q, k, v, mesh=self.mesh,
-                             axis_name=self.seq_axis,
-                             use_flash=use_flash)
-        out = out.reshape(b, t, self.dim)
+        path, axes = attention_path(b, t, self.heads, d, qkv.dtype, self.mesh,
+                                    self.seq_axis, self.use_flash,
+                                    self.flash_min_tokens)
+        spans.count("vit_attention_total", path=path)
+        if path == "rows":
+            # q, k, v stay columns of the projection's output, o comes back
+            # as the output projection reads it: no head-major copy
+            out = rows_attention(qkv, self.heads,
+                                 mesh=self.mesh if axes else None,
+                                 batch_axes=axes)
+        else:
+            qkv = qkv.reshape(b, t, 3, self.heads, d)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            # ring_attention owns the rest of the dispatch: sharded token
+            # axis → ring (with the flash kernel consuming each visiting KV
+            # shard when use_flash), unsharded → direct flash or dense.
+            out = ring_attention(
+                q, k, v, mesh=self.mesh, axis_name=self.seq_axis,
+                use_flash=self.use_flash and (
+                    self.seq_axis is not None or t >= self.flash_min_tokens))
+            out = out.reshape(b, t, self.dim)
         return nn.Dense(self.dim, dtype=self.dtype, name="proj")(out)
 
 
@@ -143,8 +181,10 @@ class Block(nn.Module):
             b_out = self.param("moe_b_out", nn.initializers.zeros, (e, self.dim), jnp.float32)
             # batch sharding only when it divides (model.init's 2-sample
             # dummy batch doesn't; correctness never depends on it)
+            # (the mesh is handed down on every multi-device run; the
+            # expert layer reads it only when its experts shard over it)
             dp = (self.mesh.shape.get(DATA_AXIS, 1)
-                  if self.mesh is not None else 1)
+                  if self.moe_axis is not None else 1)
             batch_axis = (DATA_AXIS
                           if dp > 1 and y.shape[0] % dp == 0 else None)
             # one router evaluation feeds both the gates and the balance

@@ -214,6 +214,22 @@ def _ring_shard_flash(q, k, v, *, axis_name: str, axis_size: int,
     return (o / l.transpose(0, 2, 1)[..., None]).astype(q.dtype)
 
 
+def batch_axes(mesh: Mesh, axis_name: Optional[str], rows: int) -> tuple:
+    """The mesh axes a per-row attention body shards its batch over: every
+    OTHER >1 mesh axis (the 'data' axis in this framework's meshes) — the
+    body is batch-local, and leaving the batch unsharded would replicate the
+    full global batch's attention onto every device, axis_size× redundant
+    FLOPs/memory in the O(T²) hot path. () when `rows` doesn't divide those
+    axes (e.g. the 2-sample dummy batch of model.init) — correctness never
+    depends on it."""
+    axes = tuple(
+        a for a in mesh.axis_names if a != axis_name and mesh.shape[a] > 1)
+    if axes and rows % functools.reduce(
+            lambda s, a: s * mesh.shape[a], axes, 1):
+        return ()
+    return axes
+
+
 def ring_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -253,18 +269,8 @@ def ring_attention(
     body = functools.partial(
         shard_body, axis_name=axis_name, axis_size=n, causal=causal,
         scale=scale)
-    # Batch dim shards over every OTHER >1 mesh axis (the 'data' axis in this
-    # framework's meshes): the ring body is batch-local, and leaving the batch
-    # unsharded would replicate the full global batch's attention onto every
-    # device — axis_size× redundant FLOPs/memory in the O(T²) hot path.
-    # Skipped when the batch doesn't divide those axes (e.g. the 2-sample
-    # dummy batch of model.init) — correctness never depends on it.
-    batch_axes = tuple(
-        a for a in mesh.axis_names if a != axis_name and mesh.shape[a] > 1)
-    if batch_axes and q.shape[0] % functools.reduce(
-            lambda s, a: s * mesh.shape[a], batch_axes, 1):
-        batch_axes = ()
-    spec = P(batch_axes if batch_axes else None, axis_name, None, None)
+    axes = batch_axes(mesh, axis_name, q.shape[0])
+    spec = P(axes if axes else None, axis_name, None, None)
     f = shard_map_unchecked(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return f(q, k, v)

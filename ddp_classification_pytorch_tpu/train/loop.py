@@ -166,6 +166,10 @@ _SPAN_COUNTER_HELP = {
                                  "by whether the buffer was a recycled one",
     "flash_backward_total": "attention calls traced, by the backward their "
                             "shapes chose: one fused kernel or the split",
+    "vit_attention_total": "ViT attention modules traced, by the core their "
+                           "shapes chose: the whole-row kernel pair (rows), "
+                           "the dense op, the streaming kernels (flash) or "
+                           "the ring",
 }
 
 
@@ -303,9 +307,11 @@ class Trainer:
             n1, s1 = progcache.compiled()
             spans.note(compiles=n1 - n0, compile_s=round(s1 - s0, 3))
             # what the model says it was built as, and its static counters
-            self.report = model_report(cfg.model)
+            self.report = model_report(cfg.model, self.mesh,
+                                       cfg.parallel.pipeline_microbatches)
             spans.note(**self.report.built(
-                cfg.data.batch_size * jax.process_count(), self.obs))
+                cfg.data.batch_size * jax.process_count(), self.obs,
+                cfg.data.image_size))
 
         with phase("build_steps"):
             self.train_step = make_train_step(cfg, self.model, self.tx,

@@ -65,15 +65,17 @@ def one_chip(topo):
 def kernels(monkeypatch):
     """The kernel modules with interpret mode steered off (ops/__init__
     re-exports a function named like the flash module, hence importlib);
-    returns the first two, the delta rule's (ops/kda.py) is steered only."""
+    returns the first two, the delta rule's (ops/kda.py) and the whole-row
+    attention pair's (ops/rows_attention.py) are steered only."""
     pk = importlib.import_module(
         "ddp_classification_pytorch_tpu.ops.pallas_kernels")
     fa = importlib.import_module(
         "ddp_classification_pytorch_tpu.ops.flash_attention")
     kda = importlib.import_module("ddp_classification_pytorch_tpu.ops.kda")
-    monkeypatch.setattr(pk, "_interpret", lambda: False)
-    monkeypatch.setattr(fa, "_interpret", lambda: False)
-    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    rows = importlib.import_module(
+        "ddp_classification_pytorch_tpu.ops.rows_attention")
+    for module in (pk, fa, kda, rows):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
     return pk, fa
 
 

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 from chip_compile_common import kernels, one_chip, topo  # noqa: F401
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def _compiled_text(fn, *args) -> str:
@@ -89,6 +90,39 @@ def test_flash_attention_64_wide_heads_compile(one_chip, kernels):
     are in both kernels."""
     _flash_text(kernels[1], one_chip,
                 [(2, 8192, 32, 64)] + [(2, 8192, 8, 64)] * 2, causal=True)
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "data4_batch512"])
+def test_rows_attention_compiles_on_the_projections_layout(topo, kernels,
+                                                           chips):
+    """ViT-B/16's attention core at `vitb16_pool`'s shapes: 128 images a chip
+    of 196 tokens, 12 heads of 64, bf16, read from the qkv projection's
+    (B, T, 2304) and written as (B, T, 768). Two Mosaic calls and no
+    head-major copy of q, k or v beside them; on the described 2x2 the same
+    call at batch 512 inside its shard_map over `data` (a Mosaic call the
+    compiler would have to partition is refused)."""
+    from ddp_classification_pytorch_tpu.ops import rows_attention as ra
+    from ddp_classification_pytorch_tpu.parallel import mesh as meshlib
+
+    mesh = meshlib.make_mesh(meshlib.MeshSpec(chips, 1),
+                             devices=topo.devices[:chips])
+    axes = (meshlib.DATA_AXIS,) if chips > 1 else ()
+    qkv = jax.ShapeDtypeStruct(
+        (128 * chips, 196, 3 * 12 * 64), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(meshlib.DATA_AXIS)))
+    assert ra.rows_supported(196, 12, 64, 2)
+
+    def loss(qkv):
+        out = ra.rows_attention(qkv, 12, mesh=mesh if axes else None,
+                                batch_axes=axes)
+        return jnp.sum(out.astype(jnp.float32))
+
+    with mesh:
+        text = _compiled_text(jax.value_and_grad(loss), qkv)
+    assert text.count("tpu_custom_call") == 2, text.count("tpu_custom_call")
+    assert "attn_rows_fwd" in text and "attn_rows_bwd" in text
+    assert "[128,12,64,196]" not in text and "[128,12,196,64]" not in text
+    assert "[128,12,196,196]" not in text    # nor the scores
 
 
 def test_sparse_experts_compile_to_grouped_matmul_kernels(one_chip):
