@@ -96,7 +96,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "softmax or sigmoid router with or without a group "
                         "limit, a multi-token-prediction module, a tied or "
                         "untied head, the stack run --loops times with an "
-                        "exit gate; defaults = the published "
+                        "exit gate, --objective next_token or "
+                        "block_diffusion; defaults = the published "
                         "SmallThinker-21BA3B-Instruct)")
     m.add_argument("--flash_attention", action="store_true",
                    help="ViT: Pallas streaming attention kernel for the "
@@ -161,7 +162,9 @@ def build_parser() -> argparse.ArgumentParser:
                        ("mtp_layers", int), ("mtp_weight", float),
                        ("tied_embeddings", int), ("loops", int),
                        ("sandwich_norm", int), ("pre_norm", int),
-                       ("exit_beta", float)):
+                       ("exit_beta", float), ("objective", str),
+                       ("diffusion_block", int), ("diffusion_eps", float),
+                       ("mask_id", int)):
         dec.add_argument(f"--{flag}", type=kind, default=None)
     for flag in ("rope_layout", "window_layout", "conv_layout", "kda_layout",
                  "gdn_layout"):

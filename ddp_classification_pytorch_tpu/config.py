@@ -86,10 +86,14 @@ class DecoderConfig:
     after the last layer. `loops` > 1 runs the whole stack that many times on
     its own output with the same weights (a looped language model,
     arXiv:2510.25741: `sandwich_norm`, an exit gate, a loss weighted over the
-    passes). Six published models are its fixed points: SmallThinker's, the
+    passes). `objective` says how it is trained: next-token prediction, or
+    diffusion over blocks of `diffusion_block` tokens (arXiv:2503.09573: a
+    clean and a noised stream in one pass, models/decoder_lm.py). Seven
+    published models are its fixed points: SmallThinker's, the
     DeepSeek-V3 layer as JoyAI-LLM-Flash configures it, LFM2-8B-A1B's,
     Ling-3.0-flash's (inclusionAI, `bailing_hybrid`), Ouro-2.6B (ByteDance,
-    `ouro`) and Olmo-Hybrid-7B's (allenai, `olmo_hybrid`).
+    `ouro`), Olmo-Hybrid-7B's (allenai, `olmo_hybrid`) and SDAR-30B-A3B-Chat
+    (JetLM, `sdar_moe`, arXiv:2510.06303: trained by block diffusion).
 
     A deployment that spreads a layer's experts, its heads and the
     vocabulary's rows over several chips gives each chip its share:
@@ -215,6 +219,36 @@ class DecoderConfig:
     # with sandwich_norm 1 that is Olmo's reordered block, x + RMSNorm(f(x))
     pre_norm: int = 1
     exit_beta: float = 0.05          # read only where loops > 1
+    # how the decoder is trained. "next_token": every position classifies the
+    # token after it under a causal mask. "block_diffusion" (BD3-LM,
+    # arXiv:2503.09573; SDAR): the row x_0 is cut into blocks of
+    # `diffusion_block` tokens, each block noised at its own level t (every
+    # token of it replaced by `mask_id` with probability t: data/diffusion.py
+    # makes x_t and the levels in the loader, t = diffusion_eps +
+    # (1 - diffusion_eps) j / 65,536, j uniform on 1..65,536), and ONE pass
+    # over [x_0 ; x_t] under the two-stream mask predicts every masked token
+    # from its own noised block and the clean blocks before it; the loss is
+    # the masked positions' cross-entropy weighted 1 / t, over all positions
+    # (train/steps.py::_diffusion_sums). Attention layers only, one pass, no
+    # window, no prediction module (models/factory.py refuses the rest)
+    objective: str = "next_token"
+    diffusion_block: int = 4
+    diffusion_eps: float = 1e-3
+    mask_id: int = -1                # -1 = the last row of the vocabulary held
+
+    @property
+    def mask_token(self) -> int:
+        return self.mask_id if self.mask_id >= 0 else self.vocab_size - 1
+
+    @property
+    def diffusion(self) -> bool:
+        return self.objective == "block_diffusion"
+
+    @property
+    def positions(self) -> int:
+        """Positions of a row that go through every layer: its tokens, or
+        under block diffusion the clean and the noised stream."""
+        return self.seq_len * (2 if self.diffusion else 1)
 
     @property
     def held(self) -> int:

@@ -1,8 +1,9 @@
 """A decoder over token ids — next-token training as classification of every
-position over the vocabulary.
+position over the vocabulary, or (`objective` "block_diffusion", below) the
+classification of a noised row's masked positions.
 
 ONE layer module, described by data (`config.DecoderConfig`, which the CLI
-fills — no table of variants, no second model file). Six published models
+fills — no table of variants, no second model file). Seven published models
 are its fixed points: SmallThinker-21BA3B-Instruct (PowerInfer,
 arXiv:2507.20984; the defaults), the DeepSeek-V3 layer (arXiv:2412.19437
 §2.1-2.2) as JoyAI-LLM-Flash configures it, LFM2-8B-A1B (LiquidAI,
@@ -12,7 +13,11 @@ carry a state along the row by Kimi delta attention, arXiv:2510.26692 §3),
 Ouro-2.6B (ByteDance, `ouro`, arXiv:2510.25741: the whole stack runs
 `loops` times with the same weights, below) and Olmo-Hybrid-7B (allenai,
 `olmo_hybrid`: three Gated DeltaNet layers, arXiv:2412.06464, to one of
-attention, dense, the block's norms on the sub-layers' OUTPUTS only).
+attention, dense, the block's norms on the sub-layers' OUTPUTS only) and
+SDAR-30B-A3B-Chat (JetLM, `sdar_moe`, arXiv:2510.06303: QK-normed grouped
+heads and a softmax top-8 of 128 experts, every one of which the fixed points
+before it have; what is new is that it is TRAINED by diffusion over blocks:
+the two-stream pass, below).
 With x (B, T, C), every projection without bias:
 
     h  = RMSNorm(x)                        input norm (pre_norm 0: h = x)
@@ -155,6 +160,35 @@ through p too; logits, evaluation and the top-k counts are pass R's. The
 passes are ONE `lax.scan` whose body is the stack (`LOOP_TRACED`): the program
 holds the layers once, --remat's saved names stack over the passes.
 
+With objective = "block_diffusion" (BD3-LM, arXiv:2503.09573; SDAR) a row x_0
+of L tokens is cut into blocks of B = diffusion_block; the loader (data/
+diffusion.py) draws a level t_b ∈ (0, 1] for each block and replaces each of
+its tokens by the mask id with probability t_b: x_t. The model has to give,
+for every masked position i of block b, p(x_0[i] | x_t[block b], x_0[blocks <
+b]): the noised block sees ITSELF both ways and the CLEAN text before it. All
+blocks of a row are trained in one pass over TWO STREAMS:
+
+    tokens = [x_0 ; x_t]                   (B, 2L): one embedding lookup
+    positions 0..L−1 in BOTH streams       (`rotate_half(.., streams=2)`)
+    every norm, projection, router and expert on all 2L positions, the SAME
+    leaves; attention under the mask, blk(i) = (i mod L) // B, query → key:
+        clean  i → clean  j   iff blk(i) ≥ blk(j)
+        noised i → clean  j   iff blk(i) > blk(j)
+        noised i → noised j   iff blk(i) = blk(j)
+        clean  i → noised j   never
+    (ops/attention.py::diffusion_mask; in the flash kernels `diffusion`: L² +
+    L·B of the (2L)² pairs are live and only their tiles are walked)
+    hidden → the NOISED stream's L normed states (the clean stream gives keys
+    and values and no loss)
+
+and the step (train/steps.py::_diffusion_sums) minimises Σ over the masked
+positions of CE(h_i W_head, x_0[i]) / t_b over all B·L positions, at the
+position itself. Without the loader's noise (`__call__`, a served row) the
+noised stream is the row itself: position i then reads its own block both
+ways and the blocks before it, which is what one more denoising step of the
+last block reads. Attention layers only, one pass, no window, no prediction
+module (models/factory.py refuses the rest).
+
 This chip may hold a share of each layer (`experts_held`, `first_expert`,
 a slice of the vocabulary): the router keeps its full width, the expert
 layer (ops/moe.py::sparse_moe) computes its own experts' part, and under a
@@ -204,7 +238,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..config import DecoderConfig
 from ..ops.attention import attention, flash_supported
-from ..ops.flash_attention import backward_path, flash_attention
+from ..ops.flash_attention import (backward_path, diffusion_supported,
+                                   flash_attention)
 from ..ops import gdn, kda_prepare
 from ..ops.kda import LOWER_BOUND, kda_chunked, kda_flat, takes_kernel
 from ..ops.kda_gated_norm import kda_gated_norm
@@ -212,12 +247,16 @@ from ..ops.moe import GATE_ACTIVATIONS, sparse_moe
 from ..utils.compat import shard_map_unchecked
 
 
-def rotate_half(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+def rotate_half(x: jnp.ndarray, theta: float, streams: int = 1) -> jnp.ndarray:
     """Rotary embedding over the whole head_dim of x (B, T, H, D), pairing
-    dimension i with i + D/2, positions 0..T−1, in f32."""
+    dimension i with i + D/2, positions 0..T−1, in f32; with `streams` > 1
+    the row is that many streams side by side, each at positions
+    0..T/streams−1."""
     _, t, _, d = x.shape
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angle = jnp.arange(t // streams, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    if streams > 1:
+        angle = jnp.tile(angle, (streams, 1))
     cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)[None, :, None, :]
     sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)[None, :, None, :]
     x32 = x.astype(jnp.float32)
@@ -225,13 +264,13 @@ def rotate_half(x: jnp.ndarray, theta: float) -> jnp.ndarray:
     return (x32 * cos + jnp.concatenate([-x2, x1], axis=-1) * sin).astype(x.dtype)
 
 
-def rotate_interleaved(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+def rotate_interleaved(x: jnp.ndarray, theta: float, streams: int = 1) -> jnp.ndarray:
     """Rotary embedding pairing dimension 2i with 2i + 1. The pairs are
     brought side to side first (even dimensions, then odd) and rotated as
     halves: q and k come out in the same permuted order, which their dot
     product does not see."""
     return rotate_half(
-        jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1), theta)
+        jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1), theta, streams)
 
 
 _ROTARY = {"half": rotate_half, "interleaved": rotate_interleaved}
@@ -361,9 +400,15 @@ def _causal_taps(z: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
                for j in range(taps))
 
 
-def _takes_kernels(t: int, flash_min_tokens: int) -> bool:
+def _takes_kernels(t: int, flash_min_tokens: int,
+                   diffusion_block: Optional[int] = None) -> bool:
     """Rows of `t` tokens go through the flash kernels: where they tile T and
-    beat the dense op (ModelConfig.flash_min_tokens); else the (T, T) op."""
+    beat the dense op (ModelConfig.flash_min_tokens); else the (T, T) op.
+    Under `diffusion_block` the `t` positions are two streams of a row, and a
+    stream is what the kernels tile and the floor is held against."""
+    if diffusion_block is not None:
+        return (diffusion_supported(t, diffusion_block)
+                and t // 2 >= flash_min_tokens)
     return flash_supported(t) and t >= flash_min_tokens
 
 
@@ -373,12 +418,13 @@ def flash_backward_path(cfg: DecoderConfig, dtype,
     row length ("fused" | "split", ops/flash_attention.py::backward_path);
     None where no layer reaches the kernels (no attention layer, or rows the
     dense op takes)."""
+    t, block = cfg.positions, cfg.diffusion_block if cfg.diffusion else None
     if (all(op != cfg.attention for op, _ in cfg.layer_kinds())
-            or not _takes_kernels(cfg.seq_len, flash_min_tokens)):
+            or not _takes_kernels(t, flash_min_tokens, block)):
         return None
     widths = ((cfg.head_dim, cfg.value_dim, cfg.rope_dim)
               if cfg.attention == "mla" else (cfg.head_dim, cfg.head_dim))
-    return backward_path(cfg.seq_len, widths, jnp.dtype(dtype).itemsize)
+    return backward_path(t, widths, jnp.dtype(dtype).itemsize)
 
 
 def kda_core_path(cfg: DecoderConfig) -> Optional[str]:
@@ -493,12 +539,31 @@ class DecoderLayer(nn.Module):
             return jnp.einsum("btc,ce->bte", t32, router,
                               precision=jax.lax.Precision.HIGHEST)
 
+    def _rotary(self):
+        """The configured rotary embedding; under block diffusion both
+        streams of the row at positions 0..L−1."""
+        c = self.cfg
+        return functools.partial(_ROTARY[c.rope_pairing], theta=c.rope_theta,
+                                 streams=2 if c.diffusion else 1)
+
+    def _attend(self, t: int):
+        """The attention core for rows of `t` positions under the layer's
+        mask: the kernels where they take them, else the dense op; causal
+        (and banded by the layer's window), or the two-stream mask."""
+        c = self.cfg
+        block = c.diffusion_block if c.diffusion else None
+        core = (flash_attention if _takes_kernels(t, self.flash_min_tokens, block)
+                else attention)
+        if c.diffusion:
+            return functools.partial(core, diffusion_block=block)
+        return functools.partial(core, causal=True, window=self.window)
+
     def _qkv(self, h):
         """Latent attention's → q, k, v (B, T, heads, ·) and the scores' second
         part (q_rope, k_rope)."""
         c = self.cfg
         b, t, _ = h.shape
-        rotary = functools.partial(_ROTARY[c.rope_pairing], theta=c.rope_theta)
+        rotary = self._rotary()
         # latent attention: queries through a normed bottleneck (or, with
         # q_rank 0, straight from h); one normed latent gives every head its
         # position-free key and its value, and one rotary key head serves all
@@ -658,7 +723,7 @@ class DecoderLayer(nn.Module):
             kinds.update(q_norm="all", k_norm="all")
         if c.out_gate:
             leaves["o_gate"], kinds["o_gate"] = Kernel(name="o_gate")(dim, c.heads), "cols"
-        rotary = functools.partial(_ROTARY[c.rope_pairing], theta=c.rope_theta)
+        rotary = self._rotary()
 
         def core(h, w, psum, shards):
             b, t, _ = h.shape
@@ -677,9 +742,7 @@ class DecoderLayer(nn.Module):
                 k = _rms(k, w["k_norm"], c.rms_eps).astype(self.dtype)
             if self.rope:
                 q, k = rotary(q), rotary(k)
-            attend = (flash_attention if _takes_kernels(t, self.flash_min_tokens)
-                      else attention)
-            a = attend(q, k, v, causal=True, window=self.window)
+            a = self._attend(t)(q, k, v)
             if c.out_gate:
                 gate = jax.nn.sigmoid(_project(h, w["o_gate"], self.dtype)
                                       .astype(jnp.float32))
@@ -693,10 +756,7 @@ class DecoderLayer(nn.Module):
             return self._grouped_attention(h)
         b, t, dim = h.shape
         q, k, v, (q_rope, k_rope) = self._qkv(h)
-        core = (flash_attention if _takes_kernels(t, self.flash_min_tokens)
-                else attention)
-        a = core(q, k, v, causal=True, window=self.window,
-                 q_rope=q_rope, k_rope=k_rope)
+        a = self._attend(t)(q, k, v, q_rope=q_rope, k_rope=k_rope)
         if self.cfg.out_gate:   # one sigmoid a head on the head's output
             gate = jax.nn.sigmoid(_dense(self.cfg.num_heads, self.dtype, "o_gate")(
                 h).astype(jnp.float32))
@@ -868,7 +928,17 @@ class DecoderLM(nn.Module):
                targets: Optional[jnp.ndarray] = None):
         """→ (h, loads). With `targets` (the row shifted by one) and a
         prediction module also its states: (h, loads, h_mtp), the module's
-        loads in the last row."""
+        loads in the last row. Under block diffusion `tokens` is the clean
+        row x_0 (B, L) and `targets` [x_t ; j] (B, 2, L) as the loader makes
+        them (data/diffusion.py): ONE lookup and one pass over [x_0 ; x_t]
+        (B, 2L), and h is the NOISED stream's L states; the loads count both
+        streams' positions. Without `targets` the noised stream is the row
+        itself: what a served row's block reads (its own block both ways,
+        the blocks before it), at twice the positions."""
+        c = self.cfg
+        if c.diffusion:
+            noised = tokens if targets is None else targets[:, 0]
+            tokens = jnp.concatenate([tokens, noised], axis=1)
         x = self.embed(tokens).astype(self.dtype)
         if self.cfg.loops > 1:   # dense layers only: no loads
             return self._looped(x), jnp.zeros((0, self.cfg.held), jnp.int32)
@@ -877,6 +947,8 @@ class DecoderLM(nn.Module):
             x, load = layer(x)
             if load is not None:
                 loads.append(load)
+        if c.diffusion:
+            x, targets = x[:, x.shape[1] // 2:], None
         out = self.norm_final(x).astype(self.dtype)
         if targets is None or not self.cfg.mtp_layers:
             # a decoder of dense layers only routes nothing: no row
@@ -888,7 +960,8 @@ class DecoderLM(nn.Module):
 
     def __call__(self, tokens: jnp.ndarray, train: bool = True) -> jnp.ndarray:
         # init has to reach the prediction module's leaves too
-        targets = tokens if self.is_initializing() else None
+        targets = (tokens if self.is_initializing() and self.cfg.mtp_layers
+                   else None)
         h = self.hidden(tokens, train, targets)[0]
         if self.cfg.loops > 1:
             h = h[-1]   # the last pass is the one served

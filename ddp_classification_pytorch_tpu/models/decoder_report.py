@@ -28,13 +28,31 @@ class DecoderReport(ModelReport):
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
         self.bound = self.slots = 0     # of a routing layer: set by `built`
+        self.positions = 0              # of a block-diffusion step: likewise
 
     def init_inputs(self, image_size: int) -> Any:
-        """Token ids; parameters do not depend on T, so a few positions do."""
-        return jnp.zeros((2, min(self.cfg.decoder.seq_len, 8)), jnp.int32)
+        """Token ids; parameters do not depend on T, so a few positions do
+        (under block diffusion in whole blocks)."""
+        dc = self.cfg.decoder
+        few = -(-8 // dc.diffusion_block) * dc.diffusion_block if dc.diffusion else 8
+        return jnp.zeros((2, min(dc.seq_len, few)), jnp.int32)
 
     def token_row_length(self) -> int:
         return self.cfg.decoder.seq_len
+
+    def datasets(self, train_ds, val_ds, seed: int):
+        """Under block diffusion the loader noises the rows (data/
+        diffusion.py): the training set from the loader's per-row generator,
+        the validation set from one keyed by (seed, sample) alone, so that
+        every evaluation reads the same noise."""
+        dc = self.cfg.decoder
+        if not dc.diffusion:
+            return train_ds, val_ds
+        from ..data.diffusion import NoisedTokens
+
+        return tuple(NoisedTokens(ds, dc.diffusion_block, dc.mask_token,
+                                  dc.diffusion_eps, seed, keyed=keyed)
+                     for ds, keyed in ((train_ds, False), (val_ds, True)))
 
     def built(self, rows: int, registry,
               image_size: int = 0) -> Dict[str, Any]:
@@ -62,6 +80,12 @@ class DecoderReport(ModelReport):
         if dc.loops > 1:
             notes.update(loops=dc.loops, sandwich=dc.sandwich_norm,
                          passes=LOOP_TRACED)
+        if dc.diffusion:
+            # the objective, and the mask every attention layer runs under:
+            # the two streams' (ops/attention.py::diffusion_mask)
+            notes.update(objective=dc.objective, block=dc.diffusion_block,
+                         mask_id=dc.mask_token, attn_mask="block_diffusion")
+            self.positions = rows * dc.seq_len
         path = flash_backward_path(dc, self.cfg.dtype,
                                    self.cfg.flash_min_tokens)
         if path:
@@ -84,7 +108,7 @@ class DecoderReport(ModelReport):
             # token-slots k·N a routing layer routes in a step, and the sorted
             # rows it works on while its load fits: ops/moe.py::slot_bound at
             # the step's shapes
-            self.slots = rows * dc.seq_len * dc.top_k
+            self.slots = rows * dc.positions * dc.top_k
             self.bound = slot_bound(self.slots, dc.held, dc.num_experts)
             notes["moe_bound"] = f"{self.bound}/{self.slots}"
         return notes
@@ -94,6 +118,14 @@ class DecoderReport(ModelReport):
         `moe_load` (L, e): token-slots each held expert took in each routing
         layer (`DecoderConfig.moe_layer_names`: the layer's index, or "mtp"
         for the prediction module's)."""
+        if "masked_tokens" in metrics:
+            registry.counter("diffusion_masked_tokens_total", "positions of "
+                             "the logged steps' noised rows that held the "
+                             "mask id: the ones the block-diffusion loss "
+                             "reads").inc(float(metrics["masked_tokens"]))
+            registry.counter("diffusion_positions_total", "positions of the "
+                             "logged steps' rows (rows x --seq_len): what the "
+                             "loss is normalised by").inc(float(self.positions))
         if "moe_load" not in metrics:
             return
         load = np.asarray(metrics["moe_load"])
@@ -127,5 +159,6 @@ class DecoderReport(ModelReport):
         of a decoder with a prediction module (train_loss = loss_main +
         mtp_weight x loss_mtp), or of a looped one (train_loss = Σ_t
         exit_p<t> x loss_ut<t> over the targets, less exit_beta x the
-        entropy of p)."""
+        entropy of p), or of a block-diffusion one (train_loss_level<q>: the
+        weighted loss of the blocks whose level t lies in quartile q)."""
         return sorted(k for k in metrics if k.startswith(("loss_", "exit_p")))

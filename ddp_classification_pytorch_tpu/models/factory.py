@@ -111,7 +111,8 @@ def build_decoder_lm(cfg: ModelConfig, num_classes: int,
             f"not among the router's {dc.num_experts}")
     kinds = {"attention": ("gqa", "mla"), "rope_pairing": ("half", "interleaved"),
              "activation": ("relu", "silu"), "router": ("softmax", "sigmoid"),
-             "router_tap": ("pre", "post")}
+             "router_tap": ("pre", "post"),
+             "objective": ("next_token", "block_diffusion")}
     for key, allowed in kinds.items():
         if getattr(dc, key) not in allowed:
             raise ValueError(f"decoder {key}={getattr(dc, key)!r}: one of {allowed}")
@@ -179,6 +180,29 @@ def build_decoder_lm(cfg: ModelConfig, num_classes: int,
             f"--loops {dc.loops}: the stack runs 1 or more times, and a "
             "looped stack is built of dense layers (--dense_layers = "
             "--num_layers) without a prediction module")
+    if dc.diffusion:
+        # the two-stream pass is written for attention under its own mask:
+        # the other mixers' causal taps and carried states, a window's band,
+        # a second pass and a shifted second loss have no two-stream form here
+        refused = sorted(operators & {"conv", "kda", "gdn"})
+        windowed = any(w and op == dc.attention for w, (op, _) in
+                       zip(dc.layout(dc.window_layout), dc.layer_kinds()))
+        why = (f"the {refused} mixers" if refused else
+               "a window layer (--window_layout)" if windowed else
+               f"--loops {dc.loops}" if dc.loops > 1 else
+               f"--mtp_layers {dc.mtp_layers}" if dc.mtp_layers else "")
+        if why:
+            raise ValueError(
+                f"--objective block_diffusion trains attention layers under "
+                f"the two-stream mask in one pass; it is not built for {why}")
+        if (dc.diffusion_block < 1 or dc.seq_len % dc.diffusion_block
+                or not 0 <= dc.mask_token < dc.vocab_size
+                or not 0.0 <= dc.diffusion_eps < 1.0):
+            raise ValueError(
+                f"--objective block_diffusion: rows of {dc.seq_len} tokens in "
+                f"blocks of {dc.diffusion_block}, mask id {dc.mask_token} of "
+                f"{dc.vocab_size}, eps {dc.diffusion_eps}: whole blocks, an id "
+                "of the vocabulary held, 0 <= eps < 1")
     return DecoderLM(dc, dtype=jnp.dtype(cfg.dtype), remat=cfg.remat,
                      mesh=mesh if mp > 1 else None,
                      expert_axis=MODEL_AXIS if mp > 1 else None,
@@ -309,6 +333,11 @@ class ModelReport:
     def token_row_length(self) -> int:
         raise ValueError("dataset 'tokens' feeds a model that reads token "
                          "rows (--model decoder_lm)")
+
+    def datasets(self, train_ds, val_ds, seed: int):
+        """What the loaders read: the datasets as built, or wrapped where
+        the model's objective makes its inputs in the loader."""
+        return train_ds, val_ds
 
     def built(self, rows: int, registry, image_size: int = 0) -> dict:
         """Notes for the set-up line on what was built for steps of `rows`

@@ -5,7 +5,8 @@
 
 One process-global `Recorder`, always on: a span costs two reads of
 `time.perf_counter_ns()`, a tuple and a deque append, and is taken per
-batch or step, never per sample. There is no switch; what a run measures
+batch or step, never per sample (`input.noise`, data/diffusion.py, is taken
+per ROW of tokens: thousands of tokens a row, a handful of rows a batch). There is no switch; what a run measures
 with the recorder in it is the whole cost. Nothing is written to a file
 here: readers take `snapshot()` (the newest `CAPACITY` spans) or
 `totals()` (per-name count / total / max, which never drop) when they

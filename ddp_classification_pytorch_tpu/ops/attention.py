@@ -44,6 +44,21 @@ from ..utils.compat import shard_map_unchecked
 _NEG_INF = -1e30
 
 
+def diffusion_mask(t: int, block: int) -> jnp.ndarray:
+    """(t, t) bool, True = query row sees key col: block-diffusion training's
+    two streams in one row, [clean ; noised] of L = t / 2 positions each
+    (arXiv:2503.09573). With blk(i) = (i mod L) // block: clean → clean iff
+    blk(i) ≥ blk(j) (block-causal, both ways inside a block); noised → clean
+    iff blk(i) > blk(j) (the clean text of strictly earlier blocks); noised →
+    noised iff blk(i) = blk(j) (its own block); clean → noised never."""
+    half = t // 2
+    at = jnp.arange(t)
+    noised, blk = at >= half, (at % half) // block
+    d = blk[:, None] - blk[None, :]
+    rows, cols = noised[:, None], noised[None, :]
+    return jnp.where(cols, rows & (d == 0), jnp.where(rows, d >= 1, d >= 0))
+
+
 def attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -53,6 +68,7 @@ def attention(
     window: Optional[int] = None,
     q_rope: Optional[jnp.ndarray] = None,
     k_rope: Optional[jnp.ndarray] = None,
+    diffusion_block: Optional[int] = None,
 ) -> jnp.ndarray:
     """Dense scaled-dot-product attention.
 
@@ -62,7 +78,8 @@ def attention(
     (causal only) keeps keys j with i − window < j ≤ i. `q_rope` (B, T, H,
     Dr) / `k_rope` (B, T, H_r, Dr) are a second part of the scores (latent
     attention): here they are simply joined to q and k, `k_rope` repeated to
-    k's heads — the small-T op may; the kernels do not.
+    k's heads — the small-T op may; the kernels do not. `diffusion_block`
+    (not with `causal`): the row is two streams under `diffusion_mask`.
     """
     if q_rope is not None:
         k_rope = jnp.repeat(k_rope, k.shape[2] // k_rope.shape[2], axis=2)
@@ -72,6 +89,9 @@ def attention(
         scale = q.shape[-1] ** -0.5
     if window is not None and not causal:
         raise ValueError("attention: a window needs causal=True")
+    if diffusion_block is not None and causal:
+        raise ValueError("attention: diffusion_block is a mask of its own, "
+                         "not a causal one")
     b, t, h, d = q.shape
     grouped = k.shape[2] != h
     if grouped:
@@ -86,6 +106,8 @@ def attention(
         if window is not None:
             mask &= cols > rows - window
         s = jnp.where(mask, s, _NEG_INF)
+    elif diffusion_block is not None:
+        s = jnp.where(diffusion_mask(t, diffusion_block), s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum(pv, p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)

@@ -45,6 +45,20 @@ the standard flash-attention memory shape, expressed the Pallas/Mosaic way
   lie wholly outside that band are skipped on BOTH sides in every kernel
   (compute by `pl.when`, DMA by clamping the streamed block's index
   to the band), so a window layer costs its band, not the triangle;
+- `diffusion` = (L, B) is the mask of block-diffusion training's two
+  streams (models/decoder_lm.py): the row is [clean ; noised], 2L positions,
+  blk(i) = (i mod L) // B, and a query sees a key iff clean → clean with
+  blk(i) ≥ blk(j), noised → clean with blk(i) > blk(j), noised → noised
+  with blk(i) = blk(j); clean → noised never. A noised q block has TWO live
+  runs of kv blocks (the clean ones before it, and its own), so the forward
+  and the fused backward walk a COMPACT kv dimension, L / block + the own
+  run's blocks long, whose step s maps to the kv block it visits
+  (`_diffusion_step`): the dead quadrant is never a grid step, and the dead
+  steps that remain re-request a resident block as the causal ones do. The
+  split backward's dK/dV kernel walks every q block of a kv block and
+  clamps its fetches to the two live runs. `flash_tiles_total{mask,state}`
+  counts, as a call is traced, the (q block, kv block) tiles of its square
+  that hold an allowed pair and those that do not, per kernel launched;
 - grouped KV heads (H_q = g·H_kv, models/decoder_lm.py): the K/V index maps
   read block `i // g` for query head `i`, and the backward walks the g query
   heads of a KV head in a sequential grid dimension of their own — no
@@ -66,6 +80,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -136,6 +151,126 @@ def _last_q_block(bq: int, bk: int, jk, nq: int, window):
     return jnp.minimum(((jk + 1) * bk + window - 2) // bq, nq - 1)
 
 
+# ---------------------------------------------------------------------------
+# the block-diffusion rule: two streams of L positions, blocks of B
+# ---------------------------------------------------------------------------
+
+_FAR = 1 << 30
+
+
+def _diffusion_tile(bq: int, bk: int, jq, jk, diffusion):
+    """Tile (q-block jq, kv-block jk) of a [clean ; noised] row under
+    `diffusion` = (L, B) → (its first row and first col, each LOCAL to its
+    stream, lo, hi): with d = blk(row) − blk(col) a pair is allowed iff
+    lo ≤ d ≤ hi — clean → clean d ≥ 0, noised → clean d ≥ 1, noised →
+    noised d = 0, clean → noised never (lo past every d). A tile lies in one
+    stream (the blocks divide L). Operators only: Python ints, numpy arrays
+    and traced scalars alike."""
+    half, _ = diffusion
+    noised_q, noised_k = (jq >= half // bq) * 1, (jk >= half // bk) * 1
+    lo = noised_q * (1 - noised_k) + (1 - noised_q) * noised_k * _FAR
+    hi = (1 - noised_k) * _FAR + noised_k * (noised_q - 1)
+    return jq * bq - noised_q * half, jk * bk - noised_k * half, lo, hi
+
+
+def _diffusion_mask(bq: int, bk: int, jq, jk, diffusion):
+    """(bq, bk) bool: the allowed pairs of the tile."""
+    block = diffusion[1]
+    r0, c0, lo, hi = _diffusion_tile(bq, bk, jq, jk, diffusion)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + r0
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + c0
+    if block & (block - 1) == 0:    # a shift where B is a power of two
+        shift = block.bit_length() - 1
+        d = (rows >> shift) - (cols >> shift)
+    else:
+        d = rows // block - cols // block
+    return (d >= lo) & (d <= hi)
+
+
+def _diffusion_live(bq: int, bk: int, jq, jk, diffusion):
+    """Whether the tile holds any allowed pair: its rows' and cols' blocks
+    are contiguous, so d takes every value between its extremes."""
+    block = diffusion[1]
+    r0, c0, lo, hi = _diffusion_tile(bq, bk, jq, jk, diffusion)
+    d_max = (r0 + bq - 1) // block - c0 // block
+    d_min = r0 // block - (c0 + bk - 1) // block
+    return (d_max >= lo) & (d_min <= hi)
+
+
+def _diffusion_steps(b: int, diffusion) -> int:
+    """Length of the compact kv dimension at blocks of `b`: the clean
+    stream's blocks and the noised blocks one q block's own run can span."""
+    half, block = diffusion
+    return half // b + max(block // b, 1)
+
+
+def _diffusion_step(b: int, jq, s, diffusion):
+    """Step `s` of q-block `jq` in the compact kv dimension → (the kv block
+    it visits, whether that tile is live). A clean q block's live kv blocks
+    are the clean 0..b1; a noised one's the clean 0..b1 (b1 = −1: none) and
+    the noised a2..b2 of its own diffusion blocks. Steps run through the
+    first run, then the second; the dead ones after them re-request the
+    block already resident."""
+    half, block = diffusion
+    n = half // b
+    noised = jq >= n
+    r0 = (jq - noised * n) * b
+    r1 = r0 + b - 1
+    b1 = jnp.minimum(((r1 // block - noised + 1) * block - 1) // b, n - 1)
+    a2 = n + (r0 // block) * block // b
+    b2 = n + jnp.minimum(((r1 // block + 1) * block - 1) // b, n - 1)
+    first = s <= b1
+    own = a2 + s - b1 - 1
+    live = first | (noised & (own <= b2))
+    return jnp.where(first, s, jnp.where(noised, jnp.minimum(own, b2), b1)), live
+
+
+def _diffusion_q_block(b: int, jk, qq, diffusion):
+    """The q block the split backward's dK/dV kernel fetches at step `qq` of
+    kv-block `jk`: `qq` clamped to the kv block's live q blocks — of a clean
+    one the clean a1..n−1 and the noised n + a2.., of a noised one the
+    noised blocks of its own diffusion blocks."""
+    half, block = diffusion
+    n = half // b
+    noised = jk >= n
+    c0 = (jk - noised * n) * b
+    c1 = c0 + b - 1
+    a1 = (c0 // block) * block // b
+    a2 = (c0 // block + 1) * block // b       # > n − 1: no noised q block
+    clean = jnp.where(qq < n, jnp.maximum(qq, a1),
+                      jnp.where(a2 < n, jnp.clip(qq, n + a2, 2 * n - 1), n - 1))
+    own = jnp.clip(qq, n + a1,
+                   n + jnp.minimum(((c1 // block + 1) * block - 1) // b, n - 1))
+    return jnp.where(noised, own, clean)
+
+
+def _tile_mask(bq, bk, jq, jk, causal, window, diffusion):
+    """The tile's (bq, bk) allowed pairs under whichever rule the call has,
+    None without one."""
+    if diffusion is not None:
+        return _diffusion_mask(bq, bk, jq, jk, diffusion)
+    return _causal_mask(bq, bk, jq, jk, window) if causal else None
+
+
+def _count_tiles(bq: int, bk: int, t: int, causal, window, diffusion) -> None:
+    """`flash_tiles_total{mask,state}` for one kernel launched over the
+    (t / bq, t / bk) tiles of a row's square: those that hold an allowed
+    pair, and those a kernel skips (or never walks)."""
+    jq, jk = np.arange(t // bq)[:, None], np.arange(t // bk)[None, :]
+    if diffusion is not None:
+        live = _diffusion_live(bq, bk, jq, jk, diffusion)
+    elif causal:
+        live = _tile_live(bq, bk, jq, jk, window)
+    else:
+        live = np.ones((t // bq, t // bk), bool)
+    live = int(np.sum(live))
+    mask = ("block_diffusion" if diffusion is not None else
+            "none" if not causal else "causal" if window is None else "window")
+    spans.count("flash_tiles_total", live, mask=mask, state="live")
+    spans.count("flash_tiles_total", (t // bq) * (t // bk) - live, mask=mask,
+                state="skipped")
+
+
 def _scores(q, kb, rope, scale):
     """(bq, bk) f32 scaled scores of one tile; `rope` = (q_r tile, k_r tile)
     adds the second part of a latent-attention score before the scale."""
@@ -148,25 +283,30 @@ def _scores(q, kb, rope, scale):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale, nk, causal, window=None,
-                  rope=False):
+                  rope=False, diffusion=None):
     """One (batch·head, q-block, kv-block) grid step.
 
     The kv axis is the LAST grid dimension — sequential on TPU — so the
     online-softmax accumulators persist in VMEM scratch across kv steps and
     only one (block_k, D) K/V tile is resident at a time. With `rope` two
-    more inputs follow v: the q_r tile and the (shared) k_r tile."""
+    more inputs follow v: the q_r tile and the (shared) k_r tile. Under
+    `diffusion` the last dimension is the compact one (`nk` its length) and
+    the kv block of a step comes from `_diffusion_step`."""
     if rope:
         qr_ref, kr_ref, *rest = rest
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     jq, kk = pl.program_id(1), pl.program_id(2)
+    step = kk
     # Operands stay in their input dtype (bf16 in the default recipe) so the
     # MXU runs at full rate; every accumulation is f32 via
     # preferred_element_type, and the softmax statistics are f32 throughout.
     q = q_ref[0]                                # (bq, D)
     bq = q.shape[0]
     bk = k_ref.shape[1]
+    if diffusion is not None:
+        kk, live = _diffusion_step(bq, jq, step, diffusion)
 
-    @pl.when(kk == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full((bq, _LANES), _NEG_INF, jnp.float32)
         l_scr[:] = jnp.zeros((bq, _LANES), jnp.float32)
@@ -177,8 +317,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale, nk, causal, window=None,
         vb = v_ref[0]                           # (bk, Dv)
         s = _scores(q, kb, (qr_ref[0], kr_ref[0]) if rope else None,
                     scale)                                       # (bq, bk)
-        if causal:
-            allowed = _causal_mask(bq, bk, jq, kk, window)
+        allowed = _tile_mask(bq, bk, jq, kk, causal, window, diffusion)
+        if allowed is not None:
             s = jnp.where(allowed, s, _NEG_INF)
         m = m_scr[:]
         m_cur = jnp.max(s, axis=-1, keepdims=True)               # (bq, 1)
@@ -189,10 +329,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale, nk, causal, window=None,
         # finite after the first step and exp(−NEG_INF − m) underflows to
         # exactly 0.
         p = jnp.exp(s - m_new[:, :1])                            # (bq, bk)
-        if window is not None:
+        if window is not None or diffusion is not None:
             # the first live tile of a window band can hold rows with no
             # allowed column yet (m_new still −1e30, so exp(s − m_new) = 1
-            # on masked entries): those have to be zeroed explicitly
+            # on masked entries): those have to be zeroed explicitly (so can
+            # a noised q block's clean tiles: its first diffusion block sees
+            # no clean key at all)
             p = jnp.where(allowed, p, 0.0)
         l_new = l_scr[:] * corr + jnp.broadcast_to(
             jnp.sum(p, axis=-1, keepdims=True), (bq, _LANES))
@@ -209,17 +351,26 @@ def _flash_kernel(q_ref, k_ref, v_ref, *rest, scale, nk, causal, window=None,
         # steps, so the already-resident tile is re-referenced and the DMA
         # is elided too (halved HBM traffic).
         pl.when(_tile_live(bq, bk, jq, kk, window))(_update)
+    elif diffusion is not None:
+        pl.when(live)(_update)
     else:
         _update()
 
-    @pl.when(kk == nk - 1)
+    @pl.when(step == nk - 1)
     def _write():
         o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
         lse_ref[0] = m_scr[:, :1] + jnp.log(l_scr[:, :1])
 
 
+def _blocks(t: int, diffusion):
+    """(bq, bk) of a row of `t` tokens: under `diffusion` the blocks of ONE
+    stream, so that no tile straddles the two."""
+    b = _block(t if diffusion is None else diffusion[0], cap=512)
+    return b, b
+
+
 def _flash_forward(q3, k3, v3, scale, causal=False, window=None, qr3=None,
-                   kr3=None):
+                   kr3=None, diffusion=None):
     """q (bh, T, D), k (bh // g, T, D), v (bh // g, T, Dv) → (out (bh, T,
     Dv), lse (bh, T, 1) f32); g query heads share each KV head. `qr3` (bh,
     T, Dr) and `kr3` (bh // g_r, T, Dr): the scores' second part."""
@@ -231,10 +382,14 @@ def _flash_forward(q3, k3, v3, scale, causal=False, window=None, qr3=None,
     # blocks with d=128, the (bq, bk) f32 score+probability tiles (~8 MB)
     # plus operands and double-buffered K/V approach the compiler's default
     # 16 MiB
-    bq = _block(t, cap=512)
-    bk = _block(t, cap=512)
+    bq, bk = _blocks(t, diffusion)
     grid = (bh, t // bq, t // bk)
-    if causal:
+    _count_tiles(bq, bk, t, causal, window, diffusion)
+    if diffusion is not None:
+        grid = grid[:2] + (_diffusion_steps(bk, diffusion),)
+        kv_block = lambda j, s: _diffusion_step(  # noqa: E731
+            bk, j, s, diffusion)[0]
+    elif causal:
         # Above-diagonal steps are compute-skipped in the kernel; clamping
         # the fetched kv block to the diagonal makes those steps re-request
         # the resident tile, so their DMA is elided as well (bq == bk by
@@ -261,8 +416,9 @@ def _flash_forward(q3, k3, v3, scale, causal=False, window=None, qr3=None,
         ]
         args += (qr3, kr3)
     return pl.pallas_call(
-        functools.partial(_flash_kernel, scale=scale, nk=t // bk,
-                          causal=causal, window=window, rope=rope),
+        functools.partial(_flash_kernel, scale=scale, nk=grid[2],
+                          causal=causal, window=window, rope=rope,
+                          diffusion=diffusion),
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, dv), q3.dtype),
             jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
@@ -339,7 +495,7 @@ def _p_ds(q, kb, vb, do, lse, dsum, rope, scale, allowed):
 
 def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
                  scale, nq, nk, causal, window=None, group=1, heads=1,
-                 rope=False):
+                 rope=False, diffusion=None):
     """Grid (outer heads, query head under each, q-block, kv-block from the
     last down), all sequential: stream K/V past a fixed q block, as the
     forward does, and build each live tile's P and dS ONCE for all of
@@ -347,6 +503,10 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
     dK += dSᵀ·Q·scale and dV += Pᵀ·dO. The last two are kept for the WHOLE T
     in f32 scratch, at rows kk·bk, across the `group` query heads that read
     one KV head, and written once after the last of them.
+
+    Under `diffusion` the last dimension is the compact one (`nk` its
+    length, `_diffusion_step` the kv block of a step), walked from the last
+    step down as well.
 
     `outer` walks the KV heads and `heads` = `group`; with `rope` (latent
     attention: q_r, k_r follow Δ; dQ_r, dK_r among the outputs) it walks the
@@ -363,6 +523,8 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
     q = q_ref[0]                                # (bq, D) input dtype
     bq = q.shape[0]
     bk = k_ref.shape[1]
+    if diffusion is not None:
+        kk, live = _diffusion_step(bq, jq, kk, diffusion)
     # this query head's place among those that read its KV head
     place = gg % group
     first = (jq == 0) & (step == 0)
@@ -389,7 +551,7 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
         do = do_ref[0]                          # (bq, Dv)
         p, ds = _p_ds(q, kb, v_ref[0], do, lse_ref[0], dsum_ref[0],
                       (qr_ref[0], kr_ref[0]) if rope else None, scale,
-                      _causal_mask(bq, bk, jq, kk, window) if causal else None)
+                      _tile_mask(bq, bk, jq, kk, causal, window, diffusion))
         rows = (slice(None) if nk == 1
                 else pl.ds(pl.multiple_of(kk * bk, bk), bk))
         dv_acc[rows, :] += _dot(p, do, (0, 0))                   # (bk, Dv)
@@ -401,6 +563,8 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
 
     if causal:
         pl.when(_tile_live(bq, bk, jq, kk, window))(_update)
+    elif diffusion is not None:
+        pl.when(live)(_update)
     else:
         _update()
 
@@ -422,7 +586,7 @@ def _dkvq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
-               scale, nk, causal, window=None, rope=False):
+               scale, nk, causal, window=None, rope=False, diffusion=None):
     """The split path's first kernel. Grid (bh, q-block, kv-block): stream
     K/V past a fixed q block, accumulating dQ = Σ_k dS·K·scale in VMEM
     scratch (and, with `rope`, dQ_r = Σ_k dS·K_r·scale beside it: inputs
@@ -432,11 +596,14 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
     else:
         dq_ref, dq_scr = rest
     jq, kk = pl.program_id(1), pl.program_id(2)
+    step = kk
     q = q_ref[0]                                # (bq, D) input dtype
     bq = q.shape[0]
     bk = k_ref.shape[1]
+    if diffusion is not None:   # the forward's compact kv dimension
+        kk, live = _diffusion_step(bq, jq, step, diffusion)
 
-    @pl.when(kk == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
         if rope:
@@ -446,17 +613,19 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
         kb = k_ref[0]                           # (bk, D)
         _, ds = _p_ds(q, kb, v_ref[0], do_ref[0], lse_ref[0], dsum_ref[0],
                       (qr_ref[0], kr_ref[0]) if rope else None, scale,
-                      _causal_mask(bq, bk, jq, kk, window) if causal else None)
+                      _tile_mask(bq, bk, jq, kk, causal, window, diffusion))
         dq_scr[:] += _dot(ds, kb, (1, 0)) * scale
         if rope:
             dqr_scr[:] += _dot(ds, kr_ref[0], (1, 0)) * scale
 
     if causal:
         pl.when(_tile_live(bq, bk, jq, kk, window))(_update)
+    elif diffusion is not None:
+        pl.when(live)(_update)
     else:
         _update()
 
-    @pl.when(kk == nk - 1)
+    @pl.when(step == nk - 1)
     def _write():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
         if rope:
@@ -464,7 +633,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dsum_ref, *rest,
 
 
 def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref, *rest,
-                scale, nq, causal, window=None, group=1, heads=None):
+                scale, nq, causal, window=None, group=1, heads=None,
+                diffusion=None):
     """The split path's second kernel. Grid (kv heads, kv-block, query head
     of the group, q-block): stream the Q/dO of every query head that reads
     this KV head past a fixed kv block, accumulating dK = Σ_q dSᵀ·Q·scale and
@@ -503,7 +673,7 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref, *rest,
         # q-block index is the LAST grid dim here; kv-block is dim 1
         p, ds = _p_ds(q, kb, v_ref[0], do, lse_ref[0], dsum_ref[0],
                       (qr_ref[0], kr_ref[0]) if rope else None, scale,
-                      _causal_mask(bq, bk, qq, jk, window) if causal else None)
+                      _tile_mask(bq, bk, qq, jk, causal, window, diffusion))
         dv_scr[:] += _dot(p, do, (0, 0))                         # (bk, Dv)
         dk_scr[:] += _dot(ds, q, (0, 0)) * scale
         if rope:
@@ -511,6 +681,8 @@ def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, dsum_ref, *rest,
 
     if causal:
         pl.when(_tile_live(bq, bk, qq, jk, window))(_update)
+    elif diffusion is not None:
+        pl.when(_diffusion_live(bq, bk, qq, jk, diffusion))(_update)
     else:
         _update()
 
@@ -530,7 +702,7 @@ def _vmem(shape, index):
 
 
 def _flash_backward_impl(q3, k3, v3, do3, lse, dsum, scale, causal=False,
-                         window=None, qr3=None, kr3=None):
+                         window=None, qr3=None, kr3=None, diffusion=None):
     """(bh, T, D) q, (bh, T, Dv) dO, (bh // g, T, D | Dv) k | v + (bh, T, 1)
     lse/Δ → (dq, dk, dv), O(T·D) HBM; with the scores' second part (`qr3`
     (bh, T, Dr), `kr3` (bh // g_r, T, Dr)) → (dq, dk, dv, dq_r, dk_r).
@@ -543,14 +715,18 @@ def _flash_backward_impl(q3, k3, v3, do3, lse, dsum, scale, causal=False,
                          q3.dtype.itemsize)
     spans.count("flash_backward_total", path=path)
     impl = _backward_fused if path == "fused" else _backward_split
-    return impl(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3, kr3)
+    return impl(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3, kr3,
+                diffusion)
 
 
-def _band_blocks(causal, bq, bk, nq, window):
+def _band_blocks(causal, bq, bk, nq, window, diffusion=None):
     """(kv block fetched at step kk of q-block j, q block fetched at step qq
     of kv-block j): the same DMA-elision trick as the forward — a
     compute-skipped step re-requests a block of the band (bq == bk by
     construction of _block), so nothing is fetched for it."""
+    if diffusion is not None:   # the first over the compact kv dimension
+        return (lambda j, s: _diffusion_step(bk, j, s, diffusion)[0],
+                lambda j, qq: _diffusion_q_block(bq, j, qq, diffusion))
     if not causal:
         return (lambda j, kk: kk), (lambda j, qq: qq)
     return (lambda j, kk: jnp.clip(kk, _first_kv_block(bq, bk, j, window), j),
@@ -558,14 +734,16 @@ def _band_blocks(causal, bq, bk, nq, window):
 
 
 def _backward_fused(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3,
-                    kr3):
+                    kr3, diffusion=None):
     bh, t, d = q3.shape
     dv = v3.shape[-1]
     group = bh // k3.shape[0]
     rope = qr3 is not None
-    bq = _block(t, cap=512)
-    bk = _block(t, cap=512)
+    bq, bk = _blocks(t, diffusion)
     nq, nk = t // bq, t // bk
+    _count_tiles(bq, bk, t, causal, window, diffusion)
+    if diffusion is not None:   # kv steps, not kv blocks, from here on
+        nk = _diffusion_steps(bk, diffusion)
     # the first grid dimension and the query heads under each of its entries:
     # the KV heads and their group, or K_r's heads and theirs
     outer = kr3.shape[0] if rope else k3.shape[0]
@@ -577,7 +755,7 @@ def _backward_fused(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3,
     # next row's q, dO and diagonal K/V. Walked upwards the row ends on dead
     # steps, too short to hide that fetch: 5-6 % of the kernel at 8,192
     # tokens (PERF.md §6, PR 36). dQ's sum runs over the blocks in that order.
-    band_block, _ = _band_blocks(causal, bq, bk, nq, window)
+    band_block, _ = _band_blocks(causal, bq, bk, nq, window, diffusion)
     kv_block = lambda j, step: band_block(j, nk - 1 - step)  # noqa: E731
     q_idx = lambda i, g, j, kk: (i * heads + g, j, 0)  # noqa: E731
     kv_idx = lambda i, g, j, kk: (  # noqa: E731
@@ -611,7 +789,7 @@ def _backward_fused(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3,
     return tuple(pl.pallas_call(
         functools.partial(_dkvq_kernel, scale=scale, nq=nq, nk=nk,
                           causal=causal, window=window, group=group,
-                          heads=heads, rope=rope),
+                          heads=heads, rope=rope, diffusion=diffusion),
         out_shape=out_shape,
         grid=(outer, heads, nq, nk),
         in_specs=in_specs,
@@ -626,7 +804,7 @@ def _backward_fused(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3,
 
 
 def _backward_split(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3,
-                    kr3):
+                    kr3, diffusion=None):
     """The path beyond the fused kernel's VMEM: one kernel grids over
     q-blocks and streams K/V to accumulate dQ, the other grids over kv-blocks
     and streams Q/dO to accumulate dK and dV, each rebuilding the tile's P
@@ -637,14 +815,17 @@ def _backward_split(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3,
     bh_kv = k3.shape[0]
     group = bh // bh_kv
     rope = qr3 is not None
-    bq = _block(t, cap=512)
-    bk = _block(t, cap=512)
+    bq, bk = _blocks(t, diffusion)
     nq, nk = t // bq, t // bk
+    for _ in range(2):   # two kernels walk the square
+        _count_tiles(bq, bk, t, causal, window, diffusion)
+    # the dQ kernel's kv dimension: the forward's (compact under `diffusion`)
+    kv_steps = nk if diffusion is None else _diffusion_steps(bk, diffusion)
     # the dK/dV kernel's first grid dimension and the query heads under each
     # of its entries: the KV heads and their group, or K_r's heads and theirs
     outer = kr3.shape[0] if rope else bh_kv
     heads = bh // outer
-    kv_block, q_block = _band_blocks(causal, bq, bk, nq, window)
+    kv_block, q_block = _band_blocks(causal, bq, bk, nq, window, diffusion)
     kv_idx = lambda i, j, kk: (i // group, kv_block(j, kk), 0)  # noqa: E731
     q_row_idx = lambda i, j, g, qq: (  # noqa: E731
         i * heads + g, q_block(j, qq), 0)
@@ -667,10 +848,10 @@ def _backward_split(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3,
         out_specs = [out_specs, _vmem((1, bq, dr), q_idx)]
         scratch.append(pltpu.VMEM((bq, dr), jnp.float32))
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, nk=nk, causal=causal,
-                          window=window, rope=rope),
+        functools.partial(_dq_kernel, scale=scale, nk=kv_steps, causal=causal,
+                          window=window, rope=rope, diffusion=diffusion),
         out_shape=out_shape,
-        grid=(bh, nq, nk),
+        grid=(bh, nq, kv_steps),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
@@ -702,7 +883,7 @@ def _backward_split(q3, k3, v3, do3, lse, dsum, scale, causal, window, qr3,
     dkv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, nq=nq, causal=causal,
                           window=window, group=group,
-                          heads=heads if rope else None),
+                          heads=heads if rope else None, diffusion=diffusion),
         out_shape=out_shape,
         grid=(outer, nk, heads, nq),
         in_specs=in_specs,
@@ -735,7 +916,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = False,
                     window: Optional[int] = None,
                     q_rope: Optional[jnp.ndarray] = None,
-                    k_rope: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                    k_rope: Optional[jnp.ndarray] = None,
+                    diffusion_block: Optional[int] = None) -> jnp.ndarray:
     """Scaled-dot-product attention, (B, T, H, D) → (B, T, H, Dv), optionally
     causal (row i attends keys ≤ i, matching ops/attention.py::attention)
     and, with `window`, banded (keys i − window < j ≤ i). k/v may carry
@@ -746,6 +928,10 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     H_r, add q_rope·k_ropeᵀ to the scores (latent attention: the rotary part
     of the key is one head that every query head reads); the default scale is
     then (D + Dr)^-½.
+
+    `diffusion_block` = B (not with `causal`): the row is block-diffusion
+    training's two streams, [clean ; noised] of T / 2 positions each, under
+    the mask of ops/attention.py::diffusion_mask (the module docstring).
 
     Forward and backward are both Pallas streaming kernels: O(T·D) HBM
     traffic, no (T, T) tensor materialized in either pass. Token counts
@@ -773,7 +959,15 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         raise ValueError("flash_attention: a window needs causal=True")
     if window is not None and window >= q.shape[1]:
         window = None  # the band is the whole triangle
-    if not _supported(q.shape[1]):
+    diffusion = None
+    if diffusion_block is not None:
+        if causal or q.shape[1] % (2 * diffusion_block):
+            raise ValueError(
+                f"flash_attention: diffusion_block {diffusion_block} takes a "
+                f"row of two streams of whole blocks and no causal mask, got "
+                f"T={q.shape[1]}, causal={causal}")
+        diffusion = (q.shape[1] // 2, diffusion_block)
+    if not diffusion_supported(q.shape[1], diffusion_block):
         from .attention import attention
 
         # a shape rule, not an error — but never a silent one: the caller
@@ -783,24 +977,42 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             "(need T <= 512 or a multiple of 128); falling through to the "
             "dense op ops.attention.attention", stacklevel=2)
         return attention(q, k, v, causal=causal, scale=scale, window=window,
-                         q_rope=q_rope, k_rope=k_rope)
+                         q_rope=q_rope, k_rope=k_rope,
+                         diffusion_block=diffusion_block)
     if q_rope is not None:
-        return _flash_rope(q, k, v, q_rope, k_rope, scale, causal, window)
-    return _flash(q, k, v, scale, causal, window)
+        return _flash_rope(q, k, v, q_rope, k_rope, scale, causal, window,
+                           diffusion)
+    return _flash(q, k, v, scale, causal, window, diffusion)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, scale, causal, window=None):
-    return _fa_fwd(q, k, v, scale, causal, window)[0]
+def diffusion_supported(t: int, diffusion_block: Optional[int]) -> bool:
+    """Whether the kernels take a row of `t` tokens: `_supported`, and under
+    `diffusion_block` = B of ONE stream's t / 2 tokens, with B and the
+    stream's kernel block whole multiples one of the other (a tile then lies
+    in whole diffusion blocks, or a diffusion block in whole tiles)."""
+    if diffusion_block is None:
+        return _supported(t)
+    half = t // 2
+    if not _supported(half):
+        return False
+    b = _block(half, cap=512)
+    return b % diffusion_block == 0 or diffusion_block % b == 0
 
 
-def _fa_fwd(q, k, v, scale, causal, window=None, q_rope=None, k_rope=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, scale, causal, window=None, diffusion=None):
+    return _fa_fwd(q, k, v, scale, causal, window, diffusion)[0]
+
+
+def _fa_fwd(q, k, v, scale, causal, window=None, diffusion=None, q_rope=None,
+            k_rope=None):
     rope = () if q_rope is None else (_to3(q_rope), _to3(k_rope))
     s = scale if scale is not None else (
         q.shape[-1] + (rope[0].shape[-1] if rope else 0)) ** -0.5
     b, _, h, _ = q.shape
     q3, k3, v3 = _to3(q), _to3(k), _to3(v)
-    out3, lse = _flash_forward(q3, k3, v3, s, causal, window, *rope)
+    out3, lse = _flash_forward(q3, k3, v3, s, causal, window, *rope,
+                               diffusion=diffusion)
     # names for a rematerialization policy: a caller that saves these two
     # (`jax.checkpoint_policies.save_only_these_names`) does not run the
     # forward kernel again in its backward pass
@@ -811,7 +1023,7 @@ def _fa_fwd(q, k, v, scale, causal, window=None, q_rope=None, k_rope=None):
     return _to4(out3, b, h), (q3, k3, v3, out3, lse) + rope
 
 
-def _fa_bwd(scale, causal, window, res, g):
+def _fa_bwd(scale, causal, window, diffusion, res, g):
     q3, k3, v3, out3, lse, *rope = res
     # Re-resolve from the static nondiff arg: the kernels bake `scale` into
     # their compiled body, so it must stay a Python float, not a residual
@@ -825,7 +1037,8 @@ def _fa_bwd(scale, causal, window, res, g):
     dsum = jnp.sum(do3.astype(jnp.float32) * out3.astype(jnp.float32),
                    axis=-1, keepdims=True)
     dq3, dk3, dv3, *drope = _flash_backward_impl(
-        q3, k3, v3, do3, lse, dsum, s, causal, window, *rope)
+        q3, k3, v3, do3, lse, dsum, s, causal, window, *rope,
+        diffusion=diffusion)
     h_kv = k3.shape[0] // b
     out = (_to4(dq3, b, h), _to4(dk3, b, h_kv), _to4(dv3, b, h_kv))
     if rope:
@@ -836,14 +1049,15 @@ def _fa_bwd(scale, causal, window, res, g):
 _flash.defvjp(_fa_fwd, _fa_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_rope(q, k, v, q_rope, k_rope, scale, causal, window=None):
-    return _fa_fwd(q, k, v, scale, causal, window, q_rope, k_rope)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_rope(q, k, v, q_rope, k_rope, scale, causal, window=None,
+                diffusion=None):
+    return _fa_fwd(q, k, v, scale, causal, window, diffusion, q_rope, k_rope)[0]
 
 
 _flash_rope.defvjp(
-    lambda q, k, v, q_rope, k_rope, scale, causal, window:
-    _fa_fwd(q, k, v, scale, causal, window, q_rope, k_rope), _fa_bwd)
+    lambda q, k, v, q_rope, k_rope, scale, causal, window, diffusion:
+    _fa_fwd(q, k, v, scale, causal, window, diffusion, q_rope, k_rope), _fa_bwd)
 
 
 # ---------------------------------------------------------------------------

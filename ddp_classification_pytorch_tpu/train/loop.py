@@ -166,6 +166,10 @@ _SPAN_COUNTER_HELP = {
                                  "by whether the buffer was a recycled one",
     "flash_backward_total": "attention calls traced, by the backward their "
                             "shapes chose: one fused kernel or the split",
+    "flash_tiles_total": "(q block, kv block) tiles of the attention "
+                         "kernels launched as the step was traced, by the "
+                         "call's mask: those that hold an allowed pair "
+                         "(live) and those skipped or never walked",
     "vit_attention_total": "ViT attention modules traced, by the core their "
                            "shapes chose: the whole-row kernel pair (rows), "
                            "the dense op, the streaming kernels (flash) or "
@@ -260,6 +264,10 @@ class Trainer:
         if train_ds is None:
             with phase("datasets"):
                 train_ds, val_ds = build_datasets(cfg)
+        # a model whose objective makes its inputs in the loader wraps them
+        # here, whoever built them (the CLI above, or a caller)
+        train_ds, val_ds = model_report(cfg.model).datasets(
+            train_ds, val_ds, cfg.run.seed)
         self.train_ds, self.val_ds = train_ds, val_ds
 
         with phase("mesh"):
@@ -602,10 +610,13 @@ class Trainer:
         totals = {k: float(v) for k, v in totals.items()}  # the one host sync
         self._heartbeat.touch()  # that sync proves the backend is answering
         n = max(totals["n"], 1.0)
+        # an eval step may count its top-k hits over fewer positions than its
+        # loss (block diffusion: the masked ones)
+        n_top = max(totals.get("n_top", n), 1.0)
         return {
             "val_loss": totals["loss_sum"] / n,
-            "val_top1": totals["top1"] / n,
-            "val_top3": totals["top3"] / n,
+            "val_top1": totals["top1"] / n_top,
+            "val_top3": totals["top3"] / n_top,
         }
 
     def _evaluate_nested(self) -> Dict[str, float]:

@@ -355,6 +355,46 @@ def _lm_sums(cfg: Config, model: Any, params, tokens, targets, train: bool,
     return ce, t1, t3, load, ce_mtp
 
 
+LEVEL_BANDS = 4     # the noise levels' quartiles a step's loss is split by
+
+
+def _diffusion_sums(cfg: Config, model: Any, params, tokens, targets,
+                    train: bool, valid=None):
+    """Block diffusion's sums over a batch as the loader makes it
+    (data/diffusion.py): `tokens` x_0 (B, L), `targets` [x_t ; j] (B, 2, L).
+    The two-stream pass gives the noised stream's L states a row; position i
+    of block b counts iff x_t[i] is the mask id, weighted 1 / t_b, against
+    x_0[i] itself (no shift) → (Σ weighted cross-entropy, the same by band
+    of t (LEVEL_BANDS,), top-1 and top-3 counts over the masked positions,
+    the masked count, positions by band (LEVEL_BANDS,), the expert loads).
+    ONE pass through the head: `weights` = mask / t beside its split by band
+    and the bare mask. `valid` (B,) takes wrap-padded rows out of all of
+    them."""
+    from ..data.diffusion import LEVELS, level_of
+    from ..models.decoder_lm import head_kernel
+    from ..ops.lm_head import blocked_cross_entropy
+
+    dc = cfg.model.decoder
+    hidden, load = model.apply({"params": params}, tokens, train=train,
+                               method="hidden", targets=targets)
+    noised, level = targets[:, 0].reshape(-1), targets[:, 1].reshape(-1)
+    rows = (jnp.ones(noised.shape, jnp.float32) if valid is None
+            else jnp.repeat(valid.astype(jnp.float32), targets.shape[-1]))
+    masked = rows * (noised == dc.mask_token)
+    band = jnp.minimum((level - 1) * LEVEL_BANDS // LEVELS, LEVEL_BANDS - 1)
+    in_band = band[:, None] == jnp.arange(LEVEL_BANDS)          # (N, bands)
+    weight = masked / level_of(level.astype(jnp.float32), dc.diffusion_eps)
+    with jax.named_scope("lm_head"):
+        ce, t1, t3 = blocked_cross_entropy(
+            hidden.reshape(-1, hidden.shape[-1]), head_kernel(params, dc),
+            tokens.reshape(-1), dc.head_block, jnp.dtype(cfg.model.dtype),
+            weights=jnp.concatenate(
+                [weight[:, None], weight[:, None] * in_band, masked[:, None]],
+                axis=1))
+    return (ce[0], ce[1:1 + LEVEL_BANDS], t1[-1], t3[-1], masked.sum(),
+            (rows[:, None] * in_band).sum(axis=0), load)
+
+
 def _exit_sums(cfg: Config, model: Any, params, tokens, targets):
     """A looped decoder's training sums over the N = B·T targets: with p
     (R, N) the exit gate's distribution over the R passes
@@ -393,6 +433,12 @@ def _lm_loss(cfg: Config, model: Any):
     loss_main + mtp_weight · loss_mtp, the second the mean over the T − 1
     positions that have a token after next; `loss` is the total and
     `loss_main` / `loss_mtp` stand beside it.
+    Under `decoder.objective` "block_diffusion" (`_diffusion_sums`) the batch
+    is (x_0, [x_t ; j]) and `loss` = Σ over the masked positions of
+    CE(x_0[i]) / t over ALL B·L positions; `loss_level1..4` the same by
+    quartile of t (over that quartile's positions), `masked_tokens` the
+    step's count of masked positions, top-1 and top-3 over the masked
+    positions.
     A looped stack (`decoder.loops` = R > 1) minimises the mean over the
     targets of Σ_t p(t) CE(t) − exit_beta · H(p) (`_exit_sums`); `loss` is
     that objective, `loss_ut1..R` each pass's unweighted mean cross-entropy,
@@ -419,6 +465,22 @@ def _lm_loss(cfg: Config, model: Any):
 
     if dc.loops > 1:
         return looped_loss_fn, looped_metrics_fn
+
+    def diffusion_loss_fn(params, batch_stats, tokens, targets, rng):
+        ce, *rest = _diffusion_sums(cfg, model, params, tokens, targets, True)
+        return ce / tokens.size, (batch_stats, tuple(rest))
+
+    def diffusion_metrics_fn(loss, aux, labels):
+        ce_band, t1, t3, masked, n_band, load = aux
+        by_band = ce_band / jnp.maximum(n_band, 1.0)
+        seen = jnp.maximum(masked, 1.0)
+        return {"loss": loss,
+                **{f"loss_level{i + 1}": by_band[i] for i in range(LEVEL_BANDS)},
+                "masked_tokens": masked, "top1": t1 / seen, "top3": t3 / seen,
+                "moe_load": load.astype(jnp.float32)}
+
+    if dc.diffusion:
+        return diffusion_loss_fn, diffusion_metrics_fn
 
     def loss_fn(params, batch_stats, tokens, targets, rng):
         ce, t1, t3, load, *ce_mtp = _lm_sums(cfg, model, params, tokens,
@@ -820,6 +882,18 @@ def make_eval_step(
     if workload == "arcface" and cfg.parallel.arcface_sharded_ce:
         _require_sharded_ce_mesh(mesh)
         return _make_arcface_sharded_eval(cfg, model, mesh)
+    if cfg.model.arch == "decoder_lm" and cfg.model.decoder.diffusion:
+        def diffusion_step(state: TrainState, tokens: jnp.ndarray,
+                           targets: jnp.ndarray, valid: jnp.ndarray):
+            """The training objective's weighted loss over the positions of
+            the rows where valid == 1 (`n`), and the top-k counts over their
+            masked positions (`n_top`), on the batch's own noise."""
+            ce, _, t1, t3, masked, _, _ = _diffusion_sums(
+                cfg, model, state.params, tokens, targets, False, valid)
+            return {"loss_sum": ce, "top1": t1, "top3": t3,
+                    "n": valid.sum() * tokens.shape[1], "n_top": masked}
+
+        return jax.jit(diffusion_step)
     if cfg.model.arch == "decoder_lm":
         def lm_step(state: TrainState, tokens: jnp.ndarray,
                     targets: jnp.ndarray, valid: jnp.ndarray):

@@ -55,7 +55,7 @@ _TOKEN_CELLS = _token_cells()
                          ids=[c[0] for c in _TOKEN_CELLS])
 def test_token_cell_has_its_reference_its_counts_and_its_readers(cell, spec, conf):
     """Every decoder cell (`st21b_ep4_8k`, `joyai_ep16_8k`, `lfm2_ep4_8k`,
-    `ling3_ep64_8k`, `ouro_loop4_8k`, `olmoh_tp2_8k`): what the runner and the readers import by the
+    `ling3_ep64_8k`, `ouro_loop4_8k`, `olmoh_tp2_8k`, `sdar_bd4_8k`): what the runner and the readers import by the
     configuration's names is there, the file's parameter count is the reference's own, and the
     rehearsal is held to the same numbers as the chip run."""
     import importlib

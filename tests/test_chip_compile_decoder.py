@@ -62,6 +62,12 @@ CONFIGS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
     # tokens. State 12.26 GB: held against what the chip's allocator hands out
     pytest.param("olmo_hybrid_7b.json", 766241946, 0.95 * 16_909_336_064, 1,
                  id="gated_delta_decoder"),
+    # SDAR-30B-A3B-Chat (PR 53): six layers trained by block diffusion, 1 row
+    # of 8,192 tokens as two streams (16,384 positions a layer) under the
+    # two-stream mask in the flash kernels (compact kv walk), 16 of 128
+    # experts held (bounded sorted rows); the batch is (x_0, [x_t ; j])
+    pytest.param("sdar_30b_a3b.json", 645623296, 0.95 * 16_909_336_064, 6,
+                 id="block_diffusion_decoder"),
 ])
 def test_train_step_fits_one_chip(topo, kernels, config, parameters,
                                   byte_limit, attention_blocks):
@@ -84,7 +90,12 @@ def test_train_step_fits_one_chip(topo, kernels, config, parameters,
         tokens = jax.ShapeDtypeStruct(
             (cfg.data.batch_size, cfg.model.decoder.seq_len), jnp.int32,
             sharding=meshlib.batch_sharding(mesh))
-        compiled = step.lower(state, tokens, tokens).compile()
+        labels = tokens
+        if cfg.model.decoder.diffusion:   # the loader's [x_t ; j] (B, 2, L)
+            labels = jax.ShapeDtypeStruct(
+                (cfg.data.batch_size, 2, cfg.model.decoder.seq_len), jnp.int32,
+                sharding=meshlib.batch_sharding(mesh))
+        compiled = step.lower(state, tokens, labels).compile()
     m = compiled.memory_analysis()
     print(f"{config} step by the compiler's count: arguments "
           f"{m.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
@@ -102,7 +113,7 @@ def test_train_step_fits_one_chip(topo, kernels, config, parameters,
     # and one backward a layer, the ragged-dot kernels in both branches of
     # each (the one window, the walk over windows), and of the worst-case S
     # rows no float32 array and none of the experts' width in either
-    slots = cfg.data.batch_size * dc.seq_len * dc.top_k
+    slots = cfg.data.batch_size * dc.positions * dc.top_k
     bounded = slot_bound(slots, dc.held, dc.num_experts) < slots
     conds = conditionals(text)
     assert len(conds) == 2 * len(dc.moe_layer_names()) * bounded

@@ -13,6 +13,7 @@ import ast
 import os
 
 import pytest
+import test_decoder_diffusion as diffusion
 import test_decoder_gdn as gated
 import test_decoder_hybrid as conv
 import test_decoder_kda as kda
@@ -101,6 +102,21 @@ CAPTURED = {
         "gqa_dense=2 loops=3 sandwich=1 passes=scan", 6,
         'decoder_layers_total{ffn="dense",operator="gqa"} 2\n',
         ["exit_p1", "exit_p2", "exit_p3", "loss_ut1", "loss_ut2", "loss_ut3"]),
+    # (PR 53) block diffusion: the objective and the attention layers' mask;
+    # a routing layer routes both streams' positions (8 x 2 x 128 x 4)
+    "diffusion": (
+        from_argv(diffusion.cli_argv(diffusion.ARCH)),
+        "gqa_routed=2 objective=block_diffusion block=4 mask_id=95 "
+        "attn_mask=block_diffusion moe_bound=8192/8192", 2,
+        'decoder_layers_total{ffn="routed",operator="gqa"} 2\n',
+        ["loss_level1", "loss_level2", "loss_level3", "loss_level4"]),
+    "diffusion_kernels": (
+        from_argv(diffusion.cli_argv(dict(diffusion.ARCH, seq_len=8192,
+                                          num_experts=64))),
+        "gqa_routed=2 objective=block_diffusion block=4 mask_id=95 "
+        "attn_mask=block_diffusion flash_backward=fused moe_bound=131072/524288",
+        2, 'decoder_layers_total{ffn="routed",operator="gqa"} 2\n',
+        ["loss_level1", "loss_level2", "loss_level3", "loss_level4"]),
     "resnet18": (
         from_argv(["baseline", "--dataset", "synthetic", "--model", "resnet18",
                    "--variant", "cifar", "--image_size", "32",
